@@ -9,16 +9,14 @@
 #   docker run --rm horovod-tpu                        #  the LAST stage)
 #   docker run --rm horovod-tpu python -m pytest tests/ -q
 #
-# Integration stages — the real optional frontends (reference CI runs
-# real mxnet + pyspark, docker-compose.test.yml:1-60; the dev image
-# verifies them against duck-type stand-ins only — docs/testing.md).
-# TWO stages because the pins conflict: pyspark rides the modern stack,
-# while mxnet 1.9.1 (the final mxnet release) is frozen at numpy<1.24,
-# which caps jax at 0.4.x — common/compat.py keeps the core importable
-# there (shard_map still lived in jax.experimental).
+# Integration stage — the real pyspark frontend (reference CI runs real
+# pyspark, docker-compose.test.yml:1-60; the dev image verifies it
+# against a duck-type stand-in only — docs/testing.md). There is no
+# real-mxnet stage: mxnet 1.9.1 (its final release) is frozen at
+# numpy<1.24, which the one supported installation (current jax) cannot
+# meet, so that frontend is covered by its stand-in suite alone.
 #
 #   docker build --target integration-spark -t hvd-int-spark . && docker run --rm hvd-int-spark
-#   docker build --target integration-mxnet -t hvd-int-mxnet . && docker run --rm hvd-int-mxnet
 
 # -- pyspark integration: modern stack + JRE ---------------------------------
 FROM python:3.12-slim AS integration-spark
@@ -29,19 +27,6 @@ RUN pip install --no-cache-dir \
 WORKDIR /workspace/horovod_tpu
 COPY . .
 CMD ["python", "-m", "pytest", "tests/integration/test_real_spark.py", "-m", "integration", "-q", "-rs"]
-
-# -- mxnet integration: the numpy<1.24 era stack -----------------------------
-# libgomp1: the mxnet manylinux wheel links the OpenMP runtime, which
-# slim images do not ship
-FROM python:3.10-slim AS integration-mxnet
-RUN apt-get update && apt-get install -y --no-install-recommends \
-        libgomp1 && rm -rf /var/lib/apt/lists/*
-RUN pip install --no-cache-dir \
-        "numpy==1.23.5" "jax[cpu]==0.4.25" "flax==0.8.1" "optax==0.1.9" \
-        "chex==0.1.85" einops pytest "mxnet==1.9.1"
-WORKDIR /workspace/horovod_tpu
-COPY . .
-CMD ["python", "-m", "pytest", "tests/integration/test_real_mxnet.py", "-m", "integration", "-q", "-rs"]
 
 # -- dev/CI image (LAST stage: the default `docker build .` target) ----------
 FROM python:3.12-slim AS dev

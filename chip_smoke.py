@@ -1,0 +1,654 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+One process drives the main paths once, through the entry points a user
+calls, at the full width of the flagship LM and of ResNet-50:
+
+  kernels     the three flash-attention forwards and both backwards,
+              compiled by Mosaic, against parallel.ring.full_attention at
+              the training shape and at the serving prefill lengths
+  lm_train    hvd.init -> build_mesh -> trainer.make_gspmd_step on
+              gpt2_small_tpu (12 layers, batch 16 x seq 1024, flash):
+              loss finite, falling, first step equal to full attention;
+              then the same step under trainer.instrument_step
+  resnet      bench_common.build_step("resnet50", mesh, 256, 224), the
+              shard_map data-parallel path
+  serve       ServeEngine (8 slots, max_len 1024, kv_block 16), twelve
+              seeded requests: all complete, temperature-0 tokens equal
+              to TransformerLM.apply's greedy choice
+  four_chips  (when jax.device_count() >= 4) the LM step on dp=4 and on
+              dp=2,tp=2 against the one-chip leg and against each other
+
+Every leg prints one JSON line naming the device. A leg that fails raises,
+and the run exits non-zero. There is no CPU mode: without a TPU the run
+fails before the first leg. The last line of standard output is
+
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+``--hvdrun`` is a second, separate invocation for a host with several
+chips: a parent that never touches JAX launches ``hvdrun -np <chips>`` and
+checks a one-chip-per-process eager allreduce (docs/tpus.md).
+
+tests/test_chip_smoke.py runs the same leg functions on the CPU at
+TransformerConfig.tiny with interpreted kernels.
+"""
+
+import argparse
+import dataclasses
+import functools
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+
+import numpy as np
+
+_ROOT = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(_ROOT, "examples"))
+
+LEGS = ("kernels", "lm_train", "resnet", "serve", "four_chips")
+
+#: first-step loss, flash kernels against XLA full attention, same
+#: weights and tokens (bf16 activations; measured 7e-6 on the chip)
+FLASH_VS_FULL_RTOL = 1e-3
+#: sharded against single-device first-step loss — the tolerance of
+#: tests/test_mesh_plane.py (RTOL there)
+MESH_RTOL = 5e-4
+#: kernel outputs and gradients against full_attention, bf16 operands
+KERNEL_ATOL = 3e-2
+#: a served token may differ from the reference argmax only where the
+#: reference itself is this close to a tie, in logit units (unit-variance
+#: logits at random init). The engine's cached bf16 decode and a full bf16
+#: forward round differently: on the chip 17 of 499 tokens differed, by at
+#: most 0.032; a wrong cache row or position is off by order 1.
+SERVE_TIE_TOL = 0.1
+
+
+def device_fields():
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "device_count": len(jax.devices())}
+
+
+def emit(leg, **fields):
+    print(json.dumps({"leg": leg, **device_fields(), **fields}), flush=True)
+
+
+def _check(cond, msg):
+    if not cond:
+        raise AssertionError(msg)
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+def leg_kernels(shapes, atol=KERNEL_ATOL, dtype=None):
+    """Forward (every variant) and backward of the flash kernels against
+    parallel.ring.full_attention, one compile per (shape, variant)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.parallel.ring import full_attention
+
+    dtype = dtype or jnp.bfloat16
+    t0 = time.perf_counter()
+    worst = {}
+    for shape in shapes:
+        rng = np.random.RandomState(sum(shape))
+        qkvw = [jnp.asarray(rng.randn(*shape) * 0.5, dtype)
+                for _ in range(4)]
+
+        def out_and_grads(attend):
+            """jitted (q, k, v, w) -> (out, dq, dk, dv) for sum(out * w)."""
+            def run(q, k, v, w):
+                def loss(q, k, v):
+                    out = attend(q, k, v)
+                    return jnp.sum(out.astype(jnp.float32) *
+                                   w.astype(jnp.float32)), out
+                (_, out), grads = jax.value_and_grad(
+                    loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+                return (out,) + grads
+            return jax.jit(run)
+
+        ref = out_and_grads(full_attention)(*qkvw)
+        for variant in fa.VARIANTS:
+            got = out_and_grads(functools.partial(
+                fa.flash_attention, causal=True, variant=variant))(*qkvw)
+            for name, a, b in zip(("out", "dq", "dk", "dv"), got, ref):
+                a = np.asarray(a.astype(jnp.float32))
+                b = np.asarray(b.astype(jnp.float32))
+                _check(np.isfinite(a).all(),
+                       f"{variant} {name} at {shape}: not finite")
+                err = float(np.max(np.abs(a - b)))
+                _check(err <= atol, f"{variant} {name} at {shape}: max "
+                       f"abs err {err:.4g} > {atol} against full_attention")
+                worst[name] = max(worst.get(name, 0.0), err)
+    emit("kernels", shapes=[list(s) for s in shapes],
+         variants=list(fa.VARIANTS), max_abs_err=worst, atol=atol,
+         seconds=round(time.perf_counter() - t0, 2))
+
+
+# ---------------------------------------------------------------------------
+# LM train
+# ---------------------------------------------------------------------------
+
+def mosaic_kernel_counts(lowered):
+    """{kernel name: count} of the Mosaic custom calls in a lowered step —
+    empty when the kernels were interpreted (plain HLO, no custom call)."""
+    names = re.findall(r'kernel_name\s*=\s*"([^"]+)"', lowered.as_text())
+    return {n: names.count(n) for n in sorted(set(names))}
+
+
+def _lm_setup(mesh, batch, seq, cfg):
+    import bench_common
+    return bench_common.build_transformer_step(mesh, batch, seq, cfg=cfg)
+
+
+def _run_steps(step, params, opt_state, toks, n):
+    import jax
+    losses, secs = [], []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        params, opt_state, loss = step(params, opt_state, toks)
+        jax.block_until_ready(loss)
+        secs.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+    return params, opt_state, losses, secs
+
+
+def leg_lm_train(cfg, batch, seq, steps=4, on_chip=True):
+    """The flagship recipe (examples/bench_common.build_transformer_step)
+    on one chip; returns the first-step loss for the four-chip leg."""
+    import jax
+
+    import horovod_tpu as hvd
+    from horovod_tpu import trainer
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.parallel import mesh as mesh_mod
+    from horovod_tpu.utils import costmodel
+    from horovod_tpu.utils import history as hvd_history
+    from horovod_tpu.utils import memory as hvd_memory
+    from horovod_tpu.utils import metrics as hvd_metrics
+    from horovod_tpu.utils import tracing as hvd_tracing
+
+    hvd.init()
+    mesh = mesh_mod.build_mesh(dp=1, devices=jax.devices()[:1])
+    step, params, opt_state, toks, cfg = _lm_setup(mesh, batch, seq, cfg)
+
+    # the same first step with XLA's full attention on the same weights:
+    # forward only (the step's first loss IS the loss at these weights)
+    full = tr.TransformerLM(dataclasses.replace(cfg, attention_impl="full"))
+    ref_loss = float(jax.jit(tr.lm_loss_fn(full))(params, toks))
+
+    kernels = mosaic_kernel_counts(step.lower(params, opt_state, toks))
+    if on_chip:
+        layers = cfg.num_layers
+        fwd = sum(n for k, n in kernels.items() if k.startswith("_fwd_kernel"))
+        _check(fwd == layers and kernels.get("_dq_kernel") == layers
+               and kernels.get("_dkv_kernel") == layers,
+               f"lowered step lacks the Mosaic calls (forward, dq, dkv per "
+               f"layer): {kernels} — the kernels were interpreted")
+
+    t0 = time.perf_counter()
+    params, opt_state, first, _ = _run_steps(step, params, opt_state, toks, 1)
+    setup_s = time.perf_counter() - t0
+    params, opt_state, rest, secs = _run_steps(step, params, opt_state, toks,
+                                               steps - 1)
+    losses = first + rest
+    _check(all(np.isfinite(losses)), f"loss not finite: {losses}")
+    _check(losses[-1] < losses[0], f"loss not falling: {losses}")
+    rel = abs(losses[0] - ref_loss) / abs(ref_loss)
+    _check(rel <= FLASH_VS_FULL_RTOL,
+           f"first-step loss {losses[0]} differs from full attention's "
+           f"{ref_loss} by {rel:.3g} > {FLASH_VS_FULL_RTOL}")
+
+    # the step as users run it: every observability plane on the device
+    name = "chip_smoke"
+    inst = trainer.instrument_step(
+        step, tokens_per_step=batch * seq, name=name,
+        flops_per_token=tr.matmul_flops_per_token(cfg, seq))
+    n_inst = 3
+    params, opt_state, inst_losses, _ = _run_steps(inst, params, opt_state,
+                                                   toks, n_inst)
+    _check(all(np.isfinite(inst_losses)), f"loss not finite: {inst_losses}")
+    _check(step._cache_size() == 1,
+           f"{step._cache_size()} compiles of the step, expected exactly 1")
+    site = hvd_memory.get_tracker().site_summary()[f"train:{name}"]
+    _check(site["misses"] == 1 and site["hits"] == n_inst - 1,
+           f"compile tracker saw {site}")
+    metrics = hvd_metrics.get_registry().snapshot()["metrics"]
+
+    def gauge(metric):
+        for val in metrics.get(metric, {"values": []})["values"]:
+            if val["labels"].get("loop") == name:
+                return val["value"]
+        return None
+
+    planes = {"steps_total": gauge("hvd_steps_total"),
+              "tokens_per_second": gauge("hvd_tokens_per_second")}
+    _check(planes["steps_total"] == n_inst, f"hvd_steps_total: {planes}")
+    _check(any(s["stage"] == hvd_tracing.STEP
+               for s in hvd_tracing.get_tracer().spans()),
+           "tracer recorded no step span")
+    hvd_history.flush()
+    manifest = hvd_history.load_manifest(hvd_history.history_dir()) or {}
+    recorded = (manifest.get("provenance") or manifest).get("device_kind")
+    _check(recorded == jax.devices()[0].device_kind,
+           f"history manifest names device_kind {recorded!r}")
+    spec = costmodel.chip_spec(jax.devices()[0])
+    if on_chip:  # a real chip: its kind is known and the gauges live
+        _check(spec is not None and spec.kind != "cpu",
+               f"costmodel knows no chip {jax.devices()[0].device_kind!r}")
+        planes["mfu"] = gauge("hvd_mfu")
+        planes["peak_hbm_bytes"] = gauge("hvd_step_peak_hbm_bytes")
+        _check(planes["mfu"] is not None and 0.0 < planes["mfu"] < 1.0,
+               f"hvd_mfu gauge: {planes}")
+        _check(planes["peak_hbm_bytes"] and planes["peak_hbm_bytes"] > 0,
+               f"hvd_step_peak_hbm_bytes gauge: {planes}")
+    emit("lm_train", model="gpt2_small_tpu", layers=cfg.num_layers,
+         d_model=cfg.d_model, heads=cfg.num_heads, vocab=cfg.vocab_size,
+         batch=batch, seq=seq, attention=cfg.attention_impl,
+         mosaic_kernels=kernels, losses=[round(x, 5) for x in losses],
+         full_attention_first_loss=round(ref_loss, 5),
+         flash_vs_full_rel=float(f"{rel:.3g}"),
+         setup_seconds=round(setup_s, 2),
+         step_seconds=round(float(np.median(secs)), 4),
+         compiles=step._cache_size(), instrumented=planes,
+         chip_spec=spec.kind if spec else None)
+    return losses[0]
+
+
+# ---------------------------------------------------------------------------
+# ResNet-50 train
+# ---------------------------------------------------------------------------
+
+def leg_resnet(model="resnet50", batch=256, image_size=224, steps=3):
+    import jax
+
+    import bench_common
+    import horovod_tpu as hvd
+
+    hvd.init()
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]),
+                             hvd.mesh().axis_names[:1])
+    step, params, opt_state, data = bench_common.build_step(
+        model, mesh, batch, image_size)
+    t0 = time.perf_counter()
+    params, opt_state, first, _ = _run_steps(step, params, opt_state, data, 1)
+    setup_s = time.perf_counter() - t0
+    params, opt_state, rest, secs = _run_steps(step, params, opt_state, data,
+                                               steps - 1)
+    losses = first + rest
+    _check(all(np.isfinite(losses)), f"loss not finite: {losses}")
+    _check(step._cache_size() == 1,
+           f"{step._cache_size()} compiles of the step, expected exactly 1")
+    emit("resnet", model=model, batch=batch, image_size=image_size,
+         losses=[round(x, 5) for x in losses],
+         setup_seconds=round(setup_s, 2),
+         step_seconds=round(float(np.median(secs)), 4))
+
+
+# ---------------------------------------------------------------------------
+# LM serve
+# ---------------------------------------------------------------------------
+
+def _serve_requests(vocab, lengths, max_len, seed=0):
+    from horovod_tpu.serving.queue import Request
+    rng = np.random.RandomState(seed)
+    reqs = []
+    for i, plen in enumerate(lengths):
+        new = int(min(rng.randint(16, 65), max_len - plen + 1))
+        prompt = tuple(int(t) for t in rng.randint(1, vocab, plen))
+        reqs.append(Request(f"smoke-{i}", prompt, max_new_tokens=new))
+    return reqs
+
+
+def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
+              lengths=(5, 16, 100, 513, 1000, 7, 33, 250, 640, 90, 12, 400),
+              tie_tol=SERVE_TIE_TOL):
+    """Two passes of the same seeded requests through ServeEngine — the
+    first pays every compile under a patient queue, the second runs warm
+    behind the default admission queue — then a teacher-forced check:
+    each served token must be TransformerLM.apply's argmax given the
+    tokens before it (so a plain greedy decode yields the same sequence)
+    or tie with it within ``tie_tol``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.serving import engine as engine_mod
+    from horovod_tpu.serving.queue import AdmissionQueue
+
+    model, params = tr.init_params(cfg, jax.random.PRNGKey(0))
+    params = jax.device_put(params, jax.devices()[0])
+
+    def one_pass(queue):
+        eng = engine_mod.ServeEngine(cfg, params, num_slots=slots,
+                                     max_len=max_len, kv_block=kv_block,
+                                     queue=queue, seed=0)
+        reqs = _serve_requests(cfg.vocab_size, lengths, max_len)
+        t0 = time.perf_counter()
+        for r in reqs:
+            _check(eng.submit(r), f"{r.request_id} refused at submit")
+        results = {r.request_id: r for r in eng.run_to_completion()}
+        dt = time.perf_counter() - t0
+        _check(len(results) == len(reqs),
+               f"{len(reqs) - len(results)} of {len(reqs)} requests never "
+               f"came back (rejected in the queue)")
+        bad = {k: (r.outcome, r.reason) for k, r in results.items()
+               if r.outcome != "completed"}
+        _check(not bad, f"requests not completed: {bad}")
+        for r in reqs:
+            _check(len(results[r.request_id].tokens) == r.max_new_tokens,
+                   f"{r.request_id}: {len(results[r.request_id].tokens)} "
+                   f"tokens, wanted {r.max_new_tokens}")
+        return reqs, results, dt
+
+    reqs, cold, cold_s = one_pass(AdmissionQueue(admission_timeout_s=900.0))
+    compiled = (engine_mod._prefill_jit._cache_size(),
+                engine_mod._decode_jit._cache_size())
+    _, warm, warm_s = one_pass(None)
+    _check((engine_mod._prefill_jit._cache_size(),
+            engine_mod._decode_jit._cache_size()) == compiled,
+           "the warm pass compiled")
+    _check(all(warm[k].tokens == cold[k].tokens for k in cold),
+           "the two passes served different tokens")
+
+    # reference: one full-attention forward over prompt + served tokens
+    ref_cfg = dataclasses.replace(cfg, attention_impl="full")
+    ref_model = tr.TransformerLM(ref_cfg)
+    seqs = np.zeros((len(reqs), max_len), np.int32)
+    for i, r in enumerate(reqs):
+        full = list(r.prompt) + list(cold[r.request_id].tokens)
+        seqs[i, :len(full) - 1] = full[:-1]
+    logits = jax.jit(lambda p, t: ref_model.apply({"params": p}, t).astype(
+        jnp.float32))(params, jnp.asarray(seqs))
+    logits = np.asarray(logits)
+    exact = ties = total = 0
+    worst = 0.0
+    for i, r in enumerate(reqs):
+        for j, tok in enumerate(cold[r.request_id].tokens):
+            row = logits[i, len(r.prompt) - 1 + j]
+            deficit = float(row.max() - row[tok])
+            total += 1
+            exact += int(tok == int(row.argmax()))
+            ties += int(tok != int(row.argmax()) and deficit <= tie_tol)
+            worst = max(worst, deficit)
+    _check(exact + ties == total,
+           f"{total - exact - ties} of {total} served tokens are not the "
+           f"reference's greedy choice (worst logit deficit {worst:.4g} > "
+           f"{tie_tol})")
+    emit("serve", model="gpt2_small_tpu", layers=cfg.num_layers,
+         slots=slots, max_len=max_len, kv_block=kv_block,
+         requests=len(reqs), prompt_lengths=list(lengths),
+         new_tokens=[r.max_new_tokens for r in reqs], tokens=total,
+         greedy_exact=exact, greedy_ties=ties,
+         worst_logit_deficit=round(worst, 5), tie_tol=tie_tol,
+         prefill_compiles=compiled[0], decode_compiles=compiled[1],
+         setup_seconds=round(cold_s - warm_s, 2),
+         request_seconds=round(warm_s, 3),
+         ttft_seconds_warm=round(float(np.median(
+             [r.ttft_s for r in warm.values()])), 4))
+
+
+# ---------------------------------------------------------------------------
+# four chips
+# ---------------------------------------------------------------------------
+
+def _tp_leaf_bytes_per_device(params):
+    """{device id: bytes it holds of the leaves param_specs shards over a
+    mesh axis} — whole copies on a dp-only mesh, 1/tp of them under tp."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    from horovod_tpu.models import transformer as tr
+    specs = jax.tree_util.tree_leaves(
+        tr.param_specs(params),
+        is_leaf=lambda x: isinstance(x, PartitionSpec))
+    out = {}
+    for leaf, spec in zip(jax.tree_util.tree_leaves(params), specs):
+        if all(axis is None for axis in spec):
+            continue
+        for shard in leaf.addressable_shards:
+            out[shard.device.id] = out.get(shard.device.id, 0) + \
+                shard.data.nbytes
+    return out
+
+
+def attention_operand_shapes(compiled):
+    """Per-chip operand shapes of the Mosaic attention calls in a
+    compiled step: {(b*h, s, d) as text: count}."""
+    shapes = {}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.search(r"operand_layout_constraints=\{(.*?)\}, ", line)
+        first = re.search(r"\[([0-9,]*)\]", m.group(1) if m else "")
+        key = first.group(1) if first else "?"
+        shapes[key] = shapes.get(key, 0) + 1
+    return shapes
+
+
+def leg_four_chips(cfg, batch, seq, one_chip_loss, on_chip=True,
+                   steps=3):
+    """dp=4 and dp=2,tp=2 through the same make_gspmd_step: first on the
+    one-chip leg's tokens and weights (same seeds, same batch), then at
+    ``batch`` per chip, where the two layouts must agree."""
+    import jax
+
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.parallel import mesh as mesh_mod
+
+    devices = jax.devices()[:4]
+    head_dim = cfg.d_model // cfg.num_heads
+    report = {}
+    wide = {}
+    for name, kw in (("dp4", dict(dp=4)), ("dp2_tp2", dict(dp=2, tp=2))):
+        mesh = mesh_mod.build_mesh(devices=devices, **kw)
+        if on_chip:
+            # tp neighbours (the fastest-varying mesh axis that is >1)
+            # must be ICI neighbours on the host's 2x2 torus
+            for row in mesh.devices.reshape(-1, kw.get("tp", 1)):
+                hops = [sum(abs(a - b) for a, b in zip(x.coords, y.coords))
+                        for x, y in zip(row, row[1:])]
+                _check(all(h == 1 for h in hops),
+                       f"{name}: tp neighbours {[d.coords for d in row]} "
+                       f"are not ICI neighbours")
+        entry = {"mesh": {a: int(s) for a, s in mesh.shape.items()
+                          if s > 1},
+                 "coords": [list(getattr(d, "coords", ()))
+                            for d in mesh.devices.flat]}
+        for tag, b in (("same_batch", batch), ("full_width", 4 * batch)):
+            step, params, opt_state, toks, _ = _lm_setup(mesh, b, seq, cfg)
+            if tag == "full_width":
+                entry["tp_leaf_bytes_per_device"] = \
+                    _tp_leaf_bytes_per_device(params)
+                if on_chip:
+                    compiled = step.lower(params, opt_state, toks).compile()
+                    shapes = attention_operand_shapes(compiled)
+                    dp, tp = kw.get("dp", 1), kw.get("tp", 1)
+                    want = f"{(b // dp) * (cfg.num_heads // tp)},{seq}," \
+                           f"{-(-head_dim // 128) * 128}"
+                    _check(set(shapes) == {want} and
+                           sum(shapes.values()) == 3 * cfg.num_layers,
+                           f"{name}: attention operands {shapes}, wanted "
+                           f"{3 * cfg.num_layers} calls on [{want}]")
+                    entry["attention_operands"] = shapes
+            t0 = time.perf_counter()
+            params, opt_state, losses, secs = _run_steps(
+                step, params, opt_state, toks, steps)
+            _check(all(np.isfinite(losses)), f"{name}: loss {losses}")
+            _check(step._cache_size() == 1, f"{name}: recompiled")
+            entry[tag] = {"batch": b, "losses": [round(x, 5) for x in losses],
+                          "seconds": round(time.perf_counter() - t0, 2),
+                          "step_seconds": round(float(np.median(secs[1:])),
+                                                4)}
+            if tag == "same_batch":
+                rel = abs(losses[0] - one_chip_loss) / abs(one_chip_loss)
+                _check(rel <= MESH_RTOL,
+                       f"{name}: first loss {losses[0]} against the "
+                       f"one-chip leg's {one_chip_loss}: {rel:.3g} > "
+                       f"{MESH_RTOL}")
+                entry[tag]["vs_one_chip_rel"] = float(f"{rel:.3g}")
+            else:
+                wide[name] = losses
+                live = {}
+                for d in devices:
+                    stats = d.memory_stats() if on_chip else None
+                    live[d.id] = (stats or {}).get("bytes_in_use")
+                if on_chip:
+                    _check(all(v and v > 0 for v in live.values()),
+                           f"{name}: a device holds no live buffers: {live}")
+                entry["bytes_in_use"] = live
+            del step, params, opt_state, toks
+        report[name] = entry
+    a, b = wide["dp4"], wide["dp2_tp2"]
+    rels = [abs(x - y) / abs(x) for x, y in zip(a, b)]
+    _check(max(rels) <= MESH_RTOL * 4,
+           f"layouts disagree at full width: dp4 {a}, dp2_tp2 {b}")
+    dp_bytes = report["dp4"]["tp_leaf_bytes_per_device"]
+    tp_bytes = report["dp2_tp2"]["tp_leaf_bytes_per_device"]
+    _check(len(tp_bytes) == 4 and
+           all(2 * tp_bytes[d] == dp_bytes[d] for d in dp_bytes),
+           f"tp=2 does not halve the sharded leaves on every device: "
+           f"dp=4 {dp_bytes}, tp=2 {tp_bytes}")
+    emit("four_chips", seq=seq, layouts=report,
+         layouts_max_rel=float(f"{max(rels):.3g}"))
+
+
+# ---------------------------------------------------------------------------
+# --hvdrun: one process per chip (a separate invocation; no JAX here)
+# ---------------------------------------------------------------------------
+
+_HVDRUN_CHILD = """
+import json, os, jax, numpy as np
+import horovod_tpu as hvd
+hvd.init()
+local = jax.local_devices()
+assert len(local) == 1 and local[0].platform == "tpu", local
+me = int(os.environ["HVD_PROCESS_ID"])
+out = np.asarray(hvd.allreduce(np.full((8,), float(me + 1), np.float32),
+                               average=False, name="smoke"))
+want = sum(range(1, hvd.process_count() + 1))
+assert np.allclose(out, want), (out, want)
+print(json.dumps({"rank": me, "processes": hvd.process_count(),
+                  "devices": jax.device_count(), "chip": local[0].id,
+                  "device_kind": local[0].device_kind, "sum": float(out[0])}))
+"""
+
+
+def run_hvdrun(num_proc, timeout=600):
+    """``hvdrun -np N``: N children, one chip each, one eager allreduce
+    over the negotiated plane. This parent stays off JAX."""
+    import tempfile
+    with tempfile.TemporaryDirectory() as outdir:
+        cmd = [sys.executable, "-m", "horovod_tpu.run.cli",
+               "-np", str(num_proc), "--output-dir", outdir,
+               sys.executable, "-c", _HVDRUN_CHILD]
+        proc = subprocess.run(cmd, cwd=_ROOT, capture_output=True, text=True,
+                              timeout=timeout)
+        ranks, errors = [], []
+        for r in range(num_proc):
+            with open(os.path.join(outdir, f"rank.{r}.out")) as f:
+                lines = f.read().strip().splitlines()
+            with open(os.path.join(outdir, f"rank.{r}.err")) as f:
+                errors.append(f.read()[-3000:])
+            if lines and lines[-1].startswith("{"):
+                ranks.append(json.loads(lines[-1]))
+    ok = (proc.returncode == 0 and len(ranks) == num_proc and
+          len({r["chip"] for r in ranks}) == num_proc and
+          all(r["devices"] == num_proc for r in ranks))
+    print(json.dumps({"leg": "hvdrun", "ok": ok, "num_proc": num_proc,
+                      "exit_code": proc.returncode, "ranks": ranks}),
+          flush=True)
+    if not ok:
+        sys.stderr.write(proc.stderr[-4000:] + "\n".join(errors))
+    return 0 if ok else 1
+
+
+# ---------------------------------------------------------------------------
+
+def _native_core():
+    """Which core serves the eager plane; a failed build with a compiler
+    present is an error, not a fallback."""
+    from horovod_tpu import _native
+    lib = _native.load()
+    if lib is None and shutil.which("g++"):
+        raise RuntimeError(
+            f"g++ is present but libhvd_core did not build or load: "
+            f"{_native.LOAD_ERROR}")
+    return ("native " + lib.hvd_core_version().decode()) if lib \
+        else f"python fallback ({_native.LOAD_ERROR})"
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--legs", default=",".join(LEGS),
+                    help="comma-separated subset of: " + ", ".join(LEGS))
+    ap.add_argument("--hvdrun", type=int, metavar="N", default=0,
+                    help="instead of the legs: launch hvdrun -np N, one "
+                         "chip per process (this parent stays off JAX)")
+    args = ap.parse_args(argv)
+    if args.hvdrun:
+        return run_hvdrun(args.hvdrun)
+    legs = [s for s in args.legs.split(",") if s]
+    unknown = sorted(set(legs) - set(LEGS))
+    if unknown:
+        ap.error(f"unknown legs {unknown}")
+
+    import jax
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found platform "
+              f"{device.platform!r} ({device.device_kind}); there is no CPU "
+              f"mode", file=sys.stderr)
+        return 1
+
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.utils import compile_cache
+    cache_dir = compile_cache.configure()
+    cached = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    t0 = time.perf_counter()
+    emit("start", legs=legs, native_core=_native_core(),
+         compile_cache={"dir": cache_dir, "entries_at_start": cached,
+                        "warm": cached > 0},
+         jax=jax.__version__)
+
+    train_cfg = tr.TransformerConfig.gpt2_small_tpu(
+        attention_impl="flash", tie_embeddings=True, logits_fp32=False)
+    serve_cfg = tr.TransformerConfig.gpt2_small_tpu(attention_impl="flash")
+    heads, head_dim = train_cfg.num_heads, 128
+    first_loss = None
+    if "kernels" in legs:
+        # the training shape, then prefill at the padded lengths the serve
+        # leg's prompts produce (16, 112, 528 -> 640, 1008 -> 1024)
+        leg_kernels([(16, 1024, heads, head_dim)] +
+                    [(1, s, heads, head_dim) for s in (16, 112, 528, 1008)])
+    if "lm_train" in legs or "four_chips" in legs:
+        first_loss = leg_lm_train(train_cfg, 16, 1024)
+    if "resnet" in legs:
+        leg_resnet()
+    if "serve" in legs:
+        leg_serve(serve_cfg)
+    if "four_chips" in legs:
+        if jax.device_count() >= 4:
+            leg_four_chips(train_cfg, 16, 1024, first_loss)
+        else:
+            emit("four_chips", absent=True,
+                 reason=f"{jax.device_count()} device(s)")
+    emit("done", legs=legs, seconds=round(time.perf_counter() - t0, 1))
+    print(json.dumps({"ok": True,
+                      "device": {"platform": device.platform,
+                                 "kind": device.device_kind,
+                                 "count": len(jax.devices())}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

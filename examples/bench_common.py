@@ -28,7 +28,10 @@ def build_step(model_name, mesh, batch, image_size, fp16_allreduce=False,
     images = jnp.zeros((batch, image_size, image_size, 3), jnp.bfloat16)
     labels = jnp.zeros((batch,), jnp.int32)
     variables = model.init(jax.random.PRNGKey(0), images[:2], train=False)
-    params = variables["params"]
+    # replicated ON THE MESH, like the step's outputs: host-initialised
+    # params carry a single-device sharding, and the second call (fed the
+    # first call's outputs) would compile the whole step again
+    params = trainer.replicate(variables["params"], mesh)
     batch_stats = variables.get("batch_stats", {})  # VGG has no BN
 
     compression = (hvd.Compression.bf16 if fp16_allreduce
@@ -61,9 +64,7 @@ def timed_rates(step, params, opt_state, batch_data, batch,
     img/sec. At least one warmup step always runs so trace+compile of the
     jitted step can never land inside the timed region (a compile-polluted
     first iteration would silently wreck the reported rate). The sync
-    barrier is a scalar device-to-host read — on remote-attached runtimes
-    block_until_ready can return before execution completes
-    (docs/benchmarks.md).
+    barrier is a scalar device-to-host read.
 
     With return_state=True, returns (rates, params, opt_state) — REQUIRED
     for repeated calls on the same step: the jitted step donates its
@@ -178,10 +179,9 @@ def setup_transformer_lm(on_tpu, seq=None, flash_variant=None,
     docs/benchmarks.md — keep single-sourced so harnesses cannot drift).
 
     Uses the device-side multi-step loop (trainer.make_gspmd_multi_step)
-    so host dispatch — ~3-5 ms per call through a remote-attached
-    runtime — is amortized out of the measurement; the loop scans over a
-    stacked [n_steps, batch, seq] token array, a real optimizer update
-    per inner step.
+    so host dispatch is amortized out of the measurement; the loop scans
+    over a stacked [n_steps, batch, seq] token array, a real optimizer
+    update per inner step.
 
     ``seq`` / ``flash_variant`` / ``batch_per_chip`` override the
     flagship defaults — the flash-ablation leg builds one window per
@@ -223,7 +223,7 @@ def setup_transformer_lm(on_tpu, seq=None, flash_variant=None,
         t0 = time.perf_counter()
         live["params"], live["opt"], loss = step(live["params"],
                                                  live["opt"], toks)
-        float(loss)  # scalar read = true barrier on remote runtimes
+        float(loss)  # scalar read: the barrier that ends the window
         return (time.perf_counter() - t0) / inner
 
     meta = {"batch": batch, "batch_per_chip": batch_per_chip, "seq": seq,

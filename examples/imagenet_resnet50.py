@@ -38,7 +38,7 @@ import horovod_tpu as hvd
 from horovod_tpu import callbacks as cb
 from horovod_tpu import trainer
 from horovod_tpu.models import resnet
-from horovod_tpu.utils import checkpoint
+from horovod_tpu.utils import checkpoint, compile_cache
 
 
 def parse_args():
@@ -96,6 +96,7 @@ def data_batch(data, rng, n):
 
 def main():
     args = parse_args()
+    compile_cache.configure()
     hvd.init()
     world = hvd.size()
     global_batch = args.batch_size * world

@@ -30,6 +30,7 @@ import jax
 
 from horovod_tpu.router import Router
 from horovod_tpu.serving.engine import ServeEngine
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.utils import metrics as hvd_metrics
 
 from serve_lm import make_workload, serving_config
@@ -118,6 +119,7 @@ def main(argv=None):
                          "p99 TTFT ratio")
     args = ap.parse_args(argv)
 
+    compile_cache.configure()
     on_tpu = jax.default_backend() == "tpu"
     cfg = serving_config(on_tpu)
     _, params = tr.init_params(cfg, jax.random.PRNGKey(0))

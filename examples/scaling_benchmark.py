@@ -26,6 +26,7 @@ import numpy as np
 
 import horovod_tpu as hvd
 from horovod_tpu import models
+from horovod_tpu.utils import compile_cache
 
 from bench_common import build_step, positive_int, timed_rates
 
@@ -82,6 +83,7 @@ def measure(args, n_devices):
 
 def main():
     args = parse_args()
+    compile_cache.configure()
     n_avail = len(jax.devices())
     if args.device_counts:
         try:

@@ -32,6 +32,7 @@ import numpy as np
 from horovod_tpu.models import transformer as tr
 from horovod_tpu.serving.engine import ServeEngine
 from horovod_tpu.serving.queue import AdmissionQueue, Request
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.utils import metrics as hvd_metrics
 
 
@@ -142,6 +143,7 @@ def main(argv=None):
                          "report the speedup")
     args = ap.parse_args(argv)
 
+    compile_cache.configure()
     on_tpu = jax.default_backend() == "tpu"
     cfg = serving_config(on_tpu)
     _, params = tr.init_params(cfg, jax.random.PRNGKey(0))

@@ -17,6 +17,7 @@ import numpy as np
 
 import horovod_tpu as hvd
 from horovod_tpu import models
+from horovod_tpu.utils import compile_cache
 
 from bench_common import (build_eager_image_step, build_step, positive_int,
                           timed_rates)
@@ -49,6 +50,7 @@ def parse_args():
 
 def main():
     args = parse_args()
+    compile_cache.configure()
     hvd.init()
     world = hvd.size()
     batch = args.batch_size * world

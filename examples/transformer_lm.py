@@ -37,6 +37,7 @@ from horovod_tpu import trainer
 from horovod_tpu.common.exceptions import PREEMPTED_EXIT_CODE
 from horovod_tpu.models import transformer as tr
 from horovod_tpu.parallel import mesh as mesh_mod
+from horovod_tpu.utils import compile_cache
 
 
 SIZES = {"tiny": tr.TransformerConfig.tiny,
@@ -97,6 +98,7 @@ def parse_args():
 
 def main():
     args = parse_args()
+    compile_cache.configure()
     hvd.init()
     n = hvd.size()
     # The named-mesh data plane (docs/mesh.md): CLI flags win when given;
@@ -245,10 +247,7 @@ def main():
             # checkpoint committed; the elastic supervisor's
             # --graceful-restart-on-preempt resumes from exactly here
             sys.exit(PREEMPTED_EXIT_CODE)
-    # scalar transfer, not block_until_ready: on remote-attached platforms
-    # only a device→host read is a true execution barrier (same lesson as
-    # bench.py's sync comments)
-    float(loss)
+    float(loss)  # device→host read: the barrier that ends the timed loop
     dt = time.perf_counter() - t0
     if ckptr is not None:
         ckptr.close()  # drain the async writer before reporting

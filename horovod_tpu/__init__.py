@@ -19,7 +19,6 @@ Public API parity with ``horovod.torch`` / ``horovod.tensorflow``
 
 from .version import __version__  # noqa: F401
 
-from .common import compat as _compat  # noqa: F401  (installs jax shims)
 from .common.exceptions import (  # noqa: F401
     DuplicateNameError, HorovodError, MismatchError, NotInitializedError,
     RanksLostError, ShutdownError, StalledError)

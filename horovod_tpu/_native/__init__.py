@@ -3,12 +3,14 @@
 The reference loads its native core the same way — ctypes.CDLL on the built
 extension (horovod/common/basics.py:25-28, util.py check_extension). Build
 with ``python setup.py build_native`` (or the Makefile in this directory);
-if the library is absent or fails to load, ``LIB`` is None and callers fall
-back to the pure-Python implementations, so the framework works (slower)
-without a toolchain.
+if the library cannot be built or loaded, ``LIB`` is None, the cause is
+logged and kept in ``LOAD_ERROR``, and callers fall back to the
+pure-Python implementations, so the framework works (slower) without a
+toolchain.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -17,6 +19,7 @@ _LIB_PATH = os.path.join(_DIR, "libhvd_core.so")
 
 LIB = None
 _LOAD_FAILED = False  # negative cache: never retry a failed build/load
+LOAD_ERROR = None  # why load() returned None, for callers that must know
 
 
 def _configure(lib):
@@ -84,19 +87,54 @@ def _configure(lib):
     return lib
 
 
+def _source_digest(deps, cmd):
+    """sha256 over the build command and the CONTENT of every dependency.
+    Content, not mtime: a copied or freshly checked-out tree carries
+    arbitrary timestamps, and a stale library must not outlive its
+    sources because the copy happened to touch it last."""
+    h = hashlib.sha256(" ".join(cmd).encode())
+    for path in deps:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _build(lib_path, cmd, deps, force):
+    """Run ``cmd + ["-o", lib_path]`` unless a library built from exactly
+    these ``deps`` with exactly this command is already there (the
+    digest is kept in ``<lib>.stamp`` next to it). The compiler writes
+    to a private name and the result is renamed into place, so a
+    concurrent process (hvdrun ranks on a fresh checkout) never loads a
+    half-written library."""
+    stamp_path = lib_path + ".stamp"
+    digest = _source_digest(deps, cmd)
+    if not force and os.path.exists(lib_path) and \
+            os.path.exists(stamp_path):
+        with open(stamp_path) as f:
+            if f.read().strip() == digest:
+                return lib_path
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(cmd + ["-o", tmp], check=True)
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    with open(stamp_path, "w") as f:
+        f.write(digest + "\n")
+    return lib_path
+
+
+def _src(*names):
+    return [os.path.join(_DIR, "src", n) for n in names]
+
+
 def build(force=False):
     """Compile libhvd_core.so with g++ (no external deps)."""
-    src_dir = os.path.join(_DIR, "src")
-    sources = [os.path.join(src_dir, f) for f in
-               ("hvd_core.cc", "timeline.cc", "autotune.cc")]
-    if not force and os.path.exists(_LIB_PATH):
-        newest_src = max(os.path.getmtime(s) for s in sources)
-        if os.path.getmtime(_LIB_PATH) >= newest_src:
-            return _LIB_PATH
+    sources = _src("hvd_core.cc", "timeline.cc", "autotune.cc")
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-fvisibility=hidden", "-o", _LIB_PATH] + sources
-    subprocess.run(cmd, check=True)
-    return _LIB_PATH
+           "-fvisibility=hidden"] + sources
+    return _build(_LIB_PATH, cmd, sources + _src("hvd_core.h"), force)
 
 
 _PLANE_LIB_PATH = os.path.join(_DIR, "libhvd_plane.so")
@@ -106,22 +144,14 @@ def build_plane(force=False):
     """Compile the framework-agnostic collective plane's C API
     (libhvd_plane.so from plane.h + plane_c.cc — no TensorFlow linkage;
     the ctypes surface for the torch frontend)."""
-    src_dir = os.path.join(_DIR, "src")
-    sources = [os.path.join(src_dir, "plane_c.cc")]
-    # shm_ring.h is included by plane.h: leaving it out of the dep list
-    # made edits to the shm transport silently not rebuild
-    deps = sources + [os.path.join(src_dir, "plane.h"),
-                      os.path.join(src_dir, "shm_ring.h")]
-    if not force and os.path.exists(_PLANE_LIB_PATH):
-        if os.path.getmtime(_PLANE_LIB_PATH) >= max(
-                os.path.getmtime(d) for d in deps):
-            return _PLANE_LIB_PATH
+    sources = _src("plane_c.cc")
     # -fvisibility=hidden: the inline Plane singleton must not merge
     # with libhvd_tf.so's copy when both are loaded (plane.h note)
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-fvisibility=hidden", "-o", _PLANE_LIB_PATH] + sources
-    subprocess.run(cmd, check=True)
-    return _PLANE_LIB_PATH
+           "-fvisibility=hidden"] + sources
+    # shm_ring.h is included by plane.h: both are dependencies
+    return _build(_PLANE_LIB_PATH, cmd,
+                  sources + _src("plane.h", "shm_ring.h"), force)
 
 
 _TF_LIB_PATH = os.path.join(_DIR, "libhvd_tf.so")
@@ -134,42 +164,46 @@ def build_tf(force=False):
     TensorFlow is not importable; callers treat that as 'unavailable'."""
     import tensorflow as tf  # deferred: TF is an optional frontend dep
 
-    src = os.path.join(_DIR, "src", "tf_ops.cc")
-    deps = [src, os.path.join(_DIR, "src", "plane.h"),
-            os.path.join(_DIR, "src", "shm_ring.h")]
-    if not force and os.path.exists(_TF_LIB_PATH):
-        if os.path.getmtime(_TF_LIB_PATH) >= max(
-                os.path.getmtime(d) for d in deps):
-            return _TF_LIB_PATH
+    sources = _src("tf_ops.cc")
     # -fvisibility=hidden: see build_plane (shared singleton hazard)
     cmd = (["g++", "-O2", "-shared", "-fPIC", "-pthread",
-            "-fvisibility=hidden", "-o", _TF_LIB_PATH, src]
+            "-fvisibility=hidden"] + sources
            + tf.sysconfig.get_compile_flags()
            + tf.sysconfig.get_link_flags())
-    subprocess.run(cmd, check=True)
-    return _TF_LIB_PATH
+    return _build(_TF_LIB_PATH, cmd,
+                  sources + _src("plane.h", "shm_ring.h"), force)
 
 
 def load(auto_build=True):
     """Load (building if needed) the native core; returns the lib or None.
-    A failed build/load is cached so the hot path never re-spawns g++."""
-    global LIB, _LOAD_FAILED
+    A failed build/load is cached so the hot path never re-spawns g++;
+    the cause is logged once and kept in ``LOAD_ERROR``."""
+    global LIB, _LOAD_FAILED, LOAD_ERROR
     if LIB is not None:
         return LIB
     if _LOAD_FAILED:
         return None
     if os.environ.get("HVD_DISABLE_NATIVE", "") in ("1", "true"):
         _LOAD_FAILED = True
+        LOAD_ERROR = "disabled by HVD_DISABLE_NATIVE"
         return None
     try:
         if auto_build:
-            build()  # no-op when the .so is newer than every source
+            build()  # no-op when the .so matches its sources' digest
         elif not os.path.exists(_LIB_PATH):
             raise FileNotFoundError(_LIB_PATH)
         LIB = _configure(ctypes.CDLL(_LIB_PATH))
-    except Exception:
+    except (OSError, subprocess.CalledProcessError, AttributeError) as e:
+        # no toolchain (FileNotFoundError is an OSError), a failed
+        # compile, an unloadable library or one missing a symbol: the
+        # pure-Python implementations carry on, but never silently
         LIB = None
         _LOAD_FAILED = True
+        LOAD_ERROR = f"{type(e).__name__}: {e}"
+        from ..common import hvd_logging
+        hvd_logging.warning(
+            "native core unavailable (%s); using the Python fallbacks",
+            LOAD_ERROR)
     return LIB
 
 

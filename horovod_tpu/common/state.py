@@ -63,6 +63,25 @@ def _check_initialized():
         raise NotInitializedError()
 
 
+def _open_devices():
+    """``jax.devices()``, with the one failure users of several processes
+    meet said in this framework's terms: a TPU chip belongs to one
+    process at a time (docs/tpus.md, "Chips vs processes")."""
+    try:
+        return jax.devices()
+    except RuntimeError as e:
+        if "already in use" not in str(e):
+            raise
+        raise RuntimeError(
+            f"hvd.init(): another process holds this host's TPU chips "
+            f"({e}). A chip belongs to one process at a time: run ONE "
+            f"process per host driving all its chips, or launch one "
+            f"process per chip with `hvdrun -np <chips>` from a parent "
+            f"that has not touched JAX (docs/tpus.md, \"Chips vs "
+            f"processes\"); pin helper processes to JAX_PLATFORMS=cpu."
+        ) from e
+
+
 def init_state(devices=None, mesh=None, axis_name=HVD_AXIS, config=None):
     """Populate the global state. Called by hvd.init()."""
     with _state.lock:
@@ -70,7 +89,7 @@ def init_state(devices=None, mesh=None, axis_name=HVD_AXIS, config=None):
             return _state
         if mesh is None:
             if devices is None:
-                devices = jax.devices()
+                devices = _open_devices()
             mesh = jax.sharding.Mesh(np.asarray(devices), (axis_name,))
         _state.mesh = mesh
         _state.config = config or config_mod.HorovodConfig.from_env()
@@ -108,12 +127,8 @@ def hvd_axis_name():
 def _traced_axis_index():
     """Return lax.axis_index(axis) if called under an active axis binding
     (inside shard_map/pmap), else None."""
-    try:
-        from jax._src.core import get_axis_env  # jax>=0.4.31 internal
-        axis_env = get_axis_env()
-        names = [n for n in axis_env.axis_sizes if isinstance(n, str)]
-    except (ImportError, AttributeError):  # private API may move
-        names = []
+    from jax._src.core import get_axis_env  # private: a move must be loud
+    names = [n for n in get_axis_env().axis_sizes if isinstance(n, str)]
     if not names:
         return None
     if _state.mesh is not None:

@@ -60,7 +60,7 @@ class TransformerConfig:
     # Forward accumulation variant of the flash kernel ('auto' | 'online'
     # | 'lazy' | 'twopass' — ops/flash_attention.VARIANTS; only read when
     # attention_impl routes through the flash kernel). 'auto' applies the
-    # measured heuristic in resolve_variant; HVD_FLASH_VARIANT overrides
+    # heuristic in resolve_variant; HVD_FLASH_VARIANT overrides
     # either way (the bench ablation hook).
     flash_variant: str = "auto"
     # Mixture-of-Experts: num_experts > 0 replaces the dense MLP with
@@ -140,9 +140,45 @@ def _dispatch_attention(cfg, q, k, v, sp):
         # ring_flash with the whole sequence on this worker: the flash
         # kernel IS the single-block ring
         from ..ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True,
-                               variant=cfg.flash_variant)
+        attend = functools.partial(flash_attention, causal=True,
+                                   variant=cfg.flash_variant)
+        spec = _gspmd_attention_spec(q.shape)
+        if spec is not None:
+            # A pallas_call has no GSPMD partitioning rule: left bare
+            # under a sharded jit, lowering for a TPU fails ("Mosaic
+            # kernels cannot be automatically partitioned"). shard_map
+            # over the ambient mesh hands each chip its own (batch/dp,
+            # heads/tp) slice.
+            # check_vma=False: the CPU tests' interpreted kernel does not
+            # type-check under varying-manual-axes tracking, and the
+            # specs claim nothing the check would have to prove (every
+            # unnamed mesh axis sees replicated operands).
+            attend = jax.shard_map(attend, in_specs=(spec, spec, spec),
+                                   out_specs=spec, check_vma=False)
+        return attend(q, k, v)
     return ring.full_attention(q, k, v, causal=True)
+
+
+def _gspmd_attention_spec(shape):
+    """PartitionSpec of [b, s, h, d] attention operands under the mesh the
+    enclosing jit is traced with (trainer.make_gspmd_step sets it): batch
+    over 'dp', heads over 'tp' — the layout batch_spec() and _TP_RULES
+    give the activations. None when there is nothing to split: no ambient
+    mesh, already inside a shard_map (every axis manual), or neither axis
+    larger than 1 and dividing its dimension."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return None
+    b, _, h, _ = shape
+
+    def axis(name, dim):
+        size = mesh.shape.get(name, 1)
+        return name if size > 1 and dim % size == 0 else None
+
+    dp, tp = axis("dp", b), axis("tp", h)
+    if dp is None and tp is None:
+        return None
+    return P(dp, None, tp, None)
 
 
 def _rope(x, positions):

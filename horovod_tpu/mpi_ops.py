@@ -75,11 +75,8 @@ def init(devices=None, mesh=None, axis_name=state_mod.HVD_AXIS, config=None,
         return default
 
     def _jax_distributed_live():
-        try:  # pre-initialized by the caller (the pods flow)
-            from jax._src import distributed
-            return distributed.global_state.coordinator_address is not None
-        except (ImportError, AttributeError):  # private API may move
-            return False
+        # pre-initialized by the caller (the pods flow)
+        return jax.distributed.is_initialized()
 
     if coordinator_address is None and "HVD_COORDINATOR_ADDR" in os.environ:
         coordinator_address = os.environ["HVD_COORDINATOR_ADDR"]

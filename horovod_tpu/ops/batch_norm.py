@@ -37,13 +37,12 @@ from flax import linen as nn
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pallas_compat
 from .flash_attention import _auto_interpret, _out_struct
 
 
 # sequential grid: every step accumulates into the same [1, C] output
 # blocks, which Mosaic keeps resident in VMEM across the whole grid
-_SEQ = _pallas_compat.CompilerParams(dimension_semantics=("arbitrary",))
+_SEQ = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 # a lone [rows, C] tile has no double-buffering; what bounds it is the

@@ -42,12 +42,10 @@ MAX = "max"
 
 def _bound_axis_names():
     """Names of mesh axes currently bound by shard_map/pmap tracing."""
-    try:
-        from jax._src.core import get_axis_env
-        env = get_axis_env()
-        return [n for n in env.axis_sizes if isinstance(n, str)]
-    except (ImportError, AttributeError):  # private API may move
-        return []
+    # private API: an ImportError here must stay loud — swallowing it
+    # would resolve every traced collective as eager
+    from jax._src.core import get_axis_env
+    return [n for n in get_axis_env().axis_sizes if isinstance(n, str)]
 
 
 def resolve_axis(axis_name=None, prefer_hierarchy=False):
@@ -93,13 +91,7 @@ def ensure_varying(x, axis_names):
     reduction."""
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
-    try:
-        vma = jax.typeof(x).vma
-    except AttributeError:
-        # jax builds without the varying-manual-axes type system
-        # (jax.typeof/pcast landed together): every shard_map value is
-        # implicitly varying there, so there is nothing to cast
-        return x
+    vma = jax.typeof(x).vma
     missing = tuple(a for a in axis_names if a not in vma)
     if not missing:
         return x

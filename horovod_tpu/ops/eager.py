@@ -51,7 +51,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..common import compat
 from ..common import hvd_logging as log
 from ..common import state as state_mod
 from ..parallel import mesh as mesh_lib
@@ -1449,7 +1448,7 @@ class EagerCoordinator:
 
         @jax.jit
         def f(x):
-            return compat.shard_map(
+            return jax.shard_map(
                 lambda s: lax.psum(s, axis), mesh=mesh,
                 in_specs=P(axis), out_specs=P(axis))(x)
         return f
@@ -1464,7 +1463,7 @@ class EagerCoordinator:
                 idx = lax.axis_index(axis)
                 masked = jnp.where(idx == root, s, jnp.zeros_like(s))
                 return lax.psum(masked, axis)
-            return compat.shard_map(shard_fn, mesh=mesh, in_specs=P(axis),
+            return jax.shard_map(shard_fn, mesh=mesh, in_specs=P(axis),
                                  out_specs=P(axis))(x)
         return f
 

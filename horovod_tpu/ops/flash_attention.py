@@ -24,8 +24,8 @@ accumulates dK/dV (gridded over K blocks, streaming Q/dO/lse/delta, and
 starting at the diagonal for causal). Memory is O(s·d) in backward too,
 which is what makes long-context training with this kernel viable.
 
-On non-TPU backends the kernel runs in Pallas interpret mode (tests on
-the CPU mesh), selected automatically.
+On the CPU backend the kernel runs in Pallas interpret mode (the tests'
+virtual mesh), selected automatically; a TPU never gets it.
 """
 
 import functools
@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pallas_compat
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
@@ -57,13 +56,14 @@ VARIANTS = ("online", "lazy", "twopass")
 def resolve_variant(variant, causal=True, nk=1):
     """Resolve 'auto' (and the HVD_FLASH_VARIANT env override, which wins
     over any explicit argument — the bench A/B hook) to a concrete
-    forward variant. The heuristic encodes the ablation in
-    docs/benchmarks.md: lazy whenever the k loop has ≥2 tiles (its gated
+    forward variant. The heuristic is reasoned, not measured (all three
+    variants compile and match the reference on the v5e; none has been
+    timed — ROADMAP S3): lazy whenever the k loop has ≥2 tiles (its gated
     rescale degrades to exactly the online chain in the worst case and
     skips the [block_q, d] correction otherwise); online for the 1-tile
     degenerate loop where there is nothing to defer; twopass stays
-    opt-in — its extra QK^T pass only pays off where the VPU chain
-    dominates the MXU (see the variant × shape table)."""
+    opt-in — its extra QK^T pass can only pay off where the VPU chain
+    dominates the MXU."""
     env = os.environ.get("HVD_FLASH_VARIANT", "").strip().lower()
     if env:
         variant = env
@@ -77,7 +77,17 @@ def resolve_variant(variant, causal=True, nk=1):
 
 
 def _auto_interpret():
-    return jax.default_backend() != "tpu"
+    """Mosaic on a TPU, the Pallas interpreter on the CPU (the tests),
+    and an error anywhere else: a backend that is neither must not get
+    an interpreted kernel under the compiled kernel's name."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash attention kernels run compiled on 'tpu' and interpreted "
+        f"on 'cpu'; the default backend is {backend!r}")
 
 
 def _out_struct(shape, dtype, *like):
@@ -98,7 +108,7 @@ def _out_struct(shape, dtype, *like):
 # both grid dims are independent (programs share no state): 'parallel'
 # lets Mosaic software-pipeline across grid steps instead of flushing
 # between them
-_COMPILER_PARAMS = _pallas_compat.CompilerParams(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 
@@ -754,9 +764,21 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     sq, sk = q.shape[seq_axis], k.shape[seq_axis]
     d = q.shape[-1]
     scale = d ** -0.5
-    bq, bk = fit_block(block_q, sq), fit_block(block_k, sk)
-    bq2 = fit_block(block_q_dkv, sq) if block_q_dkv else None
-    bk2 = fit_block(block_k_dkv, sk) if block_k_dkv else None
+    interpret_eff = interpret if interpret is not None else _auto_interpret()
+
+    def fit(block, s):
+        b = fit_block(block, s)
+        # Mosaic lays the [bh, 8, s] row statistics (lse, delta) out in
+        # 128-lane tiles and refuses to slice a block out of them that
+        # is not a multiple ("Slice shape along dimension 2 must be
+        # aligned to tiling (128)" from the dK/dV kernel at s = 16, 24,
+        # 112, 200): compiled, such a sequence takes 128-blocks and the
+        # end-padding below. Serving prefill lengths land here.
+        return b if interpret_eff or b % 128 == 0 else 128
+
+    bq, bk = fit(block_q, sq), fit(block_k, sk)
+    bq2 = fit(block_q_dkv, sq) if block_q_dkv else None
+    bk2 = fit(block_k_dkv, sk) if block_k_dkv else None
     pad_q, pad_k = -sq % bq, -sk % bk
     if (pad_q or pad_k) and not (causal and sq == sk):
         raise ValueError(
@@ -768,7 +790,6 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
             pads[seq_axis] = (0, p)
             return jnp.pad(t, pads)
         q, k, v = seq_pad(q, pad_q), seq_pad(k, pad_k), seq_pad(v, pad_k)
-    interpret_eff = interpret if interpret is not None else _auto_interpret()
     pad_d = 0 if interpret_eff else -d % 128
     if pad_d:
         pads = ((0, 0), (0, 0), (0, 0), (0, pad_d))

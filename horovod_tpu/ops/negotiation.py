@@ -1161,11 +1161,11 @@ def control_addresses():
         return [(host, int(port))]
     addr = os.environ.get("HVD_COORDINATOR_ADDR")
     if not addr:
-        try:  # auto-configured rendezvous (TPU pods)
-            from jax._src import distributed
-            addr = distributed.global_state.coordinator_address
-        except (ImportError, AttributeError):  # private API may move
-            addr = None
+        # auto-configured rendezvous (TPU pods); private API with no
+        # public twin — a move must be an error, not a silent fall back
+        # to non-negotiated mode
+        from jax._src import distributed
+        addr = distributed.global_state.coordinator_address
     if not addr:
         return None
     host, _, port = addr.rpartition(":")

@@ -36,7 +36,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common import compat
 from ..parallel import hierarchical as hier_mod
 from . import quantization
 
@@ -97,7 +96,7 @@ class ProcessCollectiveEngine:
             def body(s):
                 out = lax.psum(s[0], PROC_AXIS)
                 return out / self.nproc if average else out
-            return compat.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
+            return jax.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
                                  out_specs=P())(x)
         return f
 
@@ -111,7 +110,7 @@ class ProcessCollectiveEngine:
                 idx = lax.axis_index(PROC_AXIS)
                 masked = jnp.where(idx == root, s[0], jnp.zeros_like(s[0]))
                 return lax.psum(masked, PROC_AXIS)
-            return compat.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
+            return jax.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
                                  out_specs=P())(x)
         return f
 
@@ -130,7 +129,7 @@ class ProcessCollectiveEngine:
                 out = lax.psum_scatter(s[0], PROC_AXIS,
                                        scatter_dimension=0, tiled=True)
                 return out / self.nproc if average else out
-            return compat.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
+            return jax.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
                                  out_specs=P(PROC_AXIS))(x)
         return f
 
@@ -161,7 +160,7 @@ class ProcessCollectiveEngine:
                 total = jnp.sum(
                     quantization._block_decode(qp, sp, block), axis=0)
                 return quantization._block_encode(total, block, codec)
-            return compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(P(PROC_AXIS), P(PROC_AXIS)),
                 out_specs=(P(PROC_AXIS), P(PROC_AXIS)))(q, s)
         return f
@@ -183,7 +182,7 @@ class ProcessCollectiveEngine:
             def body(s):
                 return lax.all_to_all(s[0], PROC_AXIS, split_axis=0,
                                       concat_axis=0, tiled=True)
-            return compat.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
+            return jax.shard_map(body, mesh=mesh, in_specs=P(PROC_AXIS),
                                  out_specs=P(PROC_AXIS))(x)
         return f
 
@@ -288,7 +287,7 @@ class HierarchicalProcessEngine:
                 return hier_mod.hierarchical_allreduce(
                     s[0, 0], fast_axis=LOCAL_AXIS, slow_axis=HOSTS_AXIS,
                     average=average)
-            return compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=P(HOSTS_AXIS, LOCAL_AXIS),
                 out_specs=P())(x)
         return f
@@ -336,16 +335,16 @@ class HierarchicalProcessEngine:
                     full = full / world
                 dec_own = quantization._block_decode(q, s, block)
                 return full, comp[None, None], dec_own[None, None]
-            # check_rep=False: ``full`` IS replicated (it comes off
+            # check_vma=False: ``full`` IS replicated (it comes off
             # tiled all_gathers over both axes) but the static checker
             # cannot see through the dequant/requant arithmetic.
-            return compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(HOSTS_AXIS, LOCAL_AXIS),
                           P(HOSTS_AXIS, LOCAL_AXIS)),
                 out_specs=(P(), P(HOSTS_AXIS, LOCAL_AXIS),
                            P(HOSTS_AXIS, LOCAL_AXIS)),
-                check_rep=False)(x, r)
+                check_vma=False)(x, r)
         return f
 
     def allreduce(self, x, average=False):

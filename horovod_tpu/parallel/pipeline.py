@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..common import compat
 
 
 def gpipe(stage_fn, microbatches, axis_name="pp"):
@@ -294,7 +293,7 @@ def make_pipeline_step(cfg, tx, mesh, num_microbatches, pparams,
     param_specs_tree = pipeline_param_specs(pparams)
     opt_specs = trainer_mod.opt_state_specs(tx, pparams, param_specs_tree)
     batch_spec = P(dp_axis, None)
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         step, mesh=mesh, axis_names=frozenset(manual_axes),
         in_specs=(param_specs_tree, opt_specs, batch_spec),
         out_specs=(param_specs_tree, opt_specs, P())))

@@ -92,20 +92,19 @@ def ring_attention(q, k, v, axis_name="sp", causal=True):
     o0 = jnp.zeros((b, s_loc, h, d), jnp.float32)
     m0 = jnp.full((b, h, s_loc), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, s_loc), jnp.float32)
-    if hasattr(lax, "pcast"):
-        # The loop carry must have consistent varying-manual-axes types
-        # (jax>=0.8): accumulators start unvarying, and k/v may be varying
-        # over fewer axes than the loop body produces (ppermute adds the
-        # ring axis; q's mask/merge add any other bound axes). Cast
-        # everything in the carry to varying over all bound axes.
-        from ..ops.collective_ops import _bound_axis_names
-        axes = tuple(_bound_axis_names())
+    # The loop carry must have consistent varying-manual-axes types:
+    # accumulators start unvarying, and k/v may be varying over fewer
+    # axes than the loop body produces (ppermute adds the ring axis; q's
+    # mask/merge add any other bound axes). Cast everything in the carry
+    # to varying over all bound axes.
+    from ..ops.collective_ops import _bound_axis_names
+    axes = tuple(_bound_axis_names())
 
-        def vary(t):
-            have = getattr(getattr(t, "aval", None), "vma", frozenset())
-            missing = tuple(a for a in axes if a not in have)
-            return lax.pcast(t, missing, to="varying") if missing else t
-        o0, m0, l0, k, v = map(vary, (o0, m0, l0, k, v))
+    def vary(t):
+        have = getattr(getattr(t, "aval", None), "vma", frozenset())
+        missing = tuple(a for a in axes if a not in have)
+        return lax.pcast(t, missing, to="varying") if missing else t
+    o0, m0, l0, k, v = map(vary, (o0, m0, l0, k, v))
     o, m, l, _, _ = lax.fori_loop(0, axis_size, body, (o0, m0, l0, k, v))
     l = jnp.maximum(l, 1e-30)
     out = o / l.transpose(0, 2, 1)[..., None]

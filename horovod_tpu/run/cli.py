@@ -19,6 +19,7 @@ analogue of MPI_Init inside the background thread (operations.cc:869-888).
 
 import argparse
 import base64
+import glob
 import os
 import signal
 import socket
@@ -139,11 +140,47 @@ def _rank_env(rank, local_rank, host_index, h, n_proc, n_hosts,
     }
 
 
+# TPU_PROCESS_BOUNDS for one process per chip, by the host's chip count
+# (the single-host topologies: v5e-4 is a 2x2, v5e-8 a 2x4)
+_TPU_PROCESS_BOUNDS = {4: "2,2,1", 8: "2,4,1"}
+_TPU_PROCESS_PORT = 8476  # libtpu's conventional base port
+
+
+def _tpu_slot_envs(host_list, extra_env):
+    """libtpu's own per-process variables, one dict per local rank, that
+    give every rank of a single local host ONE of its TPU chips — the
+    reference's one-rank-one-accelerator model (docs/tpus.md, "Chips vs
+    processes"). Without them each rank tries to open every chip and all
+    but the first fail. None when that does not apply: several hosts,
+    ranks pinned off the TPU by JAX_PLATFORMS, or a rank count that is
+    not the host's chip count. Chips are counted by their device files:
+    a launcher that asked JAX would hold the chips its children need."""
+    if len(host_list) != 1 or not hosts.is_local(host_list[0].hostname):
+        return None
+    platforms = (extra_env or {}).get("JAX_PLATFORMS",
+                                      os.environ.get("JAX_PLATFORMS", ""))
+    if platforms and "tpu" not in platforms.split(","):
+        return None
+    n = host_list[0].slots
+    chips = len(glob.glob("/dev/vfio/[0-9]*"))  # one file per v5e chip
+    if n < 2 or chips != n or n not in _TPU_PROCESS_BOUNDS:
+        return None
+    addresses = ",".join(f"localhost:{_TPU_PROCESS_PORT + i}"
+                         for i in range(n))
+    return [{"TPU_VISIBLE_CHIPS": i,
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": _TPU_PROCESS_BOUNDS[n],
+             "TPU_PROCESS_ADDRESSES": addresses,
+             "TPU_PROCESS_PORT": _TPU_PROCESS_PORT + i,
+             "CLOUD_TPU_TASK_ID": i} for i in range(n)]
+
+
 def run_command_on_hosts(host_list, command, coordinator_addr, settings,
                          output_dir=None, extra_env=None, cancel_event=None):
     """Spawn every worker, wait, propagate first failure. Returns exit
     code. Setting cancel_event terminates all workers (exit 130)."""
     n_proc = sum(h.slots for h in host_list)
+    tpu_slots = _tpu_slot_envs(host_list, extra_env)
     procs = []
     files = []
     exit_code = 0
@@ -153,6 +190,8 @@ def run_command_on_hosts(host_list, command, coordinator_addr, settings,
             for local_rank in range(h.slots):
                 env_over = _rank_env(rank, local_rank, host_index, h, n_proc,
                                      len(host_list), coordinator_addr)
+                if tpu_slots:
+                    env_over.update(tpu_slots[local_rank])
                 if extra_env:
                     env_over.update(extra_env)
                 stdout = stderr = None

@@ -219,15 +219,20 @@ class HBMLedger:
 
     @staticmethod
     def _detect_capacity():
-        try:
-            import jax
+        import jax
 
-            from . import costmodel
-            spec = costmodel.chip_spec(jax.devices()[0])
-            return getattr(spec, "hbm_capacity_bytes", None)
-        # hvdlint: disable=HVD006(capacity detection is best-effort; a ledger without capacity still attributes bytes, only headroom is absent)
-        except Exception:  # noqa: BLE001
+        from . import costmodel
+        device = jax.devices()[0]
+        spec = costmodel.chip_spec(device)
+        if spec is None:
+            # a ledger without capacity still attributes bytes, only
+            # headroom is absent — but an unknown chip is said out loud
+            log.warning(
+                "costmodel.CHIP_SPECS has no row for device_kind %r: the "
+                "HBM ledger publishes no capacity or headroom",
+                device.device_kind)
             return None
+        return spec.hbm_capacity_bytes
 
     @property
     def capacity_bytes(self):
@@ -445,13 +450,17 @@ class instrument_compiles:
 # GSPMD resharding sentinel
 # ---------------------------------------------------------------------------
 
-# `%all-gather.5 = f32[8,128]{1,0} all-gather(f32[4,128]{1,0} %p), ...,
-#  dimensions={0}` — post-optimization HLO text. We keep the parse
-# deliberately dumb: op kind, result shape, operand shapes, gather dim.
+# `%all-gather.5 = f32[8,128]{1,0} all-gather(%p), ..., dimensions={0}`
+# — post-optimization HLO text, which names its operands without their
+# shapes: those come from the operands' own definition lines. We keep
+# the parse deliberately dumb: op kind, result shape, operand shapes,
+# gather dim.
 _HLO_SHAPED_OP_RE = re.compile(
     r"=\s*(?:\([^)]*\)\s*)?([a-z][a-z0-9]*)\[([0-9,]*)\][^=]*?"
     r"\b(all-gather|collective-permute)\(")
-_HLO_OPERAND_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
+_HLO_DEF_RE = re.compile(
+    r"%([\w.\-]+)\s*=\s*[a-z][a-z0-9]*\[([0-9,]*)\]")
+_HLO_NAME_RE = re.compile(r"%([\w.\-]+)")
 _HLO_DIMS_RE = re.compile(r"dimensions=\{(\d+)\}")
 
 
@@ -460,14 +469,18 @@ def _parse_shape(text):
 
 
 def _iter_hlo_collectives(hlo_text):
-    for line in hlo_text.splitlines():
+    lines = hlo_text.splitlines()
+    shapes = {m.group(1): _parse_shape(m.group(2))
+              for m in map(_HLO_DEF_RE.search, lines) if m}
+    for line in lines:
         m = _HLO_SHAPED_OP_RE.search(line)
         if not m:
             continue
         result_shape = _parse_shape(m.group(2))
         op = m.group(3)
-        operands = [_parse_shape(om.group(2)) for om in
-                    _HLO_OPERAND_RE.finditer(line[m.end():])]
+        args = line[m.end():].split(")", 1)[0]
+        operands = [shapes[name] for name in _HLO_NAME_RE.findall(args)
+                    if name in shapes]
         dims = _HLO_DIMS_RE.search(line)
         yield {"op": op, "result_shape": result_shape,
                "operand_shapes": operands,

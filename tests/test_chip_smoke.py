@@ -1,0 +1,197 @@
+"""chip_smoke.py on the CPU: the same leg functions the chip runs, at
+TransformerConfig.tiny with interpreted kernels — plus the things only a
+test can pin: main() refuses a CPU, imports create no backend, the
+attention call sees its per-device shape under GSPMD, and Mosaic accepts
+the kernels at the serving prefill lengths."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402
+
+
+@pytest.fixture
+def tiny():
+    """TransformerConfig.tiny cut to one layer: every leg compiles its
+    model several times, and tier 1 pays for each."""
+    import dataclasses
+    import jax.numpy as jnp
+    from horovod_tpu.models import transformer as tr
+    return dataclasses.replace(
+        tr.TransformerConfig.tiny(attention_impl="flash",
+                                  dtype=jnp.float32), num_layers=1)
+
+
+def _last_json(capsys):
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_main_refuses_a_cpu(capsys):
+    assert chip_smoke.main([]) != 0
+    captured = capsys.readouterr()
+    assert captured.out == ""  # no result line without a chip
+    assert "needs a TPU" in captured.err
+
+
+def test_kernels_leg(hvd, capsys):
+    import jax.numpy as jnp
+    chip_smoke.leg_kernels([(1, 48, 2, 16)], atol=1e-4, dtype=jnp.float32)
+    assert _last_json(capsys)["leg"] == "kernels"
+
+
+def test_lm_train_and_four_chip_legs(tiny, capsys):
+    import horovod_tpu as hvd
+    try:
+        first = chip_smoke.leg_lm_train(tiny, 4, 32, on_chip=False)
+        line = _last_json(capsys)
+        assert line["leg"] == "lm_train" and line["compiles"] == 1
+        chip_smoke.leg_four_chips(tiny, 4, 32, first, on_chip=False,
+                                  steps=2)
+        line = _last_json(capsys)
+        tp = line["layouts"]["dp2_tp2"]
+        assert len(tp["tp_leaf_bytes_per_device"]) == 4
+    finally:
+        hvd.shutdown()
+
+
+@pytest.mark.slow  # ~60 s: a bf16 ResNet-18 compiles slowly on the CPU
+def test_resnet_leg(capsys):
+    import horovod_tpu as hvd
+    try:
+        chip_smoke.leg_resnet(model="resnet18", batch=2, image_size=32,
+                              steps=2)
+        assert _last_json(capsys)["leg"] == "resnet"
+    finally:
+        hvd.shutdown()
+
+
+def test_serve_leg(tiny, capsys):
+    chip_smoke.leg_serve(tiny, slots=2, max_len=32, kv_block=8,
+                         lengths=(3, 8, 12), tie_tol=1e-4)
+    line = _last_json(capsys)
+    assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
+
+
+def test_a_failing_leg_fails_the_run(monkeypatch, tmp_path):
+    """main() wraps no leg in try/except: what a leg raises reaches the
+    interpreter as a non-zero exit."""
+    import jax
+    from horovod_tpu.utils import compile_cache
+
+    class FakeTpu:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
+    # main() would switch this process's persistent compile cache on
+    monkeypatch.setattr(compile_cache, "configure", lambda: str(tmp_path))
+
+    def boom(*a, **k):
+        raise AssertionError("leg failed")
+    monkeypatch.setattr(chip_smoke, "leg_kernels", boom)
+    with pytest.raises(AssertionError, match="leg failed"):
+        chip_smoke.main(["--legs", "kernels"])
+
+
+def test_imports_create_no_backend():
+    """Importing the package, the launcher and the smoke itself must not
+    initialise a JAX backend: a parent that did would hold the chip its
+    children need (docs/tpus.md, "Chips vs processes")."""
+    code = (
+        "import horovod_tpu, horovod_tpu.run.cli, horovod_tpu.run.launch\n"
+        "import horovod_tpu.utils.compile_cache as cc, chip_smoke\n"
+        "cc.configure()\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    subprocess.run([sys.executable, "-c", code], cwd=ROOT, check=True,
+                   timeout=120)
+
+
+def _pallas_operand_shapes(jaxpr, out):
+    """Input shapes of every pallas_call in a (nested) jaxpr; inside a
+    shard_map body these are per-device shapes."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(tuple(eqn.invars[0].aval.shape))
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _pallas_operand_shapes(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("layout,per_device_bh", [
+    (dict(dp=4), 2 * 4),         # batch 8/4, all 4 heads
+    (dict(dp=2, tp=2), 4 * 2),   # batch 8/2, heads 4/2
+])
+def test_attention_runs_on_its_per_device_shape(tiny, layout, per_device_bh):
+    """Under make_gspmd_step the flash kernels must be handed each chip's
+    (batch/dp, heads/tp) slice: a bare pallas_call has no partitioning
+    rule (Mosaic refuses it outright on a TPU)."""
+    import jax
+    import bench_common
+    from horovod_tpu.parallel import mesh as mesh_mod
+
+    mesh = mesh_mod.build_mesh(devices=jax.devices()[:4], **layout)
+    step, params, opt_state, toks, _ = bench_common.build_transformer_step(
+        mesh, 8, 64, cfg=tiny)
+    shapes = _pallas_operand_shapes(
+        jax.make_jaxpr(step)(params, opt_state, toks).jaxpr, [])
+    head_dim = tiny.d_model // tiny.num_heads
+    assert len(shapes) == 3 * tiny.num_layers  # forward, dq, dkv
+    assert set(shapes) == {(per_device_bh, 64, head_dim)}
+
+
+def test_mosaic_accepts_the_kernels_without_a_chip():
+    """Ahead-of-time compile for a v5e topology (libtpu, no device): the
+    backward kernels at a sequence shorter than the 128-lane tile — the
+    serving prefill lengths — were refused by Mosaic before
+    flash_attention aligned its blocks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this environment
+        pytest.skip(f"no TPU topology available: {e}")
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=True, interpret=False)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    for seq in (16, 200):
+        arg = jax.ShapeDtypeStruct((1, seq, 2, 64), jnp.bfloat16,
+                                   sharding=sharding)
+        jax.jit(grads).trace(arg, arg, arg).lower(
+            lowering_platforms=("tpu",)).compile()
+
+
+def test_init_names_the_process_that_holds_the_chip(monkeypatch):
+    """What libtpu says when a second process opens a taken chip (seen on
+    the v5e) reaches the user as the supported process shapes."""
+    import jax
+    import horovod_tpu as hvd
+
+    def taken():
+        raise RuntimeError(
+            "Unable to initialize backend 'tpu': ABORTED: The TPU is "
+            "already in use by process with pid 3921. Not attempting to "
+            "load libtpu.so in this process.")
+    monkeypatch.setattr(jax, "devices", taken)
+    with pytest.raises(RuntimeError, match="pid 3921.*hvdrun -np <chips>"):
+        hvd.init()
+    assert not hvd.is_initialized()

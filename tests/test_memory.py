@@ -343,14 +343,18 @@ class TestReshardingSentinel:
         params = {"w": jax.ShapeDtypeStruct((8, 16), jnp.float32)}
         specs = {"w": P("tp", None)}
         hlo = "\n".join([
+            # operands are named, not typed: shapes come from these
+            "%p0 = f32[4,16]{1,0} parameter(0)",
+            "%p1 = f32[8,16]{1,0} parameter(1)",
+            "%p2 = f32[32,32]{1,0} parameter(2)",
             # gathers w's shard back to full: the finding
-            "%ag = f32[8,16]{1,0} all-gather(f32[4,16]{1,0} %p0), "
+            "%ag = f32[8,16]{1,0} all-gather(%p0), "
             "replica_groups={{0,1}}, dimensions={0}",
             # an activation all-reduce: same result shape family, no
             # (full, shard) param pair — silent
-            "%ar = f32[8,16]{1,0} all-reduce(f32[8,16]{1,0} %p1)",
+            "%ar = f32[8,16]{1,0} all-reduce(%p1)",
             # a batch-shaped gather matching no param leaf — silent
-            "%bg = f32[64,32]{1,0} all-gather(f32[32,32]{1,0} %p2), "
+            "%bg = f32[64,32]{1,0} all-gather(%p2), "
             "dimensions={0}",
         ])
         findings = hvd_memory.scan_resharding(hlo, params, specs, mesh,

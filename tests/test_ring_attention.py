@@ -5,7 +5,6 @@ no upstream equivalent exists)."""
 import numpy as np
 import pytest
 
-from horovod_tpu.common import compat
 
 
 def _make_qkv(b=2, s=32, h=4, d=8, seed=0):
@@ -20,7 +19,7 @@ def _run_sp(hvd, fn, q, k, v, n_sp=8):
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()[:n_sp]), ("sp",))
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp")))(q, k, v)
@@ -86,7 +85,7 @@ def test_ring_attention_grad_flows(hvd):
 
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()), ("sp",))
-    g_ring = jax.jit(compat.shard_map(
+    g_ring = jax.jit(jax.shard_map(
         jax.grad(loss_ring, argnums=0), mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp")))(q, k, v)
@@ -139,7 +138,7 @@ class TestRingFlash:
                 q, k, v, causal=causal) ** 2)
 
         mesh = Mesh(np.asarray(jax.devices()), ("sp",))
-        g_ring = jax.jit(compat.shard_map(
+        g_ring = jax.jit(jax.shard_map(
             jax.grad(loss_ring, argnums=(0, 1, 2)), mesh=mesh,
             in_specs=(P(None, "sp"),) * 3,
             out_specs=(P(None, "sp"),) * 3))(q, k, v)
@@ -168,7 +167,7 @@ def test_ulysses_grad_matches_full(hvd):
         return jnp.sum(ring.ulysses_attention(q, k, v) ** 2)
 
     mesh = Mesh(np.asarray(jax.devices()), ("sp",))
-    g_uly = jax.jit(compat.shard_map(
+    g_uly = jax.jit(jax.shard_map(
         jax.grad(loss_uly, argnums=(0, 1, 2)), mesh=mesh,
         in_specs=(P(None, "sp"),) * 3,
         out_specs=(P(None, "sp"),) * 3))(q, k, v)
@@ -210,7 +209,7 @@ class TestRingFlashWireVolume:
             return jnp.sum(out.astype(jnp.float32))
 
         grad = jax.grad(loss, argnums=(0, 1, 2))
-        j = jax.jit(compat.shard_map(
+        j = jax.jit(jax.shard_map(
             grad, mesh=mesh,
             in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
             out_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp"))))

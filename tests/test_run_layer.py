@@ -200,6 +200,32 @@ class TestLocalLaunch:
         assert (tmp_path / "r0").read_text() == "2|0|127.0.0.1:12345"
         assert (tmp_path / "r1").read_text() == "2|1|127.0.0.1:12345"
 
+    def test_tpu_slot_envs_one_chip_per_rank(self, monkeypatch):
+        """A single local host whose chip count equals -np: every rank
+        gets libtpu's per-process variables for exactly one chip
+        (docs/tpus.md). Anything else — CPU-pinned ranks, a rank count
+        that is not the chip count, several hosts — gets none."""
+        from horovod_tpu.run import cli
+        chips = [f"/dev/vfio/{i}" for i in range(4)]
+        monkeypatch.setattr(
+            cli.glob, "glob",
+            lambda pat: chips if pat == "/dev/vfio/[0-9]*" else [])
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        four = hosts.parse_hosts("localhost:4")
+        envs = cli._tpu_slot_envs(four, None)
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == [0, 1, 2, 3]
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+        assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+        assert all(e["TPU_PROCESS_ADDRESSES"].count("localhost:") == 4
+                   for e in envs)
+        assert cli._tpu_slot_envs(four, {"JAX_PLATFORMS": "cpu"}) is None
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert cli._tpu_slot_envs(four, None) is not None
+        assert cli._tpu_slot_envs(hosts.parse_hosts("localhost:2"),
+                                  None) is None
+        assert cli._tpu_slot_envs(
+            hosts.parse_hosts("localhost:4,otherhost:4"), None) is None
+
     def test_failure_propagates(self):
         rc = run_command_on_hosts(
             hosts.parse_hosts("localhost:2"),
