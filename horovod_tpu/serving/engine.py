@@ -227,6 +227,10 @@ class ServeEngine:
         self._gauge_interval = config.env_float(
             "SERVE_METRICS_INTERVAL_S", 1.0)
         self._last_gauge_ts = -1e30
+        self._slow_tick_us = serve_tracing.slow_tick_us()
+        # the record of the step in progress (serving/tracing.py
+        # StepTrace); the shared null object between steps
+        self._rec = serve_tracing.NULL_STEP
 
     # -- submission -----------------------------------------------------
 
@@ -256,21 +260,33 @@ class ServeEngine:
 
     def step(self):
         """One scheduler iteration. Returns the requests that finished
-        during it (as RequestResults, also kept on self.results)."""
-        self._heartbeat()
-        self._maybe_swap()
-        dirty = self._admit()
-        self.scheduler.begin_wave()
-        dirty |= self._decode()
-        self._refresh_gauges(force=dirty)
-        # Alerting + durable history ride the serve tick too
-        # (docs/alerts.md) — interval-throttled clock compares, on the
-        # engine's clock so drills with virtual time drive them.
-        now = self._clock()
-        hvd_history.poke(now)
-        hvd_alerts.tick(now)
-        done, self._finished = self._finished, []
-        return done
+        during it (as RequestResults, also kept on self.results).
+
+        The step writes its own record (serving/tracing.py StepTrace):
+        every statement below runs under one of STEP_PHASES, and the
+        timing itself stays in that module (hvdlint HVD014)."""
+        rec = self._rec = serve_tracing.begin_step()
+        try:
+            with rec.phase("control"):
+                self._heartbeat()
+                self._maybe_swap()
+            dirty = self._admit()
+            self.scheduler.begin_wave()
+            dirty |= self._decode()
+            with rec.phase("telemetry"):
+                self._refresh_gauges(force=dirty)
+                # Alerting + durable history ride the serve tick too
+                # (docs/alerts.md) — interval-throttled clock compares,
+                # on the engine's clock so drills with virtual time
+                # drive them.
+                now = self._clock()
+                hvd_history.poke(now)
+                hvd_alerts.tick(now)
+            done, self._finished = self._finished, []
+            return done
+        finally:
+            self._rec = serve_tracing.NULL_STEP
+            rec.finish()
 
     def run_to_completion(self, max_steps=100000):
         """Drive step() until queue and batch are empty; the engine's
@@ -451,13 +467,25 @@ class ServeEngine:
     def _admit(self):
         admitted = False
         while self.scheduler.can_join():
-            req = self.queue.pop()
+            with self._rec.phase("admit"):
+                req = self._pop_admissible()
             if req is None:
                 break
+            self._prefill(req)
+            admitted = True
+        return admitted
+
+    def _pop_admissible(self):
+        """The next queued request the cache can hold for its whole
+        life, or None: the queue is empty, or its head has to wait for
+        retirements (requeued). Requests that can never fit are failed
+        on the way."""
+        while True:
+            req = self.queue.pop()
+            if req is None:
+                return None
             prompt_len = len(req.prompt)
-            # cache rows needed over the request's whole life: the final
-            # generated token is sampled but never written back
-            final_len = prompt_len + max(req.max_new_tokens - 1, 0)
+            final_len = self._final_len(req)
             if (prompt_len == 0 or final_len > self.kv.max_len or
                     self.kv.ledger._blocks_for(final_len) >
                     self.kv.ledger.total_blocks):
@@ -479,124 +507,150 @@ class ServeEngine:
                 # optimistic admit would decode for a while and then die
                 # kv_exhausted when a later joiner took the headroom.
                 self.queue.requeue(req)
-                break
-            self._prefill(req, prompt_len, final_len)
-            admitted = True
-        return admitted
+                return None
+            return req
 
-    def _prefill(self, req, prompt_len, final_len):
-        slot = self.scheduler.join(req.request_id)
-        trace = serve_tracing.trace_of(req)
-        trace.on_prefill_start(slot, prompt_len)
-        self.kv.ledger.alloc_at(slot, prompt_len, reserve=final_len)
-        s_pad = self._pad_len(prompt_len)
-        tokens = np.zeros((1, s_pad), np.int32)
-        tokens[0, :prompt_len] = req.prompt
-        rng = jax.random.fold_in(self._rng, self._step_count)
-        self._step_count += 1
-        # compile observability: each distinct padded prompt length is
-        # a real prefill recompile; a churn of them is the storm the
-        # tracker names (docs/memory.md)
-        if hvd_memory.enabled():
-            hvd_memory.get_tracker().observe("serve_prefill", (tokens,))
-        tok, pk, pv = _prefill_jit(
-            self.cfg, self.params, jnp.asarray(tokens),
-            jnp.int32(prompt_len - 1), jnp.float32(req.temperature), rng)
-        self.kv.k, self.kv.v = _write_slot(self.kv.k, self.kv.v, pk, pv,
-                                           jnp.int32(slot))
-        # the one sanctioned per-prefill readback: the first token
-        # hvdlint: disable=HVD011(first-token sample is the prefill's output)
-        first = int(jax.device_get(tok))
-        now = self._clock()
-        self._active[slot] = _Active(req, first, prompt_len, now,
-                                     generation=self._generation)
-        trace.on_prefill_end(ttft_s=self._active[slot].ttft_s)
-        trace.annotate(generation=self._generation)
-        self._m_tokens.labels(phase="prefill").inc(prompt_len)
-        self._m_tokens.labels(phase="decode").inc()
-        self._m_ttft.observe(self._active[slot].ttft_s)
-        self._metrics.event("serve_admit", request_id=req.request_id,
-                            slot=slot, prompt_len=prompt_len,
-                            trace_id=trace.trace_id,
-                            generation=self._generation,
-                            ttft_s=round(self._active[slot].ttft_s, 6))
-        if req.max_new_tokens <= 1:
-            self._retire(slot, "completed")
+    @staticmethod
+    def _final_len(req):
+        # cache rows needed over the request's whole life: the final
+        # generated token is sampled but never written back
+        return len(req.prompt) + max(req.max_new_tokens - 1, 0)
+
+    def _prefill(self, req):
+        rec = self._rec
+        with rec.phase("prefill"):
+            prompt_len = len(req.prompt)
+            slot = self.scheduler.join(req.request_id)
+            trace = serve_tracing.trace_of(req)
+            trace.on_prefill_start(slot, prompt_len)
+            self.kv.ledger.alloc_at(slot, prompt_len,
+                                    reserve=self._final_len(req))
+            s_pad = self._pad_len(prompt_len)
+            tokens = np.zeros((1, s_pad), np.int32)
+            tokens[0, :prompt_len] = req.prompt
+            rng = jax.random.fold_in(self._rng, self._step_count)
+            self._step_count += 1
+            # compile observability: each distinct padded prompt length
+            # is a real prefill recompile; a churn of them is the storm
+            # the tracker names (docs/memory.md)
+            if hvd_memory.enabled():
+                hvd_memory.get_tracker().observe("serve_prefill",
+                                                 (tokens,))
+            tok, pk, pv = _prefill_jit(
+                self.cfg, self.params, jnp.asarray(tokens),
+                jnp.int32(prompt_len - 1), jnp.float32(req.temperature),
+                rng)
+            self.kv.k, self.kv.v = _write_slot(self.kv.k, self.kv.v, pk,
+                                               pv, jnp.int32(slot))
+            rec.count("admitted")
+            rec.count("prompt_tokens", prompt_len)
+        with rec.phase("prefill_readback"):
+            # the one sanctioned per-prefill readback: the first token
+            # hvdlint: disable=HVD011(first-token sample is the prefill's output)
+            first = int(jax.device_get(tok))
+        with rec.phase("bookkeeping"):
+            now = self._clock()
+            self._active[slot] = _Active(req, first, prompt_len, now,
+                                         generation=self._generation)
+            trace.on_prefill_end(ttft_s=self._active[slot].ttft_s)
+            trace.annotate(generation=self._generation)
+            self._m_tokens.labels(phase="prefill").inc(prompt_len)
+            self._m_tokens.labels(phase="decode").inc()
+            self._m_ttft.observe(self._active[slot].ttft_s)
+            self._metrics.event(
+                "serve_admit", request_id=req.request_id, slot=slot,
+                prompt_len=prompt_len, trace_id=trace.trace_id,
+                generation=self._generation,
+                ttft_s=round(self._active[slot].ttft_s, 6))
+            if req.max_new_tokens <= 1:
+                self._retire(slot, "completed")
 
     def _decode(self):
         if not self._active:
             return False
-        # one span per fused step, its duration attributed to every
-        # request active during the tick (serving/tracing.py)
-        tick = serve_tracing.tick_span(**self.scheduler.snapshot())
-        in_tick = list(self._active.values())
-        S = self.kv.num_slots
-        # Cohort-partitioned decode (docs/fleet.md): a request decodes
-        # on the weights that admitted it, across any hot swap, so each
-        # live generation runs its own fused pass over ALL slots with
-        # its own params. Non-cohort rows park their K/V write at
-        # max_len-1, where the length mask hides the garbage until the
-        # row's own pass overwrites it with the real value — each pass
-        # writes then attends, so even a final-token write at max_len-1
-        # is read only after it lands. Between swaps there is exactly
-        # one cohort and this is the same single fused call as always.
-        cohorts = {}
-        for slot, st in self._active.items():
-            cohorts.setdefault(st.generation, []).append(slot)
-        sampled = {}
+        rec = self._rec
+        with rec.phase("decode_prepare"):
+            # one span per fused step, its duration attributed to every
+            # request active during the tick (serving/tracing.py)
+            tick = rec.tick_span(**self.scheduler.snapshot())
+            in_tick = list(self._active.values())
+            S = self.kv.num_slots
+            # Cohort-partitioned decode (docs/fleet.md): a request
+            # decodes on the weights that admitted it, across any hot
+            # swap, so each live generation runs its own fused pass over
+            # ALL slots with its own params. Non-cohort rows park their
+            # K/V write at max_len-1, where the length mask hides the
+            # garbage until the row's own pass overwrites it with the
+            # real value — each pass writes then attends, so even a
+            # final-token write at max_len-1 is read only after it
+            # lands. Between swaps there is exactly one cohort and this
+            # is the same single fused call as always.
+            cohorts = {}
+            for slot, st in self._active.items():
+                cohorts.setdefault(st.generation, []).append(slot)
+            rec.count("active", len(in_tick))
+            rec.count("cohorts", len(cohorts))
+        ids = {}  # generation -> that pass's sampled ids, every slot
         for gen in sorted(cohorts):
-            tokens = np.zeros(S, np.int32)
-            positions = np.full(S, self.kv.max_len - 1, np.int32)
-            temps = np.zeros(S, np.float32)
-            for slot in cohorts[gen]:
+            with rec.phase("decode_prepare"):
+                tokens = np.zeros(S, np.int32)
+                positions = np.full(S, self.kv.max_len - 1, np.int32)
+                temps = np.zeros(S, np.float32)
+                for slot in cohorts[gen]:
+                    st = self._active[slot]
+                    tokens[slot] = st.next_token
+                    positions[slot] = st.next_pos
+                    temps[slot] = st.request.temperature
+                rng = jax.random.fold_in(self._rng, self._step_count)
+                self._step_count += 1
+                # decode is shape-static by construction: one miss at
+                # the first step, hits forever — a second miss here IS
+                # the bug
+                if hvd_memory.enabled():
+                    hvd_memory.get_tracker().observe(
+                        "serve_decode", (tokens, positions, temps))
+                tokens, positions, temps = (
+                    jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(temps))
+            with rec.phase("decode_dispatch"):
+                nxt, self.kv.k, self.kv.v = _decode_jit(
+                    self.cfg, self._params_by_gen[gen], tokens,
+                    positions, self.kv.k, self.kv.v, temps, rng)
+            with rec.phase("decode_readback"):
+                # the one sanctioned per-step readback (one per cohort
+                # during a swap transition): this pass's sampled ids
+                # hvdlint: disable=HVD011(the per-step batched token readback)
+                ids[gen] = np.asarray(jax.device_get(nxt))
+        with rec.phase("telemetry"):
+            tick_us = serve_tracing.finish_tick(tick, len(in_tick),
+                                                self._slow_tick_us)
+            for st in in_tick:
+                serve_tracing.trace_of(st.request).on_decode_tick(tick_us)
+        with rec.phase("bookkeeping"):
+            now = self._clock()
+            for slot in list(self._active):
                 st = self._active[slot]
-                tokens[slot] = st.next_token
-                positions[slot] = st.next_pos
-                temps[slot] = st.request.temperature
-            rng = jax.random.fold_in(self._rng, self._step_count)
-            self._step_count += 1
-            # decode is shape-static by construction: one miss at the
-            # first step, hits forever — a second miss here IS the bug
-            if hvd_memory.enabled():
-                hvd_memory.get_tracker().observe(
-                    "serve_decode", (tokens, positions, temps))
-            nxt, self.kv.k, self.kv.v = _decode_jit(
-                self.cfg, self._params_by_gen[gen], jnp.asarray(tokens),
-                jnp.asarray(positions), self.kv.k, self.kv.v,
-                jnp.asarray(temps), rng)
-            # the one sanctioned per-step readback (one per cohort
-            # during a swap transition): this pass's sampled ids
-            # hvdlint: disable=HVD011(the per-step batched token readback)
-            ids = np.asarray(jax.device_get(nxt))
-            for slot in cohorts[gen]:
-                sampled[slot] = int(ids[slot])
-        tick_us = serve_tracing.finish_tick(tick,
-                                            active_slots=len(in_tick))
-        for st in in_tick:
-            serve_tracing.trace_of(st.request).on_decode_tick(tick_us)
-        now = self._clock()
-        for slot in list(self._active):
-            st = self._active[slot]
-            # the fed token's K/V landed at next_pos this step
-            if not self.kv.ledger.grow(slot, st.next_pos + 1):
-                self._retire(slot, "failed", reason="kv_exhausted")
-                continue
-            tok = sampled[slot]
-            st.generated.append(tok)
-            st.next_token = tok
-            st.next_pos += 1
-            self._m_intertoken.observe(now - st.last_token_ts)
-            st.last_token_ts = now
-            self._m_tokens.labels(phase="decode").inc()
-            req = st.request
-            if len(st.generated) >= req.max_new_tokens:
-                self._retire(slot, "completed")
-            elif (req.deadline_s is not None and
-                    now - req.arrival_ts > req.deadline_s):
-                self._retire(slot, "failed", reason="deadline")
+                # the fed token's K/V landed at next_pos this step
+                if not self.kv.ledger.grow(slot, st.next_pos + 1):
+                    self._retire(slot, "failed", reason="kv_exhausted")
+                    continue
+                tok = int(ids[st.generation][slot])
+                st.generated.append(tok)
+                st.next_token = tok
+                st.next_pos += 1
+                self._m_intertoken.observe(now - st.last_token_ts)
+                st.last_token_ts = now
+                self._m_tokens.labels(phase="decode").inc()
+                req = st.request
+                if len(st.generated) >= req.max_new_tokens:
+                    self._retire(slot, "completed")
+                elif (req.deadline_s is not None and
+                        now - req.arrival_ts > req.deadline_s):
+                    self._retire(slot, "failed", reason="deadline")
         return True
 
     def _retire(self, slot, outcome, reason=""):
+        self._rec.count("retired")
         st = self._active.pop(slot)
         self.kv.ledger.free(slot)
         self.scheduler.retire(slot)

@@ -35,14 +35,25 @@ ring, a ``serve_failover`` dump automatically contains every in-flight
 request's open spans — hvd_postmortem names them — and the Perfetto
 export lanes the closed ones per batch slot (hvd_slo --trace).
 
+A request's trace says where the REQUEST waited; the inside of one
+``ServeEngine.step()`` is the step record's (``StepTrace``): the step's
+phases on the same clock, each also a ``jax.profiler.TraceAnnotation``
+(``hvd.serve.<phase>`` under ``hvd.serve.step``) so that a profile shows
+them on the device's timeline, kept as ONE record a step in the
+tracer's step ring. A ``decode_tick`` span carries ``step=<seq>``, so a
+request's trace names the steps it rode.
+
 Default ON; ``HVD_SERVE_TRACE=0`` (or ``HVD_TRACE=0``) reduces every
-call here to a shared null object. Overhead is bench-gated at <=2% of
-the serving leg (bench.py, HVD_BENCH_SERVE_TRACE).
+call here to a shared null object; the engine reads the switch once a
+step (``begin_step``). Measured cost on the v5e, tracing on against
+``HVD_SERVE_TRACE=0`` on the same seeds: PERF.md §6, PR 24.
 
 This module is the ONE sanctioned place for request timing in
 ``serving/`` — hvdlint HVD014 flags ad-hoc ``time.*`` deltas on
 request objects anywhere else in the package.
 """
+
+from jax.profiler import TraceAnnotation
 
 from ..common import config
 from ..utils import metrics as hvd_metrics
@@ -297,27 +308,140 @@ def route_span(**attrs):
     return hvd_tracing.get_tracer().span(hvd_tracing.ROUTE, **attrs)
 
 
-def tick_span(**attrs):
-    """One span per fused decode step (the engine-wide lane)."""
-    if not enabled():
-        return hvd_tracing._NULL_SPAN
-    return hvd_tracing.get_tracer().span(hvd_tracing.DECODE_TICK,
-                                         **attrs)
+def slow_tick_us():
+    """``HVD_SERVE_TRACE_SLOW_TICK_MS`` in µs; the engine reads it once,
+    when it is built, and hands it to every ``finish_tick``."""
+    return config.env_float("SERVE_TRACE_SLOW_TICK_MS", 250.0) * 1e3
 
 
-def finish_tick(span, active_slots=0):
+def finish_tick(span, active_slots, slow_us):
     """Close a decode-tick span; returns its duration in µs (0 when
     tracing is off) and emits a ``slow_decode_tick`` event past
-    HVD_SERVE_TRACE_SLOW_TICK_MS — the per-step analogue of the
-    tracer's slow_span escalation."""
+    ``slow_us`` (HVD_SERVE_TRACE_SLOW_TICK_MS) — the per-step analogue
+    of the tracer's slow_span escalation."""
     span.close(active=active_slots)
     if span.end_us is None:
         return 0.0
     dur_us = span.end_us - span.start_us
-    slow_ms = config.env_float("SERVE_TRACE_SLOW_TICK_MS", 250.0)
-    if dur_us >= slow_ms * 1e3:
+    if dur_us >= slow_us:
         reg = hvd_metrics.get_registry()
         if reg.enabled:
             reg.event("slow_decode_tick", active=active_slots,
                       dur_ms=round(dur_us / 1e3, 3))
     return dur_us
+
+
+# -- the inside of one engine step --------------------------------------------
+
+# Phase names of a step record, in the order a step that admits and
+# decodes first meets them; the benchmark's readers use them letter for
+# letter (benchmarks/lib/step_phases.py). What each covers: docs/tracing.md.
+STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
+               "decode_prepare", "decode_dispatch", "decode_readback",
+               "bookkeeping", "telemetry")
+# counts of a step record, taken where the work happens
+STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens")
+_STEP_ANNOTATION = "hvd.serve.step"
+_PHASE_ANNOTATIONS = {p: "hvd.serve." + p for p in STEP_PHASES}
+
+
+class StepTrace:
+    """The record of one ``ServeEngine.step()``, written from inside it.
+
+    ``with step.phase(name):`` marks a phase. Phases do not nest; each
+    starts where the one before it ended (ONE clock read a phase, at its
+    end), so together they tile ``[start_us, end_us]`` with no hole: the
+    few instructions between two ``with`` blocks go to the later one. A
+    name may come several times in a step (one ``prefill`` per admitted
+    request); two of one name in a row are one entry. Each phase is also
+    a ``hvd.serve.<phase>`` TraceAnnotation and the step a
+    ``hvd.serve.step`` one: free with no profile being taken, and under
+    one the same phases on the profiler's clock. ``finish()`` makes the
+    step one record in the tracer's step ring (``Tracer.steps()``, the
+    flight dump's ``steps``).
+    """
+
+    __slots__ = ("_tracer", "_clock", "_step_annotation",
+                 "_phase_annotation", "_name", "seq", "start_us",
+                 "end_us", "phases", "counts")
+
+    def __init__(self, tracer):
+        self._tracer = tracer
+        self._clock = tracer.clock
+        self.seq = tracer.next_step_seq()
+        # a TraceAnnotation starts when it is made, not when entered
+        self._step_annotation = TraceAnnotation(_STEP_ANNOTATION)
+        self._phase_annotation = self._name = None
+        self.start_us = self.end_us = self._clock.ts_us()
+        self.phases = []
+        self.counts = dict.fromkeys(STEP_COUNTS, 0)
+
+    def phase(self, name):
+        self._phase_annotation = TraceAnnotation(_PHASE_ANNOTATIONS[name])
+        self._name = name
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        end = self._clock.ts_us()
+        self._phase_annotation.__exit__(exc_type, exc, tb)
+        if self.phases and self.phases[-1][0] == self._name:
+            self.phases[-1][2] = end
+        else:
+            self.phases.append([self._name, self.end_us, end])
+        self.end_us = end
+        return False
+
+    def count(self, name, n=1):
+        self.counts[name] += n
+
+    def tick_span(self, **attrs):
+        """The step's one ``decode_tick`` span (the engine-wide lane),
+        naming the step record it belongs to."""
+        return self._tracer.span(hvd_tracing.DECODE_TICK, step=self.seq,
+                                 **attrs)
+
+    def finish(self):
+        self._step_annotation.__exit__(None, None, None)
+        rec = {"seq": self.seq, "start_us": self.start_us,
+               "end_us": self.end_us, "phases": self.phases}
+        rec.update(self.counts)
+        self._tracer.record_step(rec)
+
+
+class _NullStepTrace:
+    """Absorbs a whole step when request tracing is off: no clock read,
+    no annotation, no record."""
+
+    seq = None
+
+    def phase(self, name):
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def count(self, name, n=1):
+        pass
+
+    def tick_span(self, **attrs):
+        return hvd_tracing._NULL_SPAN
+
+    def finish(self):
+        pass
+
+
+NULL_STEP = _NullStepTrace()
+
+
+def begin_step():
+    """Open the record of the engine step that is starting; the ONE read
+    of the switch in a step (its tick span comes from the record)."""
+    if not enabled():
+        return NULL_STEP
+    return StepTrace(hvd_tracing.get_tracer())

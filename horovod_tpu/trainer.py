@@ -75,7 +75,8 @@ def instrument_step(step_fn, tokens_per_step=None, name="train",
     same wrapper: every call reports its abstract-shape key to the
     compile tracker under site ``train:<name>`` (the recompile-storm
     signal), and ``hvd_step_peak_hbm_bytes`` tracks the allocator's
-    peak next to ``hvd_mfu`` — nulled on CPU the same way, since CPU
+    peak (``peak_bytes_in_use + peak_bytes_reserved``) next to
+    ``hvd_mfu`` — nulled on CPU the same way, since CPU
     backends expose no allocator stats. Overhead is bench-gated ≤2%
     (``HVD_BENCH_MEM``).
 

@@ -109,14 +109,22 @@ def device_memory_stats(device=None):
 
 
 def step_peak_bytes(device=None):
-    """Peak allocated device bytes (``peak_bytes_in_use``), or None on
-    backends without allocator stats — the trainer nulls its
-    ``hvd_step_peak_hbm_bytes`` gauge exactly like the CPU MFU gauge."""
+    """Peak device bytes: ``peak_bytes_in_use + peak_bytes_reserved``, or
+    None on backends without allocator stats — the trainer nulls its
+    ``hvd_step_peak_hbm_bytes`` gauge exactly like the CPU MFU gauge.
+    On the v5e runtime the first field counts arrays (arguments,
+    results) and leaves out the scratch that loaded programs reserve
+    for their temporaries, which the second counts: the LM step reads
+    7.29 GB + 3.58 GB where ``memory_analysis()`` declares 7.27 GB of
+    arguments and 3.64 GB of temporaries (chip run, PR 23; PERF.md §6).
+    ``benchmarks/run.py:peak_bytes`` sums the same two."""
     stats = device_memory_stats(device)
     if not stats:
         return None
     peak = stats.get("peak_bytes_in_use", stats.get("bytes_in_use"))
-    return int(peak) if peak is not None else None
+    if peak is None:
+        return None
+    return int(peak) + int(stats.get("peak_bytes_reserved", 0))
 
 
 def live_array_bytes():

@@ -20,8 +20,10 @@ streams stitch into ONE cross-rank trace without any extra wire traffic
 On top of the span model sits the **flight recorder**: fixed-size rings
 of finished spans (``HVD_FLIGHT_SPANS``) and negotiation-cycle records
 (``HVD_FLIGHT_CYCLES``), generalizing the metrics registry's 256-event
-ring.  It is always on (``HVD_TRACE=0`` disables) and budgeted at <=2%
-overhead on the control-plane bench (bench.py asserts it).  On
+ring, plus a fixed ring of serve-engine step records (``STEP_RING``;
+serving/tracing.py).  It is always on (``HVD_TRACE=0`` disables) and
+budgeted at <=2% overhead on the control-plane bench (bench.py asserts
+it).  On
 ``RanksLostError``, stall escalation, chaos-drill failure or SIGTERM the
 ring auto-dumps one JSON file per rank under ``HVD_FLIGHT_DIR``; the
 coordinator can also solicit a remote rank's dump over the negotiation
@@ -77,6 +79,12 @@ SERVE_STAGES = (REQUEST, QUEUE_WAIT, PREFILL, DECODE, DECODE_TICK,
                 HEARTBEAT, ROUTE)
 STAGES = (ENQUEUE, NEGOTIATE, FUSION, EXECUTE, CALLBACK, STEP,
           CYCLE) + SERVE_STAGES
+
+# Serve-engine step records (serving/tracing.py StepTrace) live in a
+# ring of their own, like the cycle records: one a step would push the
+# request spans out of the span ring within a minute. Fixed: two
+# minutes of steps at the 35 a second the serving cell runs (PERF.md).
+STEP_RING = 4096
 
 
 class Span:
@@ -218,6 +226,9 @@ class Tracer:
         # guarded_by: _lock (coordinator cycle ring)
         self._cycles = collections.deque(
             maxlen=cycle_ring or env_int("FLIGHT_CYCLES", 64))
+        # guarded_by: _lock (serve-engine step ring)
+        self._steps = collections.deque(maxlen=STEP_RING)
+        self._step_seq = 0  # guarded_by: _lock
         self._open = collections.OrderedDict()  # guarded_by: _lock
         self._last_trace = {}     # guarded_by: _lock; tensor -> trace_id
         self._spans_dropped = 0   # guarded_by: _lock
@@ -319,16 +330,39 @@ class Tracer:
         with self._lock:
             return list(self._cycles)
 
+    # -- serve-engine step records --
+
+    def next_step_seq(self):
+        """Number the engine step that is starting: the ``seq`` of its
+        record and the ``step`` attr of its ``decode_tick`` span."""
+        with self._lock:
+            self._step_seq += 1
+            return self._step_seq
+
+    def record_step(self, rec):
+        """Append one finished engine step (serving/tracing.py
+        ``StepTrace.finish``: seq, start_us, end_us, phases, counts) to
+        the step ring; the oldest falls out past ``STEP_RING``."""
+        with self._lock:
+            self._steps.append(rec)
+        return rec
+
+    def steps(self):
+        with self._lock:
+            return list(self._steps)
+
     # -- flight dump --
 
     def flight_snapshot(self, reason=""):
         """JSON-serializable flight-recorder state: finished + still-open
-        spans, cycle records, and the metrics event ring (stalls, chaos
-        injections, lost ranks — the context the spans ran in)."""
+        spans, cycle and serve-step records, and the metrics event ring
+        (stalls, chaos injections, lost ranks — the context the spans
+        ran in)."""
         with self._lock:
             spans = list(self._spans)
             open_spans = [s.to_dict() for s in self._open.values()]
             cycles = list(self._cycles)
+            steps = list(self._steps)
             dropped = self._spans_dropped
         reg = metrics_mod.get_registry()
         # Memory-plane section (docs/memory.md): HBM ledger components +
@@ -345,6 +379,7 @@ class Tracer:
             "spans": spans,
             "open_spans": open_spans,
             "cycles": cycles,
+            "steps": steps,
             "spans_dropped": dropped,
             "events": reg.events(),
             "memory": memory_mod.flight_section(),
@@ -406,11 +441,14 @@ class NullTracer:
     def cycles(self):
         return []
 
+    def steps(self):
+        return []
+
     def flight_snapshot(self, reason=""):
         return {"version": FLIGHT_VERSION, "rank": None, "reason": reason,
                 "ts_us": self.clock.ts_us(),
                 "epoch_us_at_ts0": self.clock.epoch_us_at_ts0,
-                "spans": [], "open_spans": [], "cycles": [],
+                "spans": [], "open_spans": [], "cycles": [], "steps": [],
                 "spans_dropped": 0, "events": [], "disabled": True}
 
     def dump(self, reason="", path=None):

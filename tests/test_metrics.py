@@ -495,6 +495,28 @@ class TestSatelliteInstrumentation:
         assert peak["labels"] == {"loop": "unit"}
         assert peak["value"] == 12345
 
+    def test_step_peak_sums_in_use_and_reserved(self, reg, monkeypatch):
+        # on the v5e runtime peak_bytes_in_use leaves out the scratch
+        # of loaded programs, which peak_bytes_reserved counts (PERF.md
+        # §6): the gauge publishes their sum, as benchmarks/run.py does
+        from horovod_tpu import trainer
+        from horovod_tpu.utils import memory as hvd_memory
+        monkeypatch.setattr(
+            hvd_memory, "device_memory_stats",
+            lambda device=None: {"bytes_in_use": 5,
+                                 "peak_bytes_in_use": 7_290,
+                                 "peak_bytes_reserved": 3_580})
+        assert hvd_memory.step_peak_bytes() == 10_870
+        wrapped = trainer.instrument_step(lambda x: x, name="unit")
+        wrapped(1)
+        (peak,) = reg.snapshot()["metrics"][
+            "hvd_step_peak_hbm_bytes"]["values"]
+        assert peak["value"] == 10_870
+        # a runtime without the reserved field reads the first alone
+        monkeypatch.setattr(hvd_memory, "device_memory_stats",
+                            lambda device=None: {"peak_bytes_in_use": 9})
+        assert hvd_memory.step_peak_bytes() == 9
+
     def test_instrument_step_no_peak_gauge_on_cpu(self, reg):
         # the CPU-null arm, mirroring the MFU gauge: no allocator
         # stats → the gauge is never created, not created-as-zero

@@ -6,6 +6,7 @@ requests, and the acceptance drill — inject a synthetic slow phase
 (delayed prefill, forced KV-pressure requeue) and assert the hvd_slo
 tail verdict names it."""
 
+import json
 import os
 import sys
 import time
@@ -345,6 +346,198 @@ class TestEngineIntegration:
         tracer = hvd_tracing.get_tracer()
         assert not [s for s in tracer.spans()
                     if s["stage"] in hvd_tracing.SERVE_STAGES]
+
+
+# ---------------------------------------------------------------------------
+# the step record: one per engine step, written from inside the step
+# ---------------------------------------------------------------------------
+
+class SteppingUsClock(FakeUsClock):
+    """Every read is one tick later than the last: intervals count the
+    clock reads inside them, so the record's numbers are exact."""
+
+    TICK = 1_000
+
+    def ts_us(self):
+        self.now_us += self.TICK
+        return self.now_us
+
+
+@pytest.fixture
+def stepping(reg, monkeypatch):
+    """The process tracer on a stepping clock (``reg`` restores it)."""
+    tracer = hvd_tracing.Tracer(rank=0, clock=SteppingUsClock())
+    monkeypatch.setattr(hvd_tracing, "_tracer", tracer)
+    return tracer
+
+
+def _tiles(rec):
+    """The phases cover [start_us, end_us] with no overlap and no hole."""
+    edges = [rec["start_us"]]
+    for name, start, end in rec["phases"]:
+        assert name in serve_tracing.STEP_PHASES
+        assert start == edges[-1] and end > start, rec["phases"]
+        edges.append(end)
+    return edges[-1] == rec["end_us"]
+
+
+class TestStepTrace:
+    def test_a_step_that_admits_two_and_decodes_tiles_exactly(
+            self, stepping):
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        joins, retires = [], []
+        sched = engine.scheduler
+        join, retire = sched.join, sched.retire
+        sched.join = lambda rid: joins.append(rid) or join(rid)
+        sched.retire = lambda slot: retires.append(slot) or retire(slot)
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=4))
+        engine.submit(Request("b", (2, 7, 1, 8, 2), max_new_tokens=3))
+        assert engine.step() == []
+        (rec,) = stepping.steps()
+        assert set(rec) == {"seq", "start_us", "end_us", "phases",
+                            *serve_tracing.STEP_COUNTS}
+        assert rec["seq"] == 1 and _tiles(rec)
+        one = ["admit", "prefill", "prefill_readback", "bookkeeping"]
+        assert [p[0] for p in rec["phases"]] == ["control"] + one + one + [
+            "decode_prepare", "decode_dispatch", "decode_readback",
+            "telemetry", "bookkeeping", "telemetry"]
+        # nothing but the phase's own closing read inside these
+        tick = SteppingUsClock.TICK
+        for name, start, end in rec["phases"]:
+            if name in ("control", "decode_dispatch", "decode_readback",
+                        "prefill_readback"):
+                assert end - start == tick, name
+        # the counts are what the scheduler did in that step
+        assert rec["admitted"] == len(joins) == 2
+        assert rec["active"] == 2 and rec["cohorts"] == 1
+        assert rec["prompt_tokens"] == 3 + 5
+        assert rec["retired"] == len(retires) == 0
+        done = engine.run_to_completion()
+        recs = stepping.steps()
+        assert [r["seq"] for r in recs] == list(range(1, len(recs) + 1))
+        assert all(_tiles(r) for r in recs)
+        assert sum(r["retired"] for r in recs) == len(retires) == \
+            len(done) == 2
+        assert sum(r["admitted"] for r in recs) == 2
+        # b retires in step 2, a in step 3: rows decoded 2, 2, 1
+        assert [r["active"] for r in recs] == [2, 2, 1]
+        assert [r["retired"] for r in recs] == [0, 1, 1]
+        # steps follow one another on the one clock
+        assert all(a["end_us"] <= b["start_us"]
+                   for a, b in zip(recs, recs[1:]))
+
+    def test_a_decode_tick_names_its_step_and_the_dump_holds_the_steps(
+            self, stepping):
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=4))
+        engine.run_to_completion()
+        recs = stepping.steps()
+        ticks = [s for s in stepping.spans()
+                 if s["stage"] == hvd_tracing.DECODE_TICK]
+        assert [t["attrs"]["step"] for t in ticks] == \
+            [r["seq"] for r in recs if r["active"]]
+        by_seq = {r["seq"]: r for r in recs}
+        for t in ticks:  # the tick lies inside the step it names
+            r = by_seq[t["attrs"]["step"]]
+            assert r["start_us"] < t["start_us"] < t["end_us"] < r["end_us"]
+        dump = stepping.flight_snapshot("unit_test")
+        assert dump["steps"] == recs
+        json.dumps(dump)
+        # an idle step is a record too: it looked at an empty queue
+        engine.step()
+        idle = stepping.steps()[-1]
+        assert [p[0] for p in idle["phases"]] == ["control", "admit",
+                                                  "telemetry"]
+        assert not any(idle[c] for c in serve_tracing.STEP_COUNTS)
+
+    def test_the_ring_drops_the_oldest_past_4096(self):
+        tracer = hvd_tracing.Tracer(rank=0, clock=FakeUsClock())
+        assert hvd_tracing.STEP_RING == 4096
+        for i in range(hvd_tracing.STEP_RING + 5):
+            tracer.record_step({"seq": tracer.next_step_seq()})
+        seqs = [r["seq"] for r in tracer.steps()]
+        assert seqs == list(range(6, hvd_tracing.STEP_RING + 6))
+        # the step ring is its own: it pushes no span out
+        assert tracer.flight_snapshot()["spans_dropped"] == 0
+
+    def test_tracing_off_leaves_no_record_and_makes_no_annotation(
+            self, stepping, monkeypatch):
+        made = []
+
+        class Spy(serve_tracing.TraceAnnotation):
+            def __init__(self, name, **kw):
+                made.append(name)
+                super().__init__(name, **kw)
+        monkeypatch.setattr(serve_tracing, "TraceAnnotation", Spy)
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        monkeypatch.setenv("HVD_SERVE_TRACE", "0")
+        engine.submit(Request("off", (3, 1, 4), max_new_tokens=3))
+        (res,) = engine.run_to_completion()
+        assert res.outcome == "completed"
+        assert stepping.steps() == [] and made == []
+        assert serve_tracing.begin_step() is serve_tracing.NULL_STEP
+        assert stepping.clock.now_us == 0  # not one clock read
+        # the switch is read at each step: on again, the next step records
+        monkeypatch.setenv("HVD_SERVE_TRACE", "1")
+        engine.submit(Request("on", (3, 1, 4), max_new_tokens=3))
+        engine.run_to_completion()
+        recs = stepping.steps()
+        assert len(recs) == 2
+        assert made.count("hvd.serve.step") == 2
+        assert set(made) == {"hvd.serve.step"} | {
+            "hvd.serve." + p[0] for r in recs for p in r["phases"]}
+
+    def test_a_step_that_raises_still_closes_its_record(
+            self, stepping, monkeypatch):
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=3))
+
+        def boom(*a, **kw):
+            raise RuntimeError("device lost")
+        monkeypatch.setattr(engine_mod, "_prefill_jit", boom)
+        with pytest.raises(RuntimeError, match="device lost"):
+            engine.step()
+        (rec,) = stepping.steps()
+        assert [p[0] for p in rec["phases"]] == ["control", "admit",
+                                                 "prefill"]
+        assert _tiles(rec) and engine._rec is serve_tracing.NULL_STEP
+
+    def test_a_profile_shows_the_step_and_its_phases_on_the_host_line(
+            self, reg, tmp_path):
+        """Under ``jax.profiler.trace`` the annotations land in the
+        ``.xplane.pb`` on the Python thread's line, each phase inside
+        its ``hvd.serve.step``."""
+        import glob
+        import jax
+        from jax.profiler import ProfileData
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        engine.submit(Request("warm", (3, 1, 4), max_new_tokens=2))
+        engine.run_to_completion()  # compile outside the profile
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=4))
+        with jax.profiler.trace(str(tmp_path)):
+            engine.run_to_completion()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name == "/host:CPU"
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("hvd.serve.")]
+        steps = [e for e in events if e[0] == "hvd.serve.step"]
+        recs = hvd_tracing.get_tracer().steps()[-len(steps):]
+        assert len(steps) == 3 == len(recs)
+        names = {e[0] for e in events}
+        assert names == {"hvd.serve.step"} | {
+            "hvd.serve." + p[0] for r in recs for p in r["phases"]}
+        for name, start, end in events:
+            if name != "hvd.serve.step":
+                assert any(s <= start and end <= e for _, s, e in steps)
 
 
 # ---------------------------------------------------------------------------
