@@ -359,6 +359,15 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
            "the warm pass compiled")
     _check(all(warm[k].tokens == cold[k].tokens for k in cold),
            "the two passes served different tokens")
+    # no silent copying path either: both engines' cache-writing
+    # programs consumed the arrays they were given (docs/serving.md)
+    from horovod_tpu.utils import metrics as hvd_metrics
+    reg = hvd_metrics.get_registry()
+    kv_in_place = (reg.gauge("hvd_serve_kv_in_place").value
+                   if reg.enabled else None)  # None: HVD_METRICS=0
+    _check(kv_in_place != 0,
+           "the KV cache is copied every step, not updated in place "
+           "(a donation was dropped)")
 
     # reference: one full-attention forward over prompt + served tokens
     ref_cfg = dataclasses.replace(cfg, attention_impl="full")
@@ -391,6 +400,7 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
          greedy_exact=exact, greedy_ties=ties,
          worst_logit_deficit=round(worst, 5), tie_tol=tie_tol,
          prefill_compiles=compiled[0], decode_compiles=compiled[1],
+         kv_in_place=kv_in_place,
          setup_seconds=round(cold_s - warm_s, 2),
          request_seconds=round(warm_s, 3),
          ttft_seconds_warm=round(float(np.median(

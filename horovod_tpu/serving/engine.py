@@ -21,6 +21,7 @@ flag, no drift between the system and its baseline.
 """
 
 import functools
+import logging
 import time
 
 import jax
@@ -41,6 +42,8 @@ from .queue import AdmissionQueue, RequestResult
 from .sampling import sample_tokens
 from .scheduler import SlotScheduler
 
+log = logging.getLogger("horovod_tpu.serving")
+
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _prefill_jit(cfg, params, tokens, last_index, temperature, rng):
@@ -52,16 +55,19 @@ def _prefill_jit(cfg, params, tokens, last_index, temperature, rng):
     return tok, k, v
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
+# The two programs that rewrite the cache DONATE it (kv_cache.KVCache):
+# their cache outputs alias the inputs, so the one-row-a-slot scatter and
+# the one-slot write land in place, not behind a copy of each array.
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
 def _decode_jit(cfg, params, tokens, positions, kv_k, kv_v, temps, rng):
     logits, kv_k, kv_v = decode_step(cfg, params, tokens, positions,
                                      kv_k, kv_v)
     return sample_tokens(rng, logits, temps), kv_k, kv_v
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _write_slot(kv_k, kv_v, pk, pv, slot):
-    """Copy a prefill's K/V into cache row ``slot`` (dynamic index,
+    """Write a prefill's K/V into cache row ``slot`` (dynamic index,
     static prefix length from pk's shape)."""
     s_pad = pk.shape[2]
     kv_k = kv_k.at[:, slot, :s_pad].set(pk[:, 0])
@@ -185,6 +191,15 @@ class ServeEngine:
         self._m_blocks = reg.gauge(
             "hvd_serve_kv_blocks_in_use",
             "KV-cache blocks currently claimed by active slots.")
+        self._m_in_place = reg.gauge(
+            "hvd_serve_kv_in_place",
+            "1 once an engine's first slot write and first decode step "
+            "both consumed the cache arrays they were given (the cache "
+            "is updated in place); 0 when a donation was dropped and "
+            "every call copies the cache; no value before either.")
+        # cache-writing programs whose first call on this engine has
+        # yet to show that it consumed its arrays (_note_in_place)
+        self._in_place_unchecked = {"write_slot", "decode"}
         # SLO goodput accounting (docs/serving.md): a token only counts
         # as goodput when its request completed within its deadline;
         # everything else — deadline-blown, kv-exhausted, evicted — is
@@ -540,8 +555,11 @@ class ServeEngine:
                 self.cfg, self.params, jnp.asarray(tokens),
                 jnp.int32(prompt_len - 1), jnp.float32(req.temperature),
                 rng)
-            self.kv.k, self.kv.v = _write_slot(self.kv.k, self.kv.v, pk,
-                                               pv, jnp.int32(slot))
+            kv = self.kv
+            k, v = kv.k, kv.v
+            kv.k, kv.v = _write_slot(k, v, pk, pv, jnp.int32(slot))
+            if "write_slot" in self._in_place_unchecked:
+                self._note_in_place("write_slot", k, v)
             rec.count("admitted")
             rec.count("prompt_tokens", prompt_len)
         with rec.phase("prefill_readback"):
@@ -613,9 +631,13 @@ class ServeEngine:
                     jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(temps))
             with rec.phase("decode_dispatch"):
-                nxt, self.kv.k, self.kv.v = _decode_jit(
+                kv = self.kv
+                k, v = kv.k, kv.v
+                nxt, kv.k, kv.v = _decode_jit(
                     self.cfg, self._params_by_gen[gen], tokens,
-                    positions, self.kv.k, self.kv.v, temps, rng)
+                    positions, k, v, temps, rng)
+                if "decode" in self._in_place_unchecked:
+                    self._note_in_place("decode", k, v)
             with rec.phase("decode_readback"):
                 # the one sanctioned per-step readback (one per cohort
                 # during a swap transition): this pass's sampled ids
@@ -648,6 +670,23 @@ class ServeEngine:
                         now - req.arrival_ts > req.deadline_s):
                     self._retire(slot, "failed", reason="deadline")
         return True
+
+    def _note_in_place(self, program, k, v):
+        """After the first call of a cache-writing program on this
+        engine: were the arrays that went in consumed? ``is_deleted`` is
+        a host flag (no sync). A dropped donation (JAX drops one whose
+        output is laid out or sharded unlike the input) leaves the
+        results right and copies the whole cache on every call."""
+        self._in_place_unchecked.discard(program)
+        if not (k.is_deleted() and v.is_deleted()):
+            log.warning(
+                "serving: %s did not consume the KV cache it was given; "
+                "the cache is copied on every call instead of updated "
+                "in place (hvd_serve_kv_in_place = 0)", program)
+            self._m_in_place.set(0)
+            self._in_place_unchecked.clear()  # the verdict is in
+        elif not self._in_place_unchecked:
+            self._m_in_place.set(1)
 
     def _retire(self, slot, outcome, reason=""):
         self._rec.count("retired")

@@ -144,9 +144,17 @@ class BlockLedger:
 class KVCache:
     """Dense per-slot K/V device arrays plus their ledger.
 
-    Arrays are functional state: the engine's jitted steps take them as
-    inputs and return updated versions; this object just holds the
-    current reference (one per engine, single-threaded step loop).
+    The arrays are updated in place: the engine's programs that rewrite
+    them (``engine._decode_jit``, ``engine._write_slot``) take them as
+    DONATED inputs and return them aliased to the same device memory.
+    The invariant that makes that safe: ``k`` and ``v`` here are the
+    ONLY references, and the engine rebinds them in the statement that
+    makes the call (one engine, single-threaded step loop). An array
+    that went into such a call is dead after it (``is_deleted()``), so
+    code that wants a snapshot copies BEFORE the step, and after an
+    exception raised by one of those calls the contents are gone: build
+    a new engine. Shapes, dtypes and shardings of the current arrays
+    stay readable at any time (``per_chip_bytes``, lowering).
     """
 
     def __init__(self, cfg, num_slots, max_len=None, block_size=None,

@@ -77,6 +77,7 @@ def test_serve_leg(tiny, capsys):
                          lengths=(3, 8, 12), tie_tol=1e-4)
     line = _last_json(capsys)
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
+    assert line["kv_in_place"] == 1
 
 
 def test_a_failing_leg_fails_the_run(monkeypatch, tmp_path):
@@ -149,22 +150,39 @@ def test_attention_runs_on_its_per_device_shape(tiny, layout, per_device_bh):
     assert set(shapes) == {(per_device_bh, 64, head_dim)}
 
 
-def test_mosaic_accepts_the_kernels_without_a_chip():
-    """Ahead-of-time compile for a v5e topology (libtpu, no device): the
-    backward kernels at a sequence shorter than the 128-lane tile — the
-    serving prefill lengths — were refused by Mosaic before
-    flash_attention aligned its blocks."""
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e host (libtpu, no device attached). Described here
+    and nowhere at import: one process at a time may load libtpu, so only
+    the worker that runs this file does. A compile for a described chip
+    is written to JAX's persistent cache but cannot be read back without
+    the chip, so the cache is off while these tests run."""
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    from horovod_tpu.ops.flash_attention import flash_attention
-
+    from jax.experimental.compilation_cache import compilation_cache
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no libtpu in this environment
         pytest.skip(f"no TPU topology available: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_mosaic_accepts_the_kernels_without_a_chip(topo):
+    """Ahead-of-time compile for a v5e topology: the backward kernels at
+    a sequence shorter than the 128-lane tile — the serving prefill
+    lengths — were refused by Mosaic before flash_attention aligned its
+    blocks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.ops.flash_attention import flash_attention
+
     sharding = SingleDeviceSharding(topo.devices[0])
 
     def grads(q, k, v):
@@ -178,6 +196,51 @@ def test_mosaic_accepts_the_kernels_without_a_chip():
                                    sharding=sharding)
         jax.jit(grads).trace(arg, arg, arg).lower(
             lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "write_slot"])
+def test_the_serving_programs_update_the_cache_in_place(topo, program):
+    """The TPU compiler's own word, at Baichuan-7B widths (32 heads of
+    128, 16 slots x 1536; depth 2 so it compiles in seconds): both
+    programs that rewrite the KV cache alias their cache outputs to the
+    donated inputs, and neither holds a copy of a whole cache array —
+    the 2 x 2 GB a step that the undonated programs moved at depth 10."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.serving import engine as engine_mod
+
+    layers, slots, max_len, heads, head_dim = 2, 16, 1536, 32, 128
+    cfg = tr.TransformerConfig(
+        vocab_size=64000, num_layers=layers, num_heads=heads,
+        d_model=heads * head_dim, d_ff=11008, max_seq_len=4096,
+        dtype=jnp.bfloat16, attention_impl="flash")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    kv = arr((layers, slots, max_len, heads, head_dim), jnp.bfloat16)
+    if program == "decode":
+        params = jax.tree_util.tree_map(
+            lambda a: arr(a.shape, jnp.bfloat16),
+            jax.eval_shape(lambda k: tr.init_params(cfg, k)[1],
+                           jax.random.PRNGKey(0)))
+        lowered = engine_mod._decode_jit.lower(
+            cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+            kv, kv, arr((slots,), jnp.float32), arr((2,), jnp.uint32))
+    else:
+        pk = arr((layers, 1, 1024, heads, head_dim), jnp.bfloat16)
+        lowered = engine_mod._write_slot.lower(kv, kv, pk, pk,
+                                               arr((), jnp.int32))
+    compiled = lowered.compile()
+    cache_bytes = 2 * layers * slots * max_len * heads * head_dim * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    shape = f"bf16[{layers},{slots},{max_len},{heads},{head_dim}]"
+    copies = re.findall(re.escape(shape) + r"\S*\s+copy\(.*",
+                        compiled.as_text())
+    assert not copies, copies
 
 
 def test_init_names_the_process_that_holds_the_chip(monkeypatch):

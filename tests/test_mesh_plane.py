@@ -363,3 +363,24 @@ def test_tp_engine_token_parity_and_kv_bytes(reg):
     # head axis (index 3) sharded over tp (trailing Nones normalized)
     assert tuple(tp_engine.kv.k.sharding.spec)[:4] == \
         (None, None, None, "tp")
+
+
+def test_tp_engine_updates_its_cache_in_place(reg):
+    """The head-sharded cache is donated too: its outputs keep the
+    inputs' sharding, so no donation is dropped (JAX would warn "Some
+    donated buffers were not usable" and copy the cache every call)."""
+    import warnings
+    cfg = tr.TransformerConfig.tiny(dtype=jnp.float32,
+                                    attention_impl="full")
+    _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
+    mesh = mesh_lib.build_mesh(tp=2)
+    mesh_lib.set_global_mesh(mesh)  # decode head-sharding hint
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", UserWarning)
+        tokens, engine = _serve_tokens(cfg, params, mesh=mesh)
+    assert all(len(t) == 8 for t in tokens)
+    gauge, = reg.snapshot()["metrics"]["hvd_serve_kv_in_place"]["values"]
+    assert gauge["value"] == 1
+    for arr in (engine.kv.k, engine.kv.v):
+        assert not arr.is_deleted()
+        assert tuple(arr.sharding.spec)[:4] == (None, None, None, "tp")

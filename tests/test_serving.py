@@ -278,6 +278,56 @@ class TestServeEngine:
         assert engine.kv.ledger.blocks_in_use == 0
         assert engine.active_count == 0
 
+    def test_the_cache_is_updated_in_place(self, reg):
+        """The arrays that go into a slot write or a decode step are
+        donated: dead after the call, the engine's current ones live,
+        and another engine's cache untouched."""
+        cfg, params = _tiny()
+        engine, other = _engine(cfg, params), _engine(cfg, params)
+        other_k, other_v = other.kv.k, other.kv.v
+        assert _value(reg.snapshot(), "hvd_serve_kv_in_place") is None
+        k0, v0 = engine.kv.k, engine.kv.v  # go into _write_slot
+        engine.submit(Request("r", (5, 9, 17), max_new_tokens=6))
+        engine.step()
+        k1, v1 = engine.kv.k, engine.kv.v  # go into _decode_jit
+        engine.step()
+        for dead in (k0, v0, k1, v1):
+            assert dead.is_deleted()
+        for live in (engine.kv.k, engine.kv.v):
+            assert not live.is_deleted()
+        assert engine.kv.k.shape == k0.shape
+        assert engine.kv.per_chip_bytes() == other.kv.per_chip_bytes()
+        assert _value(reg.snapshot(), "hvd_serve_kv_in_place") == 1
+        assert other.kv.k is other_k and other.kv.v is other_v
+        assert not np.asarray(other_k).any()
+        assert not np.asarray(other_v).any()
+        r, = engine.run_to_completion()
+        assert list(r.tokens) == _greedy_reference(cfg, params,
+                                                   (5, 9, 17), 6)
+
+    def test_a_dropped_donation_is_named(self, reg, monkeypatch, caplog):
+        """A program that copies the cache it was given (here: the slot
+        write with its donation taken away) reads 0 on the gauge and
+        warns once; the tokens are the same either way."""
+        from horovod_tpu.serving import engine as engine_mod
+        monkeypatch.setattr(
+            engine_mod, "_write_slot",
+            jax.jit(engine_mod._write_slot.__wrapped__))
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        k0 = engine.kv.k
+        for i in range(2):
+            engine.submit(Request(f"r{i}", (5, 9, 17), max_new_tokens=4))
+        with caplog.at_level("WARNING", logger="horovod_tpu.serving"):
+            results = engine.run_to_completion()
+        assert not k0.is_deleted()
+        assert _value(reg.snapshot(), "hvd_serve_kv_in_place") == 0
+        warned = [r for r in caplog.records
+                  if "did not consume the KV cache" in r.getMessage()]
+        assert len(warned) == 1 and "write_slot" in warned[0].getMessage()
+        want = _greedy_reference(cfg, params, (5, 9, 17), 4)
+        assert [list(r.tokens) for r in results] == [want, want]
+
     def test_continuous_join_mid_stream_and_no_leaks(self, reg):
         cfg, params = _tiny()
         engine = _engine(cfg, params, num_slots=2)
