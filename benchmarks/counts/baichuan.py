@@ -38,9 +38,12 @@ def flash_backward(bh, s, dh, bytes_per=2):
     return 5 * bh * s * s * dh, 8 * bh * s * dh * bytes_per
 
 
-def decode_step_bytes(cfg, layers, live_tokens, bytes_per=2):
+def decode_step_bytes(cfg, layers, live_tokens, rows=None, bytes_per=2):
     """Bytes one decode step has to read: every layer's weights and the
-    head once, and K and V of every live token in every layer."""
+    head once, and K and V of every live token in every layer.  ``rows``
+    (how many rows decode) is ignored: this block keeps no per-row state
+    beyond K/V; a family with recurrent or convolution state counts its
+    read and write per row here."""
     weights = (layers * layer_parameters(cfg) + head_parameters(cfg))
     kv = 2 * layers * live_tokens * cfg["hidden_size"]
     return (weights + kv) * bytes_per

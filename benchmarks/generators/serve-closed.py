@@ -267,11 +267,27 @@ class Generator:
         return checks, self.attempted, self.failed
 
 
+def make_leaves(shapes, key):
+    """The served model's leaves as the program is given them: bfloat16,
+    made on the device in one jitted call and handed out in that type.
+    Widening them inside the same call is not the same values on the TPU:
+    XLA drops the float32 -> bfloat16 -> float32 round trip there and hands
+    out the unrounded noise (chip run, PR 26: every leaf differed by up to
+    half a bfloat16 step, the reference's logits by up to 0.035)."""
+    return jax.jit(lambda k: weights.make(shapes, k, jnp.bfloat16))(key)
+
+
 def reference_logits(run, sample, quant=None):
     """For each request of ``sample``, float32 reference logits [served
     tokens, vocab] at the positions that produced each served token
     (teacher-forced: one forward over prompt + served tokens, padded to
-    ``max_len`` so that one program serves every request)."""
+    ``max_len`` so that one program serves every request).
+
+    The served model IS its bf16 leaves, and the reference is handed them
+    as they are: it widens what it uses where it uses it (exact), and may
+    work in blocks to fit.  No float32 copy of the model is held, so the
+    check's device bytes (the ``reference_memory`` line) are the leaves
+    plus the reference program's own temporaries."""
     traffic = run.traffic
     ref = run.registry.module("reference", traffic["family"])
     adapter = run.registry.module("programs", traffic["family"])
@@ -281,14 +297,19 @@ def reference_logits(run, sample, quant=None):
     rows = traffic["output_tokens"]["max"]
     out = []
     with jax.default_matmul_precision("highest"):
-        # the served model IS its bf16 weights; the reference reads the
-        # same values in float32
-        w = jax.jit(lambda k: {
-            n: a.astype(jnp.float32) for n, a in
-            weights.make(shapes, k, jnp.bfloat16).items()})(
-                tref.weights_key(run.seed))
+        w = make_leaves(shapes, tref.weights_key(run.seed))
         fwd = jax.jit(lambda w, toks, at: ref.logits_at(
-            w, toks, at, run.config, layers, quant))
+            w, toks, at, run.config, layers, quant)).lower(
+                w, jax.ShapeDtypeStruct((max_len,), jnp.int32),
+                jax.ShapeDtypeStruct((rows,), jnp.int32)).compile()
+        # memory_stats()'s peak is the whole process's, and the serving
+        # window has set it: the check's own is what its program declares
+        ma = fwd.memory_analysis()
+        run.log("reference_memory", quant=quant,
+                leaf_bytes=sum(a.nbytes for a in w.values()),
+                temp_bytes=ma.temp_size_in_bytes,
+                peak_bytes=ma.argument_size_in_bytes +
+                ma.output_size_in_bytes + ma.temp_size_in_bytes)
         for req in sample:
             seq = list(req["prompt"]) + list(req["tokens"])
             toks = np.zeros(max_len, np.int32)

@@ -148,11 +148,15 @@ def build_train(run):
                   "attention": tcfg.attention_impl})
 
 
-def build_serve(run, clock=time.monotonic):
+def build_serve(run, clock=time.monotonic, to_tree=to_tree):
+    """``to_tree`` is for a family that wraps this one: its reference names
+    the leaves its own way (found by the family's name, as a family in
+    another root has to), and its adapter nests them."""
     from horovod_tpu.serving import engine as engine_mod
     from horovod_tpu.serving.queue import AdmissionQueue
 
     config, traffic = run.config, run.traffic
+    ref = run.registry.module("reference", traffic["family"])
     layers = depth(config, traffic)
     eng_kw = traffic["engine"]
     tcfg = transformer_config(config, layers,

@@ -12,6 +12,12 @@ Departures from the published model: none in the block.  Weights are
 seeded noise (``weight_shapes`` + ``benchmarks/lib/weights.py``), depth
 is whatever the caller passes.
 
+Leaves arrive in the type they are served or trained in (bfloat16 for the
+serving check, float32 for training) and are widened to float32 where they
+are used: the operand of ``matmul``, the gathered rows of ``embed``, the
+norm gains.  bfloat16 -> float32 is exact, so no value differs from a
+float32 copy of all of them, which is never held.
+
 ``quant`` selects the control of the correctness check, the reference
 itself computed one precision step down (never used for a result):
 ``None`` is float32; ``"fp8"`` rounds every matmul operand to float8
@@ -63,7 +69,8 @@ def _int8(x, axis):
 
 
 def matmul(x, w, quant):
-    """``x @ w`` with both operands rounded as ``quant`` says."""
+    """``x @ w`` in float32, both operands rounded as ``quant`` says."""
+    w = w.astype(jnp.float32)
     if quant == "fp8":
         x, w = _fp8(x), _fp8(w)
     elif quant == "int8":
@@ -75,7 +82,7 @@ def matmul(x, w, quant):
 
 def rms_norm(x, scale):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + RMS_EPS) * scale
+    return x * jax.lax.rsqrt(var + RMS_EPS) * scale.astype(jnp.float32)
 
 
 def rotary(x, positions):

@@ -1,15 +1,31 @@
-"""A tiny benchmark tree in a temporary directory, for the self-tests.
+"""Benchmark trees in a temporary directory, for the self-tests.
 
-It adds one of each kind of thing a later PR may add (two configurations,
-four traffic mixes, limits, a per-layer metric and its reader) as NEW
-files under a root of its own, and the harness runs them with the
-repository's generators, programs and references: nothing that is there is
-edited.
+Each builder adds what a later PR may add as NEW files under a root of its
+own, placed before the roots it builds on; the harness runs them with the
+generators, programs and references that are there, and nothing that is
+there is edited.
+
+``make_root``: a tiny benchmark of its own (a configuration, three traffic
+mixes and cells, limits, a per-layer metric and its reader) over the
+repository's generators and Baichuan family.
+``make_grown_root``: the repository's ``BENCHMARK.json`` after a PR that
+added a cell, a configuration and a per-layer metric on a new layer (the
+tiny serving cell of the above): what the accepted benchmark may look like
+when the next PR arrives.
+``make_extended_root``: a benchmark (the repository's, or the grown one)
+with what a ``model_config`` PR brings (``second_family/``): a
+configuration of a second serving family with its ``programs/``,
+``reference/`` and ``counts/`` files, a traffic mix, a limits file, a cell,
+and a per-layer metric on a new layer with its reader.  The contract tests
+run on it as on the repository's benchmark, and the harness runs the added
+cell on the CPU.
 """
 
+import copy
 import io
 import json
 import os
+import shutil
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -70,25 +86,47 @@ def read(obs, args, run):
 '''
 
 
-def make_root(root):
-    bdir = os.path.join(root, "benchmarks")
-    for d in ("configs", "traffic", "limits", "metrics", "readers"):
-        os.makedirs(os.path.join(bdir, d), exist_ok=True)
-    for name, body in CONFIGS.items():
-        with open(os.path.join(bdir, "configs", name + ".json"), "w") as f:
-            json.dump(body, f)
-    for name, body in TRAFFIC.items():
-        with open(os.path.join(bdir, "traffic", name + ".json"), "w") as f:
-            json.dump(body, f)
-    for cell, _, traffic, _ in CELLS:
-        kind = "serve" if "serve" in traffic else "train"
-        with open(os.path.join(bdir, "limits", cell + ".json"), "w") as f:
-            json.dump(LIMITS[kind], f)
-    with open(os.path.join(bdir, "readers", "window_field.py"), "w") as f:
+METRIC = {"name": "test.steps", "unit": "count", "better": "higher",
+          "source": "program_counter", "layer": "test"}
+
+
+def _dump(root, kind, name, body):
+    folder = os.path.join(root, "benchmarks", kind)
+    os.makedirs(folder, exist_ok=True)
+    with open(os.path.join(folder, name + ".json"), "w") as f:
+        json.dump(body, f)
+
+
+def _tiny_files(root, cells, moves):
+    """The files of ``cells`` (of ``CELLS``) and of the metric, all new."""
+    for cell, config, traffic, _ in cells:
+        _dump(root, "configs", config, dict(CONFIGS[config], reduced=[]))
+        _dump(root, "traffic", traffic, TRAFFIC[traffic])
+        _dump(root, "limits", cell,
+              LIMITS["serve" if "serve" in traffic else "train"])
+    _dump(root, "metrics", METRIC["name"],
+          dict(METRIC, moves=moves, reader="window_field",
+               args={"field": "steps"}))
+    os.makedirs(os.path.join(root, "benchmarks", "readers"), exist_ok=True)
+    with open(os.path.join(root, "benchmarks", "readers",
+                           "window_field.py"), "w") as f:
         f.write(READER)
-    with open(os.path.join(bdir, "metrics", "test.steps.json"), "w") as f:
-        json.dump({"name": "test.steps", "reader": "window_field",
-                   "args": {"field": "steps"}}, f)
+
+
+def _entries(cells, moves, metric_cell):
+    """``BENCHMARK.json``'s entries for ``cells`` and the metric."""
+    return {
+        "configs": [{"name": n, "source": "self-test",
+                     "file": f"benchmarks/configs/{n}.json", "reduced": [],
+                     "why": "tiny"} for n in dict.fromkeys(
+                         c[1] for c in cells)],
+        "workloads": [{"name": a, "config": b, "traffic": c, "chips": d,
+                       "why": "tiny"} for a, b, c, d in cells],
+        "per_layer": [dict(METRIC, moves=moves, workloads=[metric_cell])]}
+
+
+def make_root(root):
+    _tiny_files(root, CELLS, "train_rate")
     e2e = [{"name": n, "unit": u, "better": b, "bound": 0.1,
             "source": "host_clock"}
            for n, u, b in (("train_rate", "items/s/chip", "higher"),
@@ -96,22 +134,71 @@ def make_root(root):
                            ("ttft_p90", "ms", "lower"),
                            ("tpot_p90", "ms", "lower"),
                            ("setup_s", "s", "lower"))]
-    bench = {
-        "command": ["python3", "benchmarks/run.py"], "paths": ["benchmarks"],
-        "run_seconds": 1,
-        "configs": [{"name": n, "source": "self-test",
-                     "file": f"benchmarks/configs/{n}.json", "reduced": [],
-                     "why": "tiny"} for n in CONFIGS],
-        "workloads": [{"name": a, "config": b, "traffic": c, "chips": d,
-                       "why": "tiny"} for a, b, c, d in CELLS],
-        "end_to_end": e2e,
-        "per_layer": [{"name": "test.steps", "unit": "count",
-                       "better": "higher", "source": "program_counter",
-                       "layer": "test", "moves": "train_rate",
-                       "workloads": ["tiny-lm-train"]}]}
+    bench = dict({"command": ["python3", "benchmarks/run.py"],
+                  "paths": ["benchmarks"], "run_seconds": 1,
+                  "end_to_end": e2e},
+                 **_entries(CELLS, "train_rate", "tiny-lm-train"))
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return (root, REPO)
+
+
+SECOND_FAMILY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "second_family")
+SERVING = ("serve_tokens_per_s", "ttft_p90", "tpot_p90")
+
+
+def repo_benchmark():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _grow(bench, add, cell):
+    """``bench`` with ``add``'s entries appended: nothing that is there
+    changes but the ``workloads`` lists of the serving metrics, which the
+    added cell reports."""
+    bench = copy.deepcopy(bench)
+    for section in ("configs", "workloads", "per_layer"):
+        bench[section] += add[section]
+    for m in bench["end_to_end"]:
+        if m["name"] in SERVING and "workloads" in m:
+            m["workloads"].append(cell)
+    return bench
+
+
+def grown_benchmark():
+    """The repository's benchmark after a PR that added the tiny serving
+    cell, its configuration and a metric on the layer ``test``."""
+    cell = CELLS[2]
+    return _grow(repo_benchmark(), _entries([cell], "tpot_p90", cell[0]),
+                 cell[0])
+
+
+def extended_benchmark(base=None):
+    """``base`` (the repository's ``BENCHMARK.json`` unless given) with
+    ``second_family/``'s entries appended."""
+    with open(os.path.join(SECOND_FAMILY, "additions.json")) as f:
+        add = json.load(f)
+    return _grow(base or repo_benchmark(), add, add["workloads"][0]["name"])
+
+
+def _write_benchmark(root, bench, below):
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f, indent=1)
+    return (root,) + tuple(below)
+
+
+def make_grown_root(root):
+    _tiny_files(root, CELLS[2:], "tpot_p90")
+    return _write_benchmark(root, grown_benchmark(), (REPO,))
+
+
+def make_extended_root(root, below=(REPO,), base=None):
+    """Writes the extended benchmark under ``root``, before the roots
+    ``below`` that hold ``base``'s files; returns the roots."""
+    shutil.copytree(os.path.join(SECOND_FAMILY, "benchmarks"),
+                    os.path.join(root, "benchmarks"))
+    return _write_benchmark(root, extended_benchmark(base), below)
 
 
 def run_cell(roots, workload, seed=7, seconds=0.3):
