@@ -181,9 +181,10 @@ def _gspmd_attention_spec(shape):
     return P(dp, None, tp, None)
 
 
-def _rope(x, positions):
+def _rope(x, positions, base=10000.0):
     """Rotary position embedding on ``[..., seq, heads, head_dim]`` —
     the model's native layout, no head-major transpose required.
+    ``base`` is the rotary base (``rope_theta``).
 
     Angles are computed in fp32 (positional precision matters at long
     seq), but the rotation itself runs in x's own dtype: multiplying
@@ -194,7 +195,7 @@ def _rope(x, positions):
     precision is that of the bf16 activations either way.
     """
     half = x.shape[-1] // 2
-    freq = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     # [..., s] -> [..., s, 1, half]: broadcast over the heads axis
     angles = positions[..., None, None].astype(jnp.float32) * freq
     sin = jnp.sin(angles).astype(x.dtype)

@@ -828,9 +828,12 @@ def decode_attention(q, k, v, lengths, scale=None, head_sharding=None):
     step of the serving plane (docs/serving.md).
 
     q         [batch, 1, heads, head_dim]  — the current token's query
-    k, v      [batch, s_max, heads, head_dim] — the KV cache; only the
+    k, v      [batch, s_max, kv_heads, head_dim] — the KV cache; only the
               first ``lengths[b]`` positions of row b are real, the rest
-              is whatever the allocator left there (masked out here)
+              is whatever the allocator left there (masked out here).
+              ``kv_heads`` may divide ``heads`` (grouped-query attention:
+              query head i reads key/value head i // (heads / kv_heads));
+              the cache is read as it is, never repeated per query head
     lengths   [batch] int32 — valid prefix length per row
     scale     optional softmax scale (default head_dim ** -0.5, matching
               flash_attention)
@@ -863,14 +866,29 @@ def decode_attention(q, k, v, lengths, scale=None, head_sharding=None):
         k = jax.lax.with_sharding_constraint(k, head_sharding)
         v = jax.lax.with_sharding_constraint(v, head_sharding)
     b, _, h, d = q.shape
-    s_max = k.shape[1]
+    s_max, hk = k.shape[1], k.shape[2]
+    if h % hk:
+        raise ValueError(f"decode_attention: {h} query heads over {hk} "
+                         f"key/value heads")
     scale = d ** -0.5 if scale is None else scale
+    pos = jnp.arange(s_max, dtype=jnp.int32)[None, None, :]
+    valid = pos < lengths.astype(jnp.int32)[:, None, None]
+    if hk != h:
+        # grouped: the r = h / hk query heads of a group share one read
+        # of their key/value head
+        qg = q[:, 0].reshape(b, hk, h // hk, d)
+        logits = jnp.einsum("bgrd,bsgd->bgrs", qg, k,
+                            preferred_element_type=jnp.float32)
+        logits = logits.astype(jnp.float32) * scale
+        logits = jnp.where(valid[:, :, None], logits, _NEG_INF)
+        p = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bgrs,bsgd->bgrd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(b, h, d).astype(q.dtype)[:, None]
     # [b, h, d] x [b, s, h, d] -> [b, h, s] logits, fp32 accumulation
     logits = jnp.einsum("bhd,bshd->bhs", q[:, 0], k,
                         preferred_element_type=jnp.float32)
     logits = logits.astype(jnp.float32) * scale
-    pos = jnp.arange(s_max, dtype=jnp.int32)[None, None, :]
-    valid = pos < lengths.astype(jnp.int32)[:, None, None]
     logits = jnp.where(valid, logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v,

@@ -290,7 +290,9 @@ def replicate_tree(tree, mesh=None):
 def kv_cache_spec(num_heads, mesh=None):
     """PartitionSpec for the serving KV cache ``[layers, slots, len,
     heads, head_dim]``: heads sharded over tp when tp divides them,
-    replicated otherwise (docs/serving.md, docs/mesh.md)."""
+    replicated otherwise (docs/serving.md, docs/mesh.md). ``num_heads``
+    is the head count THE CACHE HOLDS: the key/value heads of a
+    grouped-query model, not its query heads."""
     mesh = _resolve(mesh)
     tp = mesh_axis_size(mesh, "tp")
     if tp > 1 and num_heads % tp == 0:
@@ -302,8 +304,11 @@ def decode_head_sharding(num_heads):
     """Trace-time hint for the fused decode step: the head-sharded
     NamedSharding for ``[batch, s, heads, head_dim]`` activations when a
     global mesh with tp>1 dividing ``num_heads`` is committed, else None
-    (dp-only engines stay byte-identical). Reads the committed mesh only
-    — never triggers a lazy env build from inside a trace."""
+    (dp-only engines stay byte-identical). ``num_heads`` is the cache's
+    head count (the key/value heads under grouped-query attention: the
+    constraint lands on q, k and v alike, and the query heads are a
+    multiple). Reads the committed mesh only — never triggers a lazy env
+    build from inside a trace."""
     mesh = global_mesh_if_set()
     if mesh is None:
         return None

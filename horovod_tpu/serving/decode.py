@@ -14,6 +14,11 @@ tests/test_flash_attention.py and tests/test_serving.py pin it by
 asserting logits equality and token-for-token greedy agreement against
 ``TransformerLM.apply``.
 
+A model of another kind (models/hybrid.py: a recurrent mixer beside
+grouped-query attention) brings its own two forwards; ``state_shapes``,
+``prefill`` and ``decode`` at the end of this module are what the engine
+and the cache call, and they find the model by its configuration's type.
+
 Attention: prefill uses the model's own dispatch (flash kernel on TPU,
 exact full attention on CPU); decode uses ops/flash_attention.py's
 ``decode_attention`` (q_len=1 against the cache, fixed s_max masked by
@@ -21,8 +26,10 @@ per-row lengths — jit-stable as rows join/retire).
 """
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from ..models import hybrid
 from ..models.transformer import _dispatch_attention, _rope
 from ..ops.flash_attention import decode_attention
 from ..parallel import mesh as mesh_lib
@@ -116,7 +123,9 @@ def decode_step(cfg, params, tokens, positions, kv_k, kv_v):
                the row's cache; its K/V are written there)
     kv_k/kv_v  [layers, b, s_max, h, d] — the dense cache; rows beyond
                a row's length hold junk that the length mask hides, so
-               inactive slots may receive garbage writes harmlessly
+               K/V of inactive slots may receive garbage writes
+               harmlessly (true of K/V alone: a recurrent state has no
+               such hiding place, see ``decode`` below)
 
     Returns (logits [b, vocab], kv_k, kv_v) with the new token's K/V
     appended at ``positions``; attention spans 0..positions inclusive.
@@ -144,3 +153,46 @@ def decode_step(cfg, params, tokens, positions, kv_k, kv_v):
         x = x + _mlp(cfg, layer, y)
     x = _rmsnorm(x, params["ln_f"]["scale"], cfg.dtype)
     return _logits(cfg, params, x)[:, 0], kv_k, kv_v
+
+
+# -- what the engine and the cache call, for any model ------------------------
+
+def _is_hybrid(cfg):
+    return isinstance(cfg, hybrid.HybridConfig)
+
+
+def state_shapes(cfg, num_slots, max_len):
+    """{kind: ShapeDtypeStruct} of the per-slot state the model keeps,
+    every kind ``[layers, slots, ...]``: what ``KVCache`` allocates."""
+    if _is_hybrid(cfg):
+        return hybrid.state_shapes(cfg, num_slots, max_len)
+    kv = jax.ShapeDtypeStruct(
+        (cfg.num_layers, num_slots, max_len, cfg.num_heads,
+         cfg.d_model // cfg.num_heads), cfg.dtype)
+    return {"k": kv, "v": kv}
+
+
+def prefill(cfg, params, tokens, last_index):
+    """(logits [1, vocab] at ``last_index``, {kind: [layers, 1, ...]})
+    of ONE right-padded prompt ``tokens`` [1, s_pad]: the first token's
+    logits and the state the prefill leaves for its row, every kind, AS IT
+    STANDS AFTER THE LAST REAL TOKEN (``last_index``; the prompt is
+    right-padded). For K/V that is the whole padded prefix — the length
+    mask hides the pad; a recurrent kind must not have seen the pad."""
+    if _is_hybrid(cfg):
+        return hybrid.prefill(cfg, params, tokens, last_index)
+    logits, k, v = prefill_forward(cfg, params, tokens)
+    return logits[0, last_index][None], {"k": k, "v": v}
+
+
+def decode(cfg, params, tokens, positions, state, mask=None):
+    """(logits [b, vocab], state): every kind of ``state`` advanced by
+    one token for the rows in ``mask`` ([b] bool, the pass's cohort;
+    None: a model whose every kind can park a foreign row's write, K/V
+    alone). A row outside the mask keeps its recurrent kinds bit for
+    bit."""
+    if _is_hybrid(cfg):
+        return hybrid.decode(cfg, params, tokens, positions, state, mask)
+    logits, k, v = decode_step(cfg, params, tokens, positions, state["k"],
+                               state["v"])
+    return logits, {"k": k, "v": v}

@@ -4,11 +4,14 @@ One ``step()`` = admit joins, one fused decode over every batch slot,
 retire finishers. The device work is shape-static by construction:
 
   * decode always runs all ``num_slots`` rows — inactive rows compute
-    garbage that the host ignores and write garbage K/V into their own
-    (inactive) cache rows, which the next prefill overwrites. Occupancy
-    is data, not shape, so join/retire never recompiles.
+    garbage that the host ignores and write garbage into their own
+    (inactive) cache rows, which the next prefill overwrites, every
+    kind of state whole. Occupancy is data, not shape, so join/retire
+    never recompiles.
   * prefill pads each prompt to a KV-block multiple, bounding compile
-    variants at max_len / block; causal masking makes the pads inert.
+    variants at max_len / block; causal masking makes the pads inert
+    for attention, and a model with a recurrent state is told the true
+    length (decode.prefill).
   * exactly ONE host readback per decode step (the sampled token ids)
     and one per prefill (the first token) — the contract hvdlint HVD011
     enforces over this package; both sites carry the sanctioned
@@ -36,7 +39,7 @@ from ..utils import memory as hvd_memory
 from ..utils import metrics as hvd_metrics
 from ..utils import tracing as hvd_tracing
 from . import tracing as serve_tracing
-from .decode import decode_step, prefill_forward
+from .decode import decode, prefill
 from .kv_cache import KVCache
 from .queue import AdmissionQueue, RequestResult
 from .sampling import sample_tokens
@@ -47,32 +50,34 @@ log = logging.getLogger("horovod_tpu.serving")
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _prefill_jit(cfg, params, tokens, last_index, temperature, rng):
-    """Prefill + first-token sample; returns (token, k, v) with k/v
+    """Prefill + first-token sample; returns (token, state): every kind
+    of state the model keeps for the row (decode.prefill), K/V as
     [layers, 1, s_pad, h, d]."""
-    logits, k, v = prefill_forward(cfg, params, tokens)
-    row = logits[0, last_index][None]  # [1, vocab]
+    row, state = prefill(cfg, params, tokens, last_index)  # [1, vocab]
     tok = sample_tokens(rng, row, temperature[None])[0]
-    return tok, k, v
+    return tok, state
 
 
-# The two programs that rewrite the cache DONATE it (kv_cache.KVCache):
-# their cache outputs alias the inputs, so the one-row-a-slot scatter and
-# the one-slot write land in place, not behind a copy of each array.
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(4, 5))
-def _decode_jit(cfg, params, tokens, positions, kv_k, kv_v, temps, rng):
-    logits, kv_k, kv_v = decode_step(cfg, params, tokens, positions,
-                                     kv_k, kv_v)
-    return sample_tokens(rng, logits, temps), kv_k, kv_v
+# The two programs that rewrite the cache DONATE it (kv_cache.KVCache),
+# every kind of state in it: their cache outputs alias the inputs, so the
+# one-row-a-slot scatter, the state update and the one-slot write land in
+# place, not behind a copy of each array.
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(4,))
+def _decode_jit(cfg, params, tokens, positions, state, temps, rng,
+                mask=None):
+    logits, state = decode(cfg, params, tokens, positions, state, mask)
+    return sample_tokens(rng, logits, temps), state
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_slot(kv_k, kv_v, pk, pv, slot):
-    """Write a prefill's K/V into cache row ``slot`` (dynamic index,
-    static prefix length from pk's shape)."""
-    s_pad = pk.shape[2]
-    kv_k = kv_k.at[:, slot, :s_pad].set(pk[:, 0])
-    kv_v = kv_v.at[:, slot, :s_pad].set(pv[:, 0])
-    return kv_k, kv_v
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_slot(state, row, slot):
+    """Write what a prefill left (``row``: {kind: [layers, 1, ...]}) into
+    cache row ``slot`` (dynamic index) of every kind. The extent along
+    the axis after the slot is the row's own (static): the padded prefix
+    for K/V, the whole of it for a kind that is no sequence — nothing of
+    the slot's last occupant is left in those."""
+    return {kind: arr.at[:, slot, :row[kind].shape[2]].set(row[kind][:, 0])
+            for kind, arr in state.items()}
 
 
 class _Active:
@@ -194,9 +199,20 @@ class ServeEngine:
         self._m_in_place = reg.gauge(
             "hvd_serve_kv_in_place",
             "1 once an engine's first slot write and first decode step "
-            "both consumed the cache arrays they were given (the cache "
-            "is updated in place); 0 when a donation was dropped and "
-            "every call copies the cache; no value before either.")
+            "both consumed the cache arrays they were given, every kind "
+            "of state among them (the cache is updated in place); 0 "
+            "when a donation was dropped and every call copies that "
+            "array; no value before either.")
+        state_bytes = reg.gauge(
+            "hvd_serve_state_bytes",
+            "Bytes of per-slot serving state resident on one chip, by "
+            "kind (k, v; ssm and conv where the model has a recurrent "
+            "mixer).", labels=("kind",))
+        for kind, nbytes in self.kv.bytes_by_kind().items():
+            state_bytes.labels(kind=kind).set(nbytes)
+        # bytes of recurrent state one row holds over all layers: what a
+        # decode pass reads and writes again per row it advances
+        self._row_state_bytes = self.kv.row_state_bytes()
         # cache-writing programs whose first call on this engine has
         # yet to show that it consumed its arrays (_note_in_place)
         self._in_place_unchecked = {"write_slot", "decode"}
@@ -372,8 +388,9 @@ class ServeEngine:
         positions = jnp.zeros(S, jnp.int32)
         temps = jnp.zeros(S, jnp.float32)
         lowered = _decode_jit.lower(
-            self.cfg, self.params, tokens, positions, self.kv.k,
-            self.kv.v, temps, jax.random.PRNGKey(0))
+            self.cfg, self.params, tokens, positions, self.kv.arrays,
+            temps, jax.random.PRNGKey(0),
+            jnp.ones(S, bool) if self.kv.recurrent else None)
         hlo = lowered.compile().as_text()
         return hvd_memory.scan_resharding(
             hlo, self.params, param_specs(self.params), self.mesh,
@@ -388,8 +405,13 @@ class ServeEngine:
         exactly like the one it replaces."""
         if self.mesh is None:
             return params
-        from ..models.transformer import param_specs
+        from ..models.transformer import TransformerConfig, param_specs
         from ..parallel import mesh as mesh_lib
+        if not isinstance(self.cfg, TransformerConfig):
+            raise NotImplementedError(
+                "serving over a mesh places a TransformerLM's parameter "
+                f"tree only; {type(self.cfg).__name__} serves on one chip "
+                "(ROADMAP R2)")
         return mesh_lib.device_put_tree(params, param_specs(params),
                                         self.mesh)
 
@@ -551,17 +573,18 @@ class ServeEngine:
             if hvd_memory.enabled():
                 hvd_memory.get_tracker().observe("serve_prefill",
                                                  (tokens,))
-            tok, pk, pv = _prefill_jit(
+            tok, row = _prefill_jit(
                 self.cfg, self.params, jnp.asarray(tokens),
                 jnp.int32(prompt_len - 1), jnp.float32(req.temperature),
                 rng)
             kv = self.kv
-            k, v = kv.k, kv.v
-            kv.k, kv.v = _write_slot(k, v, pk, pv, jnp.int32(slot))
+            went_in = kv.arrays
+            kv.arrays = _write_slot(went_in, row, jnp.int32(slot))
             if "write_slot" in self._in_place_unchecked:
-                self._note_in_place("write_slot", k, v)
+                self._note_in_place("write_slot", went_in)
             rec.count("admitted")
             rec.count("prompt_tokens", prompt_len)
+            rec.count("state_bytes", self._row_state_bytes)
         with rec.phase("prefill_readback"):
             # the one sanctioned per-prefill readback: the first token
             # hvdlint: disable=HVD011(first-token sample is the prefill's output)
@@ -601,8 +624,11 @@ class ServeEngine:
             # garbage until the row's own pass overwrites it with the
             # real value — each pass writes then attends, so even a
             # final-token write at max_len-1 is read only after it
-            # lands. Between swaps there is exactly one cohort and this
-            # is the same single fused call as always.
+            # lands. A recurrent state has nowhere to park: a model
+            # that keeps one is told the pass's rows (``mask``) and
+            # leaves every other row's state bit for bit. Between swaps
+            # there is exactly one cohort and this is the same single
+            # fused call as always.
             cohorts = {}
             for slot, st in self._active.items():
                 cohorts.setdefault(st.generation, []).append(slot)
@@ -619,6 +645,14 @@ class ServeEngine:
                     tokens[slot] = st.next_token
                     positions[slot] = st.next_pos
                     temps[slot] = st.request.temperature
+                mask = None
+                if self.kv.recurrent:
+                    mask = np.zeros(S, bool)
+                    mask[cohorts[gen]] = True
+                    mask = jnp.asarray(mask)
+                    rec.count("state_rows", len(cohorts[gen]))
+                    rec.count("state_bytes", 2 * len(cohorts[gen])
+                              * self._row_state_bytes)
                 rng = jax.random.fold_in(self._rng, self._step_count)
                 self._step_count += 1
                 # decode is shape-static by construction: one miss at
@@ -632,12 +666,12 @@ class ServeEngine:
                     jnp.asarray(temps))
             with rec.phase("decode_dispatch"):
                 kv = self.kv
-                k, v = kv.k, kv.v
-                nxt, kv.k, kv.v = _decode_jit(
+                went_in = kv.arrays
+                nxt, kv.arrays = _decode_jit(
                     self.cfg, self._params_by_gen[gen], tokens,
-                    positions, k, v, temps, rng)
+                    positions, went_in, temps, rng, mask)
                 if "decode" in self._in_place_unchecked:
-                    self._note_in_place("decode", k, v)
+                    self._note_in_place("decode", went_in)
             with rec.phase("decode_readback"):
                 # the one sanctioned per-step readback (one per cohort
                 # during a swap transition): this pass's sampled ids
@@ -671,18 +705,22 @@ class ServeEngine:
                     self._retire(slot, "failed", reason="deadline")
         return True
 
-    def _note_in_place(self, program, k, v):
+    def _note_in_place(self, program, went_in):
         """After the first call of a cache-writing program on this
-        engine: were the arrays that went in consumed? ``is_deleted`` is
-        a host flag (no sync). A dropped donation (JAX drops one whose
-        output is laid out or sharded unlike the input) leaves the
-        results right and copies the whole cache on every call."""
+        engine: were the arrays that went in consumed, every kind of
+        them? ``is_deleted`` is a host flag (no sync). A dropped donation
+        (JAX drops one whose output is laid out or sharded unlike the
+        input) leaves the results right and copies that array whole on
+        every call."""
         self._in_place_unchecked.discard(program)
-        if not (k.is_deleted() and v.is_deleted()):
+        kept = sorted(kind for kind, arr in went_in.items()
+                      if not arr.is_deleted())
+        if kept:
             log.warning(
-                "serving: %s did not consume the KV cache it was given; "
-                "the cache is copied on every call instead of updated "
-                "in place (hvd_serve_kv_in_place = 0)", program)
+                "serving: %s did not consume the KV cache it was given "
+                "(%s); the cache is copied on every call instead of "
+                "updated in place (hvd_serve_kv_in_place = 0)", program,
+                ", ".join(kept))
             self._m_in_place.set(0)
             self._in_place_unchecked.clear()  # the verdict is in
         elif not self._in_place_unchecked:

@@ -1,4 +1,5 @@
-"""Slot-based KV cache with block-granular accounting.
+"""Slot-based cache of per-row serving state with block-granular
+accounting: K/V, and beside them whatever else a model keeps for a row.
 
 Two halves, deliberately separated:
 
@@ -6,8 +7,11 @@ Two halves, deliberately separated:
     fixed-size blocks of cache capacity, against a global block budget.
     No jax import, so the alloc/free/leak invariants test in
     microseconds (tests/test_serving.py).
-  * KVCache — the device arrays: dense, preallocated
-    [layers, slots, max_len, heads, head_dim] K and V. Dense rather
+  * KVCache — the device arrays, a named set the MODEL declares
+    (serving/decode.state_shapes), every kind [layers, slots, ...]:
+    dense, preallocated [layers, slots, max_len, heads, head_dim] K and
+    V, and for a model with a recurrent mixer its state and convolution
+    window beside them — one ledger, one slot index, one lifetime. Dense rather
     than paged-indirect because the engine decodes every slot every
     step at a static shape (docs/serving.md): a gather through a block
     table buys nothing at this batch geometry, while the dense layout
@@ -142,60 +146,105 @@ class BlockLedger:
 
 
 class KVCache:
-    """Dense per-slot K/V device arrays plus their ledger.
+    """The per-slot device arrays of every kind of state, plus their
+    ledger.
+
+    ``arrays`` is ``{kind: array}``, every kind ``[layers, slots, ...]``,
+    as the model declares them (``serving/decode.state_shapes``): ``k``
+    and ``v`` ``[layers, slots, max_len, kv_heads, head_dim]`` for every
+    model (``.k``/``.v`` read and rebind them), and for a model with a
+    recurrent mixer ``ssm`` (the recurrent state) and ``conv`` (the
+    convolution's window). A slot owns its row of EVERY kind for one
+    lifetime: a prefill writes them all whole (K/V up to the padded
+    prompt), each decode step advances them all, and retiring the slot
+    frees them together — nothing of an occupant outlives its slot,
+    because the next prefill overwrites every kind.
 
     The arrays are updated in place: the engine's programs that rewrite
-    them (``engine._decode_jit``, ``engine._write_slot``) take them as
-    DONATED inputs and return them aliased to the same device memory.
-    The invariant that makes that safe: ``k`` and ``v`` here are the
-    ONLY references, and the engine rebinds them in the statement that
-    makes the call (one engine, single-threaded step loop). An array
-    that went into such a call is dead after it (``is_deleted()``), so
-    code that wants a snapshot copies BEFORE the step, and after an
-    exception raised by one of those calls the contents are gone: build
-    a new engine. Shapes, dtypes and shardings of the current arrays
-    stay readable at any time (``per_chip_bytes``, lowering).
+    them (``engine._decode_jit``, ``engine._write_slot``) take the whole
+    set as DONATED input and return it aliased to the same device memory.
+    The invariant that makes that safe: ``arrays`` here holds the ONLY
+    references, and the engine rebinds it in the statement that makes
+    the call (one engine, single-threaded step loop). An array that went
+    into such a call is dead after it (``is_deleted()``), so code that
+    wants a snapshot copies BEFORE the step, and after an exception
+    raised by one of those calls the contents are gone: build a new
+    engine. Shapes, dtypes and shardings of the current arrays stay
+    readable at any time (``per_chip_bytes``, lowering).
     """
 
     def __init__(self, cfg, num_slots, max_len=None, block_size=None,
                  total_blocks=None, mesh=None):
         import jax.numpy as jnp
+        from . import decode
         max_len = cfg.max_seq_len if max_len is None else max_len
         self.ledger = BlockLedger(num_slots, max_len,
                                   block_size=block_size,
                                   total_blocks=total_blocks)
-        head_dim = cfg.d_model // cfg.num_heads
-        shape = (cfg.num_layers, num_slots, max_len, cfg.num_heads,
-                 head_dim)
-        self.k = jnp.zeros(shape, cfg.dtype)
-        self.v = jnp.zeros(shape, cfg.dtype)
+        shapes = decode.state_shapes(cfg, num_slots, max_len)
+        self.arrays = {kind: jnp.zeros(a.shape, a.dtype)
+                       for kind, a in shapes.items()}
+        # kinds a decode pass must not touch for rows it does not decode
+        # (K/V of such a row park at max_len - 1; these cannot)
+        self.recurrent = tuple(sorted(set(shapes) - {"k", "v"}))
         if mesh is not None:
-            # Tensor-parallel serving (docs/mesh.md): the dense arrays
-            # gain a head-sharded NamedSharding over the mesh's tp axis,
-            # so each chip holds heads/tp of the cache — the per-chip
-            # memory win that lets one replica front a model bigger
-            # than a chip. Replicated when tp doesn't divide heads.
+            # Tensor-parallel serving (docs/mesh.md): K and V gain a
+            # head-sharded NamedSharding over the mesh's tp axis, so each
+            # chip holds heads/tp of the cache — the per-chip memory win
+            # that lets one replica front a model bigger than a chip.
+            # Replicated when tp doesn't divide the cache's heads.
             from ..parallel import mesh as mesh_lib
-            spec = mesh_lib.kv_cache_spec(cfg.num_heads, mesh)
+            if self.recurrent:
+                raise NotImplementedError(
+                    "a cache with recurrent state has no sharding over a "
+                    "mesh yet (mixer heads and groups over tp: ROADMAP R2)")
+            spec = mesh_lib.kv_cache_spec(shapes["k"].shape[3], mesh)
             self.k, self.v = mesh_lib.device_put_tree(
                 (self.k, self.v), (spec, spec), mesh)
         self.max_len = max_len
 
-    def per_chip_bytes(self):
-        """Bytes of K+V cache resident on ONE chip (the shard shape
-        under the cache's committed sharding; the full array size when
-        unsharded) — what the HVD_BENCH_MESH serve arm asserts drops
-        with tp."""
+    @property
+    def k(self):
+        return self.arrays["k"]
+
+    @k.setter
+    def k(self, value):
+        self.arrays["k"] = value
+
+    @property
+    def v(self):
+        return self.arrays["v"]
+
+    @v.setter
+    def v(self, value):
+        self.arrays["v"] = value
+
+    def bytes_by_kind(self):
+        """{kind: bytes resident on ONE chip} (the shard shape under the
+        array's committed sharding; the full array when unsharded)."""
         import numpy as np
-        total = 0
-        for arr in (self.k, self.v):
+        out = {}
+        for kind, arr in self.arrays.items():
             sharding = getattr(arr, "sharding", None)
             if sharding is not None and hasattr(sharding, "shard_shape"):
                 shape = sharding.shard_shape(arr.shape)
             else:
                 shape = arr.shape
-            total += int(np.prod(shape)) * arr.dtype.itemsize
-        return total
+            out[kind] = int(np.prod(shape)) * arr.dtype.itemsize
+        return out
+
+    def per_chip_bytes(self):
+        """Bytes of cache (every kind) resident on ONE chip — what the
+        HVD_BENCH_MESH serve arm asserts drops with tp."""
+        return sum(self.bytes_by_kind().values())
+
+    def row_state_bytes(self):
+        """Bytes of recurrent state (every kind but K/V) ONE slot holds
+        over all layers: what a decode step reads and writes again for
+        each row it advances."""
+        by_kind = self.bytes_by_kind()
+        return sum(by_kind[kind] for kind in self.recurrent) \
+            // self.num_slots
 
     @property
     def num_slots(self):

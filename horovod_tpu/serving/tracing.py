@@ -340,7 +340,12 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
                "decode_prepare", "decode_dispatch", "decode_readback",
                "bookkeeping", "telemetry")
 # counts of a step record, taken where the work happens
-STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens")
+# state_rows / state_bytes: a model with a recurrent state only (0 else) —
+# rows whose state the step's decode passes advanced (== active outside
+# a hot swap), and bytes of that state the step's programs had to move
+# (read + write per advanced row, one write per admitted row)
+STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
+               "state_rows", "state_bytes")
 _STEP_ANNOTATION = "hvd.serve.step"
 _PHASE_ANNOTATIONS = {p: "hvd.serve." + p for p in STEP_PHASES}
 

@@ -222,6 +222,7 @@ def test_the_serving_programs_update_the_cache_in_place(topo, program):
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
     kv = arr((layers, slots, max_len, heads, head_dim), jnp.bfloat16)
+    state = {"k": kv, "v": kv}
     if program == "decode":
         params = jax.tree_util.tree_map(
             lambda a: arr(a.shape, jnp.bfloat16),
@@ -229,10 +230,10 @@ def test_the_serving_programs_update_the_cache_in_place(topo, program):
                            jax.random.PRNGKey(0)))
         lowered = engine_mod._decode_jit.lower(
             cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
-            kv, kv, arr((slots,), jnp.float32), arr((2,), jnp.uint32))
+            state, arr((slots,), jnp.float32), arr((2,), jnp.uint32))
     else:
         pk = arr((layers, 1, 1024, heads, head_dim), jnp.bfloat16)
-        lowered = engine_mod._write_slot.lower(kv, kv, pk, pk,
+        lowered = engine_mod._write_slot.lower(state, {"k": pk, "v": pk},
                                                arr((), jnp.int32))
     compiled = lowered.compile()
     cache_bytes = 2 * layers * slots * max_len * heads * head_dim * 2
@@ -258,3 +259,56 @@ def test_init_names_the_process_that_holds_the_chip(monkeypatch):
     with pytest.raises(RuntimeError, match="pid 3921.*hvdrun -np <chips>"):
         hvd.init()
     assert not hvd.is_initialized()
+
+
+@pytest.mark.parametrize("program", ["decode", "write_slot"])
+def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program):
+    """The same word for a model that keeps recurrent and convolution
+    state beside its K/V (models/hybrid.py; mixer 8 heads of 64 with a
+    state of 128, 4 query and 2 key/value heads of 128; 8 slots x 512,
+    depth 2): every kind of state is aliased to its donated input, and
+    the float32 state is never copied whole."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import hybrid
+    from horovod_tpu.serving import decode as serve_decode
+    from horovod_tpu.serving import engine as engine_mod
+
+    slots, max_len = 8, 512
+    cfg = hybrid.HybridConfig(
+        vocab_size=4096, num_layers=2, d_model=512, d_ff=1024, num_heads=4,
+        num_kv_heads=2, head_dim=128, ssm_heads=8, ssm_head_dim=64,
+        ssm_state=128, ssm_groups=2, attention_impl="flash")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    state = {k: arr(a.shape, a.dtype) for k, a in
+             serve_decode.state_shapes(cfg, slots, max_len).items()}
+    if program == "decode":
+        params = jax.tree_util.tree_map(
+            lambda a: arr(a.shape, a.dtype),
+            jax.eval_shape(lambda k: hybrid.init_params(cfg, k),
+                           jax.random.PRNGKey(0)))
+        lowered = engine_mod._decode_jit.lower(
+            cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+            state, arr((slots,), jnp.float32), arr((2,), jnp.uint32),
+            arr((slots,), jnp.bool_))
+    else:
+        row = {k: arr((a.shape[0], 1) + ((256,) + a.shape[3:]
+                                         if k in "kv" else a.shape[2:]),
+                      a.dtype) for k, a in state.items()}
+        lowered = engine_mod._write_slot.lower(state, row,
+                                               arr((), jnp.int32))
+    compiled = lowered.compile()
+    import math
+    state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in state.values())
+    assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
+    ssm = state["ssm"].shape
+    shape = "f32[" + ",".join(map(str, ssm)) + "]"
+    copies = re.findall(re.escape(shape) + r"\S*\s+copy\(.*",
+                        compiled.as_text())
+    assert not copies, copies

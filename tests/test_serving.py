@@ -4,6 +4,7 @@ admission control, SLO metric emission, and the engine end-to-end —
 including temp-0 parity between the KV-cached engine and a no-cache
 greedy reference over the same model."""
 
+import logging
 import os
 
 import numpy as np
@@ -14,6 +15,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.models import hybrid
 from horovod_tpu.models import transformer as tr
 from horovod_tpu.serving.kv_cache import BlockLedger, KVCache
 from horovod_tpu.serving.queue import AdmissionQueue, Request
@@ -318,13 +320,24 @@ class TestServeEngine:
         k0 = engine.kv.k
         for i in range(2):
             engine.submit(Request(f"r{i}", (5, 9, 17), max_new_tokens=4))
-        with caplog.at_level("WARNING", logger="horovod_tpu.serving"):
-            results = engine.run_to_completion()
+        # the handler goes on the ``horovod_tpu`` logger itself:
+        # ``hvd_logging.get_logger()`` stops it propagating to the root
+        # at its first use, so whether the root's handler sees the record
+        # depended on which tests ran before on this worker
+        hvd_logger = logging.getLogger("horovod_tpu")
+        hvd_logger.addHandler(caplog.handler)
+        try:
+            with caplog.at_level("WARNING", logger="horovod_tpu.serving"):
+                results = engine.run_to_completion()
+        finally:
+            hvd_logger.removeHandler(caplog.handler)
         assert not k0.is_deleted()
         assert _value(reg.snapshot(), "hvd_serve_kv_in_place") == 0
-        warned = [r for r in caplog.records
-                  if "did not consume the KV cache" in r.getMessage()]
-        assert len(warned) == 1 and "write_slot" in warned[0].getMessage()
+        warned = {id(r): r for r in caplog.records
+                  if "did not consume the KV cache" in r.getMessage()}
+        assert len(warned) == 1   # (one record, by whichever handlers)
+        said, = (r.getMessage() for r in warned.values())
+        assert "write_slot" in said and "k, v" in said
         want = _greedy_reference(cfg, params, (5, 9, 17), 4)
         assert [list(r.tokens) for r in results] == [want, want]
 
@@ -423,3 +436,210 @@ class TestServeEngine:
         assert _value(snap, "hvd_serve_intertoken_seconds") == 4
         kinds = {e["event"] for e in snap["events"]}
         assert {"serve_admit", "serve_retire"} <= kinds
+
+
+# ---------------------------------------------------------------------------
+# A model with recurrent state beside its K/V (models/hybrid.py)
+# ---------------------------------------------------------------------------
+
+def _tiny_hybrid():
+    cfg = hybrid.HybridConfig.tiny(dtype=jnp.float32, max_seq_len=64,
+                                   ssm_multipliers=(1.0, 1.0, 1.0, 1.0, 4.0))
+    return cfg, hybrid.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _prompt(n, seed):
+    return tuple(int(t) for t in
+                 np.random.default_rng(seed).integers(0, 256, n))
+
+
+def _serve(engine, requests):
+    for rid, prompt, new in requests:
+        engine.submit(Request(rid, prompt, max_new_tokens=new))
+    return {r.request_id: list(r.tokens)
+            for r in engine.run_to_completion()}
+
+
+class TestRecurrentStateInTheCache:
+    def test_the_cache_holds_every_kind_the_model_declares(self, reg):
+        cfg, params = _tiny_hybrid()
+        engine = _engine(cfg, params, num_slots=3, max_len=32)
+        kv = engine.kv
+        assert set(kv.arrays) == {"k", "v", "ssm", "conv"}
+        assert kv.recurrent == ("conv", "ssm")
+        assert kv.k.shape == (2, 3, 32, cfg.num_kv_heads, cfg.head_dim)
+        assert kv.arrays["ssm"].shape == (2, 3, 4, 8, 16)
+        assert kv.arrays["ssm"].dtype == jnp.float32
+        by_kind = kv.bytes_by_kind()
+        assert kv.per_chip_bytes() == sum(by_kind.values())
+        assert kv.row_state_bytes() * 3 == by_kind["ssm"] + by_kind["conv"]
+        snap = reg.snapshot()
+        for kind, nbytes in by_kind.items():
+            assert _value(snap, "hvd_serve_state_bytes", kind=kind) == nbytes
+        # a model with K/V alone: no recurrent kind, the gauge's two kinds
+        cfg2, params2 = _tiny()
+        plain = _engine(cfg2, params2)
+        assert set(plain.kv.arrays) == {"k", "v"}
+        assert plain.kv.recurrent == () and plain.kv.row_state_bytes() == 0
+
+    @pytest.mark.parametrize("program", ["write_slot", "decode"])
+    def test_every_kind_of_state_is_consumed(self, reg, program):
+        """Both cache-writing programs donate EVERY array of the cache:
+        what went in is dead, what came out is live, the gauge reads 1."""
+        cfg, params = _tiny_hybrid()
+        engine = _engine(cfg, params)
+        engine.submit(Request("r", _prompt(5, 1), max_new_tokens=6))
+        if program == "decode":
+            engine.step()
+        went_in = dict(engine.kv.arrays)
+        engine.step()
+        assert sorted(went_in) == ["conv", "k", "ssm", "v"]
+        assert all(a.is_deleted() for a in went_in.values())
+        assert not any(a.is_deleted() for a in engine.kv.arrays.values())
+        engine.run_to_completion()
+        assert _value(reg.snapshot(), "hvd_serve_kv_in_place") == 1
+
+    def test_a_dropped_donation_of_the_state_is_named(self, reg,
+                                                      monkeypatch, caplog):
+        from horovod_tpu.serving import engine as engine_mod
+        monkeypatch.setattr(
+            engine_mod, "_write_slot",
+            jax.jit(engine_mod._write_slot.__wrapped__))
+        cfg, params = _tiny_hybrid()
+        engine = _engine(cfg, params)
+        engine.submit(Request("r", _prompt(5, 1), max_new_tokens=3))
+        hvd_logger = logging.getLogger("horovod_tpu")
+        hvd_logger.addHandler(caplog.handler)
+        try:
+            with caplog.at_level("WARNING", logger="horovod_tpu.serving"):
+                engine.run_to_completion()
+        finally:
+            hvd_logger.removeHandler(caplog.handler)
+        assert _value(reg.snapshot(), "hvd_serve_kv_in_place") == 0
+        said = {r.getMessage() for r in caplog.records
+                if "did not consume the KV cache" in r.getMessage()}
+        assert len(said) == 1 and "conv, k, ssm, v" in said.pop()
+
+    def test_a_reused_slot_carries_nothing_of_its_last_occupant(self, reg):
+        """One slot, two requests in turn: the second's tokens and the
+        state its prefill leaves are those of an engine that never held
+        the first (every kind is overwritten whole; a recurrence would
+        carry a stale state forward for ever)."""
+        cfg, params = _tiny_hybrid()
+        first, second = _prompt(21, 2), _prompt(9, 3)
+        alone = _engine(cfg, params, num_slots=1)
+        want = _serve(alone, [("b", second, 8)])["b"]
+        engine = _engine(cfg, params, num_slots=1)
+        got = _serve(engine, [("a", first, 12), ("b", second, 8)])
+        assert got["b"] == want and len(got["a"]) == 12
+        # and the state itself, right after the second's prefill
+        fresh = _engine(cfg, params, num_slots=1)
+        fresh.submit(Request("b", second, max_new_tokens=1))
+        fresh.step()
+        used = _engine(cfg, params, num_slots=1)
+        _serve(used, [("a", first, 12)])
+        used.submit(Request("b", second, max_new_tokens=1))
+        used.step()
+        for kind in ("ssm", "conv"):
+            np.testing.assert_array_equal(
+                np.asarray(used.kv.arrays[kind]),
+                np.asarray(fresh.kv.arrays[kind]))
+
+    def test_a_row_outside_the_pass_keeps_its_state_bit_for_bit(
+            self, reg, monkeypatch):
+        """A two-cohort step (as during a hot swap): each pass advances
+        its own rows' ``ssm`` and ``conv`` and leaves the other cohort's
+        bit-identical; the tokens are those of one cohort."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = _tiny_hybrid()
+        requests = [("a", _prompt(7, 4), 6), ("b", _prompt(12, 5), 6)]
+        want = _serve(_engine(cfg, params), requests)
+
+        engine = _engine(cfg, params)
+        for rid, prompt, new in requests:
+            engine.submit(Request(rid, prompt, max_new_tokens=new))
+        engine.step()                      # both admitted, one decode
+        assert engine.active_count == 2
+        slot_b = next(s for s, st in engine._active.items()
+                      if st.request.request_id == "b")
+        engine._active[slot_b].generation = 1   # a second cohort,
+        engine._params_by_gen[1] = params        # on the same weights
+        passes = []
+        real = engine_mod._decode_jit
+
+        def spy(cfg_, params_, tokens, positions, state, temps, rng, mask):
+            before = {k: np.asarray(a) for k, a in state.items()}
+            out = real(cfg_, params_, tokens, positions, state, temps, rng,
+                       mask)
+            passes.append((np.asarray(mask), before,
+                           {k: np.asarray(a) for k, a in out[1].items()}))
+            return out
+        monkeypatch.setattr(engine_mod, "_decode_jit", spy)
+        done = engine.step()
+        assert len(passes) == 2
+        for mask, before, after in passes:
+            assert mask.sum() == 1
+            for kind in ("ssm", "conv"):
+                held = ~mask
+                np.testing.assert_array_equal(after[kind][:, held],
+                                              before[kind][:, held])
+                assert (after[kind][:, mask] != before[kind][:, mask]).any()
+        rec = hvd_tracing_steps()[-1]
+        assert rec["cohorts"] == 2 and rec["state_rows"] == 2
+        assert rec["state_bytes"] == 2 * 2 * engine.kv.row_state_bytes()
+        got = {r.request_id: list(r.tokens) for r in done}
+        got.update({r.request_id: list(r.tokens)
+                    for r in engine.run_to_completion()})
+        assert got == want
+
+    def test_the_step_record_counts_the_state(self, reg):
+        cfg, params = _tiny_hybrid()
+        engine = _engine(cfg, params)
+        engine.submit(Request("r", _prompt(5, 1), max_new_tokens=4))
+        engine.step()
+        row = engine.kv.row_state_bytes()
+        rec = hvd_tracing_steps()[-1]
+        # one admission (its row written once) and one decode pass
+        assert rec["admitted"] == 1 and rec["state_rows"] == 1
+        assert rec["state_bytes"] == row + 2 * row
+        engine.step()
+        rec = hvd_tracing_steps()[-1]
+        assert rec["state_rows"] == rec["active"] == 1
+        assert rec["state_bytes"] == 2 * row
+        # K/V alone: the counts are there and read 0
+        cfg2, params2 = _tiny()
+        plain = _engine(cfg2, params2)
+        plain.submit(Request("p", (5, 9, 17), max_new_tokens=3))
+        plain.step()
+        rec = hvd_tracing_steps()[-1]
+        assert rec["state_rows"] == 0 and rec["state_bytes"] == 0
+
+
+def hvd_tracing_steps():
+    from horovod_tpu.utils import tracing as hvd_tracing
+    return hvd_tracing.get_tracer().steps()
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2), (20, 4), (6, 1)])
+def test_grouped_query_decode_attention_is_the_equal_heads_path(heads,
+                                                                kv_heads):
+    """Fewer key/value heads than query heads: the same values as the
+    equal-heads path on K/V repeated per group (query head i reads
+    key/value head i // (heads / kv_heads))."""
+    from horovod_tpu.ops.flash_attention import decode_attention
+    k0, k1, k2 = jax.random.split(jax.random.PRNGKey(heads * 7 + kv_heads), 3)
+    b, s, d = 3, 24, 16
+    q = jax.random.normal(k0, (b, 1, heads, d))
+    k = jax.random.normal(k1, (b, s, kv_heads, d))
+    v = jax.random.normal(k2, (b, s, kv_heads, d))
+    lengths = jnp.asarray([1, 9, 24])
+    rep = heads // kv_heads
+    want = decode_attention(q, jnp.repeat(k, rep, axis=2),
+                            jnp.repeat(v, rep, axis=2), lengths)
+    got = decode_attention(q, k, v, lengths)
+    assert got.shape == (b, 1, heads, d)
+    # float32 sums in another order
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    with pytest.raises(ValueError, match="query heads"):
+        decode_attention(jnp.zeros((1, 1, 3, d)), jnp.zeros((1, s, 2, d)),
+                         jnp.zeros((1, s, 2, d)), jnp.asarray([1]))
