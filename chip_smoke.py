@@ -368,6 +368,14 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     _check(kv_in_place != 0,
            "the KV cache is copied every step, not updated in place "
            "(a donation was dropped)")
+    # ...and no step that waits for the host where nothing waits for the
+    # step: with more requests than slots every slot stays busy for most
+    # of a pass, and those steps return with their decode pass in flight
+    steps_ahead = (reg.counter("hvd_serve_steps_ahead_total").value
+                   if reg.enabled else None)
+    _check(steps_ahead != 0 or len(lengths) <= slots,
+           "every slot was busy and no step ran ahead: each decode pass "
+           "waited for the host to read the one before")
 
     # reference: one full-attention forward over prompt + served tokens
     ref_cfg = dataclasses.replace(cfg, attention_impl="full")
@@ -400,7 +408,7 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
          greedy_exact=exact, greedy_ties=ties,
          worst_logit_deficit=round(worst, 5), tie_tol=tie_tol,
          prefill_compiles=compiled[0], decode_compiles=compiled[1],
-         kv_in_place=kv_in_place,
+         kv_in_place=kv_in_place, steps_ahead=steps_ahead,
          setup_seconds=round(cold_s - warm_s, 2),
          request_seconds=round(warm_s, 3),
          ttft_seconds_warm=round(float(np.median(

@@ -12,10 +12,45 @@ retire finishers. The device work is shape-static by construction:
     variants at max_len / block; causal masking makes the pads inert
     for attention, and a model with a recurrent state is told the true
     length (decode.prefill).
-  * exactly ONE host readback per decode step (the sampled token ids)
+  * exactly ONE host readback per decode pass (the sampled token ids)
     and one per prefill (the first token) — the contract hvdlint HVD011
     enforces over this package; both sites carry the sanctioned
     disable marker.
+
+The decode step does not wait for the host. The engine has no stop
+token: a row ends by its count, its deadline or the block ledger, all
+host state that no sampled id can change. So the host knows before it
+launches a pass which rows the pass advances, where, and which of them
+it finishes; only the ids are unknown, and the next pass takes those
+from the device:
+
+  * what a pass feeds on lives on the device. ``_decode_jit`` returns
+    the ids it sampled and the positions moved on by one, and both are
+    the next call's arguments as they are; temperatures and the set of
+    rows stay where they were put. The host writes positions,
+    temperatures and rows again only in a step where a row joined or
+    left (``_place_rows``); a prefill's first token goes into the ids on
+    the device, with its slot write. The sampling key is folded inside
+    the program from the engine's key and the host's step count
+    (prefills and passes share the count, one each). A step that only
+    decodes uploads that count and nothing else.
+  * the read of a pass's ids happens where it delays nothing. After
+    launching pass k, ``_decode`` reads it at once if something at the
+    boundary waits for it (``_due``: a row gets its last token, a free
+    slot could admit a request at the next boundary, two weight
+    generations are live) and otherwise returns with it in flight; the
+    next ``step()`` launches pass k+1 FIRST and then reads and books
+    pass k while the chip runs k+1 (``_read_unread``, one statement
+    either way). At most one pass is ever unread. A request therefore
+    never finds a second program queued ahead of its prefill: its first
+    token comes when the synchronous order would have given it.
+  * what is known at launch is booked at launch: a row's count of
+    tokens (``_Active.given``), its position and its cache length
+    (``kv.ledger.grow``, which can refuse BEFORE the launch: the row
+    then goes ``kv_exhausted`` without that pass). The values are
+    appended at the read, and a deadline is seen there: a row that blew
+    it while a pass was in flight has wasted one row of that pass,
+    never got a wrong or an extra token.
 
 The drain policy turns the same engine into the static-batch baseline
 (admit only into an idle batch, run the wave to completion) that
@@ -63,40 +98,64 @@ def _prefill_jit(cfg, params, tokens, last_index, temperature, rng):
 # one-row-a-slot scatter, the state update and the one-slot write land in
 # place, not behind a copy of each array.
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(4,))
-def _decode_jit(cfg, params, tokens, positions, state, temps, rng,
-                mask=None):
-    logits, state = decode(cfg, params, tokens, positions, state, mask)
-    return sample_tokens(rng, logits, temps), state
+def _decode_jit(cfg, params, tokens, positions, state, temps, rows, key,
+                count):
+    """One decode pass over the rows in ``rows`` ([slots] bool), and what
+    the NEXT pass over the same rows feeds on, so that nothing of it has
+    to come back through the host: (ids, positions, state). ``ids`` is
+    ``tokens`` with the pass's rows replaced by what they sampled (the
+    other rows keep theirs: another cohort's, or an idle slot's junk);
+    ``positions`` moved on by one for the pass's rows. Neither is donated:
+    the host reads ``ids``, possibly a step later. The sampling key is
+    folded here from the engine's ``key`` and the host's step ``count``
+    (the same bits as ``fold_in`` on the host, without its dispatch).
+    A model with a recurrent state takes ``rows`` as its mask; K/V alone
+    need none (the host parks the other rows' positions at max_len - 1)."""
+    logits, state = decode(cfg, params, tokens, positions, state, rows)
+    rng = jax.random.fold_in(key, count)
+    ids = jnp.where(rows, sample_tokens(rng, logits, temps), tokens)
+    return ids, positions + rows.astype(positions.dtype), state
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _write_slot(state, row, slot):
+def _write_slot(state, row, slot, ids, token):
     """Write what a prefill left (``row``: {kind: [layers, 1, ...]}) into
-    cache row ``slot`` (dynamic index) of every kind. The extent along
-    the axis after the slot is the row's own (static): the padded prefix
-    for K/V, the whole of it for a kind that is no sequence — nothing of
-    the slot's last occupant is left in those."""
-    return {kind: arr.at[:, slot, :row[kind].shape[2]].set(row[kind][:, 0])
-            for kind, arr in state.items()}
+    cache row ``slot`` (dynamic index) of every kind, and its first
+    ``token`` into the device's ``ids`` at that slot: (state, ids). The
+    extent along the axis after the slot is the row's own (static): the
+    padded prefix for K/V, the whole of it for a kind that is no sequence
+    — nothing of the slot's last occupant is left in those."""
+    state = {kind: arr.at[:, slot, :row[kind].shape[2]].set(row[kind][:, 0])
+             for kind, arr in state.items()}
+    return state, ids.at[slot].set(token)
 
 
 class _Active:
     """Host-side per-slot decode state."""
 
-    __slots__ = ("request", "generated", "next_token", "next_pos",
-                 "last_token_ts", "ttft_s", "generation")
+    __slots__ = ("request", "generated", "next_pos", "last_token_ts",
+                 "ttft_s", "generation")
 
     def __init__(self, request, first_token, prompt_len, now,
                  generation=0):
         self.request = request
+        # the token VALUES the host has read; the last of them, or the
+        # one a pass in flight sampled, is in the device's ids
         self.generated = [first_token]
-        self.next_token = first_token  # fed to the next decode step
-        self.next_pos = prompt_len  # cache position it will occupy
+        # cache position the next pass writes; moved on when a pass is
+        # LAUNCHED, so it counts the tokens the row has been given
+        self.next_pos = prompt_len
         self.last_token_ts = now
         self.ttft_s = now - request.arrival_ts
         # weight generation that admitted this request: it decodes on
         # these weights to the end, across any hot swap (docs/fleet.md)
         self.generation = generation
+
+    @property
+    def given(self):
+        """Tokens the row has been given, the launch-time truth:
+        len(generated), plus one while a pass over it is unread."""
+        return self.next_pos - len(self.request.prompt) + 1
 
 
 class ServeEngine:
@@ -174,6 +233,17 @@ class ServeEngine:
         self._draining = False
         self._active = {}  # slot -> _Active
         self._finished = []
+        # What a decode pass feeds on stays on the device (docs/serving.md,
+        # "The step's order"). ``_ids``: the last token of every row, a
+        # prefill's first token or what the last pass sampled; it never
+        # comes from the host. ``_feed``: (positions, temperatures, rows)
+        # as the last pass left them for the next one, or None when the
+        # host has to write them again: a row joined or left, or the pass
+        # was one cohort's of several. ``_unread``: the ONE pass that may
+        # be in flight with its ids not read yet, (ids, [(slot, _Active)]).
+        self._ids = self._put(np.zeros(num_slots, np.int32))
+        self._feed = None
+        self._unread = None
         reg = self._metrics = hvd_metrics.get_registry()
         self._m_requests = reg.counter(
             "hvd_serve_requests_total",
@@ -203,6 +273,12 @@ class ServeEngine:
             "of state among them (the cache is updated in place); 0 "
             "when a donation was dropped and every call copies that "
             "array; no value before either.")
+        self._m_ahead = reg.counter(
+            "hvd_serve_steps_ahead_total",
+            "Engine steps that returned with their decode pass in flight "
+            "and its ids unread (every slot busy, no row finishing, one "
+            "weight generation): the next step launches its pass first "
+            "and reads this one while the chip runs.")
         state_bytes = reg.gauge(
             "hvd_serve_state_bytes",
             "Bytes of per-slot serving state resident on one chip, by "
@@ -349,7 +425,7 @@ class ServeEngine:
         off these)."""
         ledger = self.kv.ledger
         sub = self._subscriber
-        work = sum(max(st.request.max_new_tokens - len(st.generated), 0)
+        work = sum(max(st.request.max_new_tokens - st.given, 0)
                    for st in self._active.values())
         queued_tokens = (self.queue.queued_work_tokens()
                          if hasattr(self.queue, "queued_work_tokens")
@@ -383,14 +459,10 @@ class ServeEngine:
         on a clean spec tree (and always on an unsharded engine, where
         nothing is declared sharded)."""
         from ..models.transformer import param_specs
-        S = self.kv.num_slots
-        tokens = jnp.zeros(S, jnp.int32)
-        positions = jnp.zeros(S, jnp.int32)
-        temps = jnp.zeros(S, jnp.float32)
+        positions, temps, rows = self._place_rows(())
         lowered = _decode_jit.lower(
-            self.cfg, self.params, tokens, positions, self.kv.arrays,
-            temps, jax.random.PRNGKey(0),
-            jnp.ones(S, bool) if self.kv.recurrent else None)
+            self.cfg, self.params, self._ids, positions, self.kv.arrays,
+            temps, rows, self._rng, np.int32(0))
         hlo = lowered.compile().as_text()
         return hvd_memory.scan_resharding(
             hlo, self.params, param_specs(self.params), self.mesh,
@@ -579,7 +651,11 @@ class ServeEngine:
                 rng)
             kv = self.kv
             went_in = kv.arrays
-            kv.arrays = _write_slot(went_in, row, jnp.int32(slot))
+            # the first token joins the device's ids where it is: the
+            # next decode pass takes it from there, not from the host
+            kv.arrays, self._ids = _write_slot(
+                went_in, row, jnp.int32(slot), self._ids, tok)
+            self._feed = None  # a row joined
             if "write_slot" in self._in_place_unchecked:
                 self._note_in_place("write_slot", went_in)
             rec.count("admitted")
@@ -606,16 +682,41 @@ class ServeEngine:
             if req.max_new_tokens <= 1:
                 self._retire(slot, "completed")
 
+    def _put(self, array):
+        """Small host arrays (a tree of them) beside the cache, COMMITTED
+        there (replicated over a mesh) like everything a program hands
+        back: jit tells an argument that is committed from one that is not,
+        and a pass fed by the host must be the same call as one fed by the
+        pass before it."""
+        return jax.device_put(array, self.kv.vector_sharding)
+
+    def _place_rows(self, slots):
+        """(positions, temperatures, rows) on the device for a pass over
+        ``slots``, written from the host's own state. Every other row
+        parks its K/V write at max_len - 1."""
+        S = self.kv.num_slots
+        positions = np.full(S, self.kv.max_len - 1, np.int32)
+        temps = np.zeros(S, np.float32)
+        rows = np.zeros(S, bool)
+        for slot in slots:
+            st = self._active[slot]
+            positions[slot] = st.next_pos
+            temps[slot] = st.request.temperature
+            rows[slot] = True
+        return self._put((positions, temps, rows))
+
     def _decode(self):
+        """Launch one decode pass over the active rows, then read what is
+        due: the pass a step ago that is still unread, and this one too
+        unless nothing at the boundary waits for it (``_due``). The module
+        docstring has the order and why."""
         if not self._active:
-            return False
+            return False  # and nothing unread: a last row is read at once
         rec = self._rec
         with rec.phase("decode_prepare"):
             # one span per fused step, its duration attributed to every
             # request active during the tick (serving/tracing.py)
             tick = rec.tick_span(**self.scheduler.snapshot())
-            in_tick = list(self._active.values())
-            S = self.kv.num_slots
             # Cohort-partitioned decode (docs/fleet.md): a request
             # decodes on the weights that admitted it, across any hot
             # swap, so each live generation runs its own fused pass over
@@ -625,75 +726,118 @@ class ServeEngine:
             # real value — each pass writes then attends, so even a
             # final-token write at max_len-1 is read only after it
             # lands. A recurrent state has nowhere to park: a model
-            # that keeps one is told the pass's rows (``mask``) and
-            # leaves every other row's state bit for bit. Between swaps
-            # there is exactly one cohort and this is the same single
-            # fused call as always.
-            cohorts = {}
-            for slot, st in self._active.items():
-                cohorts.setdefault(st.generation, []).append(slot)
-            rec.count("active", len(in_tick))
-            rec.count("cohorts", len(cohorts))
-        ids = {}  # generation -> that pass's sampled ids, every slot
-        for gen in sorted(cohorts):
+            # that keeps one is told the pass's rows and leaves every
+            # other row's state bit for bit. Between swaps there is
+            # exactly one cohort and this is the same single fused call
+            # as always. (The generations are taken before a row can be
+            # refused below: a pass consumes its step count, and so its
+            # key, even if its last row has just gone.)
+            gens = sorted({st.generation for st in self._active.values()})
+        # Everything that decides which rows a pass advances is host state
+        # and known before the launch: the ledger says so here, a count
+        # says which rows the pass finishes, and no sampled id can change
+        # either. A row the ledger refuses goes without the pass, with the
+        # tokens it has, the last of them possibly still in flight.
+        for slot in list(self._active):
+            st = self._active.get(slot)
+            if st is not None and \
+                    not self.kv.ledger.grow(slot, st.next_pos + 1):
+                self._read_unread(tick)
+                if self._active.get(slot) is st:
+                    with rec.phase("bookkeeping"):
+                        self._retire(slot, "failed", reason="kv_exhausted")
+        launched = []
+        one = len(gens) == 1
+        # what one cohort of several left is of no use to this pass
+        feed, self._feed = self._feed if one else None, None
+        for gen in gens:
             with rec.phase("decode_prepare"):
-                tokens = np.zeros(S, np.int32)
-                positions = np.full(S, self.kv.max_len - 1, np.int32)
-                temps = np.zeros(S, np.float32)
-                for slot in cohorts[gen]:
-                    st = self._active[slot]
-                    tokens[slot] = st.next_token
-                    positions[slot] = st.next_pos
-                    temps[slot] = st.request.temperature
-                mask = None
-                if self.kv.recurrent:
-                    mask = np.zeros(S, bool)
-                    mask[cohorts[gen]] = True
-                    mask = jnp.asarray(mask)
-                    rec.count("state_rows", len(cohorts[gen]))
-                    rec.count("state_bytes", 2 * len(cohorts[gen])
-                              * self._row_state_bytes)
-                rng = jax.random.fold_in(self._rng, self._step_count)
+                slots = [slot for slot, st in self._active.items()
+                         if st.generation == gen]
+                count = np.int32(self._step_count)
                 self._step_count += 1
+                if not slots:
+                    continue
+                positions, temps, rows = feed or self._place_rows(slots)
+                for slot in slots:
+                    st = self._active[slot]
+                    st.next_pos += 1
+                    launched.append((slot, st))
+                if self.kv.recurrent:
+                    rec.count("state_rows", len(slots))
+                    rec.count("state_bytes",
+                              2 * len(slots) * self._row_state_bytes)
                 # decode is shape-static by construction: one miss at
                 # the first step, hits forever — a second miss here IS
                 # the bug
                 if hvd_memory.enabled():
                     hvd_memory.get_tracker().observe(
-                        "serve_decode", (tokens, positions, temps))
-                tokens, positions, temps = (
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(temps))
+                        "serve_decode", (self._ids, positions, temps))
             with rec.phase("decode_dispatch"):
                 kv = self.kv
                 went_in = kv.arrays
-                nxt, kv.arrays = _decode_jit(
-                    self.cfg, self._params_by_gen[gen], tokens,
-                    positions, went_in, temps, rng, mask)
+                self._ids, positions, kv.arrays = _decode_jit(
+                    self.cfg, self._params_by_gen[gen], self._ids,
+                    positions, went_in, temps, rows, self._rng, count)
+                if one:  # what the next pass over the same rows feeds on
+                    self._feed = (positions, temps, rows)
                 if "decode" in self._in_place_unchecked:
                     self._note_in_place("decode", went_in)
-            with rec.phase("decode_readback"):
-                # the one sanctioned per-step readback (one per cohort
-                # during a swap transition): this pass's sampled ids
-                # hvdlint: disable=HVD011(the per-step batched token readback)
-                ids[gen] = np.asarray(jax.device_get(nxt))
-        with rec.phase("telemetry"):
-            tick_us = serve_tracing.finish_tick(tick, len(in_tick),
-                                                self._slow_tick_us)
-            for st in in_tick:
-                serve_tracing.trace_of(st.request).on_decode_tick(tick_us)
+        rec.count("active", len(launched))
+        rec.count("cohorts", len(gens))
+        # the pass before this one first, while the chip runs this one
+        self._read_unread(tick)
+        if launched:
+            self._unread = (self._ids, launched)
+            if self._due(launched, len(gens)):
+                self._read_unread(tick)
+            else:
+                rec.count("ahead")
+                self._m_ahead.inc()
+        self._close_tick(tick)
+        return True
+
+    def _due(self, launched, cohorts):
+        """Does anything at this step's boundary wait for the pass just
+        launched? Then it is read before the step returns, today's order;
+        otherwise the step returns with it in flight and the next step
+        launches its pass before it reads this one. Decided from what the
+        engine sees, at every step:
+
+          * a row gets its last token in this pass: its result is due and
+            its slot frees, which in a closed loop is what brings the next
+            request;
+          * a request could join at the next boundary (a free slot, under
+            either policy): it must not find a second program queued
+            ahead of its prefill, so first-token latency stays what the
+            synchronous order gives;
+          * more than one weight generation is live (a hot swap's
+            cohorts): one pass each, read together.
+        """
+        return (cohorts > 1 or self.scheduler.can_join() or
+                any(st.given >= st.request.max_new_tokens
+                    for _, st in launched))
+
+    def _read_unread(self, tick):
+        """Read the ids of the pass in flight, if there is one, and book
+        them: the ONE place a decode pass's ids cross to the host, at the
+        end of the step that launched it or after the next launch."""
+        if self._unread is None:
+            return
+        rec = self._rec
+        (ids, launched), self._unread = self._unread, None
+        with rec.phase("decode_readback"):
+            # the one sanctioned readback per pass (during a swap
+            # transition one for the cohorts' passes together): the ids
+            # hvdlint: disable=HVD011(the per-step batched token readback)
+            ids = np.asarray(jax.device_get(ids))
+        self._close_tick(tick)
         with rec.phase("bookkeeping"):
             now = self._clock()
-            for slot in list(self._active):
-                st = self._active[slot]
-                # the fed token's K/V landed at next_pos this step
-                if not self.kv.ledger.grow(slot, st.next_pos + 1):
-                    self._retire(slot, "failed", reason="kv_exhausted")
-                    continue
-                tok = int(ids[st.generation][slot])
-                st.generated.append(tok)
-                st.next_token = tok
-                st.next_pos += 1
+            for slot, st in launched:
+                if self._active.get(slot) is not st:
+                    continue  # left at an earlier read: its pass is waste
+                st.generated.append(int(ids[slot]))
                 self._m_intertoken.observe(now - st.last_token_ts)
                 st.last_token_ts = now
                 self._m_tokens.labels(phase="decode").inc()
@@ -703,7 +847,18 @@ class ServeEngine:
                 elif (req.deadline_s is not None and
                         now - req.arrival_ts > req.deadline_s):
                     self._retire(slot, "failed", reason="deadline")
-        return True
+
+    def _close_tick(self, tick):
+        """Close the step's decode-tick span at its first call in a step
+        (after the step's first readback, or at the end of a step that
+        read nothing) and attribute it to the requests still decoding."""
+        if not tick.open:
+            return
+        with self._rec.phase("telemetry"):
+            tick_us = serve_tracing.finish_tick(tick, len(self._active),
+                                                self._slow_tick_us)
+            for st in self._active.values():
+                serve_tracing.trace_of(st.request).on_decode_tick(tick_us)
 
     def _note_in_place(self, program, went_in):
         """After the first call of a cache-writing program on this
@@ -729,6 +884,7 @@ class ServeEngine:
     def _retire(self, slot, outcome, reason=""):
         self._rec.count("retired")
         st = self._active.pop(slot)
+        self._feed = None  # a row left
         self.kv.ledger.free(slot)
         self.scheduler.retire(slot)
         self._m_requests.labels(outcome=outcome).inc()

@@ -171,6 +171,10 @@ class KVCache:
     raised by one of those calls the contents are gone: build a new
     engine. Shapes, dtypes and shardings of the current arrays stay
     readable at any time (``per_chip_bytes``, lowering).
+
+    ``vector_sharding`` is where a ``[slots]`` vector of the engine's
+    goes to sit beside the cache (the device's ids, positions,
+    temperatures, rows): the cache's device, or replicated over the mesh.
     """
 
     def __init__(self, cfg, num_slots, max_len=None, block_size=None,
@@ -182,7 +186,13 @@ class KVCache:
                                   block_size=block_size,
                                   total_blocks=total_blocks)
         shapes = decode.state_shapes(cfg, num_slots, max_len)
-        self.arrays = {kind: jnp.zeros(a.shape, a.dtype)
+        # COMMITTED to the device a new array lands on, from the start: a
+        # program's results are committed as soon as one argument is, and
+        # an array that is committed in one call and not in the next is
+        # another signature to jit (a second compile of the same program)
+        self.vector_sharding = jnp.zeros((), jnp.int32).sharding
+        self.arrays = {kind: jnp.zeros(a.shape, a.dtype,
+                                       device=self.vector_sharding)
                        for kind, a in shapes.items()}
         # kinds a decode pass must not touch for rows it does not decode
         # (K/V of such a row park at max_len - 1; these cannot)
@@ -201,6 +211,7 @@ class KVCache:
             spec = mesh_lib.kv_cache_spec(shapes["k"].shape[3], mesh)
             self.k, self.v = mesh_lib.device_put_tree(
                 (self.k, self.v), (spec, spec), mesh)
+            self.vector_sharding = mesh_lib.named_sharding(None, mesh)
         self.max_len = max_len
 
     @property

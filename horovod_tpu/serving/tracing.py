@@ -344,8 +344,11 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # rows whose state the step's decode passes advanced (== active outside
 # a hot swap), and bytes of that state the step's programs had to move
 # (read + write per advanced row, one write per admitted row)
+# ahead: 1 when the step returned with its decode pass in flight, its ids
+# unread (serving/engine.py ``_due``); the NEXT step's ``decode_readback``
+# then starts with that pass's ids. 0 on every other step that decoded.
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
-               "state_rows", "state_bytes")
+               "state_rows", "state_bytes", "ahead")
 _STEP_ANNOTATION = "hvd.serve.step"
 _PHASE_ANNOTATIONS = {p: "hvd.serve." + p for p in STEP_PHASES}
 

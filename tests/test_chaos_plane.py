@@ -2460,9 +2460,12 @@ class TestDrillAlertPlane:
             assert state() == "inactive"
 
             # phase 2: KV-pressure overload — slots saturate with
-            # decodes that blow staggered sub-second deadlines, so
-            # every admitted token is wasted work, by reason, and at
-            # any instant some requests sit admitted-but-unretired.
+            # decodes that blow staggered deadlines of one to five
+            # steps, so every admitted token is wasted work, by reason,
+            # and at any instant some requests sit admitted-but-
+            # unretired. (A full engine sees a blown deadline when it
+            # reads the pass, a step after launching it: with deadlines
+            # only a step apart all four rows could leave in one step.)
             t_pending = t_firing = None
             j = 0
             guard = 0
@@ -2470,7 +2473,7 @@ class TestDrillAlertPlane:
                 while len(eng.queue) < 4:
                     eng.submit(Request(f"kv-{j}", (3, 1, 4),
                                        max_new_tokens=16,
-                                       deadline_s=0.3 + 0.2 * (j % 3)))
+                                       deadline_s=0.3 + 0.45 * (j % 3)))
                     j += 1
                 results.extend(eng.step())
                 s = state()

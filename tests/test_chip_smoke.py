@@ -78,6 +78,7 @@ def test_serve_leg(tiny, capsys):
     line = _last_json(capsys)
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
+    assert line["steps_ahead"] > 0   # three requests on two slots
 
 
 def test_a_failing_leg_fails_the_run(monkeypatch, tmp_path):
@@ -230,11 +231,13 @@ def test_the_serving_programs_update_the_cache_in_place(topo, program):
                            jax.random.PRNGKey(0)))
         lowered = engine_mod._decode_jit.lower(
             cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
-            state, arr((slots,), jnp.float32), arr((2,), jnp.uint32))
+            state, arr((slots,), jnp.float32), arr((slots,), jnp.bool_),
+            arr((2,), jnp.uint32), arr((), jnp.int32))
     else:
         pk = arr((layers, 1, 1024, heads, head_dim), jnp.bfloat16)
-        lowered = engine_mod._write_slot.lower(state, {"k": pk, "v": pk},
-                                               arr((), jnp.int32))
+        lowered = engine_mod._write_slot.lower(
+            state, {"k": pk, "v": pk}, arr((), jnp.int32),
+            arr((slots,), jnp.int32), arr((), jnp.int32))
     compiled = lowered.compile()
     cache_bytes = 2 * layers * slots * max_len * heads * head_dim * 2
     assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
@@ -294,14 +297,15 @@ def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program):
                            jax.random.PRNGKey(0)))
         lowered = engine_mod._decode_jit.lower(
             cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
-            state, arr((slots,), jnp.float32), arr((2,), jnp.uint32),
-            arr((slots,), jnp.bool_))
+            state, arr((slots,), jnp.float32), arr((slots,), jnp.bool_),
+            arr((2,), jnp.uint32), arr((), jnp.int32))
     else:
         row = {k: arr((a.shape[0], 1) + ((256,) + a.shape[3:]
                                          if k in "kv" else a.shape[2:]),
                       a.dtype) for k, a in state.items()}
-        lowered = engine_mod._write_slot.lower(state, row,
-                                               arr((), jnp.int32))
+        lowered = engine_mod._write_slot.lower(
+            state, row, arr((), jnp.int32), arr((slots,), jnp.int32),
+            arr((), jnp.int32))
     compiled = lowered.compile()
     import math
     state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize

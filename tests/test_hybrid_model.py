@@ -87,7 +87,9 @@ def served_logits(hcfg, params, tokens, prompt_len, block, max_len,
                               jnp.int32(prompt_len - 1))
     state = {k: jnp.zeros(a.shape, a.dtype) for k, a in
              serve_decode.state_shapes(hcfg, slots, max_len).items()}
-    state = engine_mod._write_slot(state, state_row, jnp.int32(slot))
+    state, _ = engine_mod._write_slot(state, state_row, jnp.int32(slot),
+                                      jnp.zeros(slots, jnp.int32),
+                                      jnp.int32(tokens[prompt_len]))
     out = [np.asarray(row[0])]
     mask = np.zeros(slots, bool)
     mask[slot] = True
@@ -211,13 +213,19 @@ def test_the_init_rule_is_applied_alike_by_program_and_reference():
     assert ref.gain(cfg, "layers.0.attn.q") == 1.0
 
 
-def test_the_engine_serves_the_hybrid_model_like_any_other():
+@pytest.mark.parametrize("slots,runs_ahead", [(2, True), (4, False)])
+def test_the_engine_serves_the_hybrid_model_like_any_other(slots,
+                                                           runs_ahead):
     """Through ``ServeEngine`` itself (submit/step, scheduler, queue,
     ledger, the three jitted programs): greedy tokens of prompts of
     several lengths, batched and joined mid-stream, are the reference's
-    own greedy continuation."""
+    own greedy continuation - with every slot busy, where a step's ids
+    are read a step late and the next pass feeds on the device's own,
+    and with a slot kept free, where every pass is read at once."""
     from horovod_tpu.serving import ServeEngine
     from horovod_tpu.serving.queue import Request
+    from horovod_tpu.utils import metrics as hvd_metrics
+    reg = hvd_metrics.reset(enabled=True)
     cfg = tiny_config(mamba_chunk_size=16)
     w = drawn(cfg)
     hcfg, params = model(cfg, w, jnp.float32)
@@ -225,7 +233,7 @@ def test_the_engine_serves_the_hybrid_model_like_any_other():
     prompts = [tuple(int(t) for t in rng.integers(0, 256, n))
                for n in (5, 16, 23)]
     with jax.default_matmul_precision("highest"):
-        engine = ServeEngine(hcfg, params, num_slots=2, max_len=64,
+        engine = ServeEngine(hcfg, params, num_slots=slots, max_len=64,
                              kv_block=16)
         for i, p in enumerate(prompts):
             engine.submit(Request(f"r{i}", p, max_new_tokens=6))
@@ -241,6 +249,10 @@ def test_the_engine_serves_the_hybrid_model_like_any_other():
             assert len(got[f"r{i}"]) == 6
             assert got[f"r{i}"] == [int(t) for t in
                                     jnp.argmax(logits, axis=-1)]
+    ahead = reg.snapshot()["metrics"]["hvd_serve_steps_ahead_total"]
+    hvd_metrics.reset()
+    assert bool(ahead["values"] and ahead["values"][0]["value"]) == \
+        runs_ahead
     assert engine.kv.ledger.blocks_in_use == 0
     assert set(engine.kv.arrays) == {"k", "v", "ssm", "conv"}
     assert engine.kv.arrays["k"].shape[3] == hcfg.num_kv_heads

@@ -399,22 +399,43 @@ class TestStepTrace:
                             *serve_tracing.STEP_COUNTS}
         assert rec["seq"] == 1 and _tiles(rec)
         one = ["admit", "prefill", "prefill_readback", "bookkeeping"]
+        # both slots busy and neither row on its last token: the step
+        # returns with its pass in flight, and reads nothing
         assert [p[0] for p in rec["phases"]] == ["control"] + one + one + [
-            "decode_prepare", "decode_dispatch", "decode_readback",
-            "telemetry", "bookkeeping", "telemetry"]
+            "decode_prepare", "decode_dispatch", "telemetry"]
         # nothing but the phase's own closing read inside these
         tick = SteppingUsClock.TICK
-        for name, start, end in rec["phases"]:
-            if name in ("control", "decode_dispatch", "decode_readback",
-                        "prefill_readback"):
-                assert end - start == tick, name
+
+        def bare(rec):
+            for name, start, end in rec["phases"]:
+                if name in ("control", "decode_dispatch", "decode_readback",
+                            "prefill_readback"):
+                    assert end - start == tick, name
+        bare(rec)
         # the counts are what the scheduler did in that step
         assert rec["admitted"] == len(joins) == 2
         assert rec["active"] == 2 and rec["cohorts"] == 1
         assert rec["prompt_tokens"] == 3 + 5
         assert rec["retired"] == len(retires) == 0
-        done = engine.run_to_completion()
+        assert rec["ahead"] == 1
+        # the next step launches its pass, then reads the one before
+        # (the first readback) and, b being on its last token, its own
+        (b,) = engine.step()
+        assert b.request_id == "b" and len(b.tokens) == 3
+        rec = stepping.steps()[-1]
+        assert [p[0] for p in rec["phases"]] == [
+            "control", "decode_prepare", "decode_dispatch",
+            "decode_readback", "telemetry", "bookkeeping",
+            "decode_readback", "bookkeeping", "telemetry"]
+        bare(rec)
+        assert _tiles(rec) and rec["ahead"] == 0 and rec["active"] == 2
+        # a slot is free from here on: the synchronous order, as ever
+        done = [b] + engine.run_to_completion()
         recs = stepping.steps()
+        assert [p[0] for p in recs[-1]["phases"]] == [
+            "control", "admit", "decode_prepare", "decode_dispatch",
+            "decode_readback", "telemetry", "bookkeeping", "telemetry"]
+        bare(recs[-1])
         assert [r["seq"] for r in recs] == list(range(1, len(recs) + 1))
         assert all(_tiles(r) for r in recs)
         assert sum(r["retired"] for r in recs) == len(retires) == \

@@ -567,12 +567,13 @@ class TestRecurrentStateInTheCache:
         passes = []
         real = engine_mod._decode_jit
 
-        def spy(cfg_, params_, tokens, positions, state, temps, rng, mask):
+        def spy(cfg_, params_, tokens, positions, state, temps, rows, key,
+                count):
             before = {k: np.asarray(a) for k, a in state.items()}
-            out = real(cfg_, params_, tokens, positions, state, temps, rng,
-                       mask)
-            passes.append((np.asarray(mask), before,
-                           {k: np.asarray(a) for k, a in out[1].items()}))
+            out = real(cfg_, params_, tokens, positions, state, temps, rows,
+                       key, count)
+            passes.append((np.asarray(rows), before,
+                           {k: np.asarray(a) for k, a in out[2].items()}))
             return out
         monkeypatch.setattr(engine_mod, "_decode_jit", spy)
         done = engine.step()
@@ -618,6 +619,343 @@ class TestRecurrentStateInTheCache:
 def hvd_tracing_steps():
     from horovod_tpu.utils import tracing as hvd_tracing
     return hvd_tracing.get_tracer().steps()
+
+
+# ---------------------------------------------------------------------------
+# The decode step does not wait for the host (docs/serving.md, "The step's
+# order"): a step's ids are read a step late wherever nothing waits for them
+# ---------------------------------------------------------------------------
+
+MODELS = {"dense": _tiny, "hybrid": _tiny_hybrid}
+
+
+def _never_ahead(engine):
+    """The synchronous order on the same engine: every pass read in the
+    step that launched it (what a free slot makes of every step)."""
+    engine._due = lambda launched, cohorts: True
+    return engine
+
+
+def _drive(engine, requests, temperature=0.0, joins=()):
+    """Submit ``requests`` [(id, prompt, new)], step to the end; ``joins``
+    [(step, (id, prompt, new))] are submitted before that step. Returns
+    ({id: RequestResult}, this engine's step records)."""
+    first = len(hvd_tracing_steps())
+    for rid, prompt, new in requests:
+        engine.submit(Request(rid, prompt, max_new_tokens=new,
+                              temperature=temperature))
+    joins, out = dict(joins), {}
+    for step in range(500):
+        if step in joins:
+            rid, prompt, new = joins.pop(step)
+            engine.submit(Request(rid, prompt, max_new_tokens=new,
+                                  temperature=temperature))
+        out.update((r.request_id, r) for r in engine.step())
+        if not engine.active_count and not len(engine.queue) and not joins:
+            break
+    return out, hvd_tracing_steps()[first:]
+
+
+def _tokens(results):
+    return {rid: list(r.tokens) for rid, r in results.items()}
+
+
+REQUESTS = [("a", (5, 9, 17), 9), ("b", (4, 8, 15, 16, 23, 42), 13),
+            ("c", (7, 7, 1), 6)]
+
+
+class TestTheStepDoesNotWaitForTheHost:
+    @pytest.fixture(autouse=True)
+    def tracer(self):
+        from horovod_tpu.utils import tracing as hvd_tracing
+        hvd_tracing.reset(enabled=True, rank=0)
+        yield
+        hvd_tracing.reset()
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    def test_running_ahead_gives_the_synchronous_orders_tokens(
+            self, reg, model, temperature):
+        """Two slots, three requests (the third joins when the first
+        ends): the engine that runs ahead wherever it may gives, token
+        for token and under the same keys, what the same engine gives
+        when it reads every pass at once."""
+        cfg, params = MODELS[model]()
+        ahead, recs = _drive(_engine(cfg, params, seed=3), REQUESTS,
+                             temperature)
+        sync, sync_recs = _drive(_never_ahead(_engine(cfg, params, seed=3)),
+                                 REQUESTS, temperature)
+        assert _tokens(ahead) == _tokens(sync)
+        assert {rid: len(t) for rid, t in _tokens(ahead).items()} == \
+            {rid: new for rid, _, new in REQUESTS}
+        assert all(r.outcome == "completed" for r in ahead.values())
+        # it did run ahead, and it launched the same passes over the
+        # same rows in the same steps
+        assert sum(r["ahead"] for r in recs) >= 5
+        assert not any(r["ahead"] for r in sync_recs)
+        assert [r["active"] for r in recs] == \
+            [r["active"] for r in sync_recs]
+        assert [r["retired"] for r in recs] == \
+            [r["retired"] for r in sync_recs]
+        if temperature:   # sampled: not the greedy choice all the way
+            greedy, _ = _drive(_engine(cfg, params, seed=3), REQUESTS)
+            assert _tokens(greedy) != _tokens(ahead)
+
+    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    def test_greedy_tokens_are_those_of_an_engine_with_a_slot_kept_free(
+            self, reg, model):
+        """...and what an engine that never may run ahead gives (three
+        slots for two requests at a time), and for the dense model the
+        plain per-request reference's."""
+        cfg, params = MODELS[model]()
+        two = REQUESTS[:2]
+        full, recs = _drive(_engine(cfg, params), two)
+        spare, spare_recs = _drive(_engine(cfg, params, num_slots=3), two)
+        assert sum(r["ahead"] for r in recs) >= 5
+        assert not any(r["ahead"] for r in spare_recs)
+        assert _tokens(full) == _tokens(spare)
+        if model == "dense":
+            for rid, prompt, new in two:
+                assert _tokens(full)[rid] == \
+                    _greedy_reference(cfg, params, prompt, new)
+
+    def test_a_row_ends_on_its_last_token_and_no_pass_is_wasted(
+            self, reg, monkeypatch):
+        """Every row of every pass is a token somebody gets: the passes
+        number the longest request's tokens less its first, the rows
+        they advanced the tokens asked for less the prefills'."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = _tiny()
+        real, calls = engine_mod._decode_jit, []
+        monkeypatch.setattr(
+            engine_mod, "_decode_jit",
+            lambda *a: calls.append(int(np.asarray(a[6]).sum())) or real(*a))
+        results, recs = _drive(_engine(cfg, params), REQUESTS[:2])
+        assert {rid: len(r.tokens) for rid, r in results.items()} == \
+            {"a": 9, "b": 13}
+        assert len(calls) == 13 - 1
+        assert sum(calls) == (9 - 1) + (13 - 1) == \
+            sum(r["active"] for r in recs)
+
+    @pytest.mark.parametrize("case", ["every_slot_busy", "a_free_slot",
+                                      "a_finishing_row", "two_cohorts",
+                                      "drain_policy_mid_wave"])
+    def test_ahead_is_decided_from_what_the_engine_sees(self, reg, case):
+        cfg, params = _tiny()
+        kw = {"policy": "drain", "num_slots": 3} \
+            if case == "drain_policy_mid_wave" else {}
+        engine = _engine(cfg, params, **kw)
+        engine.submit(Request("a", (5, 9, 17), max_new_tokens=9))
+        if case != "a_free_slot":
+            engine.submit(Request(
+                "b", (4, 8, 15), max_new_tokens=2 if case ==
+                "a_finishing_row" else 9))
+        if case == "two_cohorts":
+            engine.step()
+            slot = next(iter(engine._active))
+            engine._active[slot].generation = 1
+            engine._params_by_gen[1] = params
+
+        def counted():
+            return _value(reg.snapshot(),
+                          "hvd_serve_steps_ahead_total") or 0
+        before = counted()
+        engine.step()
+        rec = hvd_tracing_steps()[-1]
+        want = int(case in ("every_slot_busy", "drain_policy_mid_wave"))
+        assert rec["ahead"] == want == counted() - before
+        assert rec["active"] >= 1
+        assert (engine._unread is not None) == bool(want)
+        # whichever way, what a launch decided is true at once: the
+        # tokens a row has been given and the cache rows it holds
+        for slot, st in engine._active.items():
+            assert st.given == len(st.generated) + want
+            assert engine.kv.ledger.length(slot) == \
+                len(st.request.prompt) + st.given - 1
+        snap = engine.load_snapshot()
+        assert snap["work_tokens"] == sum(
+            st.request.max_new_tokens - st.given
+            for st in engine._active.values())
+        results = engine.run_to_completion()
+        assert all(r.outcome == "completed" for r in results)
+        assert engine._unread is None
+
+    def test_a_hot_swap_mid_flight_keeps_each_cohort_on_its_weights(
+            self, reg):
+        """A generation arrives while the engine runs ahead: the rows in
+        flight end on the weights that admitted them, the next request
+        decodes on the new ones, and while both are live every pass is
+        read at once."""
+        cfg, old = _tiny()
+        new = jax.tree_util.tree_map(lambda a: a * 1.5, old)
+
+        class Armed:
+            generation, params, step = 7, new, 0
+            detect_ts = loaded_ts = armed_ts = 0.0
+
+        class Subscriber:
+            replica, current_generation, armed_generation = 0, 0, None
+            clock = staticmethod(lambda: 0.0)
+
+            def poll(self):
+                pass
+
+            def take_armed(self):
+                rec, self.armed_generation = self.armed_generation, None
+                return rec and Armed
+
+        sub = Subscriber()
+        engine = _engine(cfg, old, subscriber=sub)
+        requests = [("a", (5, 9, 17), 8), ("b", (4, 8, 15, 16), 14),
+                    ("c", (7, 7, 1), 6)]
+        for rid, prompt, n in requests:
+            engine.submit(Request(rid, prompt, max_new_tokens=n))
+        results = {}
+        for _ in range(3):
+            engine.step()
+        assert engine._unread is not None     # running ahead on ``old``
+        sub.armed_generation = 7
+        for _ in range(100):
+            results.update((r.request_id, r) for r in engine.step())
+            if len(results) == 3:
+                break
+        assert engine.generation == 7 and engine._unread is None
+        assert [results[r].generation for r in "abc"] == [0, 0, 7]
+        for rid, prompt, n in requests:
+            params = new if rid == "c" else old
+            assert list(results[rid].tokens) == \
+                _greedy_reference(cfg, params, prompt, n), rid
+        mixed = [r for r in hvd_tracing_steps() if r["cohorts"] == 2]
+        assert mixed and not any(r["ahead"] for r in mixed)
+        assert set(engine._params_by_gen) == {7}
+
+    @pytest.mark.parametrize("order", ["ahead", "synchronous"])
+    def test_a_refusing_ledger_ends_the_row_without_that_pass(
+            self, reg, order):
+        """``kv.ledger.grow`` refuses before the launch: the row goes
+        ``kv_exhausted`` with the tokens it has (the last of them read
+        from the pass in flight), the other row never notices."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        if order == "synchronous":
+            _never_ahead(engine)
+        grow = engine.kv.ledger.grow
+        engine.kv.ledger.grow = lambda slot, n: \
+            grow(slot, n) and not (slot == 0 and n > 3 + 4)
+        engine.submit(Request("a", (5, 9, 17), max_new_tokens=12))
+        engine.submit(Request("b", (4, 8, 15, 16, 23, 42),
+                              max_new_tokens=12))
+        engine.step()
+        assert engine._active[0].request.request_id == "a"
+        results = {r.request_id: r for r in engine.run_to_completion()}
+        a, b = results["a"], results["b"]
+        assert (a.outcome, a.reason) == ("failed", "kv_exhausted")
+        # positions 3..6 were written: the first token and four more
+        assert list(a.tokens) == _greedy_reference(
+            cfg, params, (5, 9, 17), 5)
+        assert b.outcome == "completed" and list(b.tokens) == \
+            _greedy_reference(cfg, params, (4, 8, 15, 16, 23, 42), 12)
+        assert engine.kv.ledger.blocks_in_use == 0
+        assert engine._unread is None
+
+    @pytest.mark.parametrize("order", ["ahead", "synchronous"])
+    def test_a_blown_deadline_is_seen_at_the_read(self, reg, order):
+        """Two busy slots; one row's deadline passes mid-stream. It fails
+        ``deadline`` with a prefix of its tokens, never a wrong or an
+        extra one (run ahead: at most one row of one pass is wasted); the
+        other row and the request that takes the slot are untouched."""
+        cfg, params = _tiny()
+        clock = FakeClock()
+        queue = AdmissionQueue(max_depth=8, admission_timeout_s=1e9,
+                               clock=clock)
+        engine = _engine(cfg, params, queue=queue, clock=clock)
+        if order == "synchronous":
+            _never_ahead(engine)
+        slow, other, late = (1, 2), (4, 8, 15, 16), (7, 7, 1)
+        engine.submit(Request("slow", slow, max_new_tokens=20,
+                              deadline_s=5.0))
+        engine.submit(Request("other", other, max_new_tokens=14))
+        results = {}
+        for _ in range(4):
+            engine.step()
+        clock.t = 6.0
+        engine.submit(Request("late", late, max_new_tokens=5))
+        for _ in range(60):
+            results.update((r.request_id, r) for r in engine.step())
+            if len(results) == 3:
+                break
+        got = results["slow"]
+        assert (got.outcome, got.reason) == ("failed", "deadline")
+        full = _greedy_reference(cfg, params, slow, 20)
+        assert 4 <= len(got.tokens) <= 6
+        assert list(got.tokens) == full[:len(got.tokens)]
+        assert list(results["other"].tokens) == \
+            _greedy_reference(cfg, params, other, 14)
+        assert list(results["late"].tokens) == \
+            _greedy_reference(cfg, params, late, 5)
+        rows = sum(r["active"] for r in hvd_tracing_steps())
+        tokens = sum(len(r.tokens) - 1 for r in results.values())
+        assert rows - tokens == (1 if order == "ahead" else 0)
+        assert engine.kv.ledger.blocks_in_use == 0
+        assert engine._unread is None
+
+    @pytest.mark.parametrize("policy", ["continuous", "drain"])
+    def test_nothing_is_in_flight_once_no_row_is_active(self, reg, policy):
+        """``run_to_completion`` and a draining engine's last step leave
+        no pass unread: a last row's last token is always read at once."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params, policy=policy)
+        for i in range(5):
+            engine.submit(Request(f"r{i}", (1 + i, 2, 3),
+                                  max_new_tokens=4 + 3 * i))
+        seen = False
+        for _ in range(6):
+            engine.step()
+            seen |= engine._unread is not None
+        assert seen
+        engine.begin_drain()
+        assert not engine.submit(Request("refused", (1, 2)))
+        done = 0
+        while engine.active_count or len(engine.queue):
+            done += len(engine.step())
+            assert engine.active_count or engine._unread is None
+        assert engine._unread is None and engine._feed is None
+        assert engine.kv.ledger.blocks_in_use == 0
+
+    def test_the_key_folded_in_the_program_is_the_hosts(self, reg):
+        """The sampling keys are what they were when the host folded
+        them: ``fold_in`` of a traced int32 count, inside a program,
+        gives the bits of ``fold_in`` of the same Python int outside."""
+        key = jax.random.PRNGKey(3)
+        inside = jax.jit(jax.random.fold_in)
+        for count in (0, 1, 77, 2 ** 31 - 1):
+            np.testing.assert_array_equal(
+                np.asarray(inside(key, np.int32(count))),
+                np.asarray(jax.random.fold_in(key, count)))
+
+    def test_one_decode_program_whichever_way_a_step_goes(self, reg):
+        """Fed by the host (a row joined or left) or by the pass before,
+        read at once or a step late, one cohort or two: one compiled
+        ``_decode_jit``, one ``_write_slot`` a prefill shape."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        engine.submit(Request("warm", (9, 9, 9), max_new_tokens=2))
+        engine.run_to_completion()
+        before = (engine_mod._decode_jit._cache_size(),
+                  engine_mod._write_slot._cache_size(),
+                  engine_mod._prefill_jit._cache_size())
+        _, recs = _drive(engine, REQUESTS)
+        assert {r["ahead"] for r in recs} == {0, 1}
+        engine.submit(Request("d", (5, 9, 17), max_new_tokens=6))
+        engine.submit(Request("e", (4, 8, 15), max_new_tokens=6))
+        engine.step()
+        engine._active[0].generation = 1
+        engine._params_by_gen[1] = params
+        engine.run_to_completion()
+        assert (engine_mod._decode_jit._cache_size(),
+                engine_mod._write_slot._cache_size(),
+                engine_mod._prefill_jit._cache_size()) == before
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2), (20, 4), (6, 1)])

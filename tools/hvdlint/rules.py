@@ -1912,9 +1912,13 @@ latency to one device step per generated token by keeping the decode
 loop asynchronous: the host enqueues the next step's work while the
 device executes the current one, and the ONLY forced host<->device
 rendezvous are the engine's two sanctioned readbacks — the batched
-sampled-token ids once per decode step, and the first token once per
+sampled-token ids once per decode pass, and the first token once per
 prefill (both in serving/engine.py, both carrying an inline disable
-with a reason).
+with a reason). The per-pass one is still ONE statement
+(ServeEngine._read_unread); where nothing at the step's boundary waits
+for the ids it runs a step late, after the next pass is launched, so
+the chip is never idle for it. A second readback site would put the
+wait back on the chip's critical path.
 
 Any other jax.device_get(...), .block_until_ready(), or
 np.asarray(device_value) on that path adds a full host round-trip per
