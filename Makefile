@@ -4,12 +4,12 @@ PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu
 CPU_MESH = $(CPU_ENV) XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: lint lint-concurrency test native bench examples ci clean
+.PHONY: lint lint-concurrency test native examples ci clean
 
 # distributed-correctness static analysis (tools/hvdlint, docs/hvdlint.md);
 # cheapest gate, so it leads the ci chain
 lint:
-	$(PY) -m tools.hvdlint horovod_tpu tools bench.py examples
+	$(PY) -m tools.hvdlint horovod_tpu tools examples
 	$(PY) -m tools.hvdlint --check-envdoc
 
 # whole-program lock-discipline pass (docs/concurrency.md): guarded_by
@@ -23,9 +23,6 @@ native:
 
 test:
 	$(PY) -m pytest tests/ -q
-
-bench:
-	$(PY) bench.py
 
 # example smoke runs on the virtual 8-worker CPU mesh — the reference CI
 # runs its example scripts as integration tests after pytest

@@ -13,7 +13,7 @@ echo "--- hvdlint (fastest gate: distributed-correctness static analysis)"
 # exceptions, jit impurity and leaked tracing spans statically
 # (docs/hvdlint.md); then verifies
 # docs/envvars.md still matches ENV_REGISTRY.
-python -m tools.hvdlint horovod_tpu tools bench.py examples
+python -m tools.hvdlint horovod_tpu tools examples
 python -m tools.hvdlint --check-envdoc
 
 echo "--- hvdlint --concurrency (lock discipline: guarded-by + lock order)"
@@ -167,14 +167,13 @@ echo "--- alerting & run-history plane (fast fail: WAL wire format, burn-rate ru
 python -m pytest tests/test_history.py tests/test_alerts.py -q -m "not slow"
 python tools/hvd_replay.py --selftest
 
-echo "--- perf attribution (fast fail: overlap math, roofline model, regression ledger)"
-# The perf-attribution plane (docs/profiling.md) is how every other
-# plane's "is it fast enough" question gets answered: trace
-# decomposition + overlap accounting, the analytic roofline/MFU model,
-# and the ledger that compares bench runs. All process-local math, runs
-# in seconds.
+echo "--- perf attribution (fast fail: overlap math, roofline model)"
+# The perf-attribution plane (docs/profiling.md): trace decomposition +
+# overlap accounting and the analytic roofline/MFU model behind
+# trainer.instrument_step's gauges. All process-local math, runs in
+# seconds.
 python -m pytest tests/test_profiling.py tests/test_costmodel.py \
-    tests/test_hvd_perf.py -q -m "not slow"
+    -q -m "not slow"
 
 echo "--- memory plane (fast fail: HBM ledger, recompile-storm ladder, resharding sentinel)"
 # The memory/compile observability plane (docs/memory.md) is the OOM
@@ -234,6 +233,3 @@ SCALING_LINE=$(env JAX_PLATFORMS=cpu \
         --image-size 32 --device-counts 1,2 --num-warmup-batches 1 \
         --num-iters 2 --num-batches-per-iter 2 | tail -1)
 python ci/check_scaling.py "$SCALING_LINE"
-
-echo "--- benchmark smoke"
-python bench.py

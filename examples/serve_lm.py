@@ -4,9 +4,7 @@ Generates synthetic open-loop Poisson traffic against the serving
 engine (horovod_tpu/serving/) and reports decode throughput plus
 per-request SLO latencies — and, with ``--baseline``, runs the SAME
 engine in drain (static-batch) mode so the two scheduling policies are
-compared at an equal slot budget. bench.py's HVD_BENCH_SERVE leg
-imports this module's harness functions; running it standalone prints
-one JSON result line.
+compared at an equal slot budget. Prints one JSON result line.
 
 Usage:
     # CPU, tiny config, continuous vs static side by side

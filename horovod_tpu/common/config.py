@@ -427,8 +427,8 @@ ENV_REGISTRY = (
     ("HOROVOD_PERF_ATTRIB_EVERY", True, "0", "trainer.py",
      "Capture + attribute every Nth instrumented step (profiler trace "
      "-> per-class hvd_step_breakdown_ms / overlap gauges); 0 (the "
-     "default) keeps the capture off the hot path. ~64 keeps the "
-     "amortized cost inside the 2% bench budget."),
+     "default) keeps the capture off the hot path; a cadence of 64 "
+     "or more spreads a capture's cost over that many steps."),
     ("HOROVOD_NUMERICS", True, "1", "utils/numerics.py",
      "Set 0 to replace the numerics plane (gradient health stats + "
      "divergence sentinel) with no-ops."),
@@ -616,7 +616,7 @@ ENV_REGISTRY = (
      "before lockdep reports hold_while_blocking."),
     ("HVD_RUN_LABEL", False, None, "utils/provenance.py",
      "Free-form run label stamped into provenance blocks (history "
-     "run manifest; falls back to HVD_BENCH_LABEL)."),
+     "run manifest)."),
     ("HVD_TF_NATIVE", False, "1", "tensorflow/native.py",
      "Set 0 to disable the TensorFlow native bridge."),
     ("HVD_TF_NATIVE_ADDR", False, None, "tensorflow/native.py",
@@ -629,77 +629,10 @@ ENV_REGISTRY = (
      "host:port rendezvous for the torch native bridge."),
     ("HVD_TORCH_NATIVE_TIMEOUT", False, "60", "torch/native.py",
      "Seconds to wait on torch native rendezvous/collectives."),
-    # -- bench / CI (exact names) --------------------------------------
-    ("HVD_BENCH_BATCH", False, None, "bench.py",
-     "Override the bench global batch size."),
-    ("HVD_BENCH_CKPT", False, None, "bench.py",
-     "Set 0 to skip the checkpoint-overhead gate (async saves <=2% "
-     "step time vs no checkpointing; reports the synchronous blocking "
-     "cost it replaces)."),
-    ("HVD_BENCH_PROFILE", False, None, "bench.py",
-     "Force per-op profile legs on (1) or off (0) in bench.py."),
-    ("HVD_BENCH_FLASH_ABLATION", False, None, "bench.py",
-     "Force the flash-attention ablation legs on (1) or off (0)."),
-    ("HVD_BENCH_FLIGHT", False, None, "bench.py",
-     "Set 0 to skip the flight-recorder overhead gate in bench.py."),
-    ("HVD_BENCH_HISTORY", False, None, "bench.py",
-     "Set 0 to skip the history+alerts overhead gate (WAL poke + "
-     "alert tick riding instrument_step on vs off around the real "
-     "eager LM step, interleaved best-of; asserts <=2% overhead)."),
-    ("HVD_BENCH_LABEL", False, None, "bench.py",
-     "Free-form run label stamped into the bench JSON provenance "
-     "(shows up as the run name in tools/hvd_perf.py reports)."),
-    ("HVD_BENCH_MEM", False, None, "bench.py",
-     "Set 0 to skip the memory-plane overhead gate (HBM ledger + "
-     "compile tracking on vs off around the real eager LM step, "
-     "interleaved best-of; asserts <=2% overhead and records ledger "
-     "headroom + per-site compile counts)."),
-    ("HVD_BENCH_MESH", False, None, "bench.py",
-     "Set 0 to skip the named-mesh bench leg (tp=2 vs dp-only eager "
-     "LM tokens/s/chip at equal global batch, plus the tp-sharded "
-     "serve decode arm asserting per-chip KV bytes drop >=1.9x)."),
-    ("HVD_BENCH_PERF", False, None, "bench.py",
-     "Set 0 to skip the perf-attribution overhead gate (periodic "
-     "instrument_step capture amortized <=2% vs attribution off)."),
-    ("HVD_BENCH_ELASTIC", False, None, "bench.py",
-     "Set 0 to skip the overload-shedding bench leg (shed arm must "
-     "hold admitted p99 TTFT under 2x Poisson overload while the "
-     "unshed control degrades; every rejection carries retry-after)."),
-    ("HVD_BENCH_NUMERICS", False, None, "bench.py",
-     "Set 0 to skip the numerics-overhead gate in bench.py."),
-    ("HVD_BENCH_OVERLAP", False, None, "bench.py",
-     "Set 0 to skip the overlap bench leg (barrier vs readiness-"
-     "ordered dispatch on the real eager LM step: overlap_frac, "
-     "exposed dispatch ms, tokens/s, two-level wire-byte split)."),
-    ("HVD_BENCH_QUANT", False, None, "bench.py",
-     "Set 0 to skip the quantized-wire bench leg (int8 vs bf16 wire "
-     "bytes + none-codec overhead gate)."),
-    ("HVD_BENCH_ROUTE", False, None, "bench.py",
-     "Set 0 to skip the router bench leg (2 replicas behind one "
-     "Router: aggregate decode tokens/step >=1.8x one replica; "
-     "least-loaded p99 TTFT <= round-robin under bimodal load)."),
-    ("HVD_BENCH_SERVE", False, None, "bench.py",
-     "Set 0 to skip the serving bench leg (continuous vs static "
-     "batching under Poisson load, p50/p99 TTFT)."),
-    ("HVD_BENCH_SERVE_TRACE", False, None, "bench.py",
-     "Set 0 to skip the request-tracing overhead sub-gate of the "
-     "serving bench leg (tracing on vs off <=2% wall per step)."),
-    ("HVD_BENCH_SWAP", False, None, "bench.py",
-     "Set 0 to skip the weight hot-swap sub-gate of the serving bench "
-     "leg (mid-traffic swap must hold tokens/step and p99 inter-token "
-     "vs a no-swap baseline; reports detect->swapped latency)."),
-    ("HVD_BENCH_SWAP_DIP_PCT", False, "5.0", "bench.py",
-     "Max decode tokens/step dip (percent) the swap arm may show vs "
-     "the no-swap baseline in the HVD_BENCH_SWAP gate."),
-    ("HVD_BENCH_SWAP_P99_X", False, "3.0", "bench.py",
-     "Max p99 inter-token multiple vs the no-swap baseline in the "
-     "HVD_BENCH_SWAP gate (headroom for CPU-host scheduling noise)."),
+    # -- tools / CI (exact names) -------------------------------------
     ("HVD_SLO_PCT", False, "90", "tools/hvd_slo.py",
      "Tail percentile the hvd_slo analyzer attributes (the slowest "
      "(100-pct)% of completed requests form the tail)."),
-    ("HVD_PERF_THRESHOLD_PCT", False, "5.0", "tools/hvd_perf.py",
-     "Default regression threshold (percent) for the hvd_perf bench-"
-     "trajectory gate; per-leg noise bands can only raise it."),
     ("HVD_TEST_WORKERS", False, "auto", "ci/run_tests.sh",
      "pytest-xdist worker count for the CI suite."),
 )

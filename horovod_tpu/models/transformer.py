@@ -61,7 +61,7 @@ class TransformerConfig:
     # | 'lazy' | 'twopass' — ops/flash_attention.VARIANTS; only read when
     # attention_impl routes through the flash kernel). 'auto' applies the
     # heuristic in resolve_variant; HVD_FLASH_VARIANT overrides
-    # either way (the bench ablation hook).
+    # either way (the A/B hook S3's timing needs).
     flash_variant: str = "auto"
     # Mixture-of-Experts: num_experts > 0 replaces the dense MLP with
     # models/moe.py's expert layer (experts shard over the 'ep' mesh axis).

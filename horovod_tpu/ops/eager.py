@@ -345,7 +345,7 @@ class EagerCoordinator:
         # digest that rides the next CycleRequest so the coordinator's
         # divergence sentinel can compare replicas. The monitor is read
         # through get_monitor() at each use so numerics.reset(enabled=)
-        # toggles a live engine (the bench's interleaved off/on arms).
+        # toggles a live engine.
         self._numerics_pending = None  # digest awaiting piggyback
         self._numerics_cycle = None    # seq being executed (None: local)
         self._numerics_staged = None   # fused-bucket stats matrix
@@ -484,8 +484,7 @@ class EagerCoordinator:
         and synchronize-side flushes pause), so every collective enqueued
         inside lands in ONE fused cycle on the next flush. What a
         backward pass's dispatch order gives training steps naturally,
-        benchmarks get explicitly (examples/allreduce_benchmark.py,
-        bench.py's autotune leg)."""
+        benchmarks get explicitly (examples/allreduce_benchmark.py)."""
         prev = self._paused
         self._paused = True
         try:
@@ -610,7 +609,7 @@ class EagerCoordinator:
                 e.span.close(local=True)
         t0 = time.perf_counter()
         # the plan depends on the (possibly autotuned) fusion threshold
-        # and on the codec knobs (the bench toggles compression live)
+        # and on the codec knobs (which may be toggled on a live engine)
         key = (int(self._config.fusion_threshold),
                quant_mod.config_fingerprint(self._config),
                tuple(e.signature() for e in batch))

@@ -55,7 +55,7 @@ VARIANTS = ("online", "lazy", "twopass")
 
 def resolve_variant(variant, causal=True, nk=1):
     """Resolve 'auto' (and the HVD_FLASH_VARIANT env override, which wins
-    over any explicit argument — the bench A/B hook) to a concrete
+    over any explicit argument — the A/B hook) to a concrete
     forward variant. The heuristic is reasoned, not measured (all three
     variants compile and match the reference on the v5e; none has been
     timed — ROADMAP S3): lazy whenever the k loop has ≥2 tiles (its gated
@@ -145,8 +145,8 @@ def _fwd_kernel(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q, block_k,
     multiplies); lse converts back to natural log once at the end (the
     external contract — parallel/ring.py merges in natural-log units).
 
-    Measured dead ends on v5e (b8 s1024 h12 d64, see
-    tools/flash_microbench.py): folding the softmax scale into q;
+    Dead ends a past builder measured on a v5e (b8 s1024 h12 d64,
+    per-kernel chained-loop timing): folding the softmax scale into q;
     lax.cond-skipping the causal mask on fully-visible tiles; carrying
     the row-sum in a planted ones-lane of v's head-dim padding (the MXU
     computes l for free but the end-of-loop lane extract costs more than
@@ -629,7 +629,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
     block_k = min(block_k, sk)
     # the dK/dV kernel streams Q-side tiles and grids over K blocks —
     # its optimal tile shape need not match the dQ kernel's, so the two
-    # are independently tunable (tools/flash_microbench.py --sweep-dkv)
+    # are independently tunable
     block_q_dkv = min(block_q_dkv or block_q, sq)
     block_k_dkv = min(block_k_dkv or block_k, sk)
     if sq % block_q_dkv:
@@ -754,8 +754,8 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
 
     ``variant`` selects the forward accumulation scheme (VARIANTS:
     'online' | 'lazy' | 'twopass', or 'auto' — see resolve_variant; the
-    HVD_FLASH_VARIANT env var overrides all of them, which is the bench
-    A/B hook). All variants compute the exact same softmax and write the
+    HVD_FLASH_VARIANT env var overrides all of them, which is the A/B
+    hook). All variants compute the exact same softmax and write the
     same lse residual, so the backward kernels are shared and gradients
     are variant-independent."""
     if layout not in ("bshd", "bhsd"):

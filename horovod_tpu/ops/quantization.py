@@ -322,8 +322,8 @@ def account(codec, raw_nbytes, wire_nb, axis="dp"):
 def account_leg(leg, codec, wire_nb):
     """Per-leg wire accounting for the two-level reduction: ``leg`` is
     'intra' (full-width shm traffic inside one host) or 'inter' (the
-    scarce cross-host hop). The overlap bench reads this split to prove
-    the quantized codec rides ONLY the inter-host leg — a nonzero
+    scarce cross-host hop). tests/test_overlap.py reads this split to
+    prove the quantized codec rides ONLY the inter-host leg — a nonzero
     {intra, int8} entry would mean narrow math leaked into the
     bandwidth-rich local reduction where it buys nothing."""
     reg = hvd_metrics.get_registry()

@@ -33,10 +33,9 @@ from .utils import metrics as hvd_metrics
 def _account_grad_windows(mode, enqueue_s, drain_s):
     """Host-side timing of one eager gradient reduction, split into the
     enqueue window (where overlap dispatch can hide comm) and the final
-    drain (comm still exposed after the last grad exists). The overlap
-    bench leg reads these to compute exposed_comm_ms and overlap_frac
-    from the framework's own dispatch timing rather than re-deriving
-    them outside it."""
+    drain (comm still exposed after the last grad exists): exposed
+    comm and the overlap fraction come from the framework's own
+    dispatch timing rather than being re-derived outside it."""
     reg = hvd_metrics.get_registry()
     if not reg.enabled:
         return

@@ -9,8 +9,9 @@ policies stay pure scoring math the tests can pin exactly.
 Two baselines, selectable via ``HVD_ROUTE_POLICY``:
 
   * ``round_robin``   ignore load, cycle the candidate set in id order.
-    The control arm: any smarter policy must beat it in the
-    HVD_BENCH_ROUTE imbalance leg or it isn't pulling its weight.
+    The control arm: any smarter policy must beat it under imbalance
+    (tests/test_router.py, two real replicas) or it isn't pulling its
+    weight.
   * ``least_loaded``  pick the minimum dispatch cost ``score()`` —
     a queued request weighs ``QUEUE_WEIGHT`` x an active slot (it
     hasn't even started its TTFT clock), every outstanding decode
@@ -34,8 +35,8 @@ from ..common import config
 # started, so it predicts more future occupancy than an active slot
 # mid-decode; the work term prices each outstanding decode token so a
 # 40-token request weighs five 8-token ones (queue depth alone cannot
-# tell them apart — the HVD_BENCH_ROUTE imbalance leg pins exactly
-# this); KV exhaustion means the next admit stalls regardless of
+# tell them apart — tests/test_router.py's alternating 40/8 load pins
+# exactly this); KV exhaustion means the next admit stalls regardless of
 # slots, which outweighs any queue-depth difference.
 QUEUE_WEIGHT = 4.0
 SLOT_WEIGHT = 1.0

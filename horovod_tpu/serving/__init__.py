@@ -8,7 +8,7 @@ the same model under live traffic (docs/serving.md). The pieces:
   * kv_cache.py  — slot-based KV cache: dense device arrays, host-side
                    block-granular accounting with leak invariants
   * scheduler.py — slot assignment: continuous (join/retire at any
-                   step) vs drain (static batch — the bench baseline)
+                   step) vs drain (static batch — the baseline)
   * sampling.py  — greedy / temperature sampling, jit-safe per-row mix
   * decode.py    — prefill + single-token decode forwards that apply
                    the training checkpoint's param leaves exactly

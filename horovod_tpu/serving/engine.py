@@ -53,9 +53,10 @@ from the device:
     never got a wrong or an extra token.
 
 The drain policy turns the same engine into the static-batch baseline
-(admit only into an idle batch, run the wave to completion) that
-bench.py's HVD_BENCH_SERVE leg compares against — one code path, one
-flag, no drift between the system and its baseline.
+(admit only into an idle batch, run the wave to completion) — one code
+path, one flag, no drift between the system and its baseline
+(examples/serve_lm.py --baseline; tests/test_serving.py holds
+continuous batching to at most 2/3 of its steps on the same requests).
 """
 
 import functools

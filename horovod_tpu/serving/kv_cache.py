@@ -245,8 +245,8 @@ class KVCache:
         return out
 
     def per_chip_bytes(self):
-        """Bytes of cache (every kind) resident on ONE chip — what the
-        HVD_BENCH_MESH serve arm asserts drops with tp."""
+        """Bytes of cache (every kind) resident on ONE chip: halves
+        under tp=2 (tests/test_mesh_plane.py)."""
         return sum(self.bytes_by_kind().values())
 
     def row_state_bytes(self):

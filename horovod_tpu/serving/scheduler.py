@@ -7,11 +7,12 @@ slots. Two policies:
     the moment it finishes; the device batch never drains. This is the
     serving plane's whole point: short requests stop paying for long
     ones (docs/serving.md).
-  * drain — the static-batch baseline the bench compares against: a
-    wave of requests is admitted only into an idle batch, decodes to
-    completion, and only then may the next wave join. Deliberately kept
-    in-tree so the baseline in bench.py is the same engine with one
-    flag, not a separate code path that could drift.
+  * drain — the static-batch baseline: a wave of requests is admitted
+    only into an idle batch, decodes to completion, and only then may
+    the next wave join. Deliberately kept in-tree so the baseline
+    (examples/serve_lm.py --baseline, tests/test_serving.py) is the
+    same engine with one flag, not a separate code path that could
+    drift.
 
 Invariants (tests/test_serving.py): a slot is owned by at most one
 request; join on a full batch raises; retire frees the slot for

@@ -264,7 +264,7 @@ _NULL_TRACE = _NullRequestTrace()
 def begin(request):
     """Mint the trace for a freshly submitted request. Idempotent for a
     live trace (a requeued request keeps its spans), but a CLOSED trace
-    — the same Request object resubmitted, as the bench arms do — gets
+    — the same Request object resubmitted, as A/B harnesses do — gets
     a fresh one: each submission is its own lifecycle. Called by
     AdmissionQueue.submit, so direct engine users get traced too."""
     trace = getattr(request, "trace", None)

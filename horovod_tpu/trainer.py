@@ -48,9 +48,9 @@ def instrument_step(step_fn, tokens_per_step=None, name="train",
     before stamping the end time: without the sync, async dispatch would
     time the enqueue (~µs) instead of the step. That makes it a per-step
     host sync — fine for the per-step host-loop idiom this wraps
-    (make_gspmd_step, whose callers read the loss every step anyway), wrong
-    inside a scanned multi-step. Disabled metrics make this a plain
-    passthrough of the original function.
+    (make_gspmd_step, whose callers read the loss every step anyway).
+    Disabled metrics make this a plain passthrough of the original
+    function.
 
     Two optional attribution layers (the perf-attribution plane):
 
@@ -67,9 +67,10 @@ def instrument_step(step_fn, tokens_per_step=None, name="train",
         -relative, hvd_top's "top regressing class"), and the
         exposed/hidden-comm overlap gauges. The first capture happens
         at step N, never step 1 — step 1 is compile. Capture failures
-        emit a ``perf_attrib_error`` event and never break the step;
-        the steady-state overhead is bench-gated ≤2%
-        (``HVD_BENCH_PERF``).
+        emit a ``perf_attrib_error`` event and never break the step.
+        A capture costs a profiler start and stop and a trace parse on
+        the host; the cadence N spreads that over N steps (not
+        measured on a chip).
 
     The memory plane (docs/memory.md, default-on via HVD_MEM) rides the
     same wrapper: every call reports its abstract-shape key to the
@@ -77,14 +78,13 @@ def instrument_step(step_fn, tokens_per_step=None, name="train",
     signal), and ``hvd_step_peak_hbm_bytes`` tracks the allocator's
     peak (``peak_bytes_in_use + peak_bytes_reserved``) next to
     ``hvd_mfu`` — nulled on CPU the same way, since CPU
-    backends expose no allocator stats. Overhead is bench-gated ≤2%
-    (``HVD_BENCH_MEM``).
+    backends expose no allocator stats.
 
     So does the alerting & run-history plane (docs/alerts.md,
     default-on via ``HVD_HISTORY`` / ``HVD_ALERT``): every step pokes
     the on-disk history writer and ticks the AlertManager — both are
     interval-throttled clock compares that no-op on the vast majority
-    of steps, bench-gated ≤2% (``HVD_BENCH_HISTORY``).
+    of steps.
     """
     reg = hvd_metrics.get_registry()
     if not reg.enabled:
@@ -232,7 +232,7 @@ def instrument_step(step_fn, tokens_per_step=None, name="train",
                 peak_hbm.labels(loop=name).set(pb)
         # Alerting + durable history ride the same tick (docs/alerts.md):
         # both are interval-throttled no-ops on the vast majority of
-        # steps (bench-gated ≤2%, HVD_BENCH_HISTORY).
+        # steps.
         hvd_history.poke()
         hvd_alerts.tick()
         return out
@@ -398,7 +398,7 @@ def softmax_cross_entropy(logits, labels, weights=None):
 def make_data_parallel_step(loss_fn, tx, mesh, axis_name=None,
                             compression=Compression.none,
                             fusion_threshold=None, donate=True,
-                            batch_specs=None, steps_per_call=1):
+                            batch_specs=None):
     """Compiled Horovod-style train step.
 
     ``loss_fn(params, batch) -> scalar`` is the per-worker loss on the
@@ -406,18 +406,10 @@ def make_data_parallel_step(loss_fn, tx, mesh, axis_name=None,
     opt_state, mean_loss)`` where batch's leading dim is sharded over the
     worker axis and gradients are averaged with one fused psum per fusion
     bucket before the optimizer applies them.
-
-    ``steps_per_call > 1`` runs that many optimizer updates on-device
-    per host call (lax.fori_loop), re-using the SAME batch each inner
-    step — the synthetic-benchmark loop (the reference harness feeds one
-    fixed batch repeatedly; examples/synthetic_benchmark.py): one host
-    dispatch for many updates. For real training with distinct batches
-    use steps_per_call=1 or make_gspmd_multi_step (which scans over
-    stacked batches).
     """
     axis = axis_name or mesh.axis_names[0]
 
-    def one_update(params, opt_state, batch):
+    def per_worker(params, opt_state, batch):
         # Backward pass on a device-varying copy of the params — see
         # ops.collective_ops.ensure_varying for why (replicated params
         # would make autodiff pre-sum the grads, turning the explicit
@@ -433,20 +425,6 @@ def make_data_parallel_step(loss_fn, tx, mesh, axis_name=None,
         params = optax.apply_updates(params, updates)
         mean_loss = jax.lax.pmean(loss, axis)
         return params, opt_state, mean_loss
-
-    def per_worker(params, opt_state, batch):
-        if steps_per_call == 1:
-            return one_update(params, opt_state, batch)
-
-        def body(_, carry):
-            p, o, _loss = carry
-            p, o, loss = one_update(p, o, batch)
-            # the carry's loss slot is fp32 regardless of loss_fn's
-            # dtype (a bf16 loss would trip fori_loop's carry check)
-            return p, o, loss.astype(jnp.float32)
-
-        init = (params, opt_state, jnp.float32(0))
-        return jax.lax.fori_loop(0, steps_per_call, body, init)
 
     # batch_specs: PartitionSpec pytree for the batch argument (per-leaf),
     # default: shard every leaf's leading dim over the worker axis.
@@ -486,24 +464,6 @@ def init_opt_state(tx, params, mesh=None, param_spec_tree=None):
     return jax.jit(tx.init, out_shardings=shardings)(params)
 
 
-def _gspmd_shardings(tx, mesh, param_spec_tree, batch_spec, params):
-    """Shared sharding derivation for make_gspmd_step /
-    make_gspmd_multi_step: (param, opt, batch, out) NamedShardings.
-    opt/out are None when ``params`` is not given (see the callers'
-    docstrings for why passing it matters)."""
-    param_shardings = mesh_lib.tree_shardings(param_spec_tree, mesh)
-    batch_sharding = mesh_lib.named_sharding(batch_spec, mesh)
-    if params is not None:
-        opt_shardings = mesh_lib.tree_shardings(
-            opt_state_specs(tx, params, param_spec_tree), mesh)
-        out_shardings = (param_shardings, opt_shardings,
-                         mesh_lib.named_sharding(P(), mesh))
-    else:
-        opt_shardings = None
-        out_shardings = None
-    return param_shardings, opt_shardings, batch_sharding, out_shardings
-
-
 def _traced_under(mesh, fn):
     """``fn`` traced with ``mesh`` as JAX's ambient abstract mesh, so code
     with no GSPMD partitioning rule of its own (the Pallas attention
@@ -529,8 +489,16 @@ def make_gspmd_step(loss_fn, tx, mesh, param_spec_tree, batch_spec,
     scalars whose shardings change after the first step, costing a silent
     second compilation of the whole step.
     """
-    param_shardings, opt_shardings, batch_sharding, out_shardings = \
-        _gspmd_shardings(tx, mesh, param_spec_tree, batch_spec, params)
+    param_shardings = mesh_lib.tree_shardings(param_spec_tree, mesh)
+    batch_sharding = mesh_lib.named_sharding(batch_spec, mesh)
+    if params is not None:
+        opt_shardings = mesh_lib.tree_shardings(
+            opt_state_specs(tx, params, param_spec_tree), mesh)
+        out_shardings = (param_shardings, opt_shardings,
+                         mesh_lib.named_sharding(P(), mesh))
+    else:
+        opt_shardings = None
+        out_shardings = None
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -541,47 +509,6 @@ def make_gspmd_step(loss_fn, tx, mesh, param_spec_tree, batch_spec,
     donate_argnums = (0, 1) if donate else ()
     return jax.jit(
         _traced_under(batch_sharding.mesh, step),
-        in_shardings=(param_shardings, opt_shardings, batch_sharding),
-        out_shardings=out_shardings,
-        donate_argnums=donate_argnums), param_shardings, batch_sharding
-
-
-def make_gspmd_multi_step(loss_fn, tx, mesh, param_spec_tree, batch_spec,
-                          donate=True, params=None):
-    """Device-side training loop: like make_gspmd_step but the returned
-    function runs ``lax.scan`` over a STACKED batch ``[n_steps, ...]``
-    and returns the last step's loss — n_steps optimizer updates per
-    host dispatch.
-
-    Scanning on device amortizes the host's per-step dispatch — the
-    standard JAX training-loop idiom for small-step/large-count regimes
-    (what a dispatch costs on the machine at hand is not measured yet,
-    ROADMAP queue 1). The per-step ``step`` from
-    make_gspmd_step remains the right tool when the host needs the loss
-    every step (callbacks, logging, elastic checkpoints).
-
-    The stacked batch shards as P(None, *batch_spec) — the leading
-    step axis is never split across devices.
-    """
-    param_shardings, opt_shardings, batch_sharding, out_shardings = \
-        _gspmd_shardings(tx, mesh, param_spec_tree, P(None, *batch_spec),
-                         params)
-
-    def multi_step(params, opt_state, batches):
-        def body(carry, batch):
-            p, o = carry
-            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-            updates, o = tx.update(grads, o, p)
-            p = optax.apply_updates(p, updates)
-            return (p, o), loss
-
-        (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), batches)
-        return params, opt_state, losses[-1]
-
-    donate_argnums = (0, 1) if donate else ()
-    return jax.jit(
-        _traced_under(batch_sharding.mesh, multi_step),
         in_shardings=(param_shardings, opt_shardings, batch_sharding),
         out_shardings=out_shardings,
         donate_argnums=donate_argnums), param_shardings, batch_sharding

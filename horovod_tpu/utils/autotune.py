@@ -184,9 +184,8 @@ class Autotuner:
         Boundary guard: a best cycle_time within CYCLE_BOUNDARY_FRAC of
         the top bound is NOT adopted — the threshold is kept but the
         cycle falls back to the pre-tune default, and
-        ``cycle_boundary_clamped`` is set so callers (bench.py) can
-        report the clamp instead of silently running a flat-score
-        argmax."""
+        ``cycle_boundary_clamped`` is set so callers can report the
+        clamp instead of silently running a flat-score argmax."""
         self.frozen = True
         b = self._engine.best()
         if b is not None:

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed once for every entry point.
 
-Entry points (chip_smoke.py, bench.py, the examples) call ``configure()``
+Entry points (chip_smoke.py, the examples) call ``configure()``
 before their first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
 JAX reads it itself and nothing is set in code — whoever runs the program
 owns the location. Where it is not, the cache lives at one fixed path

@@ -13,8 +13,7 @@ trainer's in-training attribution calls into this from the step loop):
 
   * ``chip_spec`` / ``CHIP_SPECS`` — nominal per-chip peak dense bf16
     FLOPs, HBM bandwidth, and ICI (interchip) bandwidth by device_kind
-    prefix. bench.py's ``_peak_flops`` delegates here so there is one
-    table to update per TPU generation.
+    prefix: the one table to update per TPU generation.
   * ``analytic_lm_costs`` — per-class FLOPs/bytes per step per chip for
     the transformer LM, derived from the SAME PaLM appendix-B
     convention as ``models.transformer.matmul_flops_per_token`` (the
@@ -23,8 +22,7 @@ trainer's in-training attribution calls into this from the step loop):
     from jax's ``cost_analysis()`` when a compiled object is at hand.
   * ``roofline`` / ``mfu_decomposition`` — per-class compute- vs
     memory- vs comm-bound verdicts (arithmetic intensity against the
-    ridge point) and the achievable-MFU decomposition embedded in the
-    bench JSON and read back by tools/hvd_perf.py.
+    ridge point) and the achievable-MFU decomposition.
 
 All "bytes" figures are a traffic *model*, not a measurement: weight
 tensors make three HBM passes per step (forward read, dgrad read, wgrad
@@ -93,11 +91,10 @@ def chip_spec(device_or_kind):
 
 
 def peak_flops(device_or_kind):
-    """Peak dense bf16 FLOPs/s for a device, or None when unknown.
-    (bench.py's MFU headline delegates here.)"""
+    """Peak dense bf16 FLOPs/s for a device, or None when unknown."""
     spec = chip_spec(device_or_kind)
     # the CPU row is a placeholder magnitude — an MFU computed against
-    # it would be noise, so the headline keeps getting None off-TPU
+    # it would be noise, so callers keep getting None off-TPU
     if spec is None or spec.kind == "cpu":
         return None
     return spec.peak_flops
@@ -319,10 +316,9 @@ def mfu_decomposition(measured_ms_per_step, costs, spec,
 def lm_attribution(cfg, seq, batch_per_chip, spec,
                    measured_ms_per_step, decomposition=None,
                    n_chips=1, wire_bytes_per_param=2.0):
-    """One-call wrapper for the bench leg: analytic costs → roofline →
-    MFU decomposition, folding in a measured ``profile_decomposition``
-    when one is at hand. Returns the dict bench.py embeds under
-    ``roofline`` in its JSON line."""
+    """One-call wrapper: analytic costs → roofline → MFU decomposition,
+    folding in a measured ``profile_decomposition`` when one is at
+    hand."""
     costs = analytic_lm_costs(cfg, seq, batch_per_chip, n_chips=n_chips,
                               wire_bytes_per_param=wire_bytes_per_param)
     by_class = measured_class_ms(decomposition) if decomposition else None

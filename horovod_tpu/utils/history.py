@@ -28,7 +28,7 @@ Wire format (one JSON object per line):
   cadence; nonzero means HVD_HISTORY_INTERVAL_S outpaced by event
   volume).
 * ``run-manifest.json`` (rank 0 / single-process only) carries the
-  same provenance block bench.py stamps (utils/provenance.py) so
+  provenance block (utils/provenance.py) so
   ``hvd_replay --diff`` compares any two runs by git sha, device
   kind/count, mesh spec and config fingerprint.
 

@@ -60,7 +60,7 @@ def enabled():
 
 
 def reset(enabled=None):
-    """Drop the process ledger/tracker singletons (tests, bench arms).
+    """Drop the process ledger/tracker singletons (tests).
 
     ``enabled`` forces the plane on/off regardless of HVD_MEM; None
     re-reads the environment on next use.

@@ -35,9 +35,10 @@ compares records across ranks: disagreement beyond
 reduced copies are redundant, so the outlier's own input is the
 evidence), the tensor, and the first bad cycle.
 
-Default-on under the same ≤2% overhead contract as the flight recorder
-(bench.py ``_bench_numerics_overhead`` enforces it); ``HVD_NUMERICS=0``
-lands every call on a shared null object.  Knobs and the verdict
+Default-on: the per-tensor statistics are a side-product of the fused
+allreduce (a few reductions over a buffer already resident), and the
+digest rides a message that is sent anyway; ``HVD_NUMERICS=0`` lands
+every call on a shared null object.  Knobs and the verdict
 runbook: docs/numerics.md.
 """
 

@@ -286,7 +286,7 @@ def profile_decomposition(trace, wall_ms=None, steps=1,
     ms/step). Composes with merged_timeline.capture(profiler_dir=...):
     the same user-supplied dir feeds merge() (the visual, host + device
     on one clock) and this function (the arithmetic). Returns a plain
-    dict — bench.py embeds it verbatim in its JSON line.
+    dict.
     """
     summary = trace if isinstance(trace, TraceSummary) else \
         summarize_trace(trace)

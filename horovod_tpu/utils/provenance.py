@@ -1,12 +1,10 @@
 """One provenance schema for every durable artifact (docs/alerts.md).
 
-``bench.py`` has stamped its JSON line with {unix_ms, device_kind,
-device_count, platform, git_sha, config_fingerprint, label} since the
-perf-ledger plane landed; the history plane's run manifest needs the
-SAME block so ``tools/hvd_replay.py --diff`` and ``tools/hvd_perf.py``
-can attribute any two artifacts — a bench round and a production run —
-by one field set instead of two that drift. This module is that block's
-single definition; bench.py and utils/history.py both call it.
+The history plane's run manifest (utils/history.py) stamps this block
+{unix_ms, device_kind, device_count, platform, git_sha,
+config_fingerprint, mesh, label} so ``tools/hvd_replay.py --diff`` can
+attribute any two runs by one field set. This module is that block's
+single definition.
 
 Every field is best-effort: a provenance stamp must never kill the
 artifact it exists to describe (no git binary in the deploy image, no
@@ -52,10 +50,9 @@ def provenance_stamp(device_count=None, config=None, label=None,
                      mesh=None, git_cwd=None):
     """The shared provenance block: git sha, device kind/count,
     platform, config fingerprint, wall-clock ms and an optional run
-    label (``HVD_BENCH_LABEL`` / ``HVD_RUN_LABEL`` when ``label`` is
-    None) — plus the mesh layout ({axis: size}) when the caller has
-    one. Pure dict of JSON scalars; absent fields are omitted, never
-    None."""
+    label (``HVD_RUN_LABEL`` when ``label`` is None) — plus the mesh
+    layout ({axis: size}) when the caller has one. Pure dict of JSON
+    scalars; absent fields are omitted, never None."""
     prov = {"unix_ms": hvd_metrics.shared_clock().epoch_us() // 1000}
     try:
         import jax
@@ -84,8 +81,7 @@ def provenance_stamp(device_count=None, config=None, label=None,
         except Exception:  # noqa: BLE001 — provenance is best-effort
             pass
     if label is None:
-        label = os.environ.get("HVD_RUN_LABEL") or \
-            os.environ.get("HVD_BENCH_LABEL")
+        label = os.environ.get("HVD_RUN_LABEL")
     if label:
         prov["label"] = str(label)
     return prov

@@ -813,3 +813,82 @@ class TestElasticEndToEnd:
         assert len(router.live_replicas()) == 1
         assert router._draining or any(
             h.state == h.RETIRED for h in router._handles.values())
+
+
+# ---------------------------------------------------------------------------
+# overload on two real engines: nothing offered is lost
+# ---------------------------------------------------------------------------
+
+class TestOverloadConservation:
+    """72 open-loop arrivals, one every other step (twice what two
+    replicas of 2 slots sustain), output lengths 8 or 40
+    (examples/serve_lm.py's own load). Counted in router steps; no
+    clock decides anything."""
+
+    N = 72
+
+    @pytest.fixture(scope="class")
+    def arms(self):
+        import os
+        import sys
+        import jax
+        import jax.numpy as jnp
+        from horovod_tpu.models import transformer as tr
+        from horovod_tpu.serving import AdmissionQueue
+        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                        "examples"))
+        from serve_lm import make_workload
+
+        cfg = tr.TransformerConfig.tiny(dtype=jnp.float32,
+                                        attention_impl="full")
+        _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
+        workload = make_workload(seed=0, n_requests=self.N, rate=0.5)
+        arrived = {req.request_id: t for t, req in workload}
+
+        def engine():
+            return ServeEngine(
+                cfg, params, num_slots=2, max_len=64, kv_block=8, seed=0,
+                queue=AdmissionQueue(max_depth=self.N + 8,
+                                     admission_timeout_s=1e9))
+
+        def run(shed_depth):
+            router = Router({0: engine(), 1: engine()},
+                            policy="least_loaded", shed_depth=shed_depth)
+            done, sheds, i, steps = [], [], 0, 0
+            while i < len(workload) or router.pending():
+                while i < len(workload) and workload[i][0] <= steps:
+                    if not router.submit(workload[i][1]):
+                        sheds.append(dict(router.last_shed))
+                    i += 1
+                done.extend((r, steps) for r in router.step())
+                steps += 1
+                assert steps < 5000
+            ttft = sorted(s - (len(r.tokens) - 1) - arrived[r.request_id]
+                          for r, s in done)
+            return {"outcomes": [r.outcome for r, _ in done],
+                    "sheds": sheds,
+                    "ttft_p99_steps": ttft[min(len(ttft) - 1,
+                                               int(0.99 * len(ttft)))]}
+
+        return {"control": run(0), "shed": run(2)}
+
+    def test_control_arm_sheds_nothing_and_finishes_everything(self, arms):
+        control = arms["control"]
+        assert control["sheds"] == []
+        assert control["outcomes"] == ["completed"] * self.N
+
+    def test_completed_plus_shed_is_what_was_offered(self, arms):
+        shed = arms["shed"]
+        assert len(shed["sheds"]) >= 1
+        assert set(shed["outcomes"]) == {"completed"}
+        assert len(shed["outcomes"]) + len(shed["sheds"]) == self.N
+
+    def test_every_shed_says_when_to_retry(self, arms):
+        assert all(s["retry_after_s"] > 0 for s in arms["shed"]["sheds"])
+
+    def test_shedding_holds_the_admitted_tail(self, arms):
+        """The control arm's backlog grows for as long as arrivals
+        outrun it, so its admitted TTFT tail (in steps) is at least
+        twice that of the arm whose queues are bounded."""
+        assert arms["control"]["ttft_p99_steps"] >= \
+            2.0 * max(arms["shed"]["ttft_p99_steps"], 1.0), arms

@@ -3,7 +3,7 @@
 reference — NOT against ``full_attention`` (which shares this repo's
 lineage) and not against each other.
 
-The grid the perf ablation runs on (docs/benchmarks.md): dtype ∈ {fp32,
+The grid (docs/benchmarks.md, "Three forwards, one backward"): dtype ∈ {fp32,
 bf16} × causal ∈ {True, False} × seq ∈ {128, 1024, 2048}, plus the
 ragged-tail case (seq not a block multiple → the causal end-padding
 path). Tolerances are asserted per dtype: fp32 2e-5 (fp32 MXU +
@@ -93,7 +93,7 @@ class TestVariantNumerics:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("causal", [True, False])
     def test_seq2048(self, hvd, variant, dtype, causal):
-        # the new flagship operating point (bench.py --seq 2048)
+        # seq 2048: four k tiles at the default block
         _check(variant, dtype, causal, s=2048, b=1, h=1, block=512, rng=2)
 
     @pytest.mark.parametrize("variant", ("lazy", "twopass"))

@@ -513,3 +513,84 @@ class TestRouterWithCanary:
         router.step()  # results flow through observe() -> verdict
         assert ctrl.state == "promoted"
         assert _events(reg.snapshot(), "route_promote")
+
+
+# ---------------------------------------------------------------------------
+# two real engines behind the router (the only tests here that import jax)
+# ---------------------------------------------------------------------------
+
+class TestTwoRealReplicas:
+    """24 requests at step 0, alternately 40 and 8 output tokens, each
+    with its own prompt, on replicas of 2 slots. Everything is counted
+    in router steps (a step drives every live engine one scheduler
+    iteration), so no verdict reads a clock."""
+
+    N = 24
+
+    @pytest.fixture(scope="class")
+    def arms(self):
+        import jax
+        import jax.numpy as jnp
+        from horovod_tpu.models import transformer as tr
+        from horovod_tpu.serving import AdmissionQueue, ServeEngine
+
+        cfg = tr.TransformerConfig.tiny(dtype=jnp.float32,
+                                        attention_impl="full")
+        _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
+
+        def engine():
+            return ServeEngine(
+                cfg, params, num_slots=2, max_len=64, kv_block=8, seed=0,
+                queue=AdmissionQueue(max_depth=self.N + 8,
+                                     admission_timeout_s=1e9))
+
+        def run(tag, submit, step, pending):
+            for i in range(self.N):
+                prompt = tuple((7 * i + j) % 250 + 1 for j in range(6))
+                assert submit(Request(f"{tag}-{i}", prompt,
+                                      max_new_tokens=8 if i % 2 else 40,
+                                      temperature=0.0))
+            done, steps = [], 0
+            while pending():
+                done.extend((r, steps) for r in step())
+                steps += 1
+                assert steps < 5000
+            assert all(r.outcome == "completed" for r, _ in done)
+            # a slot decodes one token a step, so the first token came
+            # len(tokens) - 1 steps before the step that finished it
+            ttft = sorted(s - (len(r.tokens) - 1) for r, s in done)
+            return {
+                "ids": sorted(int(r.request_id.split("-")[1])
+                              for r, _ in done),
+                "tokens_per_step": sum(len(r.tokens)
+                                       for r, _ in done) / steps,
+                "ttft_p99_steps": ttft[min(len(ttft) - 1,
+                                           int(0.99 * len(ttft)))]}
+
+        def routed(policy):
+            # shedding off: the whole load arrives at once on purpose
+            router = Router({0: engine(), 1: engine()}, policy=policy,
+                            shed_depth=0)
+            return run(policy, router.submit, router.step, router.pending)
+
+        single = engine()
+        return {
+            "single": run("single", single.submit, single.step,
+                          lambda: single.active_count or
+                          len(single.queue)),
+            "least_loaded": routed("least_loaded"),
+            "round_robin": routed("round_robin")}
+
+    def test_every_policy_completes_the_same_requests(self, arms):
+        assert arms["single"]["ids"] == arms["least_loaded"]["ids"] == \
+            arms["round_robin"]["ids"] == list(range(self.N))
+
+    def test_two_replicas_nearly_double_tokens_a_router_step(self, arms):
+        assert arms["least_loaded"]["tokens_per_step"] >= \
+            1.8 * arms["single"]["tokens_per_step"], arms
+
+    def test_least_loaded_ttft_tail_no_worse_than_round_robin(self, arms):
+        """Round-robin's parity sends every 40-token request to one
+        replica; least_loaded spreads them by the work queued."""
+        assert arms["least_loaded"]["ttft_p99_steps"] <= \
+            arms["round_robin"]["ttft_p99_steps"], arms

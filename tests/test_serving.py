@@ -372,6 +372,37 @@ class TestServeEngine:
         assert all(r.outcome == "completed" for r in results)
         assert engine.kv.ledger.blocks_in_use == 0
 
+    def test_continuous_needs_fewer_steps_than_drain(self, reg):
+        """Open-loop arrivals (one every other step) with bimodal output
+        lengths on 4 slots, examples/serve_lm.py's own load: both
+        policies finish the same requests with the same tokens, and
+        continuous batching needs at most 2/3 of drain's engine steps
+        (every short request of a drain wave waits for the wave's
+        longest). Counted in steps, so no clock is read."""
+        import sys
+        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                        "examples"))
+        from serve_lm import make_workload, run_load
+
+        cfg, params = _tiny()
+        workload = make_workload(seed=0, n_requests=48, rate=0.5)
+
+        def serve(policy):
+            engine = _engine(
+                cfg, params, num_slots=4, max_len=64, policy=policy,
+                queue=AdmissionQueue(max_depth=len(workload) + 1,
+                                     admission_timeout_s=1e9))
+            done, steps, _ = run_load(engine, workload)
+            assert engine.kv.ledger.blocks_in_use == 0
+            assert all(r.outcome == "completed" for r in done)
+            return {r.request_id: r.tokens for r in done}, steps
+
+        continuous, steps_c = serve("continuous")
+        drained, steps_d = serve("drain")
+        assert len(continuous) == len(workload)
+        assert continuous == drained
+        assert steps_d >= 1.5 * steps_c, (steps_d, steps_c)
+
     def test_too_long_request_fails_at_admission(self, reg):
         cfg, params = _tiny()
         engine = _engine(cfg, params, max_len=16)

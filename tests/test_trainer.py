@@ -164,54 +164,3 @@ class TestSingleCompile:
         for _ in range(3):
             params, opt_state, loss = step(params, opt_state, batch)
         assert step._cache_size() == 1
-
-
-class TestMultiStep:
-    def test_multi_step_matches_sequential_steps(self, hvd):
-        """make_gspmd_multi_step (device-side lax.scan training loop,
-        the bench's dispatch-free timing path) must produce the SAME
-        params/opt_state/loss as n sequential make_gspmd_step calls."""
-        cfg = tr.TransformerConfig.tiny(dtype=jnp.float32)
-        mesh = mesh_mod.build_mesh(dp=4, tp=2)
-        model = tr.TransformerLM(cfg)
-        n_steps, batch, seq = 3, 8, 32
-        rng = np.random.RandomState(0)
-        all_toks = jnp.asarray(
-            rng.randint(0, cfg.vocab_size, (n_steps, batch, seq)),
-            jnp.int32)
-        params0 = model.init(jax.random.PRNGKey(0),
-                             all_toks[0])["params"]
-        tx = optax.adamw(1e-2)
-        loss_fn = tr.lm_loss_fn(model)
-        specs = tr.param_specs(params0)
-
-        # sequential reference
-        step, pshard, bshard = trainer.make_gspmd_step(
-            loss_fn, tx, mesh, specs, tr.batch_spec(), params=params0,
-            donate=False)
-        params = jax.tree_util.tree_map(jax.device_put, params0, pshard)
-        opt_state = trainer.init_opt_state(tx, params, mesh, specs)
-        for i in range(n_steps):
-            params, opt_state, loss = step(
-                params, opt_state, jax.device_put(all_toks[i], bshard))
-
-        # device-side scan
-        mstep, mpshard, mbshard = trainer.make_gspmd_multi_step(
-            loss_fn, tx, mesh, specs, tr.batch_spec(), params=params0,
-            donate=False)
-        mparams = jax.tree_util.tree_map(jax.device_put, params0, mpshard)
-        mopt = trainer.init_opt_state(tx, mparams, mesh, specs)
-        mparams, mopt, mloss = mstep(
-            mparams, mopt, jax.device_put(all_toks, mbshard))
-
-        np.testing.assert_allclose(float(mloss), float(loss), rtol=1e-5)
-        for (ka, a), (kb, b) in zip(
-                sorted(jax.tree_util.tree_leaves_with_path(mparams),
-                       key=lambda kv: str(kv[0])),
-                sorted(jax.tree_util.tree_leaves_with_path(params),
-                       key=lambda kv: str(kv[0])),
-                strict=True):
-            assert str(ka) == str(kb)
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-5, atol=2e-6,
-                                       err_msg=str(ka))

@@ -142,8 +142,8 @@ def run_case(nproc, ntensors, steps, cache_capacity, digest_fn=None):
         "steady_req_bytes_per_worker": round(steady),
         "cold_cycle_ms": round(lat[0], 2),
         "steady_cycle_ms": round(statistics.mean(lat[1:]), 2),
-        # min is robust to scheduler noise: the overhead gate in
-        # bench.py compares best-case latencies, not means
+        # min is robust to scheduler noise: best-case latencies are
+        # compared, not means
         "best_cycle_ms": round(min(lat[1:]), 3),
     }
 

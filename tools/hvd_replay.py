@@ -24,7 +24,7 @@ Modes (composable; default is the timeline report):
   trace to line resource curves up under request lanes.
 * ``--diff OTHER_DIR`` — compare two runs: manifest provenance
   field-by-field (git sha, device kind/count, mesh, config
-  fingerprint — the bench.py block, via utils/provenance.py) plus
+  fingerprint — the block of utils/provenance.py) plus
   headline counter end-values side by side.
 * ``--incidents [--incident PATH]`` — index or pretty-read incident
   files.

@@ -12,7 +12,7 @@ import sys
 from . import envdoc
 from .engine import analyze_paths, render_baseline
 
-DEFAULT_PATHS = ["horovod_tpu", "tools", "bench.py", "examples"]
+DEFAULT_PATHS = ["horovod_tpu", "tools", "examples"]
 DEFAULT_BASELINE = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "baseline.json")
 CONCURRENCY_BASELINE = os.path.join(

@@ -1099,8 +1099,8 @@ def check_adhoc_step_timer(ctx, shared):
             "HVD013", ctx.relpath, node.lineno, node.col_offset,
             "ad-hoc step timer in a hot-path module: a raw "
             "perf_counter() here starts a parallel timing story that "
-            "never reaches the metrics registry, the perf-attribution "
-            "gauges, or the bench ledger — the numbers it produces get "
+            "never reaches the metrics registry or the perf-attribution "
+            "gauges — the numbers it produces get "
             "compared against instrumented ones and the discrepancy "
             "burns a debugging day. Step walls belong to "
             "trainer.instrument_step (hvd_step_seconds + the attribution "
@@ -1841,13 +1841,13 @@ gradient-health statistic — L2 norm, max-abs, nan/inf counts, zero
 fraction, checksum — as a single fused pass over buffers the collective
 already materialized, and folds the results into the cross-rank digest
 the coordinator's divergence sentinel compares. That design carries two
-contracts: the stats cost <=2% end-to-end (enforced by the bench.py
-numerics leg), and every health signal reaches the digest so the
-sentinel can name the divergent rank.
+contracts: the stats ride a read the collective already pays for, and
+every health signal reaches the digest so the sentinel can name the
+divergent rank.
 
 An ad-hoc ``jnp.isnan(grad).any()`` sprinkled at a call site breaks
 both. It is a second full read of the gradient (a separate kernel
-launch, uncounted by the overhead gate), it runs at trace time inside
+launch), it runs at trace time inside
 jitted code unless carefully guarded (see HVD007), and its verdict
 stays local — the coordinator never sees it, so the one rank that
 noticed the NaN logs a line while the postmortem blames nobody. The
@@ -1985,9 +1985,8 @@ hvd_step_seconds / hvd_tokens_per_second / hvd_mfu plus (at
 HOROVOD_PERF_ATTRIB_EVERY cadence) the per-class breakdown and overlap
 gauges; ``utils/profiling`` decomposes sub-step device time from
 profiler captures; ``utils.metrics.shared_clock()`` anchors
-timestamps. Every number from those paths lands in the registry, the
-bench JSON, and the hvd_perf ledger — comparable across runs and
-ranks.
+timestamps. Every number from those paths lands in the registry —
+comparable across runs and ranks.
 
 A stray ``t0 = time.perf_counter()`` around a step in an op or the
 serving loop produces a second, unpublished number for the "same"
@@ -2094,7 +2093,7 @@ The overlap plane (PR 14, docs/tensor-fusion.md) dispatches fused
 gradient buckets in reverse-layer readiness order while backward is
 still producing later leaves, so collective time hides under compute
 — the framework's core perf story (overlap_frac / exposed_comm_ms in
-the attribution gauges, gated by the HVD_BENCH_OVERLAP leg). One line
+the attribution gauges). One line
 can undo all of it: a whole-tree barrier between backward and the
 optimizer apply forces every bucket to finish before anything is
 consumed, re-serializing comm behind compute exactly as if the plane
@@ -2261,8 +2260,8 @@ A direct ``device.memory_stats()``, ``jax.live_arrays()`` or
 ``compiled.memory_analysis()`` call anywhere else is a second,
 unattributed accountant. The failure modes: the probe runs on the hot
 path (``live_arrays`` walks the whole live set; ``memory_stats`` is a
-host sync on some backends) without the plane's enabled() gate or its
-<=2% overhead budget (HVD_BENCH_MEM), its numbers never reach the
+host sync on some backends) without the plane's enabled() gate, its
+numbers never reach the
 ledger so hvd_top and the postmortem tell a different story than the
 call site saw, and CPU CI silently diverges from TPU because the raw
 call has no None-on-missing-stats contract.
