@@ -336,6 +336,8 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     rows = jnp.arange(b)
     pos2 = positions[:, None]
     lengths = positions + 1
+    if mask is not None:  # a row outside the pass attends to nothing
+        lengths = jnp.where(mask, lengths, 0)
     heads = mesh_lib.decode_head_sharding(cfg.num_kv_heads)
     kv_k, kv_v, ssm, conv = (state[n] for n in ("k", "v", "ssm", "conv"))
     x = _embed(cfg, params, tokens[:, None])
@@ -346,8 +348,8 @@ def decode(cfg, params, tokens, positions, state, mask=None):
             q, k, v = _qkv(cfg, layer["attn"], h, pos2)
             kv_k = kv_k.at[i, rows, positions].set(k[:, 0])
             kv_v = kv_v.at[i, rows, positions].set(v[:, 0])
-            attended = decode_attention(q, kv_k[i], kv_v[i], lengths,
-                                        head_sharding=heads)
+            attended = decode_attention(q, kv_k, kv_v, lengths,
+                                        head_sharding=heads, layer=i)
             attended = _dense(attended.reshape(b, 1, -1),
                               layer["attn"]["o"])
         with jax.named_scope("hvd.mixer"):

@@ -823,7 +823,187 @@ def _vjp_bwd(causal, block_q, block_k, interpret, scale, block_q_dkv,
 _flash_core.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def decode_attention(q, k, v, lengths, scale=None, head_sharding=None):
+#: Positions of one block of the decode kernel: a row of the cache is
+#: read in whole blocks up to its length. The engine's ``kv_bytes`` count
+#: (serving/engine.py) is taken under the same blocking.
+DECODE_BLOCK = 128
+_DECODE_BUFFERS = 3
+
+
+def decode_block(s_max):
+    """Positions a block of the decode kernel holds for rows of ``s_max``:
+    ``DECODE_BLOCK`` where it divides the row, else the whole row."""
+    return DECODE_BLOCK if s_max % DECODE_BLOCK == 0 else s_max
+
+
+def _decode_kernel_selected(cache_shape, head_sharding):
+    """Whether single-query attention over a cache of ``cache_shape``
+    ``[layers, batch, s_max, kv_heads, head_dim]`` runs as the Mosaic
+    kernel. Decided from what the call can see, no option: a TPU backend;
+    a program on ONE chip — no head-sharded cache, no committed mesh of
+    several devices (a bare ``pallas_call`` under a sharded jit is
+    refused); and a cache whose ``[s_max * kv_heads, head_dim]`` view costs
+    nothing: rows of whole 128-position blocks, 128-lane heads, and a
+    number of key/value heads that the tiled layout holds without padding
+    (1, 2, 4 or a multiple of 8 — with 6, 12 or 20 the compiled program
+    re-lays the whole cache for every call, which costs more than the
+    kernel saves). Everything else takes the einsum: the ``tp`` engines,
+    and the CPU backend, where the engine's tests would otherwise
+    interpret a kernel every decode step."""
+    if head_sharding is not None or jax.default_backend() != "tpu":
+        return False
+    from ..parallel import mesh as mesh_lib  # at trace time, not at import
+    mesh = mesh_lib.global_mesh_if_set()
+    if mesh is not None and mesh.size > 1:
+        return False
+    _, _, s_max, hk, d = cache_shape
+    return s_max % DECODE_BLOCK == 0 and d % 128 == 0 and \
+        (hk % 8 == 0 or hk in (1, 2, 4))
+
+
+def _decode_kernel(layer_ref, total_ref, row_ref, blk_ref, len_ref, q_ref,
+                   key_ref, k_hbm, v_hbm, o_ref, k_scr, v_scr, sem, m_scr,
+                   l_scr, acc_scr, *, block, hk, scale):
+    """All rows' single-query attention in one program: a loop over the
+    LIVE blocks of the cache, row after row (item i is block ``blk_ref[i]``
+    of row ``row_ref[i]``; ``total_ref[0]`` items), so a block above a
+    row's length is never asked for and the DMA queue stays full across
+    rows of a few blocks each.
+
+    The cache is seen as ``[layers, batch, s_max * hk, d]`` (a free
+    reshape): one block is ``block * hk`` rows, position-major, and ALL
+    heads' logits come from one matmul ``q[row] [heads, d] x block^T`` —
+    entry (r, c) means something where column c holds query head r's
+    key/value head (``c % hk == r // group``). ``key_ref`` carries c at
+    those entries and a huge number elsewhere, so one compare against the
+    row's live extent is both the head mask and the length mask. The MXU
+    does ``hk`` times the needed work; the step is bound by the stream
+    from HBM, not by it. Softmax statistics are fp32, in the exp2 domain
+    as the forward kernels'.
+    """
+    layer = layer_ref[0]
+    total = total_ref[0]
+    cols = block * hk
+    nbuf = k_scr.shape[0]
+    # a row of length 0 (a slot that does not decode) has no item
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def copies(i, buf):
+        start = pl.multiple_of(blk_ref[i] * cols, cols)
+        return [pltpu.make_async_copy(
+            hbm.at[layer, row_ref[i], pl.ds(start, cols), :], scr.at[buf],
+            sem.at[j, buf])
+            for j, (hbm, scr) in enumerate(((k_hbm, k_scr), (v_hbm, v_scr)))]
+
+    for j in range(nbuf - 1):
+        @pl.when(j < total)
+        def _prime():
+            for c in copies(j, j):
+                c.start()
+
+    def body(i, _):
+        buf = i % nbuf
+
+        @pl.when(i + nbuf - 1 < total)
+        def _prefetch():  # into the buffer item i - 1 has finished with
+            for c in copies(i + nbuf - 1, (i + nbuf - 1) % nbuf):
+                c.start()
+
+        row = row_ref[i]
+        # rows of this block below the row's length, in flattened rows
+        live = (len_ref[row] - blk_ref[i] * block) * hk
+
+        @pl.when(blk_ref[i] == 0)
+        def _begin_row():
+            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        for c in copies(i, buf):
+            c.wait()
+
+        @pl.when(live < cols)
+        def _hide_the_tail():
+            # whatever lies above the length in the row's last block must
+            # not meet a zero probability in the matmul (0 x NaN)
+            pos = jax.lax.broadcasted_iota(jnp.int32, v_scr.shape[1:], 0)
+            v_scr[buf] = jnp.where(
+                pos < live, v_scr[buf].astype(jnp.float32),
+                0.0).astype(v_scr.dtype)
+
+        s = jax.lax.dot_general(q_ref[row], k_scr[buf],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(key_ref[...] < live, s * (scale * _LOG2E), _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp2(m_prev - m_new)
+        p = jnp.exp2(s - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            p.astype(v_scr.dtype), v_scr[buf],
+            preferred_element_type=jnp.float32)
+
+        @pl.when(live <= cols)
+        def _end_row():
+            o_ref[row] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                          ).astype(o_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, total, body, 0)
+
+
+def _decode_attention_kernel(q, k, v, lengths, layer, scale):
+    """``decode_attention`` over layer ``layer`` of the whole cache
+    ``k``/``v`` ``[layers, batch, s_max, kv_heads, d]`` as the Mosaic
+    kernel. The cache goes in WHOLE (``memory_space=ANY``) with the layer
+    as a scalar: a custom call cannot fuse a slice, and a sliced operand
+    would be copied (two layers of cache a call)."""
+    layers, b, s_max, hk, d = k.shape
+    h = q.shape[2]
+    block = decode_block(s_max)
+    rows = -(-h // 16) * 16  # query heads, padded to a bf16 tile's sublanes
+    cols = block * hk
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, s_max)
+    # the work list: item i is block i - ends[row - 1] of the row whose
+    # blocks end after i
+    ends = jnp.cumsum((lengths + block - 1) // block)
+    item = jnp.arange(b * (s_max // block), dtype=jnp.int32)
+    before = ends[None, :] <= item[:, None]
+    row_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    blk_of = item - jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    r = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    c = jnp.arange(cols, dtype=jnp.int32)[None, :]
+    key = jnp.where(c % hk == r // (h // hk), c, 2 ** 30).astype(jnp.int32)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    flat = (layers, b, s_max * hk, d)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block=block, hk=hk, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        in_specs=[smem] * 5 + [vmem, vmem, hbm, hbm],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((_DECODE_BUFFERS, cols, d), k.dtype),
+            pltpu.VMEM((_DECODE_BUFFERS, cols, d), v.dtype),
+            pltpu.SemaphoreType.DMA((2, _DECODE_BUFFERS)),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="decode_attention",
+        interpret=_auto_interpret(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), ends[-1:], row_of, blk_of,
+      lengths, jnp.pad(q[:, 0], ((0, 0), (0, rows - h), (0, 0))), key,
+      k.reshape(flat), v.reshape(flat))
+    return out[:, None, :h]
+
+
+def decode_attention(q, k, v, lengths, scale=None, head_sharding=None,
+                     layer=None):
     """Single-query attention against a cached K/V prefix — the decode
     step of the serving plane (docs/serving.md).
 
@@ -833,8 +1013,12 @@ def decode_attention(q, k, v, lengths, scale=None, head_sharding=None):
               is whatever the allocator left there (masked out here).
               ``kv_heads`` may divide ``heads`` (grouped-query attention:
               query head i reads key/value head i // (heads / kv_heads));
-              the cache is read as it is, never repeated per query head
-    lengths   [batch] int32 — valid prefix length per row
+              the cache is read as it is, never repeated per query head.
+              With ``layer`` they are the WHOLE cache ``[layers, batch,
+              s_max, kv_heads, head_dim]``
+    lengths   [batch] int32 — valid prefix length per row; a row of
+              length 0 (a slot that does not decode) attends to nothing
+              and its output means nothing
     scale     optional softmax scale (default head_dim ** -0.5, matching
               flash_attention)
     head_sharding  optional NamedSharding over the head axis
@@ -843,15 +1027,24 @@ def decode_attention(q, k, v, lengths, scale=None, head_sharding=None):
               embarrassingly parallel over heads — each chip attends
               its own heads/tp slice of the cache, no cross-chip
               traffic until the output projection's psum
+    layer     optional index into a whole cache: what the decode programs
+              pass, so that the kernel can read its layer in place
 
-    Deliberately plain XLA rather than a Pallas kernel: with q_len == 1
-    the QK^T product is a [s_max, d] GEMV per (batch, head) — there is no
-    [s, s] logits matrix to avoid materializing and no q-tiling to do, so
-    the flash streaming structure buys nothing. The op is HBM-bandwidth
-    bound on reading K/V once, which XLA's fused masked-softmax-GEMV
-    already achieves, and keeping it jnp makes the masked fixed-s_max
-    shape trivially jit-stable across decode steps (no recompiles as
-    rows join/retire — lengths is data, not shape).
+    Two implementations of one contract. The op is bound by reading K/V
+    from HBM, and what decides its time is HOW MUCH it reads: the einsum
+    below reads every row to ``s_max`` and masks, the Mosaic kernel
+    (``_decode_kernel``) takes ``lengths`` as data and streams each row's
+    blocks of ``DECODE_BLOCK`` positions up to its length and none above.
+    With a third of a 16 x 1536 cache live that took the decode program
+    from 59% to 88% of its HBM roofline on a v5e (PERF_LEDGER.jsonl, PR
+    30). ``_decode_kernel_selected`` picks the kernel from the call itself
+    — a whole cache handed over with ``layer``, a TPU backend, one chip,
+    no head sharding, a cache whose tiles it can read as they lie — and
+    there is no option.
+    The einsum is the plain reference the kernel's tests compare with, the
+    head-sharded (``tp``) path, and what the CPU backend runs. Either way
+    the shape is fixed across decode steps: ``lengths`` is data, and rows
+    join and retire without a recompile.
 
     Numerics contract (tests/test_flash_attention.py): matches the last
     row of flash_attention / parallel.ring.full_attention over the same
@@ -861,16 +1054,20 @@ def decode_attention(q, k, v, lengths, scale=None, head_sharding=None):
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"decode_attention wants q [b, 1, h, d], got "
                          f"{q.shape}")
-    if head_sharding is not None:
-        q = jax.lax.with_sharding_constraint(q, head_sharding)
-        k = jax.lax.with_sharding_constraint(k, head_sharding)
-        v = jax.lax.with_sharding_constraint(v, head_sharding)
     b, _, h, d = q.shape
-    s_max, hk = k.shape[1], k.shape[2]
+    s_max, hk = k.shape[-3], k.shape[-2]
     if h % hk:
         raise ValueError(f"decode_attention: {h} query heads over {hk} "
                          f"key/value heads")
     scale = d ** -0.5 if scale is None else scale
+    if layer is not None:
+        if _decode_kernel_selected(k.shape, head_sharding):
+            return _decode_attention_kernel(q, k, v, lengths, layer, scale)
+        k, v = k[layer], v[layer]
+    if head_sharding is not None:
+        q = jax.lax.with_sharding_constraint(q, head_sharding)
+        k = jax.lax.with_sharding_constraint(k, head_sharding)
+        v = jax.lax.with_sharding_constraint(v, head_sharding)
     pos = jnp.arange(s_max, dtype=jnp.int32)[None, None, :]
     valid = pos < lengths.astype(jnp.int32)[:, None, None]
     if hk != h:

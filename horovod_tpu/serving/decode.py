@@ -21,8 +21,10 @@ and the cache call, and they find the model by its configuration's type.
 
 Attention: prefill uses the model's own dispatch (flash kernel on TPU,
 exact full attention on CPU); decode uses ops/flash_attention.py's
-``decode_attention`` (q_len=1 against the cache, fixed s_max masked by
-per-row lengths — jit-stable as rows join/retire).
+``decode_attention`` (q_len=1 against the cache, per-row lengths as data
+— jit-stable as rows join/retire; on one TPU chip a kernel that reads
+each row's live blocks and no more, elsewhere an einsum over the whole
+row under a length mask).
 """
 
 import flax.linen as nn
@@ -115,7 +117,7 @@ def prefill_forward(cfg, params, tokens):
     return _logits(cfg, params, x), jnp.stack(ks), jnp.stack(vs)
 
 
-def decode_step(cfg, params, tokens, positions, kv_k, kv_v):
+def decode_step(cfg, params, tokens, positions, kv_k, kv_v, mask=None):
     """One decode token for every cache row at a static shape.
 
     tokens     [b] int32 — the token each row feeds in this step
@@ -126,6 +128,10 @@ def decode_step(cfg, params, tokens, positions, kv_k, kv_v):
                K/V of inactive slots may receive garbage writes
                harmlessly (true of K/V alone: a recurrent state has no
                such hiding place, see ``decode`` below)
+    mask       [b] bool or None — the rows this pass decodes. A row
+               outside it still parks its K/V write where ``positions``
+               says, but attends to nothing (length 0: nothing of its
+               row is read) and its logits mean nothing
 
     Returns (logits [b, vocab], kv_k, kv_v) with the new token's K/V
     appended at ``positions``; attention spans 0..positions inclusive.
@@ -136,6 +142,8 @@ def decode_step(cfg, params, tokens, positions, kv_k, kv_v):
     pos2 = positions[:, None]  # [b, 1] per-row positions for rope
     x = _embed(cfg, params, tokens[:, None])
     lengths = positions + 1
+    if mask is not None:
+        lengths = jnp.where(mask, lengths, 0)
     # trace-time hint: head-sharded attention over the committed global
     # mesh's tp axis (None on dp-only engines — byte-identical program)
     heads = mesh_lib.decode_head_sharding(cfg.num_heads)
@@ -145,8 +153,8 @@ def decode_step(cfg, params, tokens, positions, kv_k, kv_v):
         q, k, v = _qkv(cfg, layer, y, pos2)
         kv_k = kv_k.at[i, rows, positions].set(k[:, 0])
         kv_v = kv_v.at[i, rows, positions].set(v[:, 0])
-        attn = decode_attention(q, kv_k[i], kv_v[i], lengths,
-                                head_sharding=heads)
+        attn = decode_attention(q, kv_k, kv_v, lengths,
+                                head_sharding=heads, layer=i)
         attn = attn.reshape(b, 1, cfg.d_model)
         x = x + _dense(attn, layer["attn"]["out"]["kernel"], cfg.dtype)
         y = _rmsnorm(x, layer["ln_mlp"]["scale"], cfg.dtype)
@@ -188,11 +196,11 @@ def prefill(cfg, params, tokens, last_index):
 def decode(cfg, params, tokens, positions, state, mask=None):
     """(logits [b, vocab], state): every kind of ``state`` advanced by
     one token for the rows in ``mask`` ([b] bool, the pass's cohort;
-    None: a model whose every kind can park a foreign row's write, K/V
-    alone). A row outside the mask keeps its recurrent kinds bit for
-    bit."""
+    None: every row). A row outside the mask keeps its recurrent kinds
+    bit for bit, parks its K/V write where ``positions`` says, and
+    attends to nothing."""
     if _is_hybrid(cfg):
         return hybrid.decode(cfg, params, tokens, positions, state, mask)
     logits, k, v = decode_step(cfg, params, tokens, positions, state["k"],
-                               state["v"])
+                               state["v"], mask)
     return logits, {"k": k, "v": v}

@@ -69,6 +69,7 @@ import numpy as np
 
 from ..common import config
 from ..common.exceptions import RanksLostError
+from ..ops.flash_attention import decode_block
 from ..utils import alerts as hvd_alerts
 from ..utils import history as hvd_history
 from ..utils import memory as hvd_memory
@@ -110,8 +111,10 @@ def _decode_jit(cfg, params, tokens, positions, state, temps, rows, key,
     the host reads ``ids``, possibly a step later. The sampling key is
     folded here from the engine's ``key`` and the host's step ``count``
     (the same bits as ``fold_in`` on the host, without its dispatch).
-    A model with a recurrent state takes ``rows`` as its mask; K/V alone
-    need none (the host parks the other rows' positions at max_len - 1)."""
+    ``rows`` is the model's mask: a recurrent state of a row outside it
+    is kept bit for bit; K/V need no such care for their writes (the host
+    parks the other rows' positions at max_len - 1), but attention reads
+    nothing of a row outside it (ops/flash_attention.decode_attention)."""
     logits, state = decode(cfg, params, tokens, positions, state, rows)
     rng = jax.random.fold_in(key, count)
     ids = jnp.where(rows, sample_tokens(rng, logits, temps), tokens)
@@ -290,6 +293,11 @@ class ServeEngine:
         # bytes of recurrent state one row holds over all layers: what a
         # decode pass reads and writes again per row it advances
         self._row_state_bytes = self.kv.row_state_bytes()
+        # the blocking under which decode attention reads a row's K/V
+        # (ops/flash_attention.py), and the bytes one block of one row
+        # holds over all layers: the step record's ``kv_bytes``
+        self._kv_block = decode_block(self.kv.max_len)
+        self._kv_block_bytes = self.kv.kv_block_bytes(self._kv_block)
         # cache-writing programs whose first call on this engine has
         # yet to show that it consumed its arrays (_note_in_place)
         self._in_place_unchecked = {"write_slot", "decode"}
@@ -760,10 +768,15 @@ class ServeEngine:
                 if not slots:
                     continue
                 positions, temps, rows = feed or self._place_rows(slots)
+                blocks = 0
                 for slot in slots:
                     st = self._active[slot]
                     st.next_pos += 1
                     launched.append((slot, st))
+                    blocks += -(-st.next_pos // self._kv_block)
+                # what attention has to stream: each decoding row's K/V
+                # in whole blocks up to its length, known without a read
+                rec.count("kv_bytes", blocks * self._kv_block_bytes)
                 if self.kv.recurrent:
                     rec.count("state_rows", len(slots))
                     rec.count("state_bytes",

@@ -257,6 +257,14 @@ class KVCache:
         return sum(by_kind[kind] for kind in self.recurrent) \
             // self.num_slots
 
+    def kv_block_bytes(self, block):
+        """Bytes of K and V that ``block`` positions of ONE slot hold over
+        all layers (on one chip): what a decode pass streams for each
+        block of a row it reads."""
+        by_kind = self.bytes_by_kind()
+        return (by_kind["k"] + by_kind["v"]) * block \
+            // (self.num_slots * self.max_len)
+
     @property
     def num_slots(self):
         return self.ledger.num_slots

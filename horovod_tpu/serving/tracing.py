@@ -347,6 +347,10 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # ahead: 1 when the step returned with its decode pass in flight, its ids
 # unread (serving/engine.py ``_due``); the NEXT step's ``decode_readback``
 # then starts with that pass's ids. 0 on every other step that decoded.
+# kv_bytes (not in STEP_COUNTS: only a step that decoded has it): bytes of
+# K and V the step's decode passes had to stream, each decoding row's in
+# whole blocks of ops/flash_attention.decode_block positions up to its
+# length, over all layers
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
                "state_rows", "state_bytes", "ahead")
 _STEP_ANNOTATION = "hvd.serve.step"
@@ -403,7 +407,7 @@ class StepTrace:
         return False
 
     def count(self, name, n=1):
-        self.counts[name] += n
+        self.counts[name] = self.counts.get(name, 0) + n
 
     def tick_span(self, **attrs):
         """The step's one ``decode_tick`` span (the engine-wide lane),

@@ -199,14 +199,43 @@ def test_mosaic_accepts_the_kernels_without_a_chip(topo):
             lowering_platforms=("tpu",)).compile()
 
 
-@pytest.mark.parametrize("program", ["decode", "write_slot"])
-def test_the_serving_programs_update_the_cache_in_place(topo, program):
+@pytest.fixture
+def decode_kernel(monkeypatch):
+    """The decode program as a TPU backend traces it: attention as the
+    Mosaic kernel (ops/flash_attention.py picks it from the backend, and
+    this process's is the CPU). ``_decode_jit`` is one jit for the
+    process, so what is traced here is dropped again."""
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.serving import engine as engine_mod
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    engine_mod._decode_jit.clear_cache()
+    yield
+    engine_mod._decode_jit.clear_cache()
+
+
+def _whole_copies(compiled, *arrays):
+    """The compiled program's copies of whole arrays of these (dtype,
+    shape)s."""
+    import re
+    hlo = {"bfloat16": "bf16", "float32": "f32"}
+    return [c for dtype, dims in arrays for c in re.findall(
+        re.escape(f"{hlo[str(dtype)]}[{','.join(map(str, dims))}]")
+        + r"\S*\s+copy\(.*", compiled.as_text())]
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_kernel",
+                                     "write_slot"])
+def test_the_serving_programs_update_the_cache_in_place(topo, program,
+                                                        request):
     """The TPU compiler's own word, at Baichuan-7B widths (32 heads of
     128, 16 slots x 1536; depth 2 so it compiles in seconds): both
     programs that rewrite the KV cache alias their cache outputs to the
     donated inputs, and neither holds a copy of a whole cache array —
-    the 2 x 2 GB a step that the undonated programs moved at depth 10."""
-    import re
+    the 2 x 2 GB a step that the undonated programs moved at depth 10.
+    ``decode_kernel``: the same with attention as the Mosaic kernel,
+    which Mosaic accepts at these widths, one call a layer, and which
+    reads its layer out of the whole cache without a copy of it (in
+    either shape: the kernel sees rows of positions x heads)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -224,7 +253,9 @@ def test_the_serving_programs_update_the_cache_in_place(topo, program):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
     kv = arr((layers, slots, max_len, heads, head_dim), jnp.bfloat16)
     state = {"k": kv, "v": kv}
-    if program == "decode":
+    if program == "decode_kernel":
+        request.getfixturevalue("decode_kernel")
+    if program != "write_slot":
         params = jax.tree_util.tree_map(
             lambda a: arr(a.shape, jnp.bfloat16),
             jax.eval_shape(lambda k: tr.init_params(cfg, k)[1],
@@ -241,10 +272,12 @@ def test_the_serving_programs_update_the_cache_in_place(topo, program):
     compiled = lowered.compile()
     cache_bytes = 2 * layers * slots * max_len * heads * head_dim * 2
     assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
-    shape = f"bf16[{layers},{slots},{max_len},{heads},{head_dim}]"
-    copies = re.findall(re.escape(shape) + r"\S*\s+copy\(.*",
-                        compiled.as_text())
+    copies = _whole_copies(
+        compiled, (kv.dtype, kv.shape),
+        (kv.dtype, (layers, slots, max_len * heads, head_dim)))
     assert not copies, copies
+    assert compiled.as_text().count("tpu_custom_call") == \
+        (layers if program == "decode_kernel" else 0)
 
 
 def test_init_names_the_process_that_holds_the_chip(monkeypatch):
@@ -264,14 +297,16 @@ def test_init_names_the_process_that_holds_the_chip(monkeypatch):
     assert not hvd.is_initialized()
 
 
-@pytest.mark.parametrize("program", ["decode", "write_slot"])
-def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program):
+@pytest.mark.parametrize("program", ["decode", "decode_kernel",
+                                     "write_slot"])
+def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program,
+                                                           request):
     """The same word for a model that keeps recurrent and convolution
     state beside its K/V (models/hybrid.py; mixer 8 heads of 64 with a
     state of 128, 4 query and 2 key/value heads of 128; 8 slots x 512,
     depth 2): every kind of state is aliased to its donated input, and
-    the float32 state is never copied whole."""
-    import re
+    the float32 state is never copied whole. ``decode_kernel``: with
+    grouped-query attention as the Mosaic kernel, K and V uncopied."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -290,7 +325,9 @@ def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
     state = {k: arr(a.shape, a.dtype) for k, a in
              serve_decode.state_shapes(cfg, slots, max_len).items()}
-    if program == "decode":
+    if program == "decode_kernel":
+        request.getfixturevalue("decode_kernel")
+    if program != "write_slot":
         params = jax.tree_util.tree_map(
             lambda a: arr(a.shape, a.dtype),
             jax.eval_shape(lambda k: hybrid.init_params(cfg, k),
@@ -311,8 +348,10 @@ def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program):
     state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
                       for a in state.values())
     assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
-    ssm = state["ssm"].shape
-    shape = "f32[" + ",".join(map(str, ssm)) + "]"
-    copies = re.findall(re.escape(shape) + r"\S*\s+copy\(.*",
-                        compiled.as_text())
+    ssm, k = state["ssm"], state["k"]
+    copies = _whole_copies(
+        compiled, (ssm.dtype, ssm.shape), (k.dtype, k.shape),
+        (k.dtype, k.shape[:2] + (k.shape[2] * k.shape[3], k.shape[4])))
     assert not copies, copies
+    assert compiled.as_text().count("tpu_custom_call") == \
+        (cfg.num_layers if program == "decode_kernel" else 0)

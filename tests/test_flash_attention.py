@@ -303,6 +303,94 @@ class TestDecodeAttention:
             decode_attention(q, k, v, jnp.asarray([8], jnp.int32))
 
 
+# the kernel of the decode path, interpreted: row lengths around a block's
+# edge, alone and mixed in one batch (0: a slot that does not decode)
+_DECODE_LENGTHS = {"1": [1], "127": [127], "128": [128], "129": [129],
+                   "max_len": [256], "mixed": [129, 0, 1, 256, 127, 128]}
+
+
+class TestDecodeKernel:
+    """``_decode_attention_kernel`` (Pallas, interpreted here) against the
+    einsum of ``decode_attention``, its plain reference."""
+
+    @pytest.mark.parametrize("lengths", list(_DECODE_LENGTHS))
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("heads,kv_heads,d",
+                             [(32, 32, 128), (20, 4, 64)])
+    def test_reads_live_blocks_of_its_layer_like_the_einsum(
+            self, hvd, heads, kv_heads, d, dtype, lengths):
+        """Equal and grouped heads, both dtypes: the einsum's values on
+        layer ``layer`` of a whole cache; NaN keys and infinite values
+        above each row's length, and NaN in every other layer, reach no
+        output; a row of length 0 comes back as zeros."""
+        import jax
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        dtype = jnp.dtype(dtype)
+        lens = _DECODE_LENGTHS[lengths]
+        layers, layer, b, s_max = 3, len(lens) % 3, len(lens), 256
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(heads + b), 3)
+        q = jax.random.normal(kq, (b, 1, heads, d), dtype)
+        k = jax.random.normal(kk, (b, s_max, kv_heads, d), dtype)
+        v = jax.random.normal(kv, (b, s_max, kv_heads, d), dtype)
+        lengths = jnp.asarray(lens, jnp.int32)
+        want = fa.decode_attention(q, k, v, lengths)
+        above = (jnp.arange(s_max)[None, :] >= lengths[:, None]
+                 )[:, :, None, None]
+        cache_k = jnp.full((layers,) + k.shape, jnp.nan, dtype).at[layer].set(
+            jnp.where(above, jnp.nan, k))
+        cache_v = jnp.full((layers,) + v.shape, jnp.nan, dtype).at[layer].set(
+            jnp.where(above, jnp.inf, v))
+        got = jax.jit(fa._decode_attention_kernel, static_argnums=(5,))(
+            q, cache_k, cache_v, lengths, jnp.int32(layer), d ** -0.5)
+        assert got.shape == want.shape and got.dtype == q.dtype
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        live = np.asarray(lens) > 0
+        tol = 2e-5 if dtype == jnp.float32 else 1e-2
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+        assert not got[~live].any()
+
+    def test_the_choice_is_made_from_the_call(self, hvd, monkeypatch):
+        """No option: on the CPU backend ``decode_attention`` takes the
+        einsum, with a whole cache and a layer too (the slice it took
+        itself before); where the predicate says yes the same call runs
+        the kernel; a cache the kernel cannot tile takes the einsum."""
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        q, k, v = _qkv(4, b=2, s=256, h=4, d=16)
+        lengths = jnp.asarray([130, 7], jnp.int32)
+        cache_k, cache_v = jnp.stack([k * 0, k]), jnp.stack([v * 0, v])
+        want = fa.decode_attention(q[:, :1], k, v, lengths)
+        assert not fa._decode_kernel_selected(cache_k.shape, None)
+        calls = []
+        kernel = fa._decode_attention_kernel
+        monkeypatch.setattr(fa, "_decode_attention_kernel",
+                            lambda *a: calls.append(1) or kernel(*a))
+        got = fa.decode_attention(q[:, :1], cache_k, cache_v, lengths,
+                                  layer=1)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert not calls
+        monkeypatch.setattr(fa, "_decode_kernel_selected",
+                            lambda shape, sharding: True)
+        got = fa.decode_attention(q[:, :1], cache_k, cache_v, lengths,
+                                  layer=1)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        assert calls == [1]
+        monkeypatch.undo()
+        # what the predicate looks at besides the backend
+        monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+        assert fa._decode_kernel_selected((10, 16, 1536, 32, 128), None)
+        assert fa._decode_kernel_selected((6, 32, 1536, 4, 128), None)
+        assert not fa._decode_kernel_selected((10, 16, 1536, 32, 128),
+                                              object())  # head-sharded
+        assert not fa._decode_kernel_selected((2, 2, 48, 4, 128), None)
+        assert not fa._decode_kernel_selected((2, 2, 1536, 12, 64), None)
+        # six key/value heads are padded in the cache's tiles: a copy
+        assert not fa._decode_kernel_selected((12, 8, 1024, 6, 128), None)
+        assert fa.decode_block(1536) == 128 and fa.decode_block(48) == 48
+
+
 class TestKVCachedGeneration:
     def test_cached_greedy_matches_no_cache_token_for_token(self, hvd):
         """Prefill + decode_attention steps reproduce the no-cache

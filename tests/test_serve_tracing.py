@@ -395,8 +395,9 @@ class TestStepTrace:
         engine.submit(Request("b", (2, 7, 1, 8, 2), max_new_tokens=3))
         assert engine.step() == []
         (rec,) = stepping.steps()
+        # kv_bytes: on a step that decoded, and on no other (below)
         assert set(rec) == {"seq", "start_us", "end_us", "phases",
-                            *serve_tracing.STEP_COUNTS}
+                            "kv_bytes", *serve_tracing.STEP_COUNTS}
         assert rec["seq"] == 1 and _tiles(rec)
         one = ["admit", "prefill", "prefill_readback", "bookkeeping"]
         # both slots busy and neither row on its last token: the step
@@ -472,6 +473,7 @@ class TestStepTrace:
         assert [p[0] for p in idle["phases"]] == ["control", "admit",
                                                   "telemetry"]
         assert not any(idle[c] for c in serve_tracing.STEP_COUNTS)
+        assert "kv_bytes" not in idle
 
     def test_the_ring_drops_the_oldest_past_4096(self):
         tracer = hvd_tracing.Tracer(rank=0, clock=FakeUsClock())

@@ -4,6 +4,7 @@ admission control, SLO metric emission, and the engine end-to-end —
 including temp-0 parity between the KV-cached engine and a no-cache
 greedy reference over the same model."""
 
+import dataclasses
 import logging
 import os
 
@@ -987,6 +988,146 @@ class TestTheStepDoesNotWaitForTheHost:
         assert (engine_mod._decode_jit._cache_size(),
                 engine_mod._write_slot._cache_size(),
                 engine_mod._prefill_jit._cache_size()) == before
+
+
+# ---------------------------------------------------------------------------
+# Decode attention reads each row's live K/V: the kernel inside the engine
+# (ops/flash_attention.py; interpreted here, forced on through the
+# selecting predicate: there is no option), and the count that says how
+# much there is to read
+# ---------------------------------------------------------------------------
+
+def _long_dense():
+    cfg = tr.TransformerConfig.tiny(dtype=jnp.float32,
+                                    attention_impl="full")
+    cfg = dataclasses.replace(cfg, max_seq_len=256)
+    _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def _long_hybrid():
+    cfg = hybrid.HybridConfig.tiny(dtype=jnp.float32, max_seq_len=256,
+                                   ssm_multipliers=(1.0, 1.0, 1.0, 1.0, 4.0))
+    return cfg, hybrid.init_params(cfg, jax.random.PRNGKey(0))
+
+
+LONG_MODELS = {"dense": _long_dense, "hybrid": _long_hybrid}
+# rows that cross the kernel's 128-position block while they decode, a
+# short one beside them, and two that join when a slot comes free
+LONG_REQUESTS = [("a", _prompt(126, 1), 8), ("b", _prompt(5, 2), 6),
+                 ("c", _prompt(131, 3), 5)]
+LONG_JOINS = [(7, ("d", _prompt(3, 4), 9)), (9, ("e", _prompt(120, 5), 12))]
+
+
+class TestDecodeReadsLiveKV:
+    @pytest.fixture(autouse=True)
+    def programs(self):
+        """``_decode_jit`` is one jit for the process: a program traced
+        under a forced predicate must not outlive its test."""
+        from horovod_tpu.serving import engine as engine_mod
+        from horovod_tpu.utils import tracing as hvd_tracing
+        hvd_tracing.reset(enabled=True)
+        engine_mod._decode_jit.clear_cache()
+        yield engine_mod
+        engine_mod._decode_jit.clear_cache()
+        hvd_tracing.reset()
+
+    @pytest.mark.parametrize("model", list(LONG_MODELS))
+    def test_the_kernel_in_the_engine_emits_the_einsums_tokens(
+            self, reg, programs, monkeypatch, model):
+        from horovod_tpu.ops import flash_attention as fa
+        cfg, params = LONG_MODELS[model]()
+        kw = dict(num_slots=3, max_len=256, kv_block=128)
+        want, _ = _drive(_engine(cfg, params, **kw), LONG_REQUESTS,
+                         joins=LONG_JOINS)
+        assert programs._decode_jit._cache_size() == 1
+        programs._decode_jit.clear_cache()
+        ran = []
+        kernel = fa._decode_attention_kernel
+        monkeypatch.setattr(fa, "_decode_kernel_selected",
+                            lambda shape, sharding: sharding is None)
+        monkeypatch.setattr(fa, "_decode_attention_kernel",
+                            lambda *a: ran.append(a[0].shape) or kernel(*a))
+        got, recs = _drive(_engine(cfg, params, **kw), LONG_REQUESTS,
+                           joins=LONG_JOINS)
+        assert len(ran) == cfg.num_layers  # traced once, every layer
+        assert len(got) == 5 and _tokens(got) == _tokens(want)
+        assert all(r.outcome == "completed" for r in got.values())
+        # rows joined and retired, a pass was read late and at once
+        assert {r["ahead"] for r in recs} == {0, 1}
+        assert sum(r["retired"] for r in recs) == 5
+        assert programs._decode_jit._cache_size() == 1
+
+    def test_the_step_record_counts_the_kv_a_pass_has_to_stream(
+            self, reg, programs):
+        """``kv_bytes``: each decoding row's K and V in whole blocks of
+        the kernel's 128 positions up to its length, over all layers; on
+        a CPU engine too (what is counted is the rows, not the path),
+        and on no step that decodes nothing."""
+        from benchmarks.readers import step_count_median
+        from horovod_tpu.ops.flash_attention import decode_block
+        cfg, params = _long_dense()
+        engine = _engine(cfg, params, num_slots=4, max_len=256,
+                         kv_block=128)
+        assert decode_block(256) == 128
+        # one position of one row over all layers, K and V, float32
+        position = cfg.num_layers * 2 * cfg.num_heads * 16 * 4
+        assert engine.kv.kv_block_bytes(128) == 128 * position
+        for rid, n in (("a", 126), ("b", 5), ("c", 200)):
+            engine.submit(Request(rid, _prompt(n, n), max_new_tokens=9))
+        engine.step()
+        first = hvd_tracing_steps()[-1]
+        # lengths 127, 6 and 201: 1 + 1 + 2 blocks
+        assert first["admitted"] == 3 and first["active"] == 3
+        assert first["kv_bytes"] == (1 + 1 + 2) * 128 * position
+        engine.step()
+        engine.step()
+        third = hvd_tracing_steps()[-1]
+        # 129, 8 and 203: row a has crossed into its second block
+        assert third["kv_bytes"] == (2 + 1 + 2) * 128 * position
+        engine.run_to_completion()
+        engine.step()
+        idle = hvd_tracing_steps()[-1]
+        assert idle["active"] == 0 and "kv_bytes" not in idle
+        # the form the benchmark's reader takes: a number on every
+        # decode-only step, its median scaled (attn.kv_bytes_per_step)
+        recs = [r for r in hvd_tracing_steps() if r["active"]]
+        assert all(isinstance(r["kv_bytes"], int) and r["kv_bytes"] > 0
+                   for r in recs)
+        read = step_count_median.read(
+            {"step_phases": {"window": recs}},
+            {"count": "kv_bytes", "scale": 1e-9}, None)
+        assert read == pytest.approx(5 * 128 * position * 1e-9)
+
+    def test_a_head_sharded_engine_keeps_the_einsum(self, reg, programs,
+                                                    monkeypatch):
+        """Over a ``tp`` mesh (8 virtual devices) the cache is sharded by
+        heads and the einsum runs under its sharding constraint, whatever
+        the backend: the same tokens as the unsharded engine."""
+        from horovod_tpu.ops import flash_attention as fa
+        from horovod_tpu.parallel import mesh as mesh_lib
+        cfg, params = _long_dense()
+        kw = dict(num_slots=2, max_len=256, kv_block=128)
+        requests = [("a", _prompt(126, 1), 6), ("b", _prompt(5, 2), 4)]
+        want, _ = _drive(_engine(cfg, params, **kw), requests)
+        programs._decode_jit.clear_cache()
+        seen, ran = [], []
+        monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+        selected = fa._decode_kernel_selected
+        monkeypatch.setattr(
+            fa, "_decode_kernel_selected",
+            lambda shape, sharding: seen.append(sharding) or
+            selected(shape, sharding))
+        monkeypatch.setattr(fa, "_decode_attention_kernel",
+                            lambda *a: ran.append(1))
+        mesh = mesh_lib.build_mesh(tp=2)
+        mesh_lib.set_global_mesh(mesh)
+        try:
+            got, _ = _drive(_engine(cfg, params, mesh=mesh, **kw), requests)
+        finally:
+            mesh_lib.reset_global_mesh()
+        assert len(seen) == cfg.num_layers and None not in seen
+        assert not ran and _tokens(got) == _tokens(want)
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2), (20, 4), (6, 1)])
