@@ -309,24 +309,48 @@ def _serve_requests(vocab, lengths, max_len, seed=0):
     return reqs
 
 
-def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
-              lengths=(5, 16, 100, 513, 1000, 7, 33, 250, 640, 90, 12, 400),
-              tie_tol=SERVE_TIE_TOL):
-    """Two passes of the same seeded requests through ServeEngine — the
-    first pays every compile under a patient queue, the second runs warm
-    behind the default admission queue — then a teacher-forced check:
-    each served token must be TransformerLM.apply's argmax given the
-    tokens before it (so a plain greedy decode yields the same sequence)
-    or tie with it within ``tie_tol``."""
+def _served_model(cfg):
+    """(params, rows) of a served configuration of either family: seeded
+    parameters, and the plain reference ``rows(params, seq [max_len], at
+    [k]) -> float32 logits [k, vocab]`` of a full-attention forward over
+    ``seq`` at the positions ``at`` — TransformerLM.apply, or for a
+    model with a recurrent mixer its prefill (the chunked scan, no cache),
+    once for each position."""
     import jax
     import jax.numpy as jnp
 
+    from horovod_tpu.models import hybrid
     from horovod_tpu.models import transformer as tr
+
+    ref_cfg = dataclasses.replace(cfg, attention_impl="full")
+    if isinstance(cfg, hybrid.HybridConfig):
+        params = hybrid.init_params(cfg, jax.random.PRNGKey(0))
+        rows = jax.jit(lambda p, seq, at: jax.vmap(
+            lambda i: hybrid.prefill(ref_cfg, p, seq[None], i)[0][0])(at))
+    else:
+        _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
+        ref_model = tr.TransformerLM(ref_cfg)
+        rows = jax.jit(lambda p, seq, at: ref_model.apply(
+            {"params": p}, seq[None])[0, at].astype(jnp.float32))
+    return jax.device_put(params, jax.devices()[0]), rows
+
+
+def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
+              lengths=(5, 16, 100, 513, 1000, 7, 33, 250, 640, 90, 12, 400),
+              tie_tol=SERVE_TIE_TOL, name="gpt2_small_tpu"):
+    """Two passes of the same seeded requests through ServeEngine — the
+    first pays every compile under a patient queue, the second runs warm
+    behind the default admission queue — then a teacher-forced check:
+    each served token must be the plain forward's argmax given the tokens
+    before it (so a plain greedy decode yields the same sequence) or tie
+    with it within ``tie_tol``. ``cfg`` is a TransformerConfig or a
+    HybridConfig (``_served_model``)."""
+    import jax.numpy as jnp
+
     from horovod_tpu.serving import engine as engine_mod
     from horovod_tpu.serving.queue import AdmissionQueue
 
-    model, params = tr.init_params(cfg, jax.random.PRNGKey(0))
-    params = jax.device_put(params, jax.devices()[0])
+    params, reference_rows = _served_model(cfg)
 
     def one_pass(queue):
         eng = engine_mod.ServeEngine(cfg, params, num_slots=slots,
@@ -377,21 +401,20 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
            "every slot was busy and no step ran ahead: each decode pass "
            "waited for the host to read the one before")
 
-    # reference: one full-attention forward over prompt + served tokens
-    ref_cfg = dataclasses.replace(cfg, attention_impl="full")
-    ref_model = tr.TransformerLM(ref_cfg)
-    seqs = np.zeros((len(reqs), max_len), np.int32)
-    for i, r in enumerate(reqs):
-        full = list(r.prompt) + list(cold[r.request_id].tokens)
-        seqs[i, :len(full) - 1] = full[:-1]
-    logits = jax.jit(lambda p, t: ref_model.apply({"params": p}, t).astype(
-        jnp.float32))(params, jnp.asarray(seqs))
-    logits = np.asarray(logits)
+    # reference: a full-attention forward over prompt + served tokens
+    most = max(r.max_new_tokens for r in reqs)
     exact = ties = total = 0
     worst = 0.0
-    for i, r in enumerate(reqs):
-        for j, tok in enumerate(cold[r.request_id].tokens):
-            row = logits[i, len(r.prompt) - 1 + j]
+    for r in reqs:
+        served = cold[r.request_id].tokens
+        seq = np.zeros(max_len, np.int32)
+        seq[:len(r.prompt) + len(served) - 1] = \
+            (list(r.prompt) + list(served))[:-1]
+        at = np.minimum(len(r.prompt) - 1 + np.arange(most), max_len - 1)
+        logits = np.asarray(reference_rows(params, jnp.asarray(seq),
+                                           jnp.asarray(at)))
+        for j, tok in enumerate(served):
+            row = logits[j]
             deficit = float(row.max() - row[tok])
             total += 1
             exact += int(tok == int(row.argmax()))
@@ -401,7 +424,7 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
            f"{total - exact - ties} of {total} served tokens are not the "
            f"reference's greedy choice (worst logit deficit {worst:.4g} > "
            f"{tie_tol})")
-    emit("serve", model="gpt2_small_tpu", layers=cfg.num_layers,
+    emit("serve", model=name, layers=cfg.num_layers,
          slots=slots, max_len=max_len, kv_block=kv_block,
          requests=len(reqs), prompt_lengths=list(lengths),
          new_tokens=[r.max_new_tokens for r in reqs], tokens=total,
@@ -628,6 +651,7 @@ def main(argv=None):
               f"mode", file=sys.stderr)
         return 1
 
+    from horovod_tpu.models import hybrid
     from horovod_tpu.models import transformer as tr
     from horovod_tpu.utils import compile_cache
     cache_dir = compile_cache.configure()
@@ -654,6 +678,13 @@ def main(argv=None):
         leg_resnet()
     if "serve" in legs:
         leg_serve(serve_cfg)
+        # a recurrent mixer beside grouped-query attention, at widths both
+        # of its decode kernels take (ops/ssm.py, ops/flash_attention.py)
+        leg_serve(hybrid.HybridConfig(
+            vocab_size=4096, num_layers=2, d_model=512, d_ff=1024,
+            num_heads=4, num_kv_heads=2, head_dim=128, ssm_heads=8,
+            ssm_head_dim=64, ssm_state=128, ssm_groups=2,
+            attention_impl="flash"), kv_block=128, name="hybrid_small")
     if "four_chips" in legs:
         if jax.device_count() >= 4:
             leg_four_chips(train_cfg, 16, 1024, first_loss)

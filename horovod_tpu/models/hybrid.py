@@ -360,14 +360,11 @@ def decode(cfg, params, tokens, positions, state, mask=None):
                                                   p["conv_bias"], window))
             xs, bs, cs = _mixer_split(cfg, act[:, 0])
             dt = jax.nn.softplus(dt[:, 0] + p["dt_bias"])
-            was = ssm[i]
-            now, y = ssm_ops.state_step(was, xs, dt, -jnp.exp(p["A_log"]),
-                                        bs, cs)
+            ssm, y = ssm_ops.decode_update(
+                ssm, i, xs, dt, -jnp.exp(p["A_log"]), bs, cs, mask)
             slid = jnp.concatenate([window[:, 1:], xbc], axis=1)
             if mask is not None:
-                now = jnp.where(mask[:, None, None, None], now, was)
                 slid = jnp.where(mask[:, None, None], slid, window)
-            ssm = ssm.at[i].set(now)
             conv = conv.at[i].set(slid)
             mixed = _mixer_out(cfg, p, y[:, None], xs[:, None], z)
         x = _close(cfg, layer, x, mixed, attended)

@@ -836,6 +836,17 @@ def decode_block(s_max):
     return DECODE_BLOCK if s_max % DECODE_BLOCK == 0 else s_max
 
 
+def _on_one_tpu_chip():
+    """A TPU backend and no committed mesh of several devices: where a
+    bare ``pallas_call`` inside a serving program compiles (under a
+    sharded jit Mosaic refuses it) and does not run interpreted."""
+    if jax.default_backend() != "tpu":
+        return False
+    from ..parallel import mesh as mesh_lib  # at trace time, not at import
+    mesh = mesh_lib.global_mesh_if_set()
+    return mesh is None or mesh.size == 1
+
+
 def _decode_kernel_selected(cache_shape, head_sharding):
     """Whether single-query attention over a cache of ``cache_shape``
     ``[layers, batch, s_max, kv_heads, head_dim]`` runs as the Mosaic
@@ -850,11 +861,7 @@ def _decode_kernel_selected(cache_shape, head_sharding):
     kernel saves). Everything else takes the einsum: the ``tp`` engines,
     and the CPU backend, where the engine's tests would otherwise
     interpret a kernel every decode step."""
-    if head_sharding is not None or jax.default_backend() != "tpu":
-        return False
-    from ..parallel import mesh as mesh_lib  # at trace time, not at import
-    mesh = mesh_lib.global_mesh_if_set()
-    if mesh is not None and mesh.size > 1:
+    if head_sharding is not None or not _on_one_tpu_chip():
         return False
     _, _, s_max, hk, d = cache_shape
     return s_max % DECODE_BLOCK == 0 and d % 128 == 0 and \

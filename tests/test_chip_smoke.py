@@ -72,10 +72,18 @@ def test_resnet_leg(capsys):
         hvd.shutdown()
 
 
-def test_serve_leg(tiny, capsys):
+@pytest.mark.parametrize("family", ["transformer", "hybrid"])
+def test_serve_leg(tiny, capsys, family):
+    """Either family through the one leg: a dense decoder, and a model
+    that keeps recurrent and convolution state beside its K/V."""
+    if family == "hybrid":
+        import jax.numpy as jnp
+        from horovod_tpu.models import hybrid
+        tiny = hybrid.HybridConfig.tiny(chunk=8, dtype=jnp.float32)
     chip_smoke.leg_serve(tiny, slots=2, max_len=32, kv_block=8,
-                         lengths=(3, 8, 12), tie_tol=1e-4)
+                         lengths=(3, 8, 12), tie_tol=1e-4, name=family)
     line = _last_json(capsys)
+    assert line["model"] == family
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
     assert line["steps_ahead"] > 0   # three requests on two slots
@@ -305,8 +313,10 @@ def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program,
     state beside its K/V (models/hybrid.py; mixer 8 heads of 64 with a
     state of 128, 4 query and 2 key/value heads of 128; 8 slots x 512,
     depth 2): every kind of state is aliased to its donated input, and
-    the float32 state is never copied whole. ``decode_kernel``: with
-    grouped-query attention as the Mosaic kernel, K and V uncopied."""
+    the float32 state is never copied whole. ``decode_kernel``: as a TPU
+    backend traces it, with grouped-query attention AND the state's
+    update (ops/ssm.py ``decode_update``) as Mosaic kernels, one call of
+    each a layer, K, V and the state handed to them whole and uncopied."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -345,6 +355,7 @@ def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program,
             arr((), jnp.int32))
     compiled = lowered.compile()
     import math
+    import re
     state_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
                       for a in state.values())
     assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
@@ -353,5 +364,10 @@ def test_recurrent_state_is_updated_in_place_beside_the_kv(topo, program,
         compiled, (ssm.dtype, ssm.shape), (k.dtype, k.shape),
         (k.dtype, k.shape[:2] + (k.shape[2] * k.shape[3], k.shape[4])))
     assert not copies, copies
-    assert compiled.as_text().count("tpu_custom_call") == \
-        (cfg.num_layers if program == "decode_kernel" else 0)
+    text = compiled.as_text()
+    for kernel in ("decode_attention", "state_update"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = .*tpu_custom_call",
+                              text)) == \
+            (cfg.num_layers if program == "decode_kernel" else 0)
+    assert text.count("tpu_custom_call") == \
+        (2 * cfg.num_layers if program == "decode_kernel" else 0)

@@ -148,6 +148,86 @@ def test_chunked_scan_carries_a_state_in():
     np.testing.assert_allclose(got_s, want_s, atol=2e-4)
 
 
+MASKS = {"every_row": None, "some_rows": (True, False, True, True, False),
+         "no_row": (False,) * 5}
+
+
+@pytest.fixture
+def update_kernel(monkeypatch):
+    """``decode_update`` takes its Mosaic kernel, interpreted: the
+    predicate sees a TPU backend, the kernels still see the CPU."""
+    monkeypatch.setattr(ssm, "_on_one_tpu_chip", lambda: True)
+
+
+@pytest.mark.parametrize("impl", ["state_step", "kernel"])
+@pytest.mark.parametrize("mask", list(MASKS))
+def test_decode_update_is_the_literal_scan_on_the_rows_of_the_mask(
+        mask, impl, request):
+    """Two tokens through ``decode_update`` on layer 1 of a stacked state
+    (8 heads over 2 groups of B and C) against ``literal_scan`` over the
+    same two positions: the new state and ``y`` of a row of the mask to
+    float32 rounding, the state of a row outside it, and of every other
+    layer, BIT-IDENTICAL. Both implementations: the ``jax.numpy`` form
+    the CPU runs, and the kernel, interpreted."""
+    layers, bt, h, p, g, n = 3, 5, 8, 8, 2, 128
+    if impl == "kernel":
+        request.getfixturevalue("update_kernel")
+    assert ssm._update_kernel_selected(
+        (layers, bt, h, p, n), jnp.float32) == (impl == "kernel")
+    k = jax.random.split(jax.random.PRNGKey(3), 6)
+    start = jax.random.normal(k[0], (layers, bt, h, p, n))
+    x = jax.random.normal(k[1], (bt, 2, h, p)).astype(jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(k[2], (bt, 2, h)) - 1.0)
+    a = -jnp.exp(jax.random.normal(k[3], (h,)))
+    b = jax.random.normal(k[4], (bt, 2, g, n)).astype(jnp.bfloat16)
+    c = jax.random.normal(k[5], (bt, 2, g, n)).astype(jnp.bfloat16)
+    rows = np.ones(bt, bool) if MASKS[mask] is None else \
+        np.asarray(MASKS[mask])
+    step = jax.jit(ssm.decode_update, static_argnums=1)
+    state, ys = start, []
+    for t in range(2):
+        state, y = step(state, 1, x[:, t], dt[:, t], a, b[:, t], c[:, t],
+                        None if MASKS[mask] is None else jnp.asarray(rows))
+        ys.append(y)
+    want_y, want_s = ssm.literal_scan(x, dt, a, b, c, state=start[1])
+    state, start = np.asarray(state), np.asarray(start)
+    np.testing.assert_array_equal(state[[0, 2]], start[[0, 2]])
+    np.testing.assert_array_equal(state[1][~rows], start[1][~rows])
+    np.testing.assert_allclose(state[1][rows], np.asarray(want_s)[rows],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.stack(ys, 1)[rows],
+                               np.asarray(want_y)[rows], rtol=1e-5,
+                               atol=1e-4)
+    assert ys[0].shape == (bt, h, p) and ys[0].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("impl", ["state_step", "kernel"])
+@pytest.mark.parametrize("mask", ["some_rows", "no_row"])
+def test_decode_leaves_the_state_of_a_row_outside_the_mask_as_it_was(
+        mask, impl, request):
+    """``hybrid.decode``'s promise, through the whole program: ``ssm`` and
+    ``conv`` of a row outside the mask are BIT-IDENTICAL, those of a row
+    inside it move; with either update (a state of 128 lanes, so that the
+    kernel takes it)."""
+    hcfg = hybrid.HybridConfig.tiny(ssm_state=128)
+    if impl == "kernel":
+        request.getfixturevalue("update_kernel")
+    params = hybrid.init_params(hcfg, jax.random.PRNGKey(0))
+    slots, max_len = 5, 32
+    k = jax.random.split(jax.random.PRNGKey(1), 4)
+    state = {kind: jax.random.normal(k[i], a.shape).astype(a.dtype)
+             for i, (kind, a) in enumerate(
+                 serve_decode.state_shapes(hcfg, slots, max_len).items())}
+    rows = np.asarray(MASKS[mask])
+    _, after = jax.jit(hybrid.decode, static_argnums=0)(
+        hcfg, params, jnp.arange(slots, dtype=jnp.int32),
+        jnp.full((slots,), 7, jnp.int32), state, jnp.asarray(rows))
+    for kind in ("ssm", "conv"):
+        was, now = np.asarray(state[kind]), np.asarray(after[kind])
+        np.testing.assert_array_equal(now[:, ~rows], was[:, ~rows])
+        assert all((now[:, r] != was[:, r]).any() for r in np.flatnonzero(rows))
+
+
 @pytest.mark.parametrize("prompt_len", [1, 127, 128, 129, 300])
 def test_prefill_then_decode_through_the_cache_is_the_reference(prompt_len):
     """Prefill, then 40 decoded tokens through the cache, against the
