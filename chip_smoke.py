@@ -313,13 +313,14 @@ def _served_model(cfg):
     """(params, rows) of a served configuration of either family: seeded
     parameters, and the plain reference ``rows(params, seq [max_len], at
     [k]) -> float32 logits [k, vocab]`` of a full-attention forward over
-    ``seq`` at the positions ``at`` — TransformerLM.apply, or for a
+    ``seq`` at the positions ``at`` — TransformerLM.apply, for a
     model with a recurrent mixer its prefill (the chunked scan, no cache),
-    once for each position."""
+    once for each position, and for a looped stack its plain forward over
+    every pass."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.models import hybrid
+    from horovod_tpu.models import hybrid, looped
     from horovod_tpu.models import transformer as tr
 
     ref_cfg = dataclasses.replace(cfg, attention_impl="full")
@@ -327,6 +328,10 @@ def _served_model(cfg):
         params = hybrid.init_params(cfg, jax.random.PRNGKey(0))
         rows = jax.jit(lambda p, seq, at: jax.vmap(
             lambda i: hybrid.prefill(ref_cfg, p, seq[None], i)[0][0])(at))
+    elif isinstance(cfg, looped.LoopedConfig):
+        params = looped.init_params(cfg, jax.random.PRNGKey(0))
+        rows = jax.jit(lambda p, seq, at: looped.forward(
+            ref_cfg, p, seq[None])[0][0, at].astype(jnp.float32))
     else:
         _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
         ref_model = tr.TransformerLM(ref_cfg)
@@ -343,8 +348,8 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     behind the default admission queue — then a teacher-forced check:
     each served token must be the plain forward's argmax given the tokens
     before it (so a plain greedy decode yields the same sequence) or tie
-    with it within ``tie_tol``. ``cfg`` is a TransformerConfig or a
-    HybridConfig (``_served_model``)."""
+    with it within ``tie_tol``. ``cfg`` is a TransformerConfig, a
+    HybridConfig or a LoopedConfig (``_served_model``)."""
     import jax.numpy as jnp
 
     from horovod_tpu.serving import engine as engine_mod
@@ -651,7 +656,7 @@ def main(argv=None):
               f"mode", file=sys.stderr)
         return 1
 
-    from horovod_tpu.models import hybrid
+    from horovod_tpu.models import hybrid, looped
     from horovod_tpu.models import transformer as tr
     from horovod_tpu.utils import compile_cache
     cache_dir = compile_cache.configure()
@@ -685,6 +690,12 @@ def main(argv=None):
             num_heads=4, num_kv_heads=2, head_dim=128, ssm_heads=8,
             ssm_head_dim=64, ssm_state=128, ssm_groups=2,
             attention_impl="flash"), kv_block=128, name="hybrid_small")
+        # a layer stack run four times over its weights, K/V per (pass,
+        # layer) plane, at widths the decode kernel takes
+        leg_serve(looped.LoopedConfig(
+            vocab_size=4096, num_layers=2, num_heads=4, d_model=512,
+            d_ff=1024, passes=4, rope_theta=1e6, max_seq_len=1024,
+            attention_impl="flash"), kv_block=128, name="looped_small")
     if "four_chips" in legs:
         if jax.device_count() >= 4:
             leg_four_chips(train_cfg, 16, 1024, first_loss)

@@ -18,6 +18,16 @@ A model of another kind (models/hybrid.py: a recurrent mixer beside
 grouped-query attention) brings its own two forwards; ``state_shapes``,
 ``prefill`` and ``decode`` at the end of this module are what the engine
 and the cache call, and they find the model by its configuration's type.
+A model whose layer stack runs several times over one set of weights
+(models/looped.py) is served by THIS module: ``_stack`` is the dense block
+of ``prefill_forward`` / ``decode_step`` with a norm closing each branch,
+run ``passes`` times with the same ``_qkv``, ``_mlp``, ``_rmsnorm``,
+``_logits``, ``_rope`` and kernels, K/V kept per (pass, layer) PLANE of
+the cache, ``passes x layers`` of them. The dense model's two forwards
+are kept as they were, line for line: their Python call path is part of
+what the chip's compiler is handed (a kernel's body carries its call
+site), and PR 37 measured the dense serving cell's set-up 8 s longer
+when they ran through ``_stack`` (PERF.md §6).
 
 Attention: prefill uses the model's own dispatch (flash kernel on TPU,
 exact full attention on CPU); decode uses ops/flash_attention.py's
@@ -31,7 +41,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid
+from ..models import hybrid, looped
 from ..models.transformer import _dispatch_attention, _rope
 from ..ops.flash_attention import decode_attention
 from ..parallel import mesh as mesh_lib
@@ -70,6 +80,24 @@ def _check_dense(cfg):
             "MoE expert dispatch has no cached decode path yet")
 
 
+def _check_served(cfg):
+    if _is_looped(cfg):
+        looped.check_served(cfg)
+    else:
+        _check_dense(cfg)
+
+
+def _is_looped(cfg):
+    return isinstance(cfg, looped.LoopedConfig)
+
+
+def passes(cfg):
+    """Times a decoding row runs the layer stack for one token: the
+    stack's passes over its one set of weights (models/looped.py), 1 for
+    every other model. K/V planes are ``passes x layers``."""
+    return cfg.passes if _is_looped(cfg) else 1
+
+
 def _qkv(cfg, layer, y, positions):
     head_dim = cfg.d_model // cfg.num_heads
     qkv = _dense(y, layer["attn"]["qkv"]["kernel"], cfg.dtype)
@@ -78,7 +106,9 @@ def _qkv(cfg, layer, y, positions):
     def heads(t):
         return t.reshape(t.shape[:-1] + (cfg.num_heads, head_dim))
     q, k, v = map(heads, (q, k, v))
-    return _rope(q, positions), _rope(k, positions), v
+    # the dense model's rotary base is ``_rope``'s own default
+    rope = {"base": cfg.rope_theta} if _is_looped(cfg) else {}
+    return _rope(q, positions, **rope), _rope(k, positions, **rope), v
 
 
 def _mlp(cfg, layer, y):
@@ -86,6 +116,76 @@ def _mlp(cfg, layer, y):
     up = _dense(y, layer["mlp"]["up"]["kernel"], cfg.dtype)
     return _dense(nn.silu(gate) * up, layer["mlp"]["down"]["kernel"],
                   cfg.dtype)
+
+
+def _stack(cfg, params, x, positions, attend, past):
+    """The layer stack of a looped model (models/looped.py) over ``x``
+    [b, s, d]: the dense block of ``prefill_forward`` / ``decode_step``
+    below with a norm closing each branch, ``cfg.passes`` times over its
+    one set of weights, the final norm closing EVERY pass (it feeds the
+    next one). The passes are ONE ``lax.scan`` whose body is the layers,
+    so a program holds one copy of them whatever ``passes`` is (PERF.md
+    §6, PR 37: unrolled, the cell's nine programs took 170 s to compile
+    and 91 s to load back).
+    ``attend(past, plane, q, k, v)`` -> (past, [b, s, heads, head_dim],
+    kept) is how this forward sees the past: pass t of layer i reads and
+    extends K/V plane ``t * layers + i`` (a traced index) and no other.
+    ``past`` is carried from plane to plane. Returns (each pass's
+    normalised hidden state [passes, b, s, d], the last of which the head
+    reads; past; every plane's ``kept``, stacked ``[passes x layers,
+    ...]`` in plane order)."""
+    sandwich = cfg.sandwich_norm
+
+    def one_pass(carry, t):
+        x, past = carry
+        kept = []
+        for i in range(cfg.num_layers):
+            layer = params[f"layer_{i}"]
+            y = _rmsnorm(x, layer["ln_attn"]["scale"], cfg.dtype)
+            q, k, v = _qkv(cfg, layer, y, positions)
+            past, attn, keep = attend(past, t * cfg.num_layers + i, q, k, v)
+            kept.append(keep)
+            attn = _dense(attn.reshape(x.shape),
+                          layer["attn"]["out"]["kernel"], cfg.dtype)
+            if sandwich:
+                attn = _rmsnorm(attn, layer["ln_attn_out"]["scale"],
+                                cfg.dtype)
+            x = x + attn
+            y = _rmsnorm(x, layer["ln_mlp"]["scale"], cfg.dtype)
+            y = _mlp(cfg, layer, y)
+            if sandwich:
+                y = _rmsnorm(y, layer["ln_mlp_out"]["scale"], cfg.dtype)
+            x = x + y
+        x = _rmsnorm(x, params["ln_f"]["scale"], cfg.dtype)
+        return (x, past), (x, kept)
+    # names the passes' operations in the serving programs (HLO metadata)
+    with jax.named_scope("hvd.loop.passes"):
+        if cfg.passes == 1:  # nothing to roll: the dense model's program
+            (_, past), out = one_pass((x, past), 0)
+            hidden, kept = jax.tree_util.tree_map(lambda a: a[None], out)
+        else:
+            (_, past), (hidden, kept) = jax.lax.scan(
+                one_pass, (x, past), jnp.arange(cfg.passes))
+    # a layer's [passes, ...] each -> [passes x layers, ...], plane t*L+i
+    kept = jax.tree_util.tree_map(
+        lambda *by_layer: jnp.stack(by_layer, axis=1).reshape(
+            (cfg.planes,) + by_layer[0].shape[1:]), *kept)
+    return hidden, past, kept
+
+
+def hidden_states(cfg, params, tokens):
+    """A looped model's full causal forward over ``tokens`` [b, s] up to
+    the head: (each pass's normalised hidden state [passes, b, s, d];
+    k [planes, b, s, h, d]; v like k), the rotated K/V of every (pass,
+    layer) plane."""
+    _check_served(cfg)
+    positions = jnp.arange(tokens.shape[1])[None, :]
+
+    def attend(past, plane, q, k, v):
+        return past, _dispatch_attention(cfg, q, k, v, None), (k, v)
+    hidden, _, (ks, vs) = _stack(cfg, params, _embed(cfg, params, tokens),
+                                 positions, attend, None)
+    return hidden, ks, vs
 
 
 def prefill_forward(cfg, params, tokens):
@@ -163,6 +263,33 @@ def decode_step(cfg, params, tokens, positions, kv_k, kv_v, mask=None):
     return _logits(cfg, params, x)[:, 0], kv_k, kv_v
 
 
+def looped_decode_step(cfg, params, tokens, positions, kv_k, kv_v, mask=None):
+    """``decode_step`` for a stack that runs ``cfg.passes`` times
+    (models/looped.py): the same contract over a cache of ``passes x
+    layers`` planes ``[planes, b, s_max, h, d]``. Pass t of layer i writes
+    the token's K/V into plane ``t * layers + i`` at ``positions`` and
+    attends over that plane and no other; a row outside ``mask`` parks
+    its write in every plane and reads nothing."""
+    _check_served(cfg)
+    rows = jnp.arange(tokens.shape[0])
+    lengths = positions + 1
+    if mask is not None:
+        lengths = jnp.where(mask, lengths, 0)
+    heads = mesh_lib.decode_head_sharding(cfg.num_heads)
+
+    def attend(past, plane, q, k, v):
+        # write, then read: the token attends to itself, in its own plane
+        kv_k, kv_v = past
+        kv_k = kv_k.at[plane, rows, positions].set(k[:, 0])
+        kv_v = kv_v.at[plane, rows, positions].set(v[:, 0])
+        return (kv_k, kv_v), decode_attention(
+            q, kv_k, kv_v, lengths, head_sharding=heads, layer=plane), None
+    hidden, (kv_k, kv_v), _ = _stack(
+        cfg, params, _embed(cfg, params, tokens[:, None]),
+        positions[:, None], attend, (kv_k, kv_v))
+    return _logits(cfg, params, hidden[-1])[:, 0], kv_k, kv_v
+
+
 # -- what the engine and the cache call, for any model ------------------------
 
 def _is_hybrid(cfg):
@@ -171,17 +298,21 @@ def _is_hybrid(cfg):
 
 def state_shapes(cfg, num_slots, max_len):
     """{kind: ShapeDtypeStruct} of the per-slot state the model keeps,
-    every kind ``[layers, slots, ...]``: what ``KVCache`` allocates."""
+    every kind ``[planes, slots, ...]``: what ``KVCache`` allocates. A
+    plane is a layer, and for a stack that runs several times a (pass,
+    layer): ``passes(cfg) x layers`` planes of K/V over ``layers``
+    weights."""
     if _is_hybrid(cfg):
         return hybrid.state_shapes(cfg, num_slots, max_len)
+    _check_served(cfg)
     kv = jax.ShapeDtypeStruct(
-        (cfg.num_layers, num_slots, max_len, cfg.num_heads,
+        (passes(cfg) * cfg.num_layers, num_slots, max_len, cfg.num_heads,
          cfg.d_model // cfg.num_heads), cfg.dtype)
     return {"k": kv, "v": kv}
 
 
 def prefill(cfg, params, tokens, last_index):
-    """(logits [1, vocab] at ``last_index``, {kind: [layers, 1, ...]})
+    """(logits [1, vocab] at ``last_index``, {kind: [planes, 1, ...]})
     of ONE right-padded prompt ``tokens`` [1, s_pad]: the first token's
     logits and the state the prefill leaves for its row, every kind, AS IT
     STANDS AFTER THE LAST REAL TOKEN (``last_index``; the prompt is
@@ -189,6 +320,12 @@ def prefill(cfg, params, tokens, last_index):
     mask hides the pad; a recurrent kind must not have seen the pad."""
     if _is_hybrid(cfg):
         return hybrid.prefill(cfg, params, tokens, last_index)
+    if _is_looped(cfg):
+        # every pass over the padded prompt; the head on the one row
+        hidden, ks, vs = hidden_states(cfg, params, tokens)
+        row = jax.lax.dynamic_index_in_dim(hidden[-1], last_index, axis=1,
+                                           keepdims=False)
+        return _logits(cfg, params, row), {"k": ks, "v": vs}
     logits, k, v = prefill_forward(cfg, params, tokens)
     return logits[0, last_index][None], {"k": k, "v": v}
 
@@ -201,6 +338,10 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     attends to nothing."""
     if _is_hybrid(cfg):
         return hybrid.decode(cfg, params, tokens, positions, state, mask)
+    if _is_looped(cfg):
+        logits, k, v = looped_decode_step(cfg, params, tokens, positions,
+                                          state["k"], state["v"], mask)
+        return logits, {"k": k, "v": v}
     logits, k, v = decode_step(cfg, params, tokens, positions, state["k"],
                                state["v"], mask)
     return logits, {"k": k, "v": v}

@@ -76,7 +76,7 @@ from ..utils import memory as hvd_memory
 from ..utils import metrics as hvd_metrics
 from ..utils import tracing as hvd_tracing
 from . import tracing as serve_tracing
-from .decode import decode, prefill
+from .decode import decode, passes, prefill
 from .kv_cache import KVCache
 from .queue import AdmissionQueue, RequestResult
 from .sampling import sample_tokens
@@ -287,7 +287,8 @@ class ServeEngine:
             "hvd_serve_state_bytes",
             "Bytes of per-slot serving state resident on one chip, by "
             "kind (k, v; ssm and conv where the model has a recurrent "
-            "mixer).", labels=("kind",))
+            "mixer), over all its planes (one a layer, times the passes of "
+            "a looped stack).", labels=("kind",))
         for kind, nbytes in self.kv.bytes_by_kind().items():
             state_bytes.labels(kind=kind).set(nbytes)
         # bytes of recurrent state one row holds over all layers: what a
@@ -295,7 +296,7 @@ class ServeEngine:
         self._row_state_bytes = self.kv.row_state_bytes()
         # the blocking under which decode attention reads a row's K/V
         # (ops/flash_attention.py), and the bytes one block of one row
-        # holds over all layers: the step record's ``kv_bytes``
+        # holds over all planes: the step record's ``kv_bytes``
         self._kv_block = decode_block(self.kv.max_len)
         self._kv_block_bytes = self.kv.kv_block_bytes(self._kv_block)
         # cache-writing programs whose first call on this engine has
@@ -799,6 +800,8 @@ class ServeEngine:
                     self._note_in_place("decode", went_in)
         rec.count("active", len(launched))
         rec.count("cohorts", len(gens))
+        if launched:  # stack passes the launched program runs each row
+            rec.count("passes", passes(self.cfg))
         # the pass before this one first, while the chip runs this one
         self._read_unread(tick)
         if launched:

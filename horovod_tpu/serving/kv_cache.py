@@ -8,10 +8,13 @@ Two halves, deliberately separated:
     No jax import, so the alloc/free/leak invariants test in
     microseconds (tests/test_serving.py).
   * KVCache — the device arrays, a named set the MODEL declares
-    (serving/decode.state_shapes), every kind [layers, slots, ...]:
-    dense, preallocated [layers, slots, max_len, heads, head_dim] K and
+    (serving/decode.state_shapes), every kind [planes, slots, ...]:
+    dense, preallocated [planes, slots, max_len, heads, head_dim] K and
     V, and for a model with a recurrent mixer its state and convolution
-    window beside them — one ledger, one slot index, one lifetime. Dense rather
+    window beside them — one ledger, one slot index, one lifetime. A
+    PLANE is one layer's state; a stack that runs several times over its
+    weights (models/looped.py) keeps one per (pass, layer), pass-major,
+    so there are more planes than weight layers. Dense rather
     than paged-indirect because the engine decodes every slot every
     step at a static shape (docs/serving.md): a gather through a block
     table buys nothing at this batch geometry, while the dense layout
@@ -149,10 +152,11 @@ class KVCache:
     """The per-slot device arrays of every kind of state, plus their
     ledger.
 
-    ``arrays`` is ``{kind: array}``, every kind ``[layers, slots, ...]``,
+    ``arrays`` is ``{kind: array}``, every kind ``[planes, slots, ...]``,
     as the model declares them (``serving/decode.state_shapes``): ``k``
-    and ``v`` ``[layers, slots, max_len, kv_heads, head_dim]`` for every
-    model (``.k``/``.v`` read and rebind them), and for a model with a
+    and ``v`` ``[planes, slots, max_len, kv_heads, head_dim]`` for every
+    model (``.k``/``.v`` read and rebind them; ``planes`` is the layers,
+    times the passes of a looped stack), and for a model with a
     recurrent mixer ``ssm`` (the recurrent state) and ``conv`` (the
     convolution's window). A slot owns its row of EVERY kind for one
     lifetime: a prefill writes them all whole (K/V up to the padded
@@ -230,9 +234,16 @@ class KVCache:
     def v(self, value):
         self.arrays["v"] = value
 
+    @property
+    def planes(self):
+        """Planes of K/V: one a layer, or one a (pass, layer) of a stack
+        that runs several times."""
+        return self.arrays["k"].shape[0]
+
     def bytes_by_kind(self):
-        """{kind: bytes resident on ONE chip} (the shard shape under the
-        array's committed sharding; the full array when unsharded)."""
+        """{kind: bytes resident on ONE chip} over all its planes (the
+        shard shape under the array's committed sharding; the full array
+        when unsharded)."""
         import numpy as np
         out = {}
         for kind, arr in self.arrays.items():
@@ -251,7 +262,7 @@ class KVCache:
 
     def row_state_bytes(self):
         """Bytes of recurrent state (every kind but K/V) ONE slot holds
-        over all layers: what a decode step reads and writes again for
+        over all planes: what a decode step reads and writes again for
         each row it advances."""
         by_kind = self.bytes_by_kind()
         return sum(by_kind[kind] for kind in self.recurrent) \
@@ -259,8 +270,9 @@ class KVCache:
 
     def kv_block_bytes(self, block):
         """Bytes of K and V that ``block`` positions of ONE slot hold over
-        all layers (on one chip): what a decode pass streams for each
-        block of a row it reads."""
+        all planes (on one chip): what a decode step streams for each
+        block of a row it reads (every pass of a looped stack reads its
+        own planes)."""
         by_kind = self.bytes_by_kind()
         return (by_kind["k"] + by_kind["v"]) * block \
             // (self.num_slots * self.max_len)

@@ -350,7 +350,10 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # kv_bytes (not in STEP_COUNTS: only a step that decoded has it): bytes of
 # K and V the step's decode passes had to stream, each decoding row's in
 # whole blocks of ops/flash_attention.decode_block positions up to its
-# length, over all layers
+# length, over all planes (layers x passes)
+# passes (not in STEP_COUNTS, likewise): stack passes the step's decode
+# program runs each row, from the configuration: the passes of a looped
+# stack (models/looped.py), 1 for every other model
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
                "state_rows", "state_bytes", "ahead")
 _STEP_ANNOTATION = "hvd.serve.step"

@@ -72,14 +72,18 @@ def test_resnet_leg(capsys):
         hvd.shutdown()
 
 
-@pytest.mark.parametrize("family", ["transformer", "hybrid"])
+@pytest.mark.parametrize("family", ["transformer", "hybrid", "looped"])
 def test_serve_leg(tiny, capsys, family):
-    """Either family through the one leg: a dense decoder, and a model
-    that keeps recurrent and convolution state beside its K/V."""
+    """Every family through the one leg: a dense decoder, a model that
+    keeps recurrent and convolution state beside its K/V, and a stack
+    that runs several times with K/V per (pass, layer) plane."""
+    import jax.numpy as jnp
     if family == "hybrid":
-        import jax.numpy as jnp
         from horovod_tpu.models import hybrid
         tiny = hybrid.HybridConfig.tiny(chunk=8, dtype=jnp.float32)
+    elif family == "looped":
+        from horovod_tpu.models import looped
+        tiny = looped.LoopedConfig.tiny(dtype=jnp.float32)
     chip_smoke.leg_serve(tiny, slots=2, max_len=32, kv_block=8,
                          lengths=(3, 8, 12), tie_tol=1e-4, name=family)
     line = _last_json(capsys)
@@ -286,6 +290,72 @@ def test_the_serving_programs_update_the_cache_in_place(topo, program,
     assert not copies, copies
     assert compiled.as_text().count("tpu_custom_call") == \
         (layers if program == "decode_kernel" else 0)
+
+
+@pytest.mark.parametrize("program", ["decode_kernel", "prefill"])
+def test_a_looped_stack_keeps_its_planes_in_place(topo, program, request):
+    """The same word for a stack that runs several times (models/
+    looped.py) at Ouro-2.6B widths (16 heads of 128, SwiGLU 5632, 32 slots
+    x 1536; two layers x four passes so it compiles in seconds): the
+    decode program aliases all EIGHT planes of K and V to its donated
+    input, copies no whole array, and holds the decode-attention kernel
+    once a WEIGHT layer, inside the one loop over the passes and under
+    its scope; the prefill returns K/V for every plane and holds the flash
+    kernel once a weight layer too."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import looped
+    from horovod_tpu.serving import decode as serve_decode
+    from horovod_tpu.serving import engine as engine_mod
+
+    slots, max_len = 32, 1536
+    cfg = looped.LoopedConfig(
+        vocab_size=49152, num_layers=2, num_heads=16, d_model=2048,
+        d_ff=5632, passes=4, rope_theta=1e6, max_seq_len=65536,
+        dtype=jnp.bfloat16, attention_impl="flash")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    state = {k: arr(a.shape, a.dtype) for k, a in
+             serve_decode.state_shapes(cfg, slots, max_len).items()}
+    kv = state["k"]
+    assert kv.shape == (8, slots, max_len, 16, 128)
+    params = jax.tree_util.tree_map(
+        lambda a: arr(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: looped.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    # as a TPU backend traces both programs: the kernels compiled
+    request.getfixturevalue("decode_kernel")
+    if program == "prefill":
+        engine_mod._prefill_jit.clear_cache()
+        try:
+            text = engine_mod._prefill_jit.lower(
+                cfg, params, arr((1, 256), jnp.int32), arr((), jnp.int32),
+                arr((), jnp.float32), arr((2,), jnp.uint32)
+            ).compile().as_text()
+        finally:
+            engine_mod._prefill_jit.clear_cache()
+        assert text.count("tpu_custom_call") == cfg.num_layers
+        assert "bf16[8,1,256,16,128]" in text
+        return
+    compiled = engine_mod._decode_jit.lower(
+        cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+        state, arr((slots,), jnp.float32), arr((slots,), jnp.bool_),
+        arr((2,), jnp.uint32), arr((), jnp.int32)).compile()
+    cache_bytes = 2 * 8 * slots * max_len * 16 * 128 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    copies = _whole_copies(
+        compiled, (kv.dtype, kv.shape),
+        (kv.dtype, (8, slots, max_len * 16, 128)))
+    assert not copies, copies
+    text = compiled.as_text()
+    calls = re.findall(r"%decode_attention[.\d]* = .*tpu_custom_call.*", text)
+    assert len(calls) == cfg.num_layers == text.count("tpu_custom_call")
+    assert all("hvd.loop.passes/while/body" in c for c in calls), calls[0]
 
 
 def test_init_names_the_process_that_holds_the_chip(monkeypatch):

@@ -395,9 +395,11 @@ class TestStepTrace:
         engine.submit(Request("b", (2, 7, 1, 8, 2), max_new_tokens=3))
         assert engine.step() == []
         (rec,) = stepping.steps()
-        # kv_bytes: on a step that decoded, and on no other (below)
+        # kv_bytes, passes: on a step that decoded, and on no other (below)
         assert set(rec) == {"seq", "start_us", "end_us", "phases",
-                            "kv_bytes", *serve_tracing.STEP_COUNTS}
+                            "kv_bytes", "passes",
+                            *serve_tracing.STEP_COUNTS}
+        assert rec["passes"] == 1   # a stack that runs once
         assert rec["seq"] == 1 and _tiles(rec)
         one = ["admit", "prefill", "prefill_readback", "bookkeeping"]
         # both slots busy and neither row on its last token: the step

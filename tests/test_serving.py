@@ -705,7 +705,7 @@ class TestTheStepDoesNotWaitForTheHost:
         hvd_tracing.reset()
 
     @pytest.mark.parametrize("temperature", [0.0, 0.8])
-    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    @pytest.mark.parametrize("model", ["dense", "hybrid", "looped"])
     def test_running_ahead_gives_the_synchronous_orders_tokens(
             self, reg, model, temperature):
         """Two slots, three requests (the third joins when the first
@@ -733,7 +733,7 @@ class TestTheStepDoesNotWaitForTheHost:
             greedy, _ = _drive(_engine(cfg, params, seed=3), REQUESTS)
             assert _tokens(greedy) != _tokens(ahead)
 
-    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    @pytest.mark.parametrize("model", ["dense", "hybrid", "looped"])
     def test_greedy_tokens_are_those_of_an_engine_with_a_slot_kept_free(
             self, reg, model):
         """...and what an engine that never may run ahead gives (three
@@ -1153,3 +1153,261 @@ def test_grouped_query_decode_attention_is_the_equal_heads_path(heads,
     with pytest.raises(ValueError, match="query heads"):
         decode_attention(jnp.zeros((1, 1, 3, d)), jnp.zeros((1, s, 2, d)),
                          jnp.zeros((1, s, 2, d)), jnp.asarray([1]))
+
+
+# ---------------------------------------------------------------------------
+# A stack that runs several times over one set of weights (models/looped.py):
+# K/V per (pass, layer) PLANE of the one cache, passes x layers of them
+# ---------------------------------------------------------------------------
+
+def _tiny_looped(**kw):
+    from horovod_tpu.models import looped
+    kw.setdefault("dtype", jnp.float32)
+    cfg = looped.LoopedConfig.tiny(max_seq_len=64, rope_theta=1e6, **kw)
+    return cfg, looped.init_params(cfg, jax.random.PRNGKey(0))
+
+
+MODELS["looped"] = _tiny_looped
+
+
+class TestALoopedStackInTheCache:
+    @pytest.fixture(autouse=True)
+    def tracer(self):
+        from horovod_tpu.utils import tracing as hvd_tracing
+        hvd_tracing.reset(enabled=True, rank=0)
+        yield
+        hvd_tracing.reset()
+
+    def test_the_cache_holds_a_plane_a_pass_and_layer(self, reg):
+        cfg, params = _tiny_looped()
+        engine = _engine(cfg, params, num_slots=3, max_len=32)
+        kv = engine.kv
+        assert (cfg.passes, cfg.num_layers, cfg.planes, kv.planes) == \
+            (3, 2, 6, 6)
+        assert set(kv.arrays) == {"k", "v"} and kv.recurrent == ()
+        assert kv.k.shape == kv.v.shape == (6, 3, 32, 4, 16)
+        position = 6 * 2 * 4 * 16 * 4    # one token over all planes, f32
+        assert kv.kv_block_bytes(8) == 8 * position
+        assert kv.per_chip_bytes() == 3 * 32 * position
+        snap = reg.snapshot()
+        assert _value(snap, "hvd_serve_state_bytes", kind="k") == \
+            3 * 32 * position // 2
+        # every other model: a plane a layer, one pass
+        from horovod_tpu.serving.decode import passes
+        assert passes(cfg) == 3
+        for name in ("dense", "hybrid"):
+            cfg2, params2 = MODELS[name]()
+            plain = _engine(cfg2, params2)
+            assert plain.kv.planes == cfg2.num_layers
+            assert passes(cfg2) == 1
+
+    def test_temp0_matches_no_cache_greedy(self, reg):
+        """The engine (prefill, slot write, decode through the planes)
+        against a full forward over the growing sequence every token."""
+        from horovod_tpu.models import looped
+        cfg, params = _tiny_looped()
+        prompts = {"a": _prompt(5, 1), "b": _prompt(11, 2),
+                   "c": _prompt(3, 3)}
+        got = _serve(_engine(cfg, params),
+                     [(rid, p, 7) for rid, p in prompts.items()])
+        # one program for every length: a causal forward over a padded
+        # sequence reads the same logits at the last real position
+        forward = jax.jit(lambda toks: looped.forward(cfg, params, toks)[0])
+        for rid, prompt in prompts.items():
+            toks, want = list(prompt), []
+            for _ in range(7):
+                padded = np.zeros((1, 24), np.int32)
+                padded[0, :len(toks)] = toks
+                logits = forward(jnp.asarray(padded))
+                want.append(int(jnp.argmax(logits[0, len(toks) - 1])))
+                toks.append(want[-1])
+            assert got[rid] == want
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                           (jnp.bfloat16, 0.2)])
+    def test_prefill_then_decode_through_the_cache_is_the_references_forward(
+            self, dtype, tol):
+        """Logits, not tokens: one padded prefill written into a slot by
+        the engine's own ``_write_slot``, then a decode step a token with
+        the other rows masked out, against ONE float32 forward of the
+        plain reference (benchmarks/reference/ouro.py) over the whole
+        sequence. Tolerances as tests/test_looped_model.py states them."""
+        import test_looped_model as lm
+        from horovod_tpu.serving import decode as serve_decode
+        from horovod_tpu.serving import engine as engine_mod
+        cfg = lm.tiny_config()
+        w = lm.drawn(cfg, seed=11)
+        lcfg, params = lm.model(cfg, w, dtype)
+        tokens, prompt_len, slots, slot, max_len = lm.sequence(30), 13, 3, 1, 48
+        first = np.zeros((1, 16), np.int32)
+        first[0, :prompt_len] = tokens[:prompt_len]
+        row, state_row = jax.jit(serve_decode.prefill, static_argnums=0)(
+            lcfg, params, jnp.asarray(first), jnp.int32(prompt_len - 1))
+        assert state_row["k"].shape == (8, 1, 16, 4, 16)
+        state = {k: jnp.zeros(a.shape, a.dtype) for k, a in
+                 serve_decode.state_shapes(lcfg, slots, max_len).items()}
+        state, _ = engine_mod._write_slot(
+            state, state_row, jnp.int32(slot), jnp.zeros(slots, jnp.int32),
+            jnp.int32(tokens[prompt_len]))
+        step = jax.jit(serve_decode.decode, static_argnums=0)
+        got = [np.asarray(row[0])]
+        mask = np.zeros(slots, bool)
+        mask[slot] = True
+        for j in range(prompt_len, len(tokens) - 1):
+            toks = np.zeros(slots, np.int32)
+            pos = np.full(slots, max_len - 1, np.int32)
+            toks[slot], pos[slot] = tokens[j], j
+            logits, state = step(lcfg, params, jnp.asarray(toks),
+                                 jnp.asarray(pos), state, jnp.asarray(mask))
+            got.append(np.asarray(logits[slot]))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(lm.ref.logits_at(
+                w, jnp.asarray(tokens[:-1]),
+                jnp.arange(prompt_len - 1, len(tokens) - 1), cfg, lm.LAYERS))
+        np.testing.assert_allclose(np.stack(got).astype(np.float32), want,
+                                   atol=tol)
+
+    def test_plane_t_l_plus_i_holds_pass_ts_kv_and_no_other(self, reg):
+        """After a prefill and some decode steps the slot's rows of plane
+        ``t * layers + i`` are the rotated K/V that pass t of layer i
+        computes in a forward over the whole sequence; the planes of two
+        passes of one layer differ; no other slot was written."""
+        from horovod_tpu.serving import decode as serve_decode
+        cfg, params = _tiny_looped()
+        engine = _never_ahead(_engine(cfg, params, num_slots=2, max_len=32))
+        prompt = _prompt(9, 4)
+        engine.submit(Request("r", prompt, max_new_tokens=6))
+        for _ in range(4):
+            engine.step()
+        (slot, st), = engine._active.items()
+        seq = list(prompt) + st.generated[:-1]   # the tokens in the cache
+        n = len(seq)
+        assert n == engine.kv.ledger.length(slot) == 13
+        _, ks, vs = serve_decode.hidden_states(
+            cfg, params, jnp.asarray([seq], jnp.int32))
+        assert len(ks) == cfg.planes
+        k, v = np.asarray(engine.kv.k), np.asarray(engine.kv.v)
+        for plane in range(cfg.planes):
+            np.testing.assert_allclose(k[plane, slot, :n],
+                                       np.asarray(ks[plane][0]), atol=1e-5)
+            np.testing.assert_allclose(v[plane, slot, :n],
+                                       np.asarray(vs[plane][0]), atol=1e-5)
+        layers = cfg.num_layers
+        for i in range(layers):
+            for t in range(1, cfg.passes):
+                assert np.abs(k[t * layers + i, slot, :n]
+                              - k[i, slot, :n]).max() > 0.1
+        other = 1 - slot
+        assert not k[:, other, :31].any() and not v[:, other, :31].any()
+
+    def test_a_row_outside_the_mask_parks_its_write_and_reads_nothing(self):
+        """``decode`` over two live rows with one masked out: the row in
+        the pass gets the logits and cache rows it gets alone; the other's
+        K/V is written only where ``positions`` parks it, in every plane,
+        and its live prefix is untouched."""
+        from horovod_tpu.serving import decode as serve_decode
+        cfg, params = _tiny_looped()
+        rng = np.random.default_rng(0)
+        shape = serve_decode.state_shapes(cfg, 2, 32)["k"].shape
+        state = {kind: jnp.asarray(rng.normal(size=shape), jnp.float32)
+                 for kind in ("k", "v")}
+        toks = jnp.asarray([7, 9], jnp.int32)
+        both = jnp.asarray([5, 8], jnp.int32)
+        parked = jnp.asarray([5, 31], jnp.int32)
+        want, full = serve_decode.decode(cfg, params, toks, both, state)
+        got, masked = serve_decode.decode(cfg, params, toks, parked, state,
+                                          jnp.asarray([True, False]))
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        for kind in ("k", "v"):
+            before, after = np.asarray(state[kind]), np.asarray(masked[kind])
+            np.testing.assert_array_equal(after[:, 0],
+                                          np.asarray(full[kind])[:, 0])
+            np.testing.assert_array_equal(after[:, 1, :31],
+                                          before[:, 1, :31])
+            assert (after[:, 1, 31] != before[:, 1, 31]).all(axis=(1, 2)).all()
+            assert (after[:, 0, 5] != before[:, 0, 5]).all(axis=(1, 2)).all()
+
+    def test_the_interpreted_kernel_and_the_einsum_agree_on_the_planes(
+            self, monkeypatch):
+        """One decode step over a ``[passes x layers, ...]`` cache with
+        decode attention as the Mosaic kernel (interpreted here), plane
+        ``t * layers + i`` handed over as its index: the einsum's logits
+        and cache, to float32 rounding. The passes are one loop, so the
+        program holds one kernel call a WEIGHT layer, its plane traced."""
+        from horovod_tpu.ops import flash_attention as fa
+        from horovod_tpu.serving import decode as serve_decode
+        cfg, params = _tiny_looped()
+        rng = np.random.default_rng(1)
+        shape = serve_decode.state_shapes(cfg, 3, 256)["k"].shape
+        state = {kind: jnp.asarray(rng.normal(size=shape), jnp.float32)
+                 for kind in ("k", "v")}
+        toks = jnp.asarray([7, 9, 11], jnp.int32)
+        pos = jnp.asarray([130, 255, 4], jnp.int32)
+        mask = jnp.asarray([True, False, True])
+        want, want_state = serve_decode.decode(cfg, params, toks, pos,
+                                               state, mask)
+        planes = []
+        kernel = fa._decode_attention_kernel
+        monkeypatch.setattr(fa, "_decode_kernel_selected",
+                            lambda shape, sharding: True)
+        monkeypatch.setattr(
+            fa, "_decode_attention_kernel",
+            lambda q, k, v, lengths, layer, scale:
+            planes.append(layer) or kernel(q, k, v, lengths, layer, scale))
+        got, got_state = serve_decode.decode(cfg, params, toks, pos, state,
+                                             mask)
+        assert len(planes) == cfg.num_layers
+        assert all(isinstance(p, jax.core.Tracer) for p in planes)
+        live = np.asarray(mask)
+        np.testing.assert_allclose(np.asarray(got)[live],
+                                   np.asarray(want)[live], atol=2e-5)
+        for kind in ("k", "v"):
+            np.testing.assert_allclose(np.asarray(got_state[kind])[:, live],
+                                       np.asarray(want_state[kind])[:, live],
+                                       atol=2e-5)
+
+    @pytest.mark.parametrize("entry", ["state_shapes", "engine", "prefill",
+                                       "decode"])
+    def test_an_exit_threshold_under_one_is_refused_by_name(self, entry):
+        from horovod_tpu.serving import decode as serve_decode
+        cfg, params = _tiny_looped(exit_threshold=0.5)
+        ok, _ = _tiny_looped()
+        with pytest.raises(NotImplementedError,
+                           match="exit_threshold=0.5.*different passes"):
+            if entry == "state_shapes":
+                serve_decode.state_shapes(cfg, 2, 32)
+            elif entry == "engine":
+                _engine(cfg, params)
+            elif entry == "prefill":
+                serve_decode.prefill(cfg, params,
+                                     jnp.zeros((1, 8), jnp.int32), 3)
+            else:
+                kv = jnp.zeros(serve_decode.state_shapes(ok, 2, 32)["k"].shape)
+                serve_decode.decode(cfg, params, jnp.zeros(2, jnp.int32),
+                                    jnp.zeros(2, jnp.int32),
+                                    {"k": kv, "v": kv})
+
+    def test_an_engine_over_a_mesh_is_refused_by_name(self):
+        from horovod_tpu.parallel import mesh as mesh_lib
+        cfg, params = _tiny_looped()
+        with pytest.raises(NotImplementedError,
+                           match="LoopedConfig serves on one chip"):
+            _engine(cfg, params, mesh=mesh_lib.build_mesh(tp=2))
+
+    @pytest.mark.parametrize("model,passes", [("dense", 1), ("hybrid", 1),
+                                              ("looped", 3)])
+    def test_the_step_record_counts_the_passes(self, reg, model, passes):
+        """``passes``: stack passes the step's decode program runs each
+        row, on every step that decoded and on no other."""
+        cfg, params = MODELS[model]()
+        engine = _engine(cfg, params)
+        engine.submit(Request("r", _prompt(5, 1), max_new_tokens=4))
+        engine.run_to_completion()
+        engine.step()
+        recs = hvd_tracing_steps()
+        assert [r["passes"] for r in recs if r["active"]] == [passes] * 3
+        assert "passes" not in recs[-1] and recs[-1]["active"] == 0
+        if model == "looped":
+            # K/V of a step: every pass streams its own planes
+            position = cfg.planes * 2 * 4 * 16 * 4
+            assert recs[1]["kv_bytes"] == 48 * position
