@@ -405,6 +405,15 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     _check(steps_ahead != 0 or len(lengths) <= slots,
            "every slot was busy and no step ran ahead: each decode pass "
            "waited for the host to read the one before")
+    # ...nor an admission that keeps the chip waiting: a request that joins
+    # a batch that decodes has its first token read after the step's pass
+    # is launched, and with more requests than slots some have to
+    admissions_ahead = (reg.counter("hvd_serve_admissions_ahead_total").value
+                        if reg.enabled else None)
+    _check(admissions_ahead != 0 or len(lengths) <= slots,
+           "requests joined a decoding batch and no first token was read "
+           "behind the step's decode launch: each admission held the chip "
+           "until the host had read and booked it")
 
     # reference: a full-attention forward over prompt + served tokens
     most = max(r.max_new_tokens for r in reqs)
@@ -437,6 +446,7 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
          worst_logit_deficit=round(worst, 5), tie_tol=tie_tol,
          prefill_compiles=compiled[0], decode_compiles=compiled[1],
          kv_in_place=kv_in_place, steps_ahead=steps_ahead,
+         admissions_ahead=admissions_ahead,
          setup_seconds=round(cold_s - warm_s, 2),
          request_seconds=round(warm_s, 3),
          ttft_seconds_warm=round(float(np.median(

@@ -1,7 +1,8 @@
 """The continuous-batching step loop (docs/serving.md).
 
 One ``step()`` = admit joins, one fused decode over every batch slot,
-retire finishers. The device work is shape-static by construction:
+retire finishers; everything the step runs is launched before anything
+of it is read. The device work is shape-static by construction:
 
   * decode always runs all ``num_slots`` rows — inactive rows compute
     garbage that the host ignores and write garbage into their own
@@ -12,10 +13,10 @@ retire finishers. The device work is shape-static by construction:
     variants at max_len / block; causal masking makes the pads inert
     for attention, and a model with a recurrent state is told the true
     length (decode.prefill).
-  * exactly ONE host readback per decode pass (the sampled token ids)
-    and one per prefill (the first token) — the contract hvdlint HVD011
-    enforces over this package; both sites carry the sanctioned
-    disable marker.
+  * exactly ONE host readback per decode pass (the sampled token ids,
+    ``_read_unread``) and one per prefill (the first token,
+    ``_read_first_tokens``) — the contract hvdlint HVD011 enforces over
+    this package; both sites carry the sanctioned disable marker.
 
 The decode step does not wait for the host. The engine has no stop
 token: a row ends by its count, its deadline or the block ledger, all
@@ -33,7 +34,28 @@ from the device:
     the device, with its slot write. The sampling key is folded inside
     the program from the engine's key and the host's step count
     (prefills and passes share the count, one each). A step that only
-    decodes uploads that count and nothing else.
+    decodes uploads that count and nothing else. A prefill's key is
+    folded by one small eager dispatch just before its own, and its
+    prompt, index and temperature go up with its dispatch as host values.
+  * a step that admits launches first and reads after. ``_admit``
+    dispatches the prefill and the slot write of every request the step
+    admits, back to back; ``_decode`` launches its pass over all active
+    rows, the new ones among them; only then are the first tokens read,
+    in admission order, each followed by its bookkeeping (``ttft_s``
+    stamped when the host HAS the token). At most two admissions are
+    ever unread (``_UNREAD_ADMISSIONS``): a third reads the first one
+    before it launches, with the second one's prefill queued behind, so
+    a burst holds two prefills' output rows on the device and not one a
+    free slot, and the chip still never waits; and a dispatch that
+    compiles (the first prefill of a padded length, the first pass)
+    reads them all first, so no first token waits seconds for another
+    request's program. The device's order is the
+    synchronous one (prefill, slot write, pass), so every token is the
+    same token; the host walks the pass's launch path while the chip
+    runs the prefill, and wakes up and books the admission while it
+    runs the pass, where the chip used to wait for all three. A request
+    that asks for one token takes no part in the pass and is retired at
+    its read.
   * the read of a pass's ids happens where it delays nothing. After
     launching pass k, ``_decode`` reads it at once if something at the
     boundary waits for it (``_due``: a row gets its last token, a free
@@ -134,23 +156,32 @@ def _write_slot(state, row, slot, ids, token):
     return state, ids.at[slot].set(token)
 
 
+# Admissions a step may have launched and not yet read. Two: the chip has
+# the next prefill queued behind the one whose token the host reads, so it
+# never waits for the host between them, and a burst of admissions holds
+# two prefills' output rows on the device, not one for every free slot
+# (a row lives until its slot write has run: 0.2 GB of K/V for a 1024-token
+# prompt of the Ouro cell's model; PERF.md §6, PR 38).
+_UNREAD_ADMISSIONS = 2
+
+
 class _Active:
     """Host-side per-slot decode state."""
 
     __slots__ = ("request", "generated", "next_pos", "last_token_ts",
                  "ttft_s", "generation")
 
-    def __init__(self, request, first_token, prompt_len, now,
-                 generation=0):
+    def __init__(self, request, prompt_len, generation=0):
         self.request = request
-        # the token VALUES the host has read; the last of them, or the
-        # one a pass in flight sampled, is in the device's ids
-        self.generated = [first_token]
+        # the token VALUES the host has read, none until the prefill's
+        # first token is (``_read_first_tokens``); the last of them, or
+        # the one a program in flight sampled, is in the device's ids
+        self.generated = []
         # cache position the next pass writes; moved on when a pass is
         # LAUNCHED, so it counts the tokens the row has been given
         self.next_pos = prompt_len
-        self.last_token_ts = now
-        self.ttft_s = now - request.arrival_ts
+        # stamped when the host HAS the first token
+        self.last_token_ts = self.ttft_s = None
         # weight generation that admitted this request: it decodes on
         # these weights to the end, across any hot swap (docs/fleet.md)
         self.generation = generation
@@ -158,7 +189,8 @@ class _Active:
     @property
     def given(self):
         """Tokens the row has been given, the launch-time truth:
-        len(generated), plus one while a pass over it is unread."""
+        len(generated), plus one while its prefill's first token or a
+        pass over it is unread."""
         return self.next_pos - len(self.request.prompt) + 1
 
 
@@ -245,9 +277,19 @@ class ServeEngine:
         # host has to write them again: a row joined or left, or the pass
         # was one cohort's of several. ``_unread``: the ONE pass that may
         # be in flight with its ids not read yet, (ids, [(slot, _Active)]).
+        # ``_joined``: the admissions of the step in progress whose first
+        # token is still to be read, [(slot, _Active, token)]; empty
+        # between steps.
         self._ids = self._put(np.zeros(num_slots, np.int32))
         self._feed = None
         self._unread = None
+        self._joined = []
+        self._tick = None  # the decode-tick span of the step in progress
+        # what this engine has dispatched: the padded prompt lengths it has
+        # prefilled, and "decode". The first dispatch of each traces,
+        # lowers and compiles (or loads) its program: seconds on the host
+        # that no unread first token is left behind (``_compiles``)
+        self._dispatched = set()
         reg = self._metrics = hvd_metrics.get_registry()
         self._m_requests = reg.counter(
             "hvd_serve_requests_total",
@@ -283,6 +325,12 @@ class ServeEngine:
             "and its ids unread (every slot busy, no row finishing, one "
             "weight generation): the next step launches its pass first "
             "and reads this one while the chip runs.")
+        self._m_admitted_ahead = reg.counter(
+            "hvd_serve_admissions_ahead_total",
+            "Admissions whose first token was read with a program of "
+            "their step queued behind their prefill (the step's decode "
+            "pass, or a later admission's prefill): the chip ran on "
+            "while the host read and booked it.")
         state_bytes = reg.gauge(
             "hvd_serve_state_bytes",
             "Bytes of per-slot serving state resident on one chip, by "
@@ -402,7 +450,7 @@ class ServeEngine:
             done, self._finished = self._finished, []
             return done
         finally:
-            self._rec = serve_tracing.NULL_STEP
+            self._rec, self._tick = serve_tracing.NULL_STEP, None
             rec.finish()
 
     def run_to_completion(self, max_steps=100000):
@@ -584,12 +632,24 @@ class ServeEngine:
         return min(-(-n // block) * block, self.kv.max_len)
 
     def _admit(self):
+        """Launch the prefill and the slot write of every request this
+        step can admit, back to back. The first tokens are read after the
+        step's decode launch (``_read_first_tokens``); a third admission
+        and each one after it first reads the oldest unread one, whose
+        successor's prefill is queued behind it (``_UNREAD_ADMISSIONS``),
+        and the first admission of a padded length reads them all: its
+        dispatch compiles, and a first token is not made to wait seconds
+        for another request's program."""
         admitted = False
         while self.scheduler.can_join():
             with self._rec.phase("admit"):
                 req = self._pop_admissible()
             if req is None:
                 break
+            if self._compiles(self._pad_len(len(req.prompt))):
+                self._read_first_tokens()
+            elif len(self._joined) == _UNREAD_ADMISSIONS:
+                self._read_first_tokens(keep=_UNREAD_ADMISSIONS - 1)
             self._prefill(req)
             admitted = True
         return admitted
@@ -635,62 +695,101 @@ class ServeEngine:
         # generated token is sampled but never written back
         return len(req.prompt) + max(req.max_new_tokens - 1, 0)
 
+    def _compiles(self, program):
+        """Is this the engine's first dispatch of ``program`` (a padded
+        prompt length's prefill, or "decode")? Noted as dispatched."""
+        if program in self._dispatched:
+            return False
+        self._dispatched.add(program)
+        return True
+
     def _prefill(self, req):
+        """Launch one admission: its prefill program as soon as the slot,
+        the padded prompt and the key are there (prompt, index and
+        temperature go up with the dispatch as host values), then the
+        rest of the host state that says the row is there (all known
+        without the token's value), then the slot write. The row joins
+        this step's pass unless the prefill's token is all it asked for."""
         rec = self._rec
         with rec.phase("prefill"):
             prompt_len = len(req.prompt)
             slot = self.scheduler.join(req.request_id)
-            trace = serve_tracing.trace_of(req)
-            trace.on_prefill_start(slot, prompt_len)
-            self.kv.ledger.alloc_at(slot, prompt_len,
-                                    reserve=self._final_len(req))
-            s_pad = self._pad_len(prompt_len)
-            tokens = np.zeros((1, s_pad), np.int32)
+            # the request's prefill span opens before the dispatch: a
+            # compile or a slow launch is the prefill's, not a stall
+            serve_tracing.trace_of(req).on_prefill_start(slot, prompt_len)
+            tokens = np.zeros((1, self._pad_len(prompt_len)), np.int32)
             tokens[0, :prompt_len] = req.prompt
             rng = jax.random.fold_in(self._rng, self._step_count)
             self._step_count += 1
+            tok, row = _prefill_jit(
+                self.cfg, self.params, tokens, np.int32(prompt_len - 1),
+                np.float32(req.temperature), rng)
+            self.kv.ledger.alloc_at(slot, prompt_len,
+                                    reserve=self._final_len(req))
             # compile observability: each distinct padded prompt length
             # is a real prefill recompile; a churn of them is the storm
             # the tracker names (docs/memory.md)
             if hvd_memory.enabled():
                 hvd_memory.get_tracker().observe("serve_prefill",
                                                  (tokens,))
-            tok, row = _prefill_jit(
-                self.cfg, self.params, jnp.asarray(tokens),
-                jnp.int32(prompt_len - 1), jnp.float32(req.temperature),
-                rng)
             kv = self.kv
             went_in = kv.arrays
             # the first token joins the device's ids where it is: the
             # next decode pass takes it from there, not from the host
             kv.arrays, self._ids = _write_slot(
-                went_in, row, jnp.int32(slot), self._ids, tok)
+                went_in, row, np.int32(slot), self._ids, tok)
             self._feed = None  # a row joined
             if "write_slot" in self._in_place_unchecked:
                 self._note_in_place("write_slot", went_in)
+            st = _Active(req, prompt_len, generation=self._generation)
+            if req.max_new_tokens > 1:
+                self._active[slot] = st
+            self._joined.append((slot, st, tok))
             rec.count("admitted")
             rec.count("prompt_tokens", prompt_len)
             rec.count("state_bytes", self._row_state_bytes)
-        with rec.phase("prefill_readback"):
-            # the one sanctioned per-prefill readback: the first token
-            # hvdlint: disable=HVD011(first-token sample is the prefill's output)
-            first = int(jax.device_get(tok))
-        with rec.phase("bookkeeping"):
-            now = self._clock()
-            self._active[slot] = _Active(req, first, prompt_len, now,
-                                         generation=self._generation)
-            trace.on_prefill_end(ttft_s=self._active[slot].ttft_s)
-            trace.annotate(generation=self._generation)
-            self._m_tokens.labels(phase="prefill").inc(prompt_len)
-            self._m_tokens.labels(phase="decode").inc()
-            self._m_ttft.observe(self._active[slot].ttft_s)
-            self._metrics.event(
-                "serve_admit", request_id=req.request_id, slot=slot,
-                prompt_len=prompt_len, trace_id=trace.trace_id,
-                generation=self._generation,
-                ttft_s=round(self._active[slot].ttft_s, 6))
-            if req.max_new_tokens <= 1:
-                self._retire(slot, "completed")
+
+    def _read_first_tokens(self, keep=0, launched=False):
+        """Read the first token of this step's admissions, in admission
+        order and all but the newest ``keep``, and book each: the ONE
+        place a prefill's token crosses to the host. ``launched``: the
+        step's decode pass is. An admission is read AHEAD when a program
+        of the step is queued behind its prefill, that pass or a later
+        admission's prefill: the chip runs on while the host reads and
+        books."""
+        joined = self._joined
+        if not joined:
+            return
+        rec = self._rec
+        last, n = len(joined) - 1, len(joined) - keep
+        self._joined = joined[n:]
+        for i, (slot, st, tok) in enumerate(joined[:n]):
+            with rec.phase("prefill_readback"):
+                # the one sanctioned per-prefill readback: the first token
+                # hvdlint: disable=HVD011(first-token sample is the prefill's output)
+                first = int(jax.device_get(tok))
+            with rec.phase("bookkeeping"):
+                req = st.request
+                st.generated.append(first)
+                st.last_token_ts = now = self._clock()
+                st.ttft_s = now - req.arrival_ts
+                trace = serve_tracing.trace_of(req)
+                trace.on_prefill_end(ttft_s=st.ttft_s)
+                trace.annotate(generation=st.generation)
+                self._m_tokens.labels(phase="prefill").inc(len(req.prompt))
+                self._m_tokens.labels(phase="decode").inc()
+                self._m_ttft.observe(st.ttft_s)
+                if launched or i < last:
+                    rec.count("admitted_ahead")
+                    self._m_admitted_ahead.inc()
+                self._metrics.event(
+                    "serve_admit", request_id=req.request_id, slot=slot,
+                    prompt_len=len(req.prompt), trace_id=trace.trace_id,
+                    generation=st.generation,
+                    ttft_s=round(st.ttft_s, 6))
+                if req.max_new_tokens <= 1:  # took no part in the pass
+                    self._active[slot] = st
+                    self._retire(slot, "completed")
 
     def _put(self, array):
         """Small host arrays (a tree of them) beside the cache, COMMITTED
@@ -716,17 +815,25 @@ class ServeEngine:
         return self._put((positions, temps, rows))
 
     def _decode(self):
-        """Launch one decode pass over the active rows, then read what is
-        due: the pass a step ago that is still unread, and this one too
-        unless nothing at the boundary waits for it (``_due``). The module
-        docstring has the order and why."""
+        """Launch one decode pass over the active rows, the ones this
+        step admitted among them, then read what is due: the pass a step
+        ago that is still unread, the first tokens of this step's
+        admissions, and this pass too unless nothing at the boundary
+        waits for it (``_due``). The module docstring has the order and
+        why."""
         if not self._active:
-            return False  # and nothing unread: a last row is read at once
+            # nothing unread either (a last row is read at once); a
+            # request that asked for its prefill's token alone ends here
+            self._read_first_tokens()
+            return False
         rec = self._rec
         with rec.phase("decode_prepare"):
             # one span per fused step, its duration attributed to every
-            # request active during the tick (serving/tracing.py)
-            tick = rec.tick_span(**self.scheduler.snapshot())
+            # request active during the tick (serving/tracing.py). In a
+            # step that admitted it opens once the first tokens are read:
+            # the wait for them is the prefills', and no row's decode.
+            if not self._joined:
+                self._open_tick()
             # Cohort-partitioned decode (docs/fleet.md): a request
             # decodes on the weights that admitted it, across any hot
             # swap, so each live generation runs its own fused pass over
@@ -752,10 +859,13 @@ class ServeEngine:
             st = self._active.get(slot)
             if st is not None and \
                     not self.kv.ledger.grow(slot, st.next_pos + 1):
-                self._read_unread(tick)
+                self._read_unread()
+                self._read_first_tokens()
                 if self._active.get(slot) is st:
                     with rec.phase("bookkeeping"):
                         self._retire(slot, "failed", reason="kv_exhausted")
+        if self._compiles("decode"):  # this engine's first pass, likewise
+            self._read_first_tokens()
         launched = []
         one = len(gens) == 1
         # what one cohort of several left is of no use to this pass
@@ -802,16 +912,21 @@ class ServeEngine:
         rec.count("cohorts", len(gens))
         if launched:  # stack passes the launched program runs each row
             rec.count("passes", passes(self.cfg))
-        # the pass before this one first, while the chip runs this one
-        self._read_unread(tick)
+        # everything is launched: now what came before it, in the chip's
+        # order and while the chip runs on. The pass before this one, ...
+        self._read_unread()
+        # ... this step's prefills ...
+        self._read_first_tokens(launched=bool(launched))
+        self._open_tick()
+        # ... and this pass, if anything waits for it
         if launched:
             self._unread = (self._ids, launched)
             if self._due(launched, len(gens)):
-                self._read_unread(tick)
+                self._read_unread()
             else:
                 rec.count("ahead")
                 self._m_ahead.inc()
-        self._close_tick(tick)
+        self._close_tick()
         return True
 
     def _due(self, launched, cohorts):
@@ -835,7 +950,7 @@ class ServeEngine:
                 any(st.given >= st.request.max_new_tokens
                     for _, st in launched))
 
-    def _read_unread(self, tick):
+    def _read_unread(self):
         """Read the ids of the pass in flight, if there is one, and book
         them: the ONE place a decode pass's ids cross to the host, at the
         end of the step that launched it or after the next launch."""
@@ -848,7 +963,7 @@ class ServeEngine:
             # transition one for the cohorts' passes together): the ids
             # hvdlint: disable=HVD011(the per-step batched token readback)
             ids = np.asarray(jax.device_get(ids))
-        self._close_tick(tick)
+        self._close_tick()
         with rec.phase("bookkeeping"):
             now = self._clock()
             for slot, st in launched:
@@ -865,11 +980,18 @@ class ServeEngine:
                         now - req.arrival_ts > req.deadline_s):
                     self._retire(slot, "failed", reason="deadline")
 
-    def _close_tick(self, tick):
+    def _open_tick(self):
+        """Open the step's one decode-tick span, unless it has one."""
+        if self._tick is None:
+            self._tick = self._rec.tick_span(**self.scheduler.snapshot())
+
+    def _close_tick(self):
         """Close the step's decode-tick span at its first call in a step
-        (after the step's first readback, or at the end of a step that
-        read nothing) and attribute it to the requests still decoding."""
-        if not tick.open:
+        (after the first readback of a decode pass, or at the end of a
+        step that read none) and attribute it to the requests still
+        decoding."""
+        tick = self._tick
+        if tick is None or not tick.open:
             return
         with self._rec.phase("telemetry"):
             tick_us = serve_tracing.finish_tick(tick, len(self._active),

@@ -333,9 +333,12 @@ def finish_tick(span, active_slots, slow_us):
 
 # -- the inside of one engine step --------------------------------------------
 
-# Phase names of a step record, in the order a step that admits and
-# decodes first meets them; the benchmark's readers use them letter for
-# letter (benchmarks/lib/step_phases.py). What each covers: docs/tracing.md.
+# Phase names of a step record; the benchmark's readers use them letter
+# for letter (benchmarks/lib/step_phases.py). A step that admits and
+# decodes launches everything before it reads anything: ``prefill`` (one a
+# request), the two ``decode_`` launch phases, and only then one
+# ``prefill_readback`` and ``bookkeeping`` a request. What each covers:
+# docs/tracing.md.
 STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
                "decode_prepare", "decode_dispatch", "decode_readback",
                "bookkeeping", "telemetry")
@@ -347,6 +350,14 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # ahead: 1 when the step returned with its decode pass in flight, its ids
 # unread (serving/engine.py ``_due``); the NEXT step's ``decode_readback``
 # then starts with that pass's ids. 0 on every other step that decoded.
+# admitted_ahead: the step's admissions whose first token was read with a
+# program of the step queued behind their prefill (``_read_first_tokens``):
+# the step's decode pass, or a later admission's prefill where a step
+# admits more than two. The chip ran on meanwhile: == admitted on every
+# step that admits into a batch that decodes; less where nothing was
+# launched behind the last one (a request that asks for one token into an
+# idle batch, a row the ledger refused before the launch, a dispatch that
+# compiles: what is unread is read before it)
 # kv_bytes (not in STEP_COUNTS: only a step that decoded has it): bytes of
 # K and V the step's decode passes had to stream, each decoding row's in
 # whole blocks of ops/flash_attention.decode_block positions up to its
@@ -355,7 +366,7 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # program runs each row, from the configuration: the passes of a looped
 # stack (models/looped.py), 1 for every other model
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
-               "state_rows", "state_bytes", "ahead")
+               "state_rows", "state_bytes", "ahead", "admitted_ahead")
 _STEP_ANNOTATION = "hvd.serve.step"
 _PHASE_ANNOTATIONS = {p: "hvd.serve." + p for p in STEP_PHASES}
 

@@ -91,6 +91,10 @@ def test_serve_leg(tiny, capsys, family):
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
     assert line["steps_ahead"] > 0   # three requests on two slots
+    # two of three in each round: an engine's first pass counts as one that
+    # compiles, and what is unread is read before it (a counter of the
+    # process, so a later family's line reads more)
+    assert line["admissions_ahead"] >= 4
 
 
 def test_a_failing_leg_fails_the_run(monkeypatch, tmp_path):

@@ -386,6 +386,10 @@ class TestStepTrace:
             self, stepping):
         cfg, params = _tiny()
         engine = _engine(cfg, params)
+        # compiled first: a dispatch that compiles reads what is unread
+        engine.submit(Request("warm", (9, 9, 9), max_new_tokens=2))
+        engine.run_to_completion()
+        warm = len(stepping.steps())
         joins, retires = [], []
         sched = engine.scheduler
         join, retire = sched.join, sched.retire
@@ -394,18 +398,22 @@ class TestStepTrace:
         engine.submit(Request("a", (3, 1, 4), max_new_tokens=4))
         engine.submit(Request("b", (2, 7, 1, 8, 2), max_new_tokens=3))
         assert engine.step() == []
-        (rec,) = stepping.steps()
+        (rec,) = stepping.steps()[warm:]
         # kv_bytes, passes: on a step that decoded, and on no other (below)
         assert set(rec) == {"seq", "start_us", "end_us", "phases",
                             "kv_bytes", "passes",
                             *serve_tracing.STEP_COUNTS}
         assert rec["passes"] == 1   # a stack that runs once
-        assert rec["seq"] == 1 and _tiles(rec)
-        one = ["admit", "prefill", "prefill_readback", "bookkeeping"]
-        # both slots busy and neither row on its last token: the step
-        # returns with its pass in flight, and reads nothing
-        assert [p[0] for p in rec["phases"]] == ["control"] + one + one + [
-            "decode_prepare", "decode_dispatch", "telemetry"]
+        assert rec["seq"] == warm + 1 and _tiles(rec)
+        # everything is launched before anything is read: both prefills,
+        # the pass over both rows, and only then each first token, in
+        # admission order, with its bookkeeping. Both slots busy and
+        # neither row on its last token: the step returns with its pass
+        # in flight, and reads nothing of it
+        launch, read = ["admit", "prefill"], ["prefill_readback",
+                                              "bookkeeping"]
+        assert [p[0] for p in rec["phases"]] == ["control"] + 2 * launch + [
+            "decode_prepare", "decode_dispatch"] + 2 * read + ["telemetry"]
         # nothing but the phase's own closing read inside these
         tick = SteppingUsClock.TICK
 
@@ -420,7 +428,7 @@ class TestStepTrace:
         assert rec["active"] == 2 and rec["cohorts"] == 1
         assert rec["prompt_tokens"] == 3 + 5
         assert rec["retired"] == len(retires) == 0
-        assert rec["ahead"] == 1
+        assert rec["ahead"] == 1 and rec["admitted_ahead"] == 2
         # the next step launches its pass, then reads the one before
         # (the first readback) and, b being on its last token, its own
         (b,) = engine.step()
@@ -434,12 +442,13 @@ class TestStepTrace:
         assert _tiles(rec) and rec["ahead"] == 0 and rec["active"] == 2
         # a slot is free from here on: the synchronous order, as ever
         done = [b] + engine.run_to_completion()
-        recs = stepping.steps()
+        recs = stepping.steps()[warm:]
         assert [p[0] for p in recs[-1]["phases"]] == [
             "control", "admit", "decode_prepare", "decode_dispatch",
             "decode_readback", "telemetry", "bookkeeping", "telemetry"]
         bare(recs[-1])
-        assert [r["seq"] for r in recs] == list(range(1, len(recs) + 1))
+        assert [r["seq"] for r in recs] == \
+            list(range(warm + 1, warm + len(recs) + 1))
         assert all(_tiles(r) for r in recs)
         assert sum(r["retired"] for r in recs) == len(retires) == \
             len(done) == 2

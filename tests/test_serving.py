@@ -692,6 +692,27 @@ def _tokens(results):
     return {rid: list(r.tokens) for rid, r in results.items()}
 
 
+def _subscriber(new):
+    """A fleet subscriber that hands out ``new`` as generation 7 once its
+    ``armed_generation`` is set."""
+    class Armed:
+        generation, params, step = 7, new, 0
+        detect_ts = loaded_ts = armed_ts = 0.0
+
+    class Subscriber:
+        replica, current_generation, armed_generation = 0, 0, None
+        clock = staticmethod(lambda: 0.0)
+
+        def poll(self):
+            pass
+
+        def take_armed(self):
+            rec, self.armed_generation = self.armed_generation, None
+            return rec and Armed
+
+    return Subscriber()
+
+
 REQUESTS = [("a", (5, 9, 17), 9), ("b", (4, 8, 15, 16, 23, 42), 13),
             ("c", (7, 7, 1), 6)]
 
@@ -820,23 +841,7 @@ class TestTheStepDoesNotWaitForTheHost:
         read at once."""
         cfg, old = _tiny()
         new = jax.tree_util.tree_map(lambda a: a * 1.5, old)
-
-        class Armed:
-            generation, params, step = 7, new, 0
-            detect_ts = loaded_ts = armed_ts = 0.0
-
-        class Subscriber:
-            replica, current_generation, armed_generation = 0, 0, None
-            clock = staticmethod(lambda: 0.0)
-
-            def poll(self):
-                pass
-
-            def take_armed(self):
-                rec, self.armed_generation = self.armed_generation, None
-                return rec and Armed
-
-        sub = Subscriber()
+        sub = _subscriber(new)
         engine = _engine(cfg, old, subscriber=sub)
         requests = [("a", (5, 9, 17), 8), ("b", (4, 8, 15, 16), 14),
                     ("c", (7, 7, 1), 6)]
@@ -988,6 +993,377 @@ class TestTheStepDoesNotWaitForTheHost:
         assert (engine_mod._decode_jit._cache_size(),
                 engine_mod._write_slot._cache_size(),
                 engine_mod._prefill_jit._cache_size()) == before
+
+
+# ---------------------------------------------------------------------------
+# An admitting step launches everything before it reads anything
+# (docs/serving.md, "The step's order"): prefills, slot writes and the decode
+# pass over the new rows too, then each first token in admission order
+# ---------------------------------------------------------------------------
+
+def _reads_at_once(engine):
+    """The synchronous admission on the same engine: each first token read
+    and booked before anything else is launched."""
+    launch = engine._prefill
+
+    def prefill(req):
+        launch(req)
+        engine._read_first_tokens()
+    engine._prefill = prefill
+    return engine
+
+
+def _warm(engine):
+    """One request through the engine: its decode program and the prefill
+    of every prompt of up to 8 tokens are compiled. (A dispatch that
+    compiles reads every unread first token before it: no request waits
+    seconds for another one's program.)"""
+    engine.submit(Request("warm", (9, 9, 9), max_new_tokens=2))
+    engine.run_to_completion()
+    return engine
+
+
+def _spy(monkeypatch, log, clock=None):
+    """Log every program launch and every host read of the engine's, in
+    order; ``clock`` moves on by one at each read."""
+    from horovod_tpu.serving import engine as engine_mod
+    for name in ("_prefill_jit", "_write_slot", "_decode_jit"):
+        real = getattr(engine_mod, name)
+        monkeypatch.setattr(
+            engine_mod, name,
+            lambda *a, _real=real, _name=name: log.append(_name) or _real(*a))
+    get = jax.device_get
+
+    def device_get(x):
+        log.append("read")
+        if clock is not None:
+            clock.t += 1.0
+        return get(x)
+    monkeypatch.setattr(jax, "device_get", device_get)
+
+
+class TestAnAdmittingStepLaunchesBeforeItReads:
+    @pytest.fixture(autouse=True)
+    def tracer(self):
+        from horovod_tpu.utils import tracing as hvd_tracing
+        hvd_tracing.reset(enabled=True, rank=0)
+        yield
+        hvd_tracing.reset()
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    @pytest.mark.parametrize("model", ["dense", "hybrid", "looped"])
+    def test_launching_first_gives_the_synchronous_admissions_tokens(
+            self, reg, model, temperature):
+        """Two slots, four requests, the last two joining as rows end:
+        token for token and under the same keys what the same engine gives
+        when it reads each first token before it launches anything else,
+        with the same passes over the same rows in the same steps."""
+        cfg, params = MODELS[model]()
+        requests = REQUESTS + [("d", (2, 7, 1, 8), 5)]
+        first, recs = _drive(_warm(_engine(cfg, params, seed=3)), requests,
+                             temperature)
+        sync, sync_recs = _drive(
+            _reads_at_once(_warm(_engine(cfg, params, seed=3))), requests,
+            temperature)
+        assert _tokens(first) == _tokens(sync)
+        assert {rid: len(t) for rid, t in _tokens(first).items()} == \
+            {rid: new for rid, _, new in requests}
+        for key in ("admitted", "active", "retired", "ahead"):
+            assert [r[key] for r in recs] == [r[key] for r in sync_recs]
+        # every admission was read behind its step's decode launch, and
+        # in the synchronous order none
+        assert [r["admitted_ahead"] for r in recs] == \
+            [r["admitted"] for r in recs]
+        assert sum(r["admitted"] for r in recs) == 4
+        assert not any(r["admitted_ahead"] for r in sync_recs)
+        snap = reg.snapshot()
+        assert _value(snap, "hvd_serve_admissions_ahead_total") == 4
+        assert [e["request_id"] for e in _events(snap, "serve_admit")] == \
+            2 * ["warm", "a", "b", "c", "d"]
+
+    def test_two_admissions_are_both_launched_before_either_is_read(
+            self, reg, monkeypatch):
+        """...and the pass over both rows too; each ``ttft_s`` is stamped
+        at its own read."""
+        cfg, params = _tiny()
+        clock = FakeClock()
+        engine = _warm(_engine(cfg, params, clock=clock, queue=AdmissionQueue(
+            max_depth=8, admission_timeout_s=1e9, clock=clock)))
+        log = []
+        _spy(monkeypatch, log, clock)
+        engine.submit(Request("a", (5, 9, 17), max_new_tokens=9))
+        engine.submit(Request("b", (4, 8, 15), max_new_tokens=9))
+        assert engine.step() == []
+        assert log == 2 * ["_prefill_jit", "_write_slot"] + [
+            "_decode_jit", "read", "read"]
+        a, b = (engine._active[slot] for slot in sorted(engine._active))
+        assert (a.ttft_s, b.ttft_s) == (1.0, 2.0)
+        assert (a.last_token_ts, b.last_token_ts) == (1.0, 2.0)
+        assert a.given == b.given == 2 and engine._unread is not None
+        rec = hvd_tracing_steps()[-1]
+        assert rec["admitted"] == rec["admitted_ahead"] == rec["active"] == 2
+        results = {r.request_id: r for r in engine.run_to_completion()}
+        assert (results["a"].ttft_s, results["b"].ttft_s) == (1.0, 2.0)
+        for rid, prompt in (("a", (5, 9, 17)), ("b", (4, 8, 15))):
+            assert list(results[rid].tokens) == \
+                _greedy_reference(cfg, params, prompt, 9)
+
+    def test_a_burst_of_admissions_keeps_two_unread_and_the_chip_fed(
+            self, reg, monkeypatch):
+        """Four requests into four free slots: the third admission reads
+        the first one's token before it launches, the fourth the second
+        one's, each with the next prefill already queued behind it; the
+        last two are read behind the decode launch. So the device holds
+        two prefills' output rows at most, and never waits for the host."""
+        cfg, params = _tiny()
+        engine = _warm(_engine(cfg, params, num_slots=4))
+        log = []
+        _spy(monkeypatch, log)
+        prompts = {"a": (5, 9, 17), "b": (4, 8, 15), "c": (7, 7, 1),
+                   "d": (2, 7, 1, 8)}
+        for rid, prompt in prompts.items():
+            engine.submit(Request(rid, prompt, max_new_tokens=6))
+        assert engine.step() == []
+        launch = ["_prefill_jit", "_write_slot"]
+        assert log == 2 * launch + ["read"] + launch + ["read"] + launch + [
+            "_decode_jit", "read", "read"]
+        rec = hvd_tracing_steps()[-1]
+        assert rec["admitted"] == rec["admitted_ahead"] == 4
+        assert rec["active"] == 4 and rec["ahead"] == 1
+        results = {r.request_id: r for r in engine.run_to_completion()}
+        for rid, prompt in prompts.items():
+            assert list(results[rid].tokens) == \
+                _greedy_reference(cfg, params, prompt, 6)
+
+    def test_a_dispatch_that_compiles_leaves_no_first_token_unread(
+            self, reg, monkeypatch):
+        """A new engine's first step: the second prompt pads to a length
+        not prefilled before and the pass is the engine's first, so each
+        of those dispatches traces, lowers and compiles, seconds on the
+        host; what is unread is read before it, as the synchronous order
+        would."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        log = []
+        _spy(monkeypatch, log)
+        engine.submit(Request("a", (5, 9, 17), max_new_tokens=6))
+        engine.submit(Request("b", tuple(range(1, 12)), max_new_tokens=6))
+        assert engine.step() == []
+        launch = ["_prefill_jit", "_write_slot"]
+        assert log == launch + ["read"] + launch + ["read", "_decode_jit"]
+        assert hvd_tracing_steps()[-1]["admitted_ahead"] == 0
+        engine.run_to_completion()
+        # the same two lengths again: nothing compiles, nothing waits
+        del log[:]
+        engine.submit(Request("c", (7, 7, 1), max_new_tokens=6))
+        engine.submit(Request("d", tuple(range(2, 13)), max_new_tokens=6))
+        engine.step()
+        assert log == 2 * launch + ["_decode_jit", "read", "read"]
+        assert hvd_tracing_steps()[-1]["admitted_ahead"] == 2
+
+    @pytest.mark.parametrize("new,idle", [(1, False), (2, False), (1, True)])
+    def test_a_request_that_asks_for_one_token_or_two(self, reg, new, idle):
+        """One token: the prefill's, so the row takes no part in the
+        step's pass (known before the launch) and is retired at its read.
+        Two: it joins the pass as its last row, which is read at once."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        if not idle:
+            engine.submit(Request("long", (4, 8, 15, 16), max_new_tokens=9))
+            engine.step()
+        engine.submit(Request("short", (5, 9, 17), max_new_tokens=new))
+        (short,) = engine.step()
+        assert short.outcome == "completed" and list(short.tokens) == \
+            _greedy_reference(cfg, params, (5, 9, 17), new)
+        rec = hvd_tracing_steps()[-1]
+        assert rec["admitted"] == rec["retired"] == 1 and rec["ahead"] == 0
+        assert rec["active"] == (0 if idle else new)
+        # read behind the launch, where there was one
+        assert rec["admitted_ahead"] == (0 if idle else 1)
+        assert engine._unread is None and not engine._joined
+        assert engine.active_count == (0 if idle else 1)
+        (long,) = engine.run_to_completion() or [None]
+        assert idle or list(long.tokens) == _greedy_reference(
+            cfg, params, (4, 8, 15, 16), 9)
+        assert engine.kv.ledger.blocks_in_use == 0
+
+    def test_a_ledger_that_refuses_the_new_rows_first_pass(self, reg):
+        """``grow`` refuses before the launch: the new row's first token is
+        read there and then, it goes ``kv_exhausted`` with that token, and
+        the row beside it never notices."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        grow = engine.kv.ledger.grow
+        engine.kv.ledger.grow = lambda slot, n: slot != 1 and grow(slot, n)
+        engine.submit(Request("kept", (4, 8, 15, 16), max_new_tokens=7))
+        engine.submit(Request("refused", (5, 9, 17), max_new_tokens=7))
+        (refused,) = engine.step()
+        assert (refused.request_id, refused.outcome, refused.reason) == \
+            ("refused", "failed", "kv_exhausted")
+        assert list(refused.tokens) == \
+            _greedy_reference(cfg, params, (5, 9, 17), 1)
+        assert refused.ttft_s is not None
+        rec = hvd_tracing_steps()[-1]
+        # both read before the launch: the first with the second one's
+        # prefill queued behind it, the second with nothing
+        assert rec["admitted"] == 2 and rec["admitted_ahead"] == 1
+        assert rec["active"] == 1 and rec["retired"] == 1
+        (kept,) = engine.run_to_completion()
+        assert list(kept.tokens) == \
+            _greedy_reference(cfg, params, (4, 8, 15, 16), 7)
+        assert engine.kv.ledger.blocks_in_use == 0
+
+    def test_a_deadline_blown_during_the_prefill_is_seen_at_the_passes_read(
+            self, reg, monkeypatch):
+        """No deadline is looked at where a first token is read, as ever:
+        the row joins the pass and fails ``deadline`` where that is read,
+        with the two tokens it has."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = _tiny()
+        clock = FakeClock(1.0)
+        engine = _engine(cfg, params, clock=clock, queue=AdmissionQueue(
+            max_depth=8, admission_timeout_s=1e9, clock=clock))
+        real = engine_mod._prefill_jit
+
+        def slow(*a):
+            clock.t = 7.0
+            return real(*a)
+        monkeypatch.setattr(engine_mod, "_prefill_jit", slow)
+        engine.submit(Request("late", (5, 9, 17), max_new_tokens=9,
+                              deadline_s=5.0))
+        (late,) = engine.run_to_completion()
+        assert (late.outcome, late.reason) == ("failed", "deadline")
+        assert late.ttft_s == 6.0
+        assert list(late.tokens) == \
+            _greedy_reference(cfg, params, (5, 9, 17), 2)
+        assert engine.kv.ledger.blocks_in_use == 0
+
+    def test_a_hot_swap_in_the_admitting_step(self, reg):
+        """A generation is armed and a request waits: the step swaps,
+        admits the request on the new weights and runs one pass a cohort,
+        all launched before the first token is read; every pass is read at
+        once while both generations live."""
+        cfg, old = _tiny()
+        new = jax.tree_util.tree_map(lambda a: a * 1.5, old)
+        sub = _subscriber(new)
+        engine = _engine(cfg, old, subscriber=sub)
+        engine.submit(Request("a", (5, 9, 17), max_new_tokens=8))
+        engine.step()
+        sub.armed_generation = 7
+        engine.submit(Request("c", (7, 7, 1), max_new_tokens=6))
+        engine.step()
+        rec = hvd_tracing_steps()[-1]
+        assert engine.generation == 7
+        assert rec["admitted"] == rec["admitted_ahead"] == 1
+        assert rec["cohorts"] == 2 and rec["active"] == 2
+        assert rec["ahead"] == 0 and engine._unread is None
+        results = {r.request_id: r for r in engine.run_to_completion()}
+        assert [results[r].generation for r in "ac"] == [0, 7]
+        assert list(results["a"].tokens) == \
+            _greedy_reference(cfg, old, (5, 9, 17), 8)
+        assert list(results["c"].tokens) == \
+            _greedy_reference(cfg, new, (7, 7, 1), 6)
+
+    def test_the_drain_policy_admits_its_wave_the_same_way(self, reg):
+        cfg, params = _tiny()
+        results, recs = _drive(_warm(_engine(cfg, params, policy="drain")),
+                               REQUESTS)
+        for rid, prompt, new in REQUESTS:
+            assert list(results[rid].tokens) == \
+                _greedy_reference(cfg, params, prompt, new)
+        # two into the idle batch, the third once the wave has drained
+        assert [r["admitted"] for r in recs if r["admitted"]] == [2, 1]
+        assert [r["admitted_ahead"] for r in recs] == \
+            [r["admitted"] for r in recs]
+
+    @pytest.mark.parametrize("model", ["dense", "hybrid", "looped"])
+    def test_the_prefills_key_is_the_hosts_fold_in_of_the_shared_count(
+            self, reg, monkeypatch, model):
+        """Prefills and passes draw from one count in the order they are
+        launched, as they always did: a prefill is handed
+        ``fold_in(engine key, count)`` folded on the host, a pass the key
+        and the count themselves."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = MODELS[model]()
+        engine = _warm(_engine(cfg, params, seed=3))
+        counts = []
+        real_prefill, real_decode = (engine_mod._prefill_jit,
+                                     engine_mod._decode_jit)
+
+        def prefill(*a):
+            count = engine._step_count - 1    # taken just before the call
+            assert np.array_equal(
+                np.asarray(a[-1]),
+                np.asarray(jax.random.fold_in(engine._rng, count)))
+            counts.append(count)
+            return real_prefill(*a)
+
+        def decode(*a):
+            assert a[-2] is engine._rng
+            counts.append(int(a[-1]))
+            return real_decode(*a)
+        monkeypatch.setattr(engine_mod, "_prefill_jit", prefill)
+        monkeypatch.setattr(engine_mod, "_decode_jit", decode)
+        start = engine._step_count
+        _drive(engine, REQUESTS, temperature=0.8)
+        assert counts == list(range(start, start + len(counts)))
+        assert len(counts) > len(REQUESTS)
+
+    @pytest.mark.parametrize("feed", ["numpy", "jax"])
+    def test_prefill_jit_is_the_parents_program_however_it_is_fed(
+            self, reg, feed):
+        """``_prefill_jit`` keeps its six arguments, and host values of the
+        dtypes it was always traced with are the same signature as the
+        device values the engine used to convert them to: one lowered
+        text, one compiled program a shape."""
+        import inspect
+        from horovod_tpu.serving import engine as engine_mod
+        assert list(inspect.signature(
+            engine_mod._prefill_jit).parameters) == [
+                "cfg", "params", "tokens", "last_index", "temperature",
+                "rng"]
+        cfg, params = _tiny()
+        _warm(_engine(cfg, params))
+        tokens = np.zeros((1, 8), np.int32)
+        tokens[0, :3] = (5, 9, 17)
+        rng = jax.random.fold_in(jax.random.PRNGKey(3), 5)
+        fed = {"numpy": (tokens, np.int32(2), np.float32(0.8), rng),
+               "jax": (jnp.asarray(tokens), jnp.int32(2), jnp.float32(0.8),
+                       rng)}
+        before = engine_mod._prefill_jit._cache_size()
+        tok, _ = engine_mod._prefill_jit(cfg, params, *fed[feed])
+        assert engine_mod._prefill_jit._cache_size() == before
+        want, _ = engine_mod._prefill_jit(cfg, params, *fed["jax"])
+        assert int(tok) == int(want)
+        texts = {how: engine_mod._prefill_jit.lower(
+            cfg, params, *args).as_text() for how, args in fed.items()}
+        assert texts["numpy"] == texts["jax"]
+        slot_texts = {
+            str(kind): engine_mod._write_slot.lower(
+                {"k": jnp.zeros((2, 4, 8))}, {"k": jnp.zeros((2, 1, 8))},
+                kind(1), jnp.zeros(4, jnp.int32), jnp.int32(7)).as_text()
+            for kind in (np.int32, jnp.int32)}
+        assert len(set(slot_texts.values())) == 1
+
+    def test_one_prefill_program_a_shape_whichever_way_a_step_admits(
+            self, reg):
+        """First admission or hundredth, greedy or sampled, alone in its
+        step or one of two, read behind the launch or at once: one compiled
+        ``_prefill_jit`` and one ``_write_slot`` a padded prompt length."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = _tiny()
+        engine = _warm(_engine(cfg, params))
+
+        def compiled():
+            return (engine_mod._prefill_jit._cache_size(),
+                    engine_mod._write_slot._cache_size(),
+                    engine_mod._decode_jit._cache_size())
+        before = compiled()
+        _drive(engine, REQUESTS, temperature=0.8)
+        _drive(_reads_at_once(engine), REQUESTS)
+        engine.submit(Request("one", (5, 9, 17), max_new_tokens=1))
+        engine.run_to_completion()
+        assert compiled() == before
 
 
 # ---------------------------------------------------------------------------

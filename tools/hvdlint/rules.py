@@ -1917,8 +1917,12 @@ prefill (both in serving/engine.py, both carrying an inline disable
 with a reason). The per-pass one is still ONE statement
 (ServeEngine._read_unread); where nothing at the step's boundary waits
 for the ids it runs a step late, after the next pass is launched, so
-the chip is never idle for it. A second readback site would put the
-wait back on the chip's critical path.
+the chip is never idle for it. The per-prefill one is ONE statement too
+(ServeEngine._read_first_tokens), and runs after everything the
+admitting step runs is launched, the decode pass over the new rows
+included, so the chip works on behind the prefill while the host reads
+and books. A second readback site would put the wait back on the chip's
+critical path.
 
 Any other jax.device_get(...), .block_until_ready(), or
 np.asarray(device_value) on that path adds a full host round-trip per
