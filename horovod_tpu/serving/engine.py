@@ -276,10 +276,11 @@ class ServeEngine:
         # as the last pass left them for the next one, or None when the
         # host has to write them again: a row joined or left, or the pass
         # was one cohort's of several. ``_unread``: the ONE pass that may
-        # be in flight with its ids not read yet, (ids, [(slot, _Active)]).
-        # ``_joined``: the admissions of the step in progress whose first
-        # token is still to be read, [(slot, _Active, token)]; empty
-        # between steps.
+        # be in flight with its ids not read yet, (ids, [(slot, _Active)],
+        # its launch's number in the step record). ``_joined``: the
+        # admissions of the step in progress whose first token is still to
+        # be read, [(slot, _Active, token, the prefill's launch number)];
+        # empty between steps.
         self._ids = self._put(np.zeros(num_slots, np.int32))
         self._feed = None
         self._unread = None
@@ -719,11 +720,14 @@ class ServeEngine:
             serve_tracing.trace_of(req).on_prefill_start(slot, prompt_len)
             tokens = np.zeros((1, self._pad_len(prompt_len)), np.int32)
             tokens[0, :prompt_len] = req.prompt
-            rng = jax.random.fold_in(self._rng, self._step_count)
+            with rec.launch("_threefry_fold_in"):
+                rng = jax.random.fold_in(self._rng, self._step_count)
             self._step_count += 1
-            tok, row = _prefill_jit(
-                self.cfg, self.params, tokens, np.int32(prompt_len - 1),
-                np.float32(req.temperature), rng)
+            with rec.launch("_prefill_jit") as n:
+                tok, row = _prefill_jit(
+                    self.cfg, self.params, tokens,
+                    np.int32(prompt_len - 1), np.float32(req.temperature),
+                    rng)
             self.kv.ledger.alloc_at(slot, prompt_len,
                                     reserve=self._final_len(req))
             # compile observability: each distinct padded prompt length
@@ -736,15 +740,16 @@ class ServeEngine:
             went_in = kv.arrays
             # the first token joins the device's ids where it is: the
             # next decode pass takes it from there, not from the host
-            kv.arrays, self._ids = _write_slot(
-                went_in, row, np.int32(slot), self._ids, tok)
+            with rec.launch("_write_slot"):
+                kv.arrays, self._ids = _write_slot(
+                    went_in, row, np.int32(slot), self._ids, tok)
             self._feed = None  # a row joined
             if "write_slot" in self._in_place_unchecked:
                 self._note_in_place("write_slot", went_in)
             st = _Active(req, prompt_len, generation=self._generation)
             if req.max_new_tokens > 1:
                 self._active[slot] = st
-            self._joined.append((slot, st, tok))
+            self._joined.append((slot, st, tok, n))
             rec.count("admitted")
             rec.count("prompt_tokens", prompt_len)
             rec.count("state_bytes", self._row_state_bytes)
@@ -763,11 +768,12 @@ class ServeEngine:
         rec = self._rec
         last, n = len(joined) - 1, len(joined) - keep
         self._joined = joined[n:]
-        for i, (slot, st, tok) in enumerate(joined[:n]):
+        for i, (slot, st, tok, launch) in enumerate(joined[:n]):
             with rec.phase("prefill_readback"):
                 # the one sanctioned per-prefill readback: the first token
                 # hvdlint: disable=HVD011(first-token sample is the prefill's output)
                 first = int(jax.device_get(tok))
+            rec.read(launch)
             with rec.phase("bookkeeping"):
                 req = st.request
                 st.generated.append(first)
@@ -901,9 +907,10 @@ class ServeEngine:
             with rec.phase("decode_dispatch"):
                 kv = self.kv
                 went_in = kv.arrays
-                self._ids, positions, kv.arrays = _decode_jit(
-                    self.cfg, self._params_by_gen[gen], self._ids,
-                    positions, went_in, temps, rows, self._rng, count)
+                with rec.launch("_decode_jit") as n:
+                    self._ids, positions, kv.arrays = _decode_jit(
+                        self.cfg, self._params_by_gen[gen], self._ids,
+                        positions, went_in, temps, rows, self._rng, count)
                 if one:  # what the next pass over the same rows feeds on
                     self._feed = (positions, temps, rows)
                 if "decode" in self._in_place_unchecked:
@@ -920,7 +927,7 @@ class ServeEngine:
         self._open_tick()
         # ... and this pass, if anything waits for it
         if launched:
-            self._unread = (self._ids, launched)
+            self._unread = (self._ids, launched, n)
             if self._due(launched, len(gens)):
                 self._read_unread()
             else:
@@ -957,12 +964,13 @@ class ServeEngine:
         if self._unread is None:
             return
         rec = self._rec
-        (ids, launched), self._unread = self._unread, None
+        (ids, launched, n), self._unread = self._unread, None
         with rec.phase("decode_readback"):
             # the one sanctioned readback per pass (during a swap
             # transition one for the cohorts' passes together): the ids
             # hvdlint: disable=HVD011(the per-step batched token readback)
             ids = np.asarray(jax.device_get(ids))
+        rec.read(n)
         self._close_tick()
         with rec.phase("bookkeeping"):
             now = self._clock()
@@ -994,8 +1002,8 @@ class ServeEngine:
         if tick is None or not tick.open:
             return
         with self._rec.phase("telemetry"):
-            tick_us = serve_tracing.finish_tick(tick, len(self._active),
-                                                self._slow_tick_us)
+            tick_us = serve_tracing.finish_tick(
+                tick, len(self._active), self._slow_tick_us, self._rec)
             for st in self._active.values():
                 serve_tracing.trace_of(st.request).on_decode_tick(tick_us)
 

@@ -41,7 +41,15 @@ phases on the same clock, each also a ``jax.profiler.TraceAnnotation``
 (``hvd.serve.<phase>`` under ``hvd.serve.step``) so that a profile shows
 them on the device's timeline, kept as ONE record a step in the
 tracer's step ring. A ``decode_tick`` span carries ``step=<seq>``, so a
-request's trace names the steps it rode.
+request's trace names the steps it rode. Beside the phases the record
+holds the step's ``launches``, one entry a dispatch of a device program
+where it is made (a number that runs on across steps, the program's name
+as a profile shows it, the host's call), and its ``reads``, one entry a
+read-back naming the launch whose result it waited for: which program a
+phase launched and which pass a ``decode_readback`` read are said, not
+guessed, and the time the host KNEW the chip had nothing queued (every
+launch read back, the next not yet made) can be added up from a flight
+dump's ``steps`` alone (docs/tracing.md).
 
 Default ON; ``HVD_SERVE_TRACE=0`` (or ``HVD_TRACE=0``) reduces every
 call here to a shared null object; the engine reads the switch once a
@@ -52,6 +60,8 @@ This module is the ONE sanctioned place for request timing in
 ``serving/`` — hvdlint HVD014 flags ad-hoc ``time.*`` deltas on
 request objects anywhere else in the package.
 """
+
+import contextlib
 
 from jax.profiler import TraceAnnotation
 
@@ -314,11 +324,14 @@ def slow_tick_us():
     return config.env_float("SERVE_TRACE_SLOW_TICK_MS", 250.0) * 1e3
 
 
-def finish_tick(span, active_slots, slow_us):
+def finish_tick(span, active_slots, slow_us, step):
     """Close a decode-tick span; returns its duration in µs (0 when
     tracing is off) and emits a ``slow_decode_tick`` event past
     ``slow_us`` (HVD_SERVE_TRACE_SLOW_TICK_MS) — the per-step analogue
-    of the tracer's slow_span escalation."""
+    of the tracer's slow_span escalation. ``step``, the record the
+    span came from (``tick_span``), says where the time went: the event
+    carries ``step=<seq>``, ``where=<phase>`` and the program read or
+    launched there (``StepTrace.slowest_since``)."""
     span.close(active=active_slots)
     if span.end_us is None:
         return 0.0
@@ -327,7 +340,8 @@ def finish_tick(span, active_slots, slow_us):
         reg = hvd_metrics.get_registry()
         if reg.enabled:
             reg.event("slow_decode_tick", active=active_slots,
-                      dur_ms=round(dur_us / 1e3, 3))
+                      dur_ms=round(dur_us / 1e3, 3),
+                      **step.slowest_since(span.start_us))
     return dur_us
 
 
@@ -367,8 +381,39 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # stack (models/looped.py), 1 for every other model
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
                "state_rows", "state_bytes", "ahead", "admitted_ahead")
+# Beside the phases a record holds two lists, in the order things happened:
+# launches: [n, program, call_start_us, call_end_us], one entry a dispatch
+# of a device program from the step (``StepTrace.launch``). ``n`` numbers
+# the launches of a tracer's life, across steps, as ``seq`` numbers its
+# steps; ``program`` is the name under which a profile shows the run, less
+# ``jit_`` and the hash (``_decode_jit``, ``_prefill_jit``, ``_write_slot``,
+# ``_threefry_fold_in`` for a prefill's eager key fold: one call, one entry,
+# though a TPU runs a ``convert_element_type`` of the count before it); the
+# two times are the host's call: the chip runs launches in the order of
+# their numbers.
+# reads: [n, start_us, end_us], one entry a read-back (``StepTrace.read``):
+# ``n`` is the launch whose result the ``device_get`` returned, possibly a
+# launch of the step before (``ahead``); the times are those of the
+# ``prefill_readback`` / ``decode_readback`` phase entry the read lies in.
 _STEP_ANNOTATION = "hvd.serve.step"
 _PHASE_ANNOTATIONS = {p: "hvd.serve." + p for p in STEP_PHASES}
+
+
+class _Launch:
+    """One dispatch being made (``StepTrace.launch``): entered it gives the
+    launch's number, left it stamps the end of the host's call."""
+
+    __slots__ = ("_clock", "_entry")
+
+    def __init__(self, clock, entry):
+        self._clock, self._entry = clock, entry
+
+    def __enter__(self):
+        return self._entry[0]
+
+    def __exit__(self, exc_type, exc, tb):
+        self._entry[3] = self._clock.ts_us()
+        return False
 
 
 class StepTrace:
@@ -382,14 +427,18 @@ class StepTrace:
     request); two of one name in a row are one entry. Each phase is also
     a ``hvd.serve.<phase>`` TraceAnnotation and the step a
     ``hvd.serve.step`` one: free with no profile being taken, and under
-    one the same phases on the profiler's clock. ``finish()`` makes the
-    step one record in the tracer's step ring (``Tracer.steps()``, the
-    flight dump's ``steps``).
+    one the same phases on the profiler's clock. ``with step.launch(
+    program) as n:`` goes around ONE dispatch of a device program, inside
+    whatever phase it is made in (two clock reads), and ``step.read(n)``
+    follows the readback phase that returned launch ``n``'s result (no
+    clock read: the phase entry's times). ``finish()`` makes the step one
+    record in the tracer's step ring (``Tracer.steps()``, the flight
+    dump's ``steps``).
     """
 
     __slots__ = ("_tracer", "_clock", "_step_annotation",
                  "_phase_annotation", "_name", "seq", "start_us",
-                 "end_us", "phases", "counts")
+                 "end_us", "phases", "launches", "reads", "counts")
 
     def __init__(self, tracer):
         self._tracer = tracer
@@ -400,6 +449,8 @@ class StepTrace:
         self._phase_annotation = self._name = None
         self.start_us = self.end_us = self._clock.ts_us()
         self.phases = []
+        self.launches = []
+        self.reads = []
         self.counts = dict.fromkeys(STEP_COUNTS, 0)
 
     def phase(self, name):
@@ -423,6 +474,51 @@ class StepTrace:
     def count(self, name, n=1):
         self.counts[name] = self.counts.get(name, 0) + n
 
+    def launch(self, program):
+        """``with step.launch(program) as n:`` around the dispatch of one
+        device program, where it is made; ``n`` is the launch's number,
+        for the read-back that will wait for it."""
+        start = self._clock.ts_us()
+        entry = [self._tracer.next_launch_seq(), program, start, start]
+        self.launches.append(entry)
+        return _Launch(self._clock, entry)
+
+    def read(self, n):
+        """The readback phase that has just closed returned the result of
+        launch ``n`` (``None``: launched with tracing off, not booked)."""
+        if n is not None:
+            _, start, end = self.phases[-1]
+            self.reads.append([n, start, end])
+
+    def slowest_since(self, ts_us):
+        """Where the step's time went since ``ts_us``: ``step`` (its
+        ``seq``), ``where`` (the longest phase entry that ended after
+        ``ts_us``) and, when a read-back or a launch call lies in that
+        entry, ``read`` or ``launch`` (its program)."""
+        out = {"step": self.seq}
+        covered = [p for p in self.phases if p[2] > ts_us]
+        if not covered:
+            return out
+        out["where"], start, end = max(covered, key=lambda p: p[2] - p[1])
+        reads = [r for r in self.reads if start <= r[1] and r[2] <= end]
+        calls = [c for c in self.launches if start <= c[2] and c[3] <= end]
+        if reads:
+            out["read"] = self._program_of(reads[-1][0])
+        elif calls:
+            out["launch"] = max(calls, key=lambda c: c[3] - c[2])[1]
+        return out
+
+    def _program_of(self, n):
+        """The program of launch ``n``: one of this step's, or (a pass
+        read a step later) of the record before it."""
+        before = self._tracer.steps()[-1:]
+        for launches in [self.launches] + [r.get("launches", ())
+                                           for r in before]:
+            for entry in launches:
+                if entry[0] == n:
+                    return entry[1]
+        return None
+
     def tick_span(self, **attrs):
         """The step's one ``decode_tick`` span (the engine-wide lane),
         naming the step record it belongs to."""
@@ -432,7 +528,8 @@ class StepTrace:
     def finish(self):
         self._step_annotation.__exit__(None, None, None)
         rec = {"seq": self.seq, "start_us": self.start_us,
-               "end_us": self.end_us, "phases": self.phases}
+               "end_us": self.end_us, "phases": self.phases,
+               "launches": self.launches, "reads": self.reads}
         rec.update(self.counts)
         self._tracer.record_step(rec)
 
@@ -455,6 +552,12 @@ class _NullStepTrace:
     def count(self, name, n=1):
         pass
 
+    def launch(self, program):
+        return _NULL_LAUNCH
+
+    def read(self, n):
+        pass
+
     def tick_span(self, **attrs):
         return hvd_tracing._NULL_SPAN
 
@@ -463,6 +566,7 @@ class _NullStepTrace:
 
 
 NULL_STEP = _NullStepTrace()
+_NULL_LAUNCH = contextlib.nullcontext()  # entered it gives None: no number
 
 
 def begin_step():

@@ -230,6 +230,7 @@ class Tracer:
         # guarded_by: _lock (serve-engine step ring)
         self._steps = collections.deque(maxlen=STEP_RING)
         self._step_seq = 0  # guarded_by: _lock
+        self._launch_seq = 0  # guarded_by: _lock
         self._open = collections.OrderedDict()  # guarded_by: _lock
         self._last_trace = {}     # guarded_by: _lock; tensor -> trace_id
         self._spans_dropped = 0   # guarded_by: _lock
@@ -340,10 +341,19 @@ class Tracer:
             self._step_seq += 1
             return self._step_seq
 
+    def next_launch_seq(self):
+        """Number one dispatch of a device program from an engine step:
+        the ``n`` of its entry in the step record's ``launches`` and of
+        the ``reads`` entry that waited for it. Runs on across steps."""
+        with self._lock:
+            self._launch_seq += 1
+            return self._launch_seq
+
     def record_step(self, rec):
         """Append one finished engine step (serving/tracing.py
-        ``StepTrace.finish``: seq, start_us, end_us, phases, counts) to
-        the step ring; the oldest falls out past ``STEP_RING``."""
+        ``StepTrace.finish``: seq, start_us, end_us, phases, launches,
+        reads, counts) to the step ring; the oldest falls out past
+        ``STEP_RING``."""
         with self._lock:
             self._steps.append(rec)
         return rec

@@ -401,7 +401,7 @@ class TestStepTrace:
         (rec,) = stepping.steps()[warm:]
         # kv_bytes, passes: on a step that decoded, and on no other (below)
         assert set(rec) == {"seq", "start_us", "end_us", "phases",
-                            "kv_bytes", "passes",
+                            "launches", "reads", "kv_bytes", "passes",
                             *serve_tracing.STEP_COUNTS}
         assert rec["passes"] == 1   # a stack that runs once
         assert rec["seq"] == warm + 1 and _tiles(rec)
@@ -414,14 +414,17 @@ class TestStepTrace:
                                               "bookkeeping"]
         assert [p[0] for p in rec["phases"]] == ["control"] + 2 * launch + [
             "decode_prepare", "decode_dispatch"] + 2 * read + ["telemetry"]
-        # nothing but the phase's own closing read inside these
+        # nothing but the phase's own closing read inside these, and in
+        # a dispatch the two reads around its one launch besides
         tick = SteppingUsClock.TICK
 
         def bare(rec):
             for name, start, end in rec["phases"]:
-                if name in ("control", "decode_dispatch", "decode_readback",
+                if name in ("control", "decode_readback",
                             "prefill_readback"):
                     assert end - start == tick, name
+                elif name == "decode_dispatch":
+                    assert end - start == 3 * tick
         bare(rec)
         # the counts are what the scheduler did in that step
         assert rec["admitted"] == len(joins) == 2
@@ -524,6 +527,36 @@ class TestStepTrace:
         assert set(made) == {"hvd.serve.step"} | {
             "hvd.serve." + p[0] for r in recs for p in r["phases"]}
 
+    def test_tracing_off_carries_no_launch_number_and_the_same_tokens(
+            self, stepping, monkeypatch):
+        cfg, params = _tiny()
+
+        def serve():
+            engine = _engine(cfg, params, seed=3)
+            engine.submit(Request("g", (3, 1, 4), max_new_tokens=5))
+            engine.submit(Request("s", (2, 7, 1, 8), max_new_tokens=4,
+                                  temperature=0.9))
+            return engine, {r.request_id: r.tokens
+                            for r in engine.run_to_completion()}
+        _, on = serve()
+        recorded = len(stepping.steps())
+        assert recorded and all(r["launches"] for r in stepping.steps())
+        monkeypatch.setenv("HVD_SERVE_TRACE", "0")
+        engine, off = serve()
+        assert off == on and len(stepping.steps()) == recorded
+        # what the null step hands out is no number: a pass launched with
+        # the switch off and read with it on books no read
+        engine.submit(Request("x", (3, 1, 4), max_new_tokens=4))
+        engine.submit(Request("y", (3, 1, 4), max_new_tokens=4))
+        engine.step()
+        assert engine._unread[2] is None
+        monkeypatch.setenv("HVD_SERVE_TRACE", "1")
+        engine.step()
+        rec = stepping.steps()[-1]
+        assert [lc[1] for lc in rec["launches"]] == ["_decode_jit"]
+        assert "decode_readback" in [p[0] for p in rec["phases"]]
+        assert rec["reads"] == []
+
     def test_a_step_that_raises_still_closes_its_record(
             self, stepping, monkeypatch):
         from horovod_tpu.serving import engine as engine_mod
@@ -540,6 +573,10 @@ class TestStepTrace:
         assert [p[0] for p in rec["phases"]] == ["control", "admit",
                                                  "prefill"]
         assert _tiles(rec) and engine._rec is serve_tracing.NULL_STEP
+        # the call that raised is in the ledger, and it was left
+        assert [lc[1] for lc in rec["launches"]] == [FOLD, PREFILL]
+        assert all(c0 < c1 for _, _, c0, c1 in rec["launches"])
+        assert rec["reads"] == []
 
     def test_a_profile_shows_the_step_and_its_phases_on_the_host_line(
             self, reg, tmp_path):
@@ -572,6 +609,160 @@ class TestStepTrace:
         for name, start, end in events:
             if name != "hvd.serve.step":
                 assert any(s <= start and end <= e for _, s, e in steps)
+
+
+# ---------------------------------------------------------------------------
+# the launch ledger: every dispatch where it is made, every read-back naming
+# the launch it waited for
+# ---------------------------------------------------------------------------
+
+FOLD, PREFILL, WRITE, DECODE = ("_threefry_fold_in", "_prefill_jit",
+                                "_write_slot", "_decode_jit")
+ADMISSION = [FOLD, PREFILL, WRITE]
+
+
+def _inside(rec, t0, t1):
+    """The name of the phase entry that [t0, t1] lies in."""
+    (name,) = [n for n, s, e in rec["phases"] if s <= t0 and t1 <= e]
+    return name
+
+
+def _warm(engine, n=1):
+    for i in range(n):
+        engine.submit(Request(f"warm{i}", (9, 9, 9), max_new_tokens=2))
+    engine.run_to_completion()
+
+
+class TestLaunchLedger:
+    def test_every_jitted_call_of_a_step_is_one_entry_and_numbers_run_on(
+            self, stepping, monkeypatch):
+        """Counted against the calls themselves, over a run that admits,
+        decodes ahead, retires and admits again."""
+        import jax
+        from horovod_tpu.serving import engine as engine_mod
+        calls = []
+
+        def counted(name, fn):
+            def call(*a, **kw):
+                # the eager fold alone: the programs fold a traced count
+                if name != FOLD or isinstance(a[1], int):
+                    calls.append(name)
+                return fn(*a, **kw)
+            return call
+        for name, attr in ((PREFILL, "_prefill_jit"), (WRITE, "_write_slot"),
+                           (DECODE, "_decode_jit")):
+            monkeypatch.setattr(engine_mod, attr,
+                                counted(name, getattr(engine_mod, attr)))
+        monkeypatch.setattr(jax.random, "fold_in",
+                            counted(FOLD, jax.random.fold_in))
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        for i, new in enumerate((4, 3, 2, 5)):
+            engine.submit(Request(f"r{i}", (3, 1, 4, 1 + i),
+                                  max_new_tokens=new))
+        done = engine.run_to_completion()
+        assert len(done) == 4
+        recs = stepping.steps()
+        flat = [lc for r in recs for lc in r["launches"]]
+        assert [lc[1] for lc in flat] == calls and DECODE in calls
+        assert [lc[0] for lc in flat] == list(range(1, len(flat) + 1))
+        for r in recs:
+            for n, program, c0, c1 in r["launches"]:
+                assert c0 < c1 and _inside(r, c0, c1) == (
+                    "decode_dispatch" if program == DECODE else "prefill")
+        # every read names a launch made before it, of the right kind: a
+        # first token its prefill, a pass's ids its decode launch; every
+        # prefill and every pass is read exactly once, no slot write ever
+        program = {lc[0]: lc[1] for lc in flat}
+        read = [rd for r in recs for rd in r["reads"]]
+        assert sorted(rd[0] for rd in read) == sorted(
+            n for n, p in program.items() if p in (PREFILL, DECODE))
+        for r in recs:
+            for n, start, end in r["reads"]:
+                assert [n, start, end][1:] in [p[1:] for p in r["phases"]]
+                assert _inside(r, start, end) == (
+                    "prefill_readback" if program[n] == PREFILL
+                    else "decode_readback")
+                c1 = next(lc[3] for lc in flat if lc[0] == n)
+                assert c1 <= start
+
+    def test_a_step_that_admits_two_launches_all_seven_then_reads_the_two(
+            self, stepping):
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        _warm(engine)
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=4))
+        engine.submit(Request("b", (2, 7, 1, 8, 2), max_new_tokens=3))
+        engine.step()
+        rec = stepping.steps()[-1]
+        assert [lc[1] for lc in rec["launches"]] == 2 * ADMISSION + [DECODE]
+        first = rec["launches"][0][0]
+        assert [lc[0] for lc in rec["launches"]] == \
+            list(range(first, first + 7))
+        # the two prefills, in admission order; the pass is in flight
+        assert [rd[0] for rd in rec["reads"]] == [first + 1, first + 4]
+        assert rec["ahead"] == 1
+        readbacks = [p[1:] for p in rec["phases"]
+                     if p[0] == "prefill_readback"]
+        assert [rd[1:] for rd in rec["reads"]] == readbacks
+        # ... and is the first thing the next step reads, after its own
+        # launch; b is on its last token, so that pass is read as well
+        engine.step()
+        nxt = stepping.steps()[-1]
+        assert [lc[:2] for lc in nxt["launches"]] == [[first + 7, DECODE]]
+        assert [rd[0] for rd in nxt["reads"]] == [first + 6, first + 7]
+        assert nxt["reads"][0][1] >= nxt["launches"][0][3]
+
+    def test_a_third_admission_reads_the_oldest_unread_first(self, stepping):
+        cfg, params = _tiny()
+        engine = _engine(cfg, params, num_slots=4)
+        _warm(engine)
+        for rid in "abc":
+            engine.submit(Request(rid, (3, 1, 4), max_new_tokens=4))
+        engine.step()
+        rec = stepping.steps()[-1]
+        assert [lc[1] for lc in rec["launches"]] == 3 * ADMISSION + [DECODE]
+        first = rec["launches"][0][0]
+        assert [rd[0] for rd in rec["reads"]][:3] == \
+            [first + 1, first + 4, first + 7]
+        # a's token is read with b's prefill queued behind it and before
+        # c's key is folded; b's and c's after the decode launch
+        a, b, c = rec["reads"][:3]
+        assert rec["launches"][5][3] <= a[1] and \
+            a[2] <= rec["launches"][6][2]
+        assert rec["launches"][9][3] <= b[1] <= c[1]
+        # a free slot: the pass is read before the step returns
+        assert rec["ahead"] == 0 and rec["reads"][3][0] == first + 9
+
+    @pytest.mark.parametrize("slow,where,key", [
+        ("device_get", "decode_readback", "read"),
+        ("_decode_jit", "decode_dispatch", "launch")])
+    def test_a_slow_tick_says_which_step_and_where(
+            self, reg, monkeypatch, slow, where, key):
+        import jax
+        from horovod_tpu.serving import engine as engine_mod
+        monkeypatch.setenv("HVD_SERVE_TRACE_SLOW_TICK_MS", "30")
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        _warm(engine)
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=3))
+        engine.step()   # admits; its tick opens after the first token
+        target = engine_mod if slow == "_decode_jit" else jax
+        real = getattr(target, slow)
+
+        def delayed(*a, **kw):
+            time.sleep(0.05)
+            return real(*a, **kw)
+        monkeypatch.setattr(target, slow, delayed)
+        engine.step()
+        monkeypatch.setattr(target, slow, real)
+        engine.run_to_completion()
+        (event,) = _events(reg.snapshot(), "slow_decode_tick")
+        rec = hvd_tracing.get_tracer().steps()[-2]
+        assert event["step"] == rec["seq"] and event["where"] == where
+        assert event[key] == DECODE and event["dur_ms"] >= 50
+        assert event["where"] == max(
+            rec["phases"], key=lambda p: p[2] - p[1])[0]
 
 
 # ---------------------------------------------------------------------------
