@@ -59,9 +59,9 @@ class TransformerConfig:
     attention_impl: str = "full"
     # Forward accumulation variant of the flash kernel ('auto' | 'online'
     # | 'lazy' | 'twopass' — ops/flash_attention.VARIANTS; only read when
-    # attention_impl routes through the flash kernel). 'auto' applies the
-    # heuristic in resolve_variant; HVD_FLASH_VARIANT overrides
-    # either way (the A/B hook S3's timing needs).
+    # attention_impl routes through the flash kernel). 'auto' is the
+    # fastest measured (resolve_variant: online); HVD_FLASH_VARIANT
+    # overrides either way (the A/B hook).
     flash_variant: str = "auto"
     # Mixture-of-Experts: num_experts > 0 replaces the dense MLP with
     # models/moe.py's expert layer (experts shard over the 'ep' mesh axis).

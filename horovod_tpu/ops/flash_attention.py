@@ -1,19 +1,23 @@
 """Fused (flash) attention as a Pallas TPU kernel — forward and backward.
 
 The hot op of the flagship transformer. XLA's default attention
-materializes the [s, s] logits in HBM; this kernel keeps K/V in HBM and
-streams block_k-sized tiles into double-buffered VMEM scratch with async
-DMA, maintaining an online-softmax accumulator — HBM traffic is O(s·d),
-VMEM residency is O(block·d) regardless of sequence length:
+materializes the [s, s] logits in HBM; this kernel walks block_k-sized
+tiles of K/V, maintaining an online-softmax accumulator — HBM traffic is
+O(s·d):
 
   * logits tiles computed with ``jnp.dot(..., preferred_element_type=
     fp32)`` → MXU at full precision for the softmax math
   * block sizes default to 512 (measured fastest on v5e; see
-    flash_attention's docstring); the lane dim is head_dim
+    ``call_block``); the lane dim is head_dim
   * causal masking per tile from broadcasted iotas, and the K-block loop
     stops at the diagonal (dynamic fori bound), skipping the ~half of
     tiles that are fully in the future
-  * DMA for tile t+1 issues before compute on tile t (double buffering)
+  * the forward holds one head's whole K and V in VMEM where they fit
+    (``kv_resident``: the pipeline fetches the next head's behind this
+    one's tiles); past that, and in the backward, K/V stay in HBM and
+    tiles stream into double-buffered VMEM scratch with async DMA, tile
+    t+1 issued before compute on tile t, so VMEM residency is O(block·d)
+    regardless of sequence length
 
 Backward is the standard flash-attention recomputation scheme, also as
 Pallas kernels: the forward additionally writes the per-row log-sum-exp
@@ -29,6 +33,7 @@ virtual mesh), selected automatically; a TPU never gets it.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -43,27 +48,35 @@ _LN2 = 0.6931471805599453
 
 #: Forward accumulation variants (the backward kernels are shared — every
 #: variant writes the same natural-log lse residual):
-#:   online  — the classic per-tile rescale chain (r5 kernel)
+#:   online  — the per-tile rescale chain; what 'auto' runs (measured
+#:             fastest, resolve_variant)
 #:   lazy    — deferred rescale: running max + un-normalized accumulator,
 #:             the [block_q, d] correction runs only on tiles that raise
 #:             the max (diagonal-first k order so it stabilizes early)
 #:   twopass — pass 1 computes the row max (matmul + rowmax only), pass 2
 #:             re-computes QK^T and accumulates exp2(s−m)@V with NO
 #:             loop-carried correction at all
+#: lazy and twopass are opt-in and lost on the chip (ROADMAP D8 deletes
+#: them with the option).
 VARIANTS = ("online", "lazy", "twopass")
 
 
 def resolve_variant(variant, causal=True, nk=1):
     """Resolve 'auto' (and the HVD_FLASH_VARIANT env override, which wins
-    over any explicit argument — the A/B hook) to a concrete
-    forward variant. The heuristic is reasoned, not measured (all three
-    variants compile and match the reference on the v5e; none has been
-    timed — ROADMAP S3): lazy whenever the k loop has ≥2 tiles (its gated
-    rescale degrades to exactly the online chain in the worst case and
-    skips the [block_q, d] correction otherwise); online for the 1-tile
-    degenerate loop where there is nothing to defer; twopass stays
-    opt-in — its extra QK^T pass can only pay off where the VPU chain
-    dominates the MXU."""
+    over any explicit argument: the A/B hook) to a concrete forward
+    variant. ``causal`` and ``nk`` (k tiles a row) are what the call can
+    see and a choice would be made from; today's table gives one answer
+    for all of them. Measured (PR 41, v5e, causal bf16 ``[bh, s, dh]`` =
+    ``[64, 4096, 128]`` at 512 x 512 blocks, nk = 8; kernel ms a step and
+    share of the compute roofline; docs/benchmarks.md has the whole
+    table): online 2.22 ms, 62.8% (3.31 ms, 42.2% before its rewrite);
+    twopass 4.65 ms, 30.0%; lazy 7.99 ms, 17.4%. So ``auto`` is online
+    at every ``nk``: at nk = 1 (most serving prefills) there is nothing to
+    defer or to pass over twice, and from nk = 2 on the other two pay for
+    what they save (lazy: a relayout of its 1-D statistics and a
+    vector-to-scalar branch every tile; twopass: half as many matmuls
+    again). Until PR 41 ``auto`` took lazy for nk >= 2 by reasoning, with
+    nothing timed: it was the slowest of the three."""
     env = os.environ.get("HVD_FLASH_VARIANT", "").strip().lower()
     if env:
         variant = env
@@ -72,7 +85,7 @@ def resolve_variant(variant, causal=True, nk=1):
             f"unknown flash variant {variant!r}; expected one of "
             f"{VARIANTS + ('auto',)}")
     if variant == "auto":
-        return "lazy" if nk >= 2 else "online"
+        return "online"
     return variant
 
 
@@ -136,37 +149,73 @@ def _wait_all(streams, slot, i):
         s(slot, i).wait()
 
 
-def _fwd_kernel(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q, block_k,
-                seq_k, causal, scale):
-    """Online-softmax forward. The inner loop is deliberately VPU-lean —
-    the softmax chain, not the matmuls, is the measured bottleneck at
-    head_dim 64/128: it runs in the exp2 domain with log2(e) folded into
-    the scalar logit scale (one exp2 pass per tile, no hidden ln2
-    multiplies); lse converts back to natural log once at the end (the
-    external contract — parallel/ring.py merges in natural-log units).
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
+                seq_k, causal, scale, kv_resident):
+    """Online-softmax forward: what ``auto`` runs at every shape.
 
-    Dead ends a past builder measured on a v5e (b8 s1024 h12 d64,
-    per-kernel chained-loop timing): folding the softmax scale into q;
-    lax.cond-skipping the causal mask on fully-visible tiles; carrying
-    the row-sum in a planted ones-lane of v's head-dim padding (the MXU
-    computes l for free but the end-of-loop lane extract costs more than
-    the per-tile VPU reduction it saves, +25%); a manual 1-deep software
-    pipeline of the next tile's logits matmul against the current tile's
-    softmax (the [block_q, block_k] fp32 logits carry spills, +50%); and
-    the stock jax.experimental pallas flash kernel's grid-over-kv design
-    (2.7x slower end-to-end at this shape). Straight-line + fori_loop
-    with double-buffered manual DMA is the fastest form found.
+    One k tile is two whole-tile matmuls with the softmax chain between
+    them, in the exp2 domain with log2(e) folded into the scalar logit
+    scale; lse converts back to natural log once at the end (the
+    external contract: parallel/ring.py merges in natural-log units).
+    ``k_ref`` / ``v_ref`` are one head's whole K and V in VMEM
+    (``kv_resident``) or the HBM arrays, streamed tile by tile.
+
+    Measured on a v5e at the training cell's shape, causal bf16
+    [bh, s, dh] = [64, 4096, 128], 512 x 512 blocks (PR 41;
+    docs/benchmarks.md, "Flash-kernel lessons", has the table): 2.22 ms a
+    step, 62.8% of the compute roofline, where the MXU's own time is
+    1.57 ms (1,024 cycles a tile: each of the four takes 512 rows of q
+    and 512 of p, one row a cycle; 36 tiles a head). What a tile costs
+    beyond that is vector loads and stores: the [512, 512] float32 logits
+    are 256 vector registers, four register files, so every pass over
+    them goes through VMEM, and that is not to be had cheaper. What WAS
+    to be had, in the order it paid:
+
+      * m, l and the accumulator live in VMEM scratch, read where they
+        are used and written once a tile. Carried as loop values (this
+        kernel until PR 41) their 192 registers were copied through
+        spill slots at both ends of every iteration: 390 of 1,468
+        bundles a tile in which the MXU stood still (3.31 -> 2.84 ms).
+      * m and l are kept REPLICATED over the lanes, ``[block_q, 128]``:
+        a lane reduction leaves its result on every lane, so they are
+        stored as they fall and broadcast against the tile by naming the
+        same registers again (``jnp.tile``): no relayout. A 1-D
+        ``[block_q]`` statistic written to scratch (the lazy kernel) is
+        re-laid from sublanes to lanes and back on every tile: 1,172
+        ``vperm.slane`` and 1,630 stores in 2,980 bundles, 7.99 ms.
+      * K/V of the whole head in VMEM (2.84 -> 2.22 ms): streamed, each
+        q block's first tile is waited for with nothing to hide it
+        behind, about 1 us, 512 times a step. A third buffer does not
+        help (2.85 ms) and the waits inside the loop are only 0.07 ms.
+
+    Dead ends at this shape, counted in bundles of the compiled loop body
+    (1,190 a tile streamed, 1,131 resident) and the first timed on the
+    chip: masking only the diagonal tile in a second loop (1,189 and
+    1,177 bundles; 2.847 ms beside 2.843: compare and select ride in
+    free slots of an MXU-bound body, and the code doubles); the chain
+    run per block of 64-256 rows (each block pushes the MXU's weights
+    again: 1,508-2,299); the logits written to a scratch of their own
+    and read back in row blocks (1,986-2,159); ``[block_q, 1]``
+    statistics (1,968: a lane broadcast at every use); the first tile
+    peeled to skip the zeroing (-4.5% of the bundles for a second copy
+    of the body: not taken). A past builder's, from ``b8 s1024 h12 d64``
+    and not measured again: folding the softmax scale into q; the row
+    sum carried in a planted ones-lane of v's head-dim padding (at
+    ``dh`` 128 there is no padding lane); a manual 1-deep software
+    pipeline of the next tile's logits; the stock jax.experimental
+    pallas flash kernel's grid-over-kv design.
     """
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     d = q_ref.shape[-1]
     # matmul operands stay in the input dtype (bf16 runs the MXU at full
-    # rate; fp32 would quarter it on v5e) — accumulation is fp32 via
+    # rate; fp32 would quarter it on v5e): accumulation is fp32 via
     # preferred_element_type, softmax statistics are fp32 throughout.
     q = q_ref[0]                                # [block_q, d]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
     scale2 = scale * _LOG2E                     # logits in log2 units
+    # lanes the row statistics are replicated over: 128 compiled (blocks
+    # and head_dim are multiples of it), what divides both interpreted
+    lanes = math.gcd(128, block_k, d)
 
     nk_total = seq_k // block_k
     if causal:
@@ -177,13 +226,68 @@ def _fwd_kernel(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q, block_k,
     else:
         nk = nk_total
 
-    def scoped(k_scr, v_scr, sem_k, sem_v):
-        streams = [_stream(k_hbm, bh, block_k, k_scr, sem_k),
-                   _stream(v_hbm, bh, block_k, v_scr, sem_v)]
+    def attend(tile, m_scr, l_scr, acc_scr):
+        """The k loop; ``tile(kb)`` hands over k and v of tile kb."""
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        def body(kb, _):
+            k, v = tile(kb)
+            s = jnp.dot(q, k.T,
+                        preferred_element_type=jnp.float32) * scale2
+            if causal:
+                # both iotas made here: hoisted, the [block_q, block_k]
+                # positions are 256 registers read back every tile
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+            m = m_scr[...]                      # [block_q, lanes]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp2(m - m_new)
+            p = jnp.exp2(s - jnp.tile(m_new, (1, block_k // lanes)))
+            m_scr[...] = m_new
+            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            acc_scr[...] = acc_scr[...] * jnp.tile(alpha, (1, d // lanes)) \
+                + jnp.dot(p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+            return 0
+
+        jax.lax.fori_loop(0, nk, body, 0)
+        l = jnp.clip(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / jnp.tile(l, (1, d // lanes))).astype(
+            o_ref.dtype)
+        # per-row log-sum-exp in NATURAL log (the backward's softmax
+        # residual and ring.py's merge contract), replicated over an
+        # 8-row sublane dim to satisfy the TPU (8, 128) tile rule: every
+        # row of the transpose is the statistic, rows along the lanes
+        lse = (m_scr[...] + jnp.log2(l)) * _LN2
+        lse_ref[0] = jnp.broadcast_to(lse.T[:1], (8, block_q))
+
+    stats = dict(m_scr=pltpu.VMEM((block_q, lanes), jnp.float32),
+                 l_scr=pltpu.VMEM((block_q, lanes), jnp.float32),
+                 acc_scr=pltpu.VMEM((block_q, d), jnp.float32))
+
+    if kv_resident:
+        # k_ref / v_ref are this head's whole K and V, in VMEM
+        def whole(m_scr, l_scr, acc_scr):
+            def tile(kb):
+                rows = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+                return k_ref[0, rows, :], v_ref[0, rows, :]
+            attend(tile, m_scr, l_scr, acc_scr)
+
+        pl.run_scoped(whole, **stats)
+        return
+
+    def streamed(k_scr, v_scr, sem_k, sem_v, m_scr, l_scr, acc_scr):
+        streams = [_stream(k_ref, bh, block_k, k_scr, sem_k),
+                   _stream(v_ref, bh, block_k, v_scr, sem_v)]
         _start_all(streams, 0, 0)
 
-        def body(kb, carry):
-            m, l, acc = carry
+        def tile(kb):
             slot = kb % 2
 
             @pl.when(kb + 1 < nk)
@@ -191,40 +295,16 @@ def _fwd_kernel(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q, block_k,
                 _start_all(streams, (kb + 1) % 2, kb + 1)
 
             _wait_all(streams, slot, kb)
-            k = k_scr[slot]
-            v = v_scr[slot]
-            s = jnp.dot(q, k.T,
-                        preferred_element_type=jnp.float32) * scale2
-            if causal:
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp2(s - m_new[:, None])
-            alpha = jnp.exp2(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1)
-            acc = acc * alpha[:, None] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            return m_new, l, acc
+            return k_scr[slot], v_scr[slot]
 
-        init = (jnp.full((block_q,), _NEG_INF, jnp.float32),
-                jnp.zeros((block_q,), jnp.float32),
-                jnp.zeros((block_q, d), jnp.float32))
-        m, l, acc = jax.lax.fori_loop(0, nk, body, init)
-        l = jnp.clip(l, 1e-30)
-        o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-        # per-row log-sum-exp in NATURAL log (the backward's softmax
-        # residual and ring.py's merge contract), replicated over an
-        # 8-row sublane dim to satisfy the TPU (8, 128) tile rule
-        lse_ref[0] = jnp.broadcast_to(
-            ((m + jnp.log2(l)) * _LN2)[None, :], (8, m.shape[0]))
+        attend(tile, m_scr, l_scr, acc_scr)
 
     pl.run_scoped(
-        scoped,
-        k_scr=pltpu.VMEM((2, block_k, d), k_hbm.dtype),
-        v_scr=pltpu.VMEM((2, block_k, d), v_hbm.dtype),
+        streamed,
+        k_scr=pltpu.VMEM((2, block_k, d), k_ref.dtype),
+        v_scr=pltpu.VMEM((2, block_k, d), v_ref.dtype),
         sem_k=pltpu.SemaphoreType.DMA((2,)),
-        sem_v=pltpu.SemaphoreType.DMA((2,)))
+        sem_v=pltpu.SemaphoreType.DMA((2,)), **stats)
 
 
 def _fwd_kernel_lazy(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q,
@@ -243,7 +323,13 @@ def _fwd_kernel_lazy(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q,
     to exactly the online chain, gated once per tile, never to less
     numerical care: a skipped rescale means every alpha was exactly 1.
     Same lse contract as _fwd_kernel (natural log, 8-sublane replicated),
-    so the backward kernels are shared unchanged."""
+    so the backward kernels are shared unchanged.
+
+    Measured (PR 41, v5e, [64, 4096, 128]): 7.99 ms a step, 17.4% of the
+    roofline, the slowest of the three: the 1-D statistics are re-laid
+    on their way into and out of the ``[2, block_q]`` scratch on every
+    tile, and the gate is a vector-to-scalar reduction feeding a branch.
+    Opt-in since then (``resolve_variant``)."""
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     d = q_ref.shape[-1]
@@ -330,7 +416,12 @@ def _fwd_kernel_twopass(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q,
     tile (+50% forward MXU work) and K streamed twice (HBM traffic still
     O(s·d)); the bet is shapes where the VPU softmax chain, not the MXU,
     is the bottleneck. Numerics: m is exact (not running), so p ≤ 1
-    always; same lse contract, shared backward."""
+    always; same lse contract, shared backward.
+
+    Measured (PR 41, v5e, [64, 4096, 128]): 4.65 ms a step, 30.0% of the
+    roofline: the forward is bound by the MXU once its chain is out of
+    the way, so half as many matmuls again cost what they look like.
+    Opt-in (``resolve_variant``)."""
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     d = q_ref.shape[-1]
@@ -411,6 +502,26 @@ def _fwd_kernel_twopass(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q,
         sem_v=pltpu.SemaphoreType.DMA((2,)))
 
 
+#: Bytes of VMEM the online forward may fill with one head's K and V, each
+#: held twice (the pipeline fetches the next head's while this one's are
+#: read): 8 MiB is what fits beside the kernel's own buffers under the
+#: default 16 MiB limit (compiled for a described v5e: bf16 heads of 128
+#: at 8,192 keys fit, at 16,384 they do not).
+_KV_RESIDENT_BYTES = 8 << 20
+
+
+def kv_resident(sk, d, dtype):
+    """Whether the online forward holds a head's whole K and V in VMEM
+    (handed over by the pipeline, fetched once a head) or streams them
+    tile by tile: from the shapes alone. Streamed, every q block's first
+    tile is waited for with nothing to hide it behind, about 1 us on a
+    v5e: 512 times a step at the training cell's shape, 2.84 ms where
+    the resident kernel takes 2.22 (docs/benchmarks.md). Past the budget
+    (long-context and ring shards) the stream keeps VMEM use independent
+    of the sequence length."""
+    return 4 * sk * d * jnp.dtype(dtype).itemsize <= _KV_RESIDENT_BYTES
+
+
 _FWD_KERNELS = {"online": _fwd_kernel, "lazy": _fwd_kernel_lazy,
                 "twopass": _fwd_kernel_twopass}
 
@@ -444,20 +555,26 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, scale=None,
         kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
         vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
 
+    # K/V stay in HBM and the kernel DMAs block_k tiles into
+    # double-buffered VMEM scratch, so VMEM use is independent of sequence
+    # length; the online forward takes a head's whole K/V where they fit
+    kv_spec = pl.BlockSpec(memory_space=pl.ANY)
+    where = {}
+    if variant == "online":
+        where["kv_resident"] = kv_resident(sk, d, k.dtype)
+        if where["kv_resident"]:
+            kv_spec = pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0))
     kernel = functools.partial(_FWD_KERNELS[variant], block_q=block_q,
                                block_k=block_k, seq_k=sk, causal=causal,
-                               scale=scale)
+                               scale=scale, **where)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, sq // block_q),
         compiler_params=_COMPILER_PARAMS,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            # K/V stay in HBM; the kernel DMAs block_k tiles into
-            # double-buffered VMEM scratch, so VMEM use is independent of
-            # sequence length
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -719,6 +836,22 @@ def fit_block(block, s):
     return b
 
 
+def call_block(block, s, compiled=True):
+    """The block one ``flash_attention`` call runs a sequence of ``s``
+    with: ``fit_block``, and compiled never a block that is no multiple
+    of 128. Mosaic lays the [bh, 8, s] row statistics (lse, delta) out in
+    128-lane tiles and refuses to slice a block out of them that is not a
+    multiple ("Slice shape along dimension 2 must be aligned to tiling
+    (128)" from the dK/dV kernel at s = 16, 24, 112, 200): compiled, such
+    a sequence takes 128-blocks and end-padding. Serving prefill lengths
+    land here. Forward and backward share the blocks: 512 x 512 is the
+    fastest forward measured at ``[64, 4096, 128]`` (docs/benchmarks.md:
+    1024 x 512 2.52 ms against 2.22, 512 x 1024 and 256 x 512 behind it),
+    as it is the backward's."""
+    b = fit_block(block, s)
+    return b if not compiled or b % 128 == 0 else 128
+
+
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, causal, block_q, block_k, interpret, scale,
@@ -740,8 +873,9 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     positions. Numerically equivalent to parallel.ring.full_attention
     (exact softmax, fp32 accumulation), in forward and backward, with
     O(s·d) memory in both. Default 512-blocks measured fastest on v5e
-    (b8 s1024 h12 d64, 12 layers fwd+bwd: 34.7 ms at 512 vs 76.8 ms at
-    128; XLA full attention 49.4 ms).
+    for the forward at the training cell's shape (PR 41, ``call_block``;
+    a past builder read the same for forward and backward together at
+    ``b8 s1024 h12 d64``).
 
     Sequence lengths need not divide the block sizes for causal
     self-attention (sq == sk): inputs are end-padded to the next block
@@ -753,11 +887,11 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     drop out of every dot product).
 
     ``variant`` selects the forward accumulation scheme (VARIANTS:
-    'online' | 'lazy' | 'twopass', or 'auto' — see resolve_variant; the
-    HVD_FLASH_VARIANT env var overrides all of them, which is the A/B
-    hook). All variants compute the exact same softmax and write the
-    same lse residual, so the backward kernels are shared and gradients
-    are variant-independent."""
+    'online' | 'lazy' | 'twopass', or 'auto', which is 'online', the
+    fastest measured: see resolve_variant; the HVD_FLASH_VARIANT env var
+    overrides all of them, which is the A/B hook). All variants compute
+    the exact same softmax and write the same lse residual, so the
+    backward kernels are shared and gradients are variant-independent."""
     if layout not in ("bshd", "bhsd"):
         raise ValueError(f"unknown layout {layout!r}")
     seq_axis = 2 if layout == "bhsd" else 1
@@ -766,16 +900,7 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     scale = d ** -0.5
     interpret_eff = interpret if interpret is not None else _auto_interpret()
 
-    def fit(block, s):
-        b = fit_block(block, s)
-        # Mosaic lays the [bh, 8, s] row statistics (lse, delta) out in
-        # 128-lane tiles and refuses to slice a block out of them that
-        # is not a multiple ("Slice shape along dimension 2 must be
-        # aligned to tiling (128)" from the dK/dV kernel at s = 16, 24,
-        # 112, 200): compiled, such a sequence takes 128-blocks and the
-        # end-padding below. Serving prefill lengths land here.
-        return b if interpret_eff or b % 128 == 0 else 128
-
+    fit = functools.partial(call_block, compiled=not interpret_eff)
     bq, bk = fit(block_q, sq), fit(block_k, sk)
     bq2 = fit(block_q_dkv, sq) if block_q_dkv else None
     bk2 = fit(block_k_dkv, sk) if block_k_dkv else None

@@ -114,6 +114,107 @@ class TestVariantNumerics:
                                    rtol=2e-5, atol=2e-5)
 
 
+def _ref_lse(q, k, causal):
+    """Natural-log row log-sum-exp of the scaled (masked) logits,
+    [b, h, s]: what the forward hands the backward and ring.py."""
+    import jax
+    import jax.numpy as jnp
+    s = jnp.einsum("bqhd,bkhd->bhqk", jnp.asarray(q, jnp.float32),
+                   jnp.asarray(k, jnp.float32)) * (q.shape[-1] ** -0.5)
+    if causal:
+        sq, sk = q.shape[1], k.shape[1]
+        s = jnp.where(jnp.asarray(np.tril(np.ones((sq, sk), bool),
+                                          k=sk - sq)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
+class TestAutoForwardAtHeadDim128:
+    """What ``auto`` runs (PR 41: the online chain with lane-replicated
+    statistics in VMEM scratch) at the head width the cells run, 128,
+    with two or more k tiles a row: the statistics are then replicated
+    over all 128 lanes and rescaled between tiles, as compiled."""
+
+    @pytest.mark.parametrize("kv", ["resident", "streamed"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_out_and_lse(self, hvd, monkeypatch, dtype, causal, kv):
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        if kv == "streamed":  # what rows past the VMEM budget take
+            monkeypatch.setattr(fa, "_KV_RESIDENT_BYTES", 0)
+        assert fa.kv_resident(384, 128, dtype) is (kv == "resident")
+        q, k, v = _qkv(11, b=1, s=384, h=2, d=128,
+                       dtype=getattr(jnp, dtype))
+        variant = fa.resolve_variant("auto", causal=causal, nk=3)
+        out, lse = fa._flash_fwd(q, k, v, causal, 128, 128, True,
+                                 variant=variant)
+        assert out.dtype == q.dtype and lse.dtype == jnp.float32
+        assert lse.shape == (2, 8, 384)
+        rtol, atol = _TOL[dtype]
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32),
+            np.asarray(_ref_attention(q, k, v, causal), np.float32),
+            rtol=rtol, atol=atol)
+        want = np.asarray(_ref_lse(q, k, causal)).reshape(2, 1, 384)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.broadcast_to(want, (2, 8, 384)),
+            rtol=rtol, atol=atol)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_grad_matches_reference(self, hvd, dtype, causal):
+        import jax
+        import jax.numpy as jnp
+        from horovod_tpu.ops.flash_attention import flash_attention
+        q, k, v = _qkv(12, b=1, s=256, h=2, d=128,
+                       dtype=getattr(jnp, dtype))
+
+        g = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128).astype(
+                jnp.float32) ** 2), argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(lambda q, k, v: jnp.sum(
+            _ref_attention(q, k, v, causal=causal) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+        tol = {"float32": 1e-4, "bfloat16": 6e-2}[dtype]
+        for a, b in zip(g, g_ref):
+            assert a.dtype == q.dtype
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       rtol=tol, atol=tol)
+
+    def test_rising_max(self, hvd):
+        """Every later k tile raises the row max: the rescale of l and of
+        the accumulator runs with alpha < 1 on every tile."""
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        q, k, v = _qkv(13, b=1, s=512, h=1, d=128)
+        ramp = jnp.linspace(0.5, 8.0, 512)[None, :, None, None]
+        k = (k * ramp).astype(k.dtype)
+        out, lse = fa._flash_fwd(q, k, v, False, 128, 128, True,
+                                 variant=fa.resolve_variant("auto", nk=4))
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_ref_attention(q, k, v, False)),
+            rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(lse[:, 0]),
+            np.asarray(_ref_lse(q, k, False)).reshape(1, 512),
+            rtol=2e-5, atol=2e-5)
+
+    def test_ragged_tail(self, hvd):
+        """300 positions on 128-blocks: end-padded to 384, three k tiles
+        on the last q block, 84 padded keys the mask has to discard."""
+        import jax.numpy as jnp
+        from horovod_tpu.ops.flash_attention import flash_attention
+        q, k, v = _qkv(14, b=1, s=300, h=2, d=128, dtype=jnp.bfloat16)
+        out = flash_attention(q, k, v, causal=True, block_q=128,
+                              block_k=128)
+        assert out.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32),
+            np.asarray(_ref_attention(q, k, v, True), np.float32),
+            rtol=5e-2, atol=5e-2)
+
+
 class TestVariantGradients:
     @pytest.mark.parametrize("variant", VARIANTS)
     def test_grad_matches_reference(self, hvd, variant):
@@ -157,8 +258,46 @@ class TestVariantSelection:
     def test_auto_heuristic(self, hvd):
         from horovod_tpu.ops.flash_attention import resolve_variant
         assert resolve_variant("auto", nk=1) == "online"
-        assert resolve_variant("auto", nk=2) == "lazy"
-        assert resolve_variant("auto", nk=4) == "lazy"
+        assert resolve_variant("auto", nk=2) == "online"
+        assert resolve_variant("auto", causal=False, nk=4) == "online"
+
+    @pytest.mark.parametrize("s, block, nk", [
+        (4096, 512, 8),    # the training cell: [64, 4096, 128]
+        (128, 128, 1),     # serving prefill, the padded lengths
+        (256, 256, 1),
+        (512, 512, 1),
+        (640, 128, 5),     # 512 does not divide it: fit_block halves
+        (896, 128, 7),
+        (1024, 512, 2),
+    ])
+    def test_what_a_call_runs_with(self, hvd, s, block, nk):
+        """``auto``, the blocks and where K/V are read from are pure
+        functions of the call's shapes (PR 41): at the training shape
+        and at every prefill length the online forward on a head's
+        whole K/V in VMEM, at the default 512-blocks or what fits."""
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        assert fa.call_block(512, s) == block
+        assert -(-s // block) == nk
+        assert fa.resolve_variant("auto", causal=True, nk=nk) == "online"
+        assert fa.kv_resident(s, 128, jnp.bfloat16)
+
+    @pytest.mark.parametrize("s, dtype, resident", [
+        (8192, "bfloat16", True), (16384, "bfloat16", False),
+        (4096, "float32", True), (8192, "float32", False)])
+    def test_long_rows_are_streamed(self, hvd, s, dtype, resident):
+        """Past 8 MiB of K/V buffers a head (two operands, held twice)
+        the forward streams tiles, and VMEM use stays independent of the
+        sequence length."""
+        from horovod_tpu.ops import flash_attention as fa
+        assert fa.kv_resident(s, 128, dtype) is resident
+
+    def test_compiled_blocks_are_multiples_of_128(self, hvd):
+        from horovod_tpu.ops import flash_attention as fa
+        assert fa.call_block(512, 200) == 128
+        assert fa.call_block(512, 200, compiled=False) == 200
+        assert fa.call_block(512, 384) == 384
+        assert fa.call_block(256, 4096) == 256
 
     def test_unknown_raises(self, hvd):
         from horovod_tpu.ops.flash_attention import resolve_variant
@@ -177,7 +316,7 @@ class TestVariantSelection:
     def test_env_empty_is_ignored(self, hvd, monkeypatch):
         from horovod_tpu.ops.flash_attention import resolve_variant
         monkeypatch.setenv("HVD_FLASH_VARIANT", "")
-        assert resolve_variant("auto", nk=4) == "lazy"
+        assert resolve_variant("auto", nk=4) == "online"
 
     def test_transformer_config_plumbs_variant(self, hvd):
         """cfg.flash_variant reaches the kernel: a model pinned to each
