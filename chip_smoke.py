@@ -316,11 +316,12 @@ def _served_model(cfg):
     ``seq`` at the positions ``at`` — TransformerLM.apply, for a
     model with a recurrent mixer its prefill (the chunked scan, no cache),
     once for each position, and for a looped stack its plain forward over
-    every pass."""
+    every pass, for latent attention with experts its plain (expanded)
+    forward."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.models import hybrid, looped
+    from horovod_tpu.models import hybrid, latent_moe, looped
     from horovod_tpu.models import transformer as tr
 
     ref_cfg = dataclasses.replace(cfg, attention_impl="full")
@@ -332,6 +333,10 @@ def _served_model(cfg):
         params = looped.init_params(cfg, jax.random.PRNGKey(0))
         rows = jax.jit(lambda p, seq, at: looped.forward(
             ref_cfg, p, seq[None])[0][0, at].astype(jnp.float32))
+    elif isinstance(cfg, latent_moe.LatentMoEConfig):
+        params = latent_moe.init_params(cfg, jax.random.PRNGKey(0))
+        rows = jax.jit(lambda p, seq, at: latent_moe.forward(
+            ref_cfg, p, seq[None])[0][0, at].astype(jnp.float32))
     else:
         _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
         ref_model = tr.TransformerLM(ref_cfg)
@@ -340,16 +345,43 @@ def _served_model(cfg):
     return jax.device_put(params, jax.devices()[0]), rows
 
 
+def decode_attention_selected(cfg, slots, max_len):
+    """Which decode attention this backend runs over a cache of ``slots``
+    x ``max_len``: the positional kinds the model declares, and whether
+    the Mosaic kernel that reads them by length and in place was selected
+    (ops/flash_attention.py decides from the call; False on the CPU, over
+    a mesh, and for a cache whose tiles it cannot read as they lie)."""
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.serving import decode as serve_decode
+    shapes = serve_decode.state_shapes(cfg, slots, max_len)
+    kinds = [k for k in serve_decode.positional_kinds(cfg) if k in shapes]
+    if kinds == ["latent"]:
+        kernel = fa._latent_kernel_selected(shapes["latent"].shape,
+                                            cfg.kv_rank)
+    else:
+        kernel = fa._decode_kernel_selected(shapes["k"].shape, None)
+    return {"kinds": kinds, "kernel": bool(kernel)}
+
+
 def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
               lengths=(5, 16, 100, 513, 1000, 7, 33, 250, 640, 90, 12, 400),
-              tie_tol=SERVE_TIE_TOL, name="gpt2_small_tpu"):
+              tie_tol=SERVE_TIE_TOL, name="gpt2_small_tpu",
+              routed_elsewhere=(0.0, 0.0)):
     """Two passes of the same seeded requests through ServeEngine — the
     first pays every compile under a patient queue, the second runs warm
     behind the default admission queue — then a teacher-forced check:
     each served token must be the plain forward's argmax given the tokens
     before it (so a plain greedy decode yields the same sequence) or tie
     with it within ``tie_tol``. ``cfg`` is a TransformerConfig, a
-    HybridConfig or a LoopedConfig (``_served_model``)."""
+    HybridConfig, a LoopedConfig or a LatentMoEConfig (``_served_model``).
+    The line says which decode attention ran (``decode_attention``).
+    ``routed_elsewhere`` (share, deficit): routing is discrete, and two
+    bfloat16 paths of one model with experts (the expanded plain forward,
+    the served prefill and absorbed decode) round a token's router scores
+    differently, so at a near tie they give it another expert; at most
+    ``share`` of the served tokens may miss the plain forward's choice by
+    more than ``tie_tol``, none by more than ``deficit``. (0, 0) for a
+    model without experts."""
     import jax.numpy as jnp
 
     from horovod_tpu.serving import engine as engine_mod
@@ -419,6 +451,7 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     most = max(r.max_new_tokens for r in reqs)
     exact = ties = total = 0
     worst = 0.0
+    flip_share, flip_tol = routed_elsewhere
     for r in reqs:
         served = cold[r.request_id].tokens
         seq = np.zeros(max_len, np.int32)
@@ -434,15 +467,18 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
             exact += int(tok == int(row.argmax()))
             ties += int(tok != int(row.argmax()) and deficit <= tie_tol)
             worst = max(worst, deficit)
-    _check(exact + ties == total,
-           f"{total - exact - ties} of {total} served tokens are not the "
-           f"reference's greedy choice (worst logit deficit {worst:.4g} > "
-           f"{tie_tol})")
+    missed = total - exact - ties
+    _check(missed <= flip_share * total and
+           (not missed or worst <= flip_tol),
+           f"{missed} of {total} served tokens are not the reference's "
+           f"greedy choice (worst logit deficit {worst:.4g} > {tie_tol}; "
+           f"allowed: {flip_share:.1%} of them, each within {flip_tol})")
     emit("serve", model=name, layers=cfg.num_layers,
          slots=slots, max_len=max_len, kv_block=kv_block,
+         decode_attention=decode_attention_selected(cfg, slots, max_len),
          requests=len(reqs), prompt_lengths=list(lengths),
          new_tokens=[r.max_new_tokens for r in reqs], tokens=total,
-         greedy_exact=exact, greedy_ties=ties,
+         greedy_exact=exact, greedy_ties=ties, greedy_missed=missed,
          worst_logit_deficit=round(worst, 5), tie_tol=tie_tol,
          prefill_compiles=compiled[0], decode_compiles=compiled[1],
          kv_in_place=kv_in_place, steps_ahead=steps_ahead,
@@ -666,7 +702,7 @@ def main(argv=None):
               f"mode", file=sys.stderr)
         return 1
 
-    from horovod_tpu.models import hybrid, looped
+    from horovod_tpu.models import hybrid, latent_moe, looped
     from horovod_tpu.models import transformer as tr
     from horovod_tpu.utils import compile_cache
     cache_dir = compile_cache.configure()
@@ -706,6 +742,22 @@ def main(argv=None):
             vocab_size=4096, num_layers=2, num_heads=4, d_model=512,
             d_ff=1024, passes=4, rope_theta=1e6, max_seq_len=1024,
             attention_impl="flash"), kv_block=128, name="looped_small")
+        # latent attention (ONE latent kind in the cache, 128 + 64 numbers
+        # in 256 lanes) and dropless experts (8 of which 2, one shared,
+        # behind a dense first layer), at widths its decode kernel, the
+        # flash kernel and the grouped product take
+        leg_serve(latent_moe.LatentMoEConfig(
+            vocab_size=4096, num_layers=3, d_model=512, num_heads=4,
+            q_rank=128, kv_rank=128, nope_dim=64, rope_dim=64, v_dim=128,
+            rope_theta=1e6, d_ff=1024, first_dense=1, num_experts=8,
+            experts_per_tok=2, shared_experts=1, d_expert=256,
+            route_scale=1.8, max_seq_len=1024, attention_impl="flash"),
+            kv_block=128, name="latent_moe_small",
+            # 8 experts of which 2: one token routed elsewhere is half its
+            # routed output; the v5e misses 4 of 500 tokens, the worst by
+            # 0.675 (PR 42, the same on every run: the requests are seeded):
+            # held to that reading, a fifth token or a wider miss fails
+            routed_elsewhere=(0.01, 0.8))
     if "four_chips" in legs:
         if jax.device_count() >= 4:
             leg_four_chips(train_cfg, 16, 1024, first_loss)

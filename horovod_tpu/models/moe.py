@@ -15,6 +15,14 @@ the GShard/Switch pattern, not a per-device gather/scatter runtime:
   * A Switch-style load-balance auxiliary loss is exposed via
     ``sow('losses', 'moe_aux_loss', ...)``; training steps can pull it from
     the mutable collection and add ``aux_weight *`` it to the task loss.
+
+Serving has another contract, and another layer for it at the end of this
+module: ``route`` and ``experts``, plain functions over parameter leaves
+(models/latent_moe.py calls them in its prefill and its decode). That
+layer is DROPLESS: there is no capacity, every token gets every expert it
+chose, and an expert that no token chose is not computed and its weights
+are not read (tokens sorted by expert, one grouped product over the
+experts routed to). ``MoEMLP`` above stays what the training path runs.
 """
 
 from typing import Optional
@@ -101,3 +109,68 @@ def aux_loss_from(mutables, weight=0.01):
     for leaf in jax.tree_util.tree_leaves(losses):
         total = total + jnp.sum(leaf)
     return weight * total
+
+
+# -- the dropless layer that serving runs ------------------------------------
+
+def route(y, w_router, bias, k, scale=1.0, normalise=True):
+    """Which ``k`` experts each token takes, and with what weight.
+
+    y [..., d]; w_router [d, E]; bias [E] or None. Scores are sigmoids of
+    float32 logits. ``bias`` SELECTS and never weighs (the aux-loss-free
+    balancing of DeepSeek-V3's ``noaux_tc``): the top ``k`` of ``score +
+    bias`` are taken, and their weights are the scores alone, divided by
+    their sum if ``normalise``, times ``scale``.
+
+    Returns (idx [..., k] int32, weights [..., k] float32)."""
+    logits = jnp.dot(y, w_router.astype(y.dtype),
+                     preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    chosen = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(chosen, k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalise:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights * scale
+
+
+def experts(y, idx, weights, gate, up, down, mask=None):
+    """``sum_j weights[t, j] * Expert_idx[t, j](y[t])`` for every token, no
+    token dropped: there is no capacity.
+
+    y [t, d]; idx, weights [t, k] (``route``); gate, up [E, d, f] and down
+    [E, f, d], the experts' SwiGLU stacked. The ``t * k`` assignments are
+    sorted by expert, so each expert's tokens lie together, and the three
+    products are GROUPED (``jax.lax.ragged_dot``: group e is expert e's
+    rows against expert e's matrix): an expert with no row costs no
+    product and no read of its weights beyond what the product's tiling
+    forces, and no expert is computed for a token under a mask. The
+    outputs go back to the tokens' order and are summed under their
+    weights in float32.
+
+    ``mask`` [t] bool: a token outside it (a slot that does not decode, a
+    prompt's padding) is routed to NO expert (its assignments sort behind
+    the last group, which a grouped product leaves alone) and gets zeros.
+
+    Returns (out [t, d] in y's dtype, load [E] int32: the assignments each
+    expert got)."""
+    t, k = idx.shape
+    num = gate.shape[0]
+    flat = idx.reshape(t * k)
+    if mask is not None:
+        flat = jnp.where(jnp.repeat(mask, k), flat, num)
+    order = jnp.argsort(flat, stable=True)
+    load = jnp.sum(flat[:, None] == jnp.arange(num, dtype=flat.dtype),
+                   axis=0, dtype=jnp.int32)
+    rows = jnp.take(y, order // k, axis=0)               # [t * k, d]
+    hidden = jax.nn.silu(jax.lax.ragged_dot(rows, gate, load)) \
+        * jax.lax.ragged_dot(rows, up, load)
+    out = jax.lax.ragged_dot(hidden, down, load)         # [t * k, d]
+    # back to the tokens' order: assignment a sits at row inverse[a]
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=order.dtype))
+    out = jnp.take(out, inverse, axis=0).reshape(t, k, -1)
+    if mask is not None:  # rows behind the last group hold what they held
+        out = jnp.where(mask[:, None, None], out, 0)
+    out = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)
+    return out.astype(y.dtype), load

@@ -1223,3 +1223,176 @@ def decode_attention(q, k, v, lengths, scale=None, head_sharding=None,
     out = jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)[:, None]
+
+
+# -- single-query attention over a LATENT cache (models/latent_moe.py) --------
+
+def _latent_kernel_selected(cache_shape, value_dim):
+    """Whether ``latent_decode_attention`` over a cache of ``cache_shape``
+    ``[planes, batch, s_max, 1, lanes]`` runs as the Mosaic kernel: the
+    conditions of ``_decode_kernel_selected`` for ONE key head whose first
+    ``value_dim`` lanes are the value, both in whole lane tiles (Mosaic
+    refuses to slice a block ``[128, 576]`` out of HBM: "must be aligned
+    to tiling (128)")."""
+    if not _on_one_tpu_chip():
+        return False
+    _, _, s_max, hk, lanes = cache_shape
+    return hk == 1 and s_max % DECODE_BLOCK == 0 and \
+        lanes % 128 == 0 and value_dim % 128 == 0
+
+
+def _latent_decode_kernel(plane_ref, total_ref, row_ref, blk_ref, len_ref,
+                          q_ref, c_hbm, o_ref, c_scr, sem, m_scr, l_scr,
+                          acc_scr, *, block, value_dim, scale):
+    """``_decode_kernel`` for a latent cache: the same loop over the LIVE
+    blocks, row after row, but ONE stream. A block ``[block, lanes]`` is
+    read from HBM once and used twice: all of it is the key, and its first
+    ``value_dim`` lanes are the value. One key head: no head mask, only
+    the length's."""
+    plane = plane_ref[0]
+    total = total_ref[0]
+    nbuf = c_scr.shape[0]
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def copy(i, buf):
+        start = pl.multiple_of(blk_ref[i] * block, block)
+        return pltpu.make_async_copy(
+            c_hbm.at[plane, row_ref[i], pl.ds(start, block), :],
+            c_scr.at[buf], sem.at[buf])
+
+    for j in range(nbuf - 1):
+        @pl.when(j < total)
+        def _prime():
+            copy(j, j).start()
+
+    def body(i, _):
+        buf = i % nbuf
+
+        @pl.when(i + nbuf - 1 < total)
+        def _prefetch():  # into the buffer item i - 1 has finished with
+            copy(i + nbuf - 1, (i + nbuf - 1) % nbuf).start()
+
+        row = row_ref[i]
+        live = len_ref[row] - blk_ref[i] * block
+
+        @pl.when(blk_ref[i] == 0)
+        def _begin_row():
+            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        copy(i, buf).wait()
+
+        @pl.when(live < block)
+        def _hide_the_tail():  # 0 x NaN, as in ``_decode_kernel``
+            pos = jax.lax.broadcasted_iota(jnp.int32, c_scr.shape[1:], 0)
+            c_scr[buf] = jnp.where(
+                pos < live, c_scr[buf].astype(jnp.float32),
+                0.0).astype(c_scr.dtype)
+
+        s = jax.lax.dot_general(q_ref[row], c_scr[buf],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < live, s * (scale * _LOG2E), _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp2(m_prev - m_new)
+        p = jnp.exp2(s - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            p.astype(c_scr.dtype), c_scr[buf, :, :value_dim],
+            preferred_element_type=jnp.float32)
+
+        @pl.when(live <= block)
+        def _end_row():
+            o_ref[row] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                          ).astype(o_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, total, body, 0)
+
+
+def _latent_decode_attention_kernel(q, cache, lengths, plane, value_dim,
+                                    scale):
+    planes, b, s_max, _, lanes = cache.shape
+    h = q.shape[1]
+    block = decode_block(s_max)
+    rows = -(-h // 16) * 16  # query heads, padded to a bf16 tile's sublanes
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, s_max)
+    # the work list of ``_decode_attention_kernel``
+    ends = jnp.cumsum((lengths + block - 1) // block)
+    item = jnp.arange(b * (s_max // block), dtype=jnp.int32)
+    before = ends[None, :] <= item[:, None]
+    row_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    blk_of = item - jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, block=block,
+                          value_dim=value_dim, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, rows, value_dim), q.dtype),
+        in_specs=[smem] * 5 + [vmem, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((_DECODE_BUFFERS, block, lanes), cache.dtype),
+            pltpu.SemaphoreType.DMA((_DECODE_BUFFERS,)),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, value_dim), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="latent_decode_attention",
+        interpret=_auto_interpret(),
+    )(jnp.asarray(plane, jnp.int32).reshape(1), ends[-1:], row_of, blk_of,
+      lengths, jnp.pad(q, ((0, 0), (0, rows - h), (0, 0))),
+      cache.reshape(planes, b, s_max, lanes))
+    return out[:, :h]
+
+
+def latent_decode_attention(q, cache, lengths, plane, value_dim,
+                            scale=None):
+    """Single-query attention over a LATENT cache: every query head over
+    ONE key head whose first ``value_dim`` lanes are also the value (the
+    absorbed form of latent attention, models/latent_moe.py).
+
+    q        [batch, heads, lanes]: the absorbed query, laid out as the
+             cache's entries are (zeros where those hold padding)
+    cache    the WHOLE cache [planes, batch, s_max, 1, lanes]; only the
+             first ``lengths[b]`` positions of row b in plane ``plane``
+             are real
+    lengths  [batch] int32; a row of length 0 attends to nothing and its
+             output means nothing
+    scale    the softmax scale (default ``lanes ** -0.5``; a model whose
+             heads had another width before the absorption passes its own)
+
+    Returns [batch, heads, value_dim]: ``sum_t p_t c_t``, still in the
+    latent's space. Two implementations of one contract, as
+    ``decode_attention``: on one TPU chip a Mosaic kernel that takes the
+    lengths as data and streams each row's blocks of ``DECODE_BLOCK``
+    positions of the plane ONCE, in place, and none above its length;
+    elsewhere an einsum over the whole plane under a length mask
+    (``_latent_kernel_selected`` decides from the call; there is no
+    option). fp32 softmax, products in the cache's dtype with fp32
+    accumulation."""
+    if q.ndim != 3 or cache.ndim != 5 or cache.shape[3] != 1 or \
+            q.shape[2] != cache.shape[4]:
+        raise ValueError(f"latent_decode_attention wants q [b, h, lanes] "
+                         f"and a cache [planes, b, s, 1, lanes], got "
+                         f"{q.shape} and {cache.shape}")
+    s_max, lanes = cache.shape[2], cache.shape[4]
+    scale = lanes ** -0.5 if scale is None else scale
+    if _latent_kernel_selected(cache.shape, value_dim):
+        return _latent_decode_attention_kernel(q, cache, lengths, plane,
+                                               value_dim, scale)
+    latent = cache[plane, :, :, 0]                       # [b, s, lanes]
+    logits = jnp.einsum("bhw,bsw->bhs", q, latent,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(s_max, dtype=jnp.int32)[None, None, :] \
+        < lengths.astype(jnp.int32)[:, None, None]
+    p = jax.nn.softmax(jnp.where(valid, logits, _NEG_INF), axis=-1)
+    out = jnp.einsum("bhs,bsv->bhv", p.astype(cache.dtype),
+                     latent[..., :value_dim],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)

@@ -15,9 +15,9 @@ asserting logits equality and token-for-token greedy agreement against
 ``TransformerLM.apply``.
 
 A model of another kind (models/hybrid.py: a recurrent mixer beside
-grouped-query attention) brings its own two forwards; ``state_shapes``,
-``prefill`` and ``decode`` at the end of this module are what the engine
-and the cache call, and they find the model by its configuration's type.
+grouped-query attention; models/latent_moe.py: latent attention, dropless
+experts) brings its own two forwards; ``state_shapes``, ``prefill`` and
+``decode`` at the end are what the engine and the cache call, by type.
 A model whose layer stack runs several times over one set of weights
 (models/looped.py) is served by THIS module: ``_stack`` is the dense block
 of ``prefill_forward`` / ``decode_step`` with a norm closing each branch,
@@ -33,15 +33,14 @@ Attention: prefill uses the model's own dispatch (flash kernel on TPU,
 exact full attention on CPU); decode uses ops/flash_attention.py's
 ``decode_attention`` (q_len=1 against the cache, per-row lengths as data
 — jit-stable as rows join/retire; on one TPU chip a kernel that reads
-each row's live blocks and no more, elsewhere an einsum over the whole
-row under a length mask).
+each row's live blocks, elsewhere an einsum under a length mask).
 """
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid, looped
+from ..models import hybrid, latent_moe, looped
 from ..models.transformer import _dispatch_attention, _rope
 from ..ops.flash_attention import decode_attention
 from ..parallel import mesh as mesh_lib
@@ -52,8 +51,9 @@ def _dense(x, kernel, dtype):
                     dtype=dtype).apply({"params": {"kernel": kernel}}, x)
 
 
-def _rmsnorm(x, scale, dtype):
-    return nn.RMSNorm(dtype=dtype).apply({"params": {"scale": scale}}, x)
+def _rmsnorm(x, scale, dtype, eps=1e-6):
+    norm = nn.RMSNorm(epsilon=eps, dtype=dtype)
+    return norm.apply({"params": {"scale": scale}}, x)
 
 
 def _embed(cfg, params, tokens):
@@ -76,8 +76,8 @@ def _logits(cfg, params, x):
 def _check_dense(cfg):
     if cfg.num_experts > 0:
         raise NotImplementedError(
-            "serving supports dense configs only (num_experts=0); the "
-            "MoE expert dispatch has no cached decode path yet")
+            "a TransformerConfig is served dense (num_experts=0): experts "
+            "are served by models/latent_moe.py (LatentMoEConfig), dropless")
 
 
 def _check_served(cfg):
@@ -292,8 +292,8 @@ def looped_decode_step(cfg, params, tokens, positions, kv_k, kv_v, mask=None):
 
 # -- what the engine and the cache call, for any model ------------------------
 
-def _is_hybrid(cfg):
-    return isinstance(cfg, hybrid.HybridConfig)
+def _own(cfg):  # the module of a model that brings its own forwards
+    return _OWN_FORWARDS.get(type(cfg))
 
 
 def state_shapes(cfg, num_slots, max_len):
@@ -302,8 +302,8 @@ def state_shapes(cfg, num_slots, max_len):
     plane is a layer, and for a stack that runs several times a (pass,
     layer): ``passes(cfg) x layers`` planes of K/V over ``layers``
     weights."""
-    if _is_hybrid(cfg):
-        return hybrid.state_shapes(cfg, num_slots, max_len)
+    if _own(cfg):
+        return _own(cfg).state_shapes(cfg, num_slots, max_len)
     _check_served(cfg)
     kv = jax.ShapeDtypeStruct(
         (passes(cfg) * cfg.num_layers, num_slots, max_len, cfg.num_heads,
@@ -318,8 +318,8 @@ def prefill(cfg, params, tokens, last_index):
     STANDS AFTER THE LAST REAL TOKEN (``last_index``; the prompt is
     right-padded). For K/V that is the whole padded prefix — the length
     mask hides the pad; a recurrent kind must not have seen the pad."""
-    if _is_hybrid(cfg):
-        return hybrid.prefill(cfg, params, tokens, last_index)
+    if _own(cfg):
+        return _own(cfg).prefill(cfg, params, tokens, last_index)
     if _is_looped(cfg):
         # every pass over the padded prompt; the head on the one row
         hidden, ks, vs = hidden_states(cfg, params, tokens)
@@ -334,10 +334,10 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     """(logits [b, vocab], state): every kind of ``state`` advanced by
     one token for the rows in ``mask`` ([b] bool, the pass's cohort;
     None: every row). A row outside the mask keeps its recurrent kinds
-    bit for bit, parks its K/V write where ``positions`` says, and
-    attends to nothing."""
-    if _is_hybrid(cfg):
-        return hybrid.decode(cfg, params, tokens, positions, state, mask)
+    bit for bit, parks its positional write where ``positions`` says, and
+    attends to nothing. A model with experts: a third, ``ROUTED_COUNTS``."""
+    if _own(cfg):
+        return _own(cfg).decode(cfg, params, tokens, positions, state, mask)
     if _is_looped(cfg):
         logits, k, v = looped_decode_step(cfg, params, tokens, positions,
                                           state["k"], state["v"], mask)
@@ -345,3 +345,24 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     logits, k, v = decode_step(cfg, params, tokens, positions, state["k"],
                                state["v"], mask)
     return logits, {"k": k, "v": v}
+
+
+_OWN_FORWARDS = {hybrid.HybridConfig: hybrid,
+                 latent_moe.LatentMoEConfig: latent_moe}
+
+
+def positional_kinds(cfg):
+    """The kinds of ``state_shapes`` that hold one entry a POSITION of a
+    row: masked by the row's length, so a row that a pass does not decode
+    may park its write at the row's end, and counted by the step record's
+    ``kv_bytes``. Every other kind is recurrent: one state a row, which a
+    pass must leave bit for bit for the rows it does not decode. ``k`` and
+    ``v``, or what a model of its own declares (``POSITIONAL``)."""
+    return getattr(_own(cfg), "POSITIONAL", ("k", "v"))
+
+
+#: what a model with experts returns from ``decode`` as a third result, an
+#: int32 vector read back with the pass's ids: the (layer, expert) pairs
+#: that a decoding row was routed to, summed over the expert layers, and
+#: the most assignments any one expert got
+ROUTED_COUNTS = ("experts_touched", "expert_tokens_max")

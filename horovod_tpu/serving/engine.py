@@ -98,7 +98,7 @@ from ..utils import memory as hvd_memory
 from ..utils import metrics as hvd_metrics
 from ..utils import tracing as hvd_tracing
 from . import tracing as serve_tracing
-from .decode import decode, passes, prefill
+from .decode import ROUTED_COUNTS, decode, passes, prefill
 from .kv_cache import KVCache
 from .queue import AdmissionQueue, RequestResult
 from .sampling import sample_tokens
@@ -134,13 +134,13 @@ def _decode_jit(cfg, params, tokens, positions, state, temps, rows, key,
     folded here from the engine's ``key`` and the host's step ``count``
     (the same bits as ``fold_in`` on the host, without its dispatch).
     ``rows`` is the model's mask: a recurrent state of a row outside it
-    is kept bit for bit; K/V need no such care for their writes (the host
-    parks the other rows' positions at max_len - 1), but attention reads
-    nothing of a row outside it (ops/flash_attention.decode_attention)."""
-    logits, state = decode(cfg, params, tokens, positions, state, rows)
+    is kept bit for bit; K/V need no such care (the host parks the other
+    rows' positions at max_len - 1), but attention reads nothing of such a
+    row. ``more``: [] or, of a model with experts, [decode.ROUTED_COUNTS]."""
+    logits, state, *more = decode(cfg, params, tokens, positions, state, rows)
     rng = jax.random.fold_in(key, count)
     ids = jnp.where(rows, sample_tokens(rng, logits, temps), tokens)
-    return ids, positions + rows.astype(positions.dtype), state
+    return ids, positions + rows.astype(positions.dtype), state, more
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -277,10 +277,10 @@ class ServeEngine:
         # host has to write them again: a row joined or left, or the pass
         # was one cohort's of several. ``_unread``: the ONE pass that may
         # be in flight with its ids not read yet, (ids, [(slot, _Active)],
-        # its launch's number in the step record). ``_joined``: the
-        # admissions of the step in progress whose first token is still to
-        # be read, [(slot, _Active, token, the prefill's launch number)];
-        # empty between steps.
+        # its launch's number in the step record, what a model with experts
+        # counted in it). ``_joined``: the admissions of the step in progress
+        # whose first token is still to be read, [(slot, _Active, token, the
+        # prefill's launch number)]; empty between steps.
         self._ids = self._put(np.zeros(num_slots, np.int32))
         self._feed = None
         self._unread = None
@@ -335,9 +335,9 @@ class ServeEngine:
         state_bytes = reg.gauge(
             "hvd_serve_state_bytes",
             "Bytes of per-slot serving state resident on one chip, by "
-            "kind (k, v; ssm and conv where the model has a recurrent "
-            "mixer), over all its planes (one a layer, times the passes of "
-            "a looped stack).", labels=("kind",))
+            "kind (k, v, or the one latent of latent attention; ssm and "
+            "conv of a recurrent mixer), over all its planes (one a layer, "
+            "times the passes of a looped stack).", labels=("kind",))
         for kind, nbytes in self.kv.bytes_by_kind().items():
             state_bytes.labels(kind=kind).set(nbytes)
         # bytes of recurrent state one row holds over all layers: what a
@@ -872,7 +872,7 @@ class ServeEngine:
                         self._retire(slot, "failed", reason="kv_exhausted")
         if self._compiles("decode"):  # this engine's first pass, likewise
             self._read_first_tokens()
-        launched = []
+        launched, routed = [], []
         one = len(gens) == 1
         # what one cohort of several left is of no use to this pass
         feed, self._feed = self._feed if one else None, None
@@ -908,9 +908,10 @@ class ServeEngine:
                 kv = self.kv
                 went_in = kv.arrays
                 with rec.launch("_decode_jit") as n:
-                    self._ids, positions, kv.arrays = _decode_jit(
+                    self._ids, positions, kv.arrays, more = _decode_jit(
                         self.cfg, self._params_by_gen[gen], self._ids,
                         positions, went_in, temps, rows, self._rng, count)
+                routed += more  # what a model with experts counted
                 if one:  # what the next pass over the same rows feeds on
                     self._feed = (positions, temps, rows)
                 if "decode" in self._in_place_unchecked:
@@ -927,7 +928,7 @@ class ServeEngine:
         self._open_tick()
         # ... and this pass, if anything waits for it
         if launched:
-            self._unread = (self._ids, launched, n)
+            self._unread = (self._ids, launched, n, routed)
             if self._due(launched, len(gens)):
                 self._read_unread()
             else:
@@ -964,13 +965,19 @@ class ServeEngine:
         if self._unread is None:
             return
         rec = self._rec
-        (ids, launched, n), self._unread = self._unread, None
+        (ids, launched, n, routed), self._unread = self._unread, None
         with rec.phase("decode_readback"):
             # the one sanctioned readback per pass (during a swap
-            # transition one for the cohorts' passes together): the ids
+            # transition one for the cohorts' passes together): the ids,
+            # and WITH them what a model with experts counted in the pass
+            # (``routed``: empty for every other model)
             # hvdlint: disable=HVD011(the per-step batched token readback)
-            ids = np.asarray(jax.device_get(ids))
+            ids, routed = jax.device_get((ids, routed))
+            ids = np.asarray(ids)
         rec.read(n)
+        for counts in routed:  # in the record of the step that READS them
+            for name, value in zip(ROUTED_COUNTS, counts):
+                rec.peak(name, int(value))
         self._close_tick()
         with rec.phase("bookkeeping"):
             now = self._clock()

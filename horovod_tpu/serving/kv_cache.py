@@ -11,7 +11,10 @@ Two halves, deliberately separated:
     (serving/decode.state_shapes), every kind [planes, slots, ...]:
     dense, preallocated [planes, slots, max_len, heads, head_dim] K and
     V, and for a model with a recurrent mixer its state and convolution
-    window beside them — one ledger, one slot index, one lifetime. A
+    window beside them — one ledger, one slot index, one lifetime. The
+    model also declares which kinds are POSITIONAL (one entry a position,
+    hidden by the row's length: K and V, or the one latent of
+    models/latent_moe.py); every other kind is RECURRENT. A
     PLANE is one layer's state; a stack that runs several times over its
     weights (models/looped.py) keeps one per (pass, layer), pass-major,
     so there are more planes than weight layers. Dense rather
@@ -153,12 +156,28 @@ class KVCache:
     ledger.
 
     ``arrays`` is ``{kind: array}``, every kind ``[planes, slots, ...]``,
-    as the model declares them (``serving/decode.state_shapes``): ``k``
-    and ``v`` ``[planes, slots, max_len, kv_heads, head_dim]`` for every
-    model (``.k``/``.v`` read and rebind them; ``planes`` is the layers,
-    times the passes of a looped stack), and for a model with a
-    recurrent mixer ``ssm`` (the recurrent state) and ``conv`` (the
-    convolution's window). A slot owns its row of EVERY kind for one
+    as the model declares them (``serving/decode.state_shapes``), and
+    each kind is positional or recurrent BY DECLARATION
+    (``serving/decode.positional_kinds``), not by its name:
+
+      * ``positional``: ``[planes, slots, max_len, ...]``, one entry a
+        position of the row. Attention hides what lies above a row's
+        length, so a row that a pass does not decode parks its write at
+        ``max_len - 1``, a prefill writes the padded prefix and the
+        step record's ``kv_bytes`` counts these kinds
+        (``kv_block_bytes``). ``k`` and ``v`` ``[planes, slots, max_len,
+        kv_heads, head_dim]`` for a dense, a looped and a hybrid model
+        (``.k``/``.v`` read and rebind them; ``planes`` is the layers,
+        times the passes of a looped stack); ``latent`` ``[planes, slots,
+        max_len, 1, lanes]`` for a model with latent attention: ONE kind,
+        key and value at once, and no per-head K or V of a cached token
+        anywhere.
+      * ``recurrent``: one state a row, with nowhere to park: a pass
+        leaves it bit for bit for the rows it does not decode. ``ssm``
+        (the recurrent state) and ``conv`` (the convolution's window) of
+        a model with a recurrent mixer.
+
+    A slot owns its row of EVERY kind for one
     lifetime: a prefill writes them all whole (K/V up to the padded
     prompt), each decode step advances them all, and retiring the slot
     frees them together — nothing of an occupant outlives its slot,
@@ -198,9 +217,12 @@ class KVCache:
         self.arrays = {kind: jnp.zeros(a.shape, a.dtype,
                                        device=self.vector_sharding)
                        for kind, a in shapes.items()}
-        # kinds a decode pass must not touch for rows it does not decode
-        # (K/V of such a row park at max_len - 1; these cannot)
-        self.recurrent = tuple(sorted(set(shapes) - {"k", "v"}))
+        # what the model declares: kinds with one entry a position (a row
+        # that does not decode parks its write at max_len - 1), and the
+        # kinds a decode pass must not touch for such a row
+        self.positional = tuple(k for k in decode.positional_kinds(cfg)
+                                if k in shapes)
+        self.recurrent = tuple(sorted(set(shapes) - set(self.positional)))
         if mesh is not None:
             # Tensor-parallel serving (docs/mesh.md): K and V gain a
             # head-sharded NamedSharding over the mesh's tp axis, so each
@@ -212,6 +234,12 @@ class KVCache:
                 raise NotImplementedError(
                     "a cache with recurrent state has no sharding over a "
                     "mesh yet (mixer heads and groups over tp: ROADMAP R2)")
+            if self.positional != ("k", "v"):
+                raise NotImplementedError(
+                    f"a {type(cfg).__name__}'s cache "
+                    f"({', '.join(self.positional)}) has no sharding over a "
+                    "mesh yet: a latent is one key head, which tp cannot "
+                    "split (ROADMAP R4)")
             spec = mesh_lib.kv_cache_spec(shapes["k"].shape[3], mesh)
             self.k, self.v = mesh_lib.device_put_tree(
                 (self.k, self.v), (spec, spec), mesh)
@@ -236,9 +264,9 @@ class KVCache:
 
     @property
     def planes(self):
-        """Planes of K/V: one a layer, or one a (pass, layer) of a stack
-        that runs several times."""
-        return self.arrays["k"].shape[0]
+        """Planes of the positional kinds: one a layer, or one a (pass,
+        layer) of a stack that runs several times."""
+        return self.arrays[self.positional[0]].shape[0]
 
     def bytes_by_kind(self):
         """{kind: bytes resident on ONE chip} over all its planes (the
@@ -261,20 +289,20 @@ class KVCache:
         return sum(self.bytes_by_kind().values())
 
     def row_state_bytes(self):
-        """Bytes of recurrent state (every kind but K/V) ONE slot holds
-        over all planes: what a decode step reads and writes again for
-        each row it advances."""
+        """Bytes of recurrent state (every kind that is not positional)
+        ONE slot holds over all planes: what a decode step reads and
+        writes again for each row it advances."""
         by_kind = self.bytes_by_kind()
         return sum(by_kind[kind] for kind in self.recurrent) \
             // self.num_slots
 
     def kv_block_bytes(self, block):
-        """Bytes of K and V that ``block`` positions of ONE slot hold over
-        all planes (on one chip): what a decode step streams for each
-        block of a row it reads (every pass of a looped stack reads its
-        own planes)."""
+        """Bytes of the positional kinds (K and V, or the latent) that
+        ``block`` positions of ONE slot hold over all planes (on one
+        chip): what a decode step streams for each block of a row it
+        reads (every pass of a looped stack reads its own planes)."""
         by_kind = self.bytes_by_kind()
-        return (by_kind["k"] + by_kind["v"]) * block \
+        return sum(by_kind[kind] for kind in self.positional) * block \
             // (self.num_slots * self.max_len)
 
     @property

@@ -379,6 +379,14 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # passes (not in STEP_COUNTS, likewise): stack passes the step's decode
 # program runs each row, from the configuration: the passes of a looped
 # stack (models/looped.py), 1 for every other model
+# experts_touched, expert_tokens_max (not in STEP_COUNTS: only a model with
+# experts, models/latent_moe.py, and only a step that READ a decode pass
+# back): the (layer, expert) pairs that at least one decoding row of that
+# pass was routed to, summed over the expert layers (what the pass had to
+# read of the experts' weights), and the most assignments any one expert
+# got. Counted on the device and read back with the pass's ids, so with a
+# pass in flight they land in the record of the step that reads it, as its
+# tokens do (``ahead``)
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
                "state_rows", "state_bytes", "ahead", "admitted_ahead")
 # Beside the phases a record holds two lists, in the order things happened:
@@ -474,6 +482,11 @@ class StepTrace:
     def count(self, name, n=1):
         self.counts[name] = self.counts.get(name, 0) + n
 
+    def peak(self, name, n):
+        """A count that several passes of one step do not add up to: the
+        largest any of them read (a hot swap's cohorts; else one pass)."""
+        self.counts[name] = max(self.counts.get(name, 0), n)
+
     def launch(self, program):
         """``with step.launch(program) as n:`` around the dispatch of one
         device program, where it is made; ``n`` is the launch's number,
@@ -550,6 +563,9 @@ class _NullStepTrace:
         return False
 
     def count(self, name, n=1):
+        pass
+
+    def peak(self, name, n):
         pass
 
     def launch(self, program):

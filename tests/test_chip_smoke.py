@@ -72,11 +72,13 @@ def test_resnet_leg(capsys):
         hvd.shutdown()
 
 
-@pytest.mark.parametrize("family", ["transformer", "hybrid", "looped"])
+@pytest.mark.parametrize("family", ["transformer", "hybrid", "looped",
+                                    "latent_moe"])
 def test_serve_leg(tiny, capsys, family):
     """Every family through the one leg: a dense decoder, a model that
-    keeps recurrent and convolution state beside its K/V, and a stack
-    that runs several times with K/V per (pass, layer) plane."""
+    keeps recurrent and convolution state beside its K/V, a stack that
+    runs several times with K/V per (pass, layer) plane, and latent
+    attention with dropless experts over ONE latent kind."""
     import jax.numpy as jnp
     if family == "hybrid":
         from horovod_tpu.models import hybrid
@@ -84,10 +86,17 @@ def test_serve_leg(tiny, capsys, family):
     elif family == "looped":
         from horovod_tpu.models import looped
         tiny = looped.LoopedConfig.tiny(dtype=jnp.float32)
+    elif family == "latent_moe":
+        from horovod_tpu.models import latent_moe
+        tiny = latent_moe.LatentMoEConfig.tiny(dtype=jnp.float32)
     chip_smoke.leg_serve(tiny, slots=2, max_len=32, kv_block=8,
                          lengths=(3, 8, 12), tie_tol=1e-4, name=family)
     line = _last_json(capsys)
     assert line["model"] == family
+    # the CPU backend: the einsum, whatever the kind
+    assert line["decode_attention"] == {
+        "kinds": ["latent"] if family == "latent_moe" else ["k", "v"],
+        "kernel": False}
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
     assert line["steps_ahead"] > 0   # three requests on two slots
@@ -360,6 +369,97 @@ def test_a_looped_stack_keeps_its_planes_in_place(topo, program, request):
     calls = re.findall(r"%decode_attention[.\d]* = .*tpu_custom_call.*", text)
     assert len(calls) == cfg.num_layers == text.count("tpu_custom_call")
     assert all("hvd.loop.passes/while/body" in c for c in calls), calls[0]
+
+
+@pytest.mark.parametrize("program", ["decode_kernel", "prefill"])
+def test_a_latent_cache_is_read_in_place_and_experts_are_grouped(
+        topo, program, request):
+    """The TPU compiler's word for latent attention and dropless experts
+    (models/latent_moe.py) at GLM-4.7-Flash widths (20 heads, ranks 768 /
+    512, 192 + 64 and 256 wide heads, 64 experts of 1536 of which 4, one
+    shared, 64 slots x 1536; one dense and one expert layer so it
+    compiles in seconds). Decode: the ONE latent kind is aliased to the
+    donated input and no whole copy of it is held; the latent kernel once
+    a layer, reading the cache whole; the experts' three products are
+    grouped custom calls under ``hvd.moe.experts`` that take the stacks
+    as they lie (no copy, no re-laying of a stack); one int32 vector more
+    comes back. Prefill: the flash kernel at head width 256 once a layer,
+    and the row's latent of every layer."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import latent_moe
+    from horovod_tpu.serving import decode as serve_decode
+    from horovod_tpu.serving import engine as engine_mod
+
+    slots, max_len = 64, 1536
+    cfg = latent_moe.LatentMoEConfig(
+        vocab_size=154880, num_layers=2, d_model=2048, num_heads=20,
+        q_rank=768, kv_rank=512, nope_dim=192, rope_dim=64, v_dim=256,
+        rope_theta=1e6, d_ff=10240, first_dense=1, num_experts=64,
+        experts_per_tok=4, shared_experts=1, d_expert=1536,
+        route_scale=1.8, max_seq_len=202752, dtype=jnp.bfloat16,
+        attention_impl="flash")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    state = {k: arr(a.shape, a.dtype) for k, a in
+             serve_decode.state_shapes(cfg, slots, max_len).items()}
+    latent = state["latent"]
+    assert set(state) == {"latent"}
+    assert latent.shape == (2, slots, max_len, 1, 640)
+    params = jax.tree_util.tree_map(
+        lambda a: arr(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: latent_moe.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    request.getfixturevalue("decode_kernel")
+    if program == "prefill":
+        engine_mod._prefill_jit.clear_cache()
+        try:
+            text = engine_mod._prefill_jit.lower(
+                cfg, params, arr((1, 256), jnp.int32), arr((), jnp.int32),
+                arr((), jnp.float32), arr((2,), jnp.uint32)
+            ).compile().as_text()
+        finally:
+            engine_mod._prefill_jit.clear_cache()
+        flash = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line
+                 and line.count("bf16[20,256,256]") >= 4]   # out, q, k, v
+        assert len(flash) == cfg.num_layers, len(flash)
+        assert "bf16[2,1,256,1,640]" in text
+        assert len(re.findall(r"%ragged-dot[-\w.]* = f32|"
+                              r"%ragged-dot[-\w.]* = bf16", text)) == 3
+        return
+    compiled = engine_mod._decode_jit.lower(
+        cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+        state, arr((slots,), jnp.float32), arr((slots,), jnp.bool_),
+        arr((2,), jnp.uint32), arr((), jnp.int32)).compile()
+    cache_bytes = 2 * slots * max_len * 640 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    stacks = [(latent.dtype, (64, 2048, 1536)),
+              (latent.dtype, (64, 1536, 2048))]
+    copies = _whole_copies(compiled, (latent.dtype, latent.shape),
+                           (latent.dtype, (2, slots, max_len, 640)), *stacks)
+    assert not copies, copies
+    text = compiled.as_text()
+    calls = re.findall(r"%latent_decode_attention[.\d]* = .*tpu_custom_call.*",
+                       text)
+    assert len(calls) == cfg.num_layers
+    assert all("hvd.mla.attend" in c and "bf16[2,64,1536,640]" in c
+               for c in calls), calls[0][:400]
+    grouped = [line for line in text.splitlines()
+               if re.match(r"\s*%ragged-dot[-\w.]* = (f32|bf16)\[256,", line)]
+    assert len(grouped) == 3, len(grouped)
+    # XLA names its grouped product itself; the scope names what feeds it
+    for scope in ("hvd.moe.route", "hvd.moe.experts", "hvd.moe.shared"):
+        assert scope in text, scope
+    # what a pass routed comes back beside ids, positions and the cache
+    shapes = [str(s.shape) for s in jax.tree_util.tree_leaves(
+        compiled.out_info)]
+    assert shapes.count("(2,)") == 1
 
 
 def test_init_names_the_process_that_holds_the_chip(monkeypatch):

@@ -339,3 +339,96 @@ class TestVariantSelection:
         for other in outs[1:]:
             np.testing.assert_allclose(outs[0], other, rtol=2e-5,
                                        atol=2e-5)
+
+
+class TestLatentDecodeKernel:
+    """``latent_decode_attention`` (ops/flash_attention.py): the Mosaic
+    kernel, interpreted here, against the einsum under a length mask. ONE
+    key head whose first ``value_dim`` lanes are the value; 2 x 128-position
+    blocks a row. fp32 operands: 2e-5, the tolerance of the forward kernels
+    (exp2-domain online softmax against ``jax.nn.softmax``)."""
+
+    @staticmethod
+    def _inputs(b=5, heads=4, s_max=256, lanes=256, planes=3, seed=0):
+        import jax.numpy as jnp
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.normal(size=(b, heads, lanes)), jnp.float32)
+        cache = jnp.asarray(rng.normal(size=(planes, b, s_max, 1, lanes)),
+                            jnp.float32)
+        return q, cache
+
+    @pytest.mark.parametrize("lengths", [
+        (0, 1, 128, 129, 256),      # nothing, one, a block's edge, max_len
+        (256, 256, 256, 256, 256),
+        (0, 0, 0, 0, 0),
+        (7, 0, 255, 127, 1)])
+    def test_the_kernel_is_the_einsum_at_every_length(self, lengths):
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        q, cache = self._inputs()
+        lens = jnp.asarray(lengths, jnp.int32)
+        for plane in (0, 2):
+            # the CPU backend takes the einsum; the kernel by its wrapper
+            want = fa.latent_decode_attention(q, cache, lens, plane, 128,
+                                              scale=0.1)
+            got = fa._latent_decode_attention_kernel(q, cache, lens, plane,
+                                                     128, 0.1)
+            assert got.shape == want.shape == (5, 4, 128)
+            live = np.asarray(lengths) > 0
+            np.testing.assert_allclose(np.asarray(got)[live],
+                                       np.asarray(want)[live],
+                                       rtol=2e-5, atol=2e-5)
+            assert not np.asarray(got)[~live].any()   # a row of length 0
+
+    def test_what_lies_above_a_length_is_never_read(self):
+        """NaN above every row's length, in the plane that is read and
+        all over the others: the kernel's output is finite and equal."""
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        kernel = fa._latent_decode_attention_kernel
+        q, cache = self._inputs()
+        lengths = np.asarray([3, 128, 200, 0, 256])
+        lens = jnp.asarray(lengths, jnp.int32)
+        want = kernel(q, cache, lens, 1, 128, 256 ** -0.5)
+        above = np.arange(256)[None, :] >= lengths[:, None]
+        poisoned = np.array(cache)
+        poisoned[1][above] = np.nan
+        poisoned[[0, 2]] = np.nan
+        got = kernel(q, jnp.asarray(poisoned), lens, 1, 128, 256 ** -0.5)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_the_value_is_the_keys_first_lanes_and_the_plane_is_data(self):
+        """Against plain numpy; the plane index traced, as the decode
+        program hands it over."""
+        import jax
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        q, cache = self._inputs(b=2, heads=3, lanes=384)
+        lens = jnp.asarray([100, 256], jnp.int32)
+        got = jax.jit(lambda plane: fa._latent_decode_attention_kernel(
+            q, cache, lens, plane, 256, 384 ** -0.5))(jnp.int32(2))
+        for row, n in enumerate((100, 256)):
+            keys = np.asarray(cache)[2, row, :n, 0]
+            logits = np.asarray(q)[row] @ keys.T * 384 ** -0.5
+            p = np.exp(logits - logits.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            np.testing.assert_allclose(np.asarray(got)[row],
+                                       p @ keys[:, :256], rtol=2e-5,
+                                       atol=2e-5)
+
+    def test_the_kernel_is_chosen_from_what_the_call_sees(self, monkeypatch):
+        from horovod_tpu.ops import flash_attention as fa
+        cell = (7, 64, 1536, 1, 640)
+        assert not fa._latent_kernel_selected(cell, 512)     # the CPU
+        monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+        assert fa._latent_kernel_selected(cell, 512)
+        # rows of no whole blocks, lanes of no whole tiles, two key heads
+        assert not fa._latent_kernel_selected((7, 64, 1500, 1, 640), 512)
+        assert not fa._latent_kernel_selected((7, 64, 1536, 1, 576), 512)
+        assert not fa._latent_kernel_selected((7, 64, 1536, 2, 640), 512)
+        assert not fa._latent_kernel_selected(cell, 500)
+        with pytest.raises(ValueError, match="wants q"):
+            fa.latent_decode_attention(
+                np.zeros((2, 4, 576), np.float32),
+                np.zeros((1, 2, 128, 1, 640), np.float32),
+                np.zeros(2, np.int32), 0, 512)

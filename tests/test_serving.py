@@ -1787,3 +1787,239 @@ class TestALoopedStackInTheCache:
             # K/V of a step: every pass streams its own planes
             position = cfg.planes * 2 * 4 * 16 * 4
             assert recs[1]["kv_bytes"] == 48 * position
+
+
+# ---------------------------------------------------------------------------
+# Latent attention and dropless experts (models/latent_moe.py): ONE
+# positional kind in the cache, declared by the model; what a pass routed
+# in the step record
+# ---------------------------------------------------------------------------
+
+def _tiny_latent_moe(**kw):
+    from horovod_tpu.models import latent_moe
+    kw.setdefault("dtype", jnp.float32)
+    cfg = latent_moe.LatentMoEConfig.tiny(max_seq_len=64, rope_theta=1e6,
+                                          **kw)
+    return cfg, latent_moe.init_params(cfg, jax.random.PRNGKey(0))
+
+
+MODELS["latent_moe"] = _tiny_latent_moe
+
+
+class TestALatentCacheAndExperts:
+    @pytest.fixture(autouse=True)
+    def tracer(self):
+        import gc
+        from horovod_tpu.utils import tracing as hvd_tracing
+        hvd_tracing.reset(enabled=True, rank=0)
+        yield
+        hvd_tracing.reset()
+        # an engine dies with its cycles: a float32 cache of 128 lanes is
+        # large enough for tests/benchmarks' "no float32 copy is alive"
+        gc.collect()
+
+    def test_kinds_are_positional_or_recurrent_by_declaration(self, reg):
+        """Not by the names ``k`` and ``v``: the latent is positional (it
+        counts as K/V bytes, a row parks its write), and the three other
+        families' kinds, planes and bytes are what they were."""
+        from horovod_tpu.serving.decode import positional_kinds
+        cfg, params = _tiny_latent_moe()
+        engine = _engine(cfg, params, num_slots=3, max_len=32)
+        kv = engine.kv
+        assert positional_kinds(cfg) == kv.positional == ("latent",)
+        assert kv.recurrent == () and set(kv.arrays) == {"latent"}
+        # 32 + 8 numbers a token a plane, in one 128-lane tile
+        assert (cfg.latent_dim, cfg.latent_lanes) == (40, 128)
+        assert kv.arrays["latent"].shape == (3, 3, 32, 1, 128)
+        assert kv.planes == cfg.num_layers == 3
+        position = 3 * 128 * 4           # one token over all planes, f32
+        assert kv.kv_block_bytes(8) == 8 * position
+        assert kv.per_chip_bytes() == 3 * 32 * position
+        assert kv.row_state_bytes() == 0
+        snap = reg.snapshot()
+        assert _value(snap, "hvd_serve_state_bytes", kind="latent") == \
+            3 * 32 * position
+        for name, kinds, rec in (("dense", ("k", "v"), ()),
+                                 ("looped", ("k", "v"), ()),
+                                 ("hybrid", ("k", "v"), ("conv", "ssm"))):
+            cfg2, params2 = MODELS[name]()
+            other = _engine(cfg2, params2).kv
+            assert positional_kinds(cfg2) == other.positional == kinds
+            assert other.recurrent == rec
+            assert other.planes == other.k.shape[0]
+            by_kind = other.bytes_by_kind()
+            assert other.kv_block_bytes(8) == \
+                (by_kind["k"] + by_kind["v"]) * 8 // (2 * 48)
+
+    def test_temp0_matches_no_cache_greedy(self, reg):
+        """Prefill (expanded), slot write, decode (absorbed) with rows
+        joining and retiring, against a full forward over the growing
+        sequence every token."""
+        from horovod_tpu.models import latent_moe
+        cfg, params = _tiny_latent_moe()
+        prompts = {"a": _prompt(5, 1), "b": _prompt(11, 2),
+                   "c": _prompt(3, 3), "d": _prompt(17, 4)}
+        new = {"a": 7, "b": 3, "c": 9, "d": 5}
+        got = _serve(_engine(cfg, params),
+                     [(rid, p, new[rid]) for rid, p in prompts.items()])
+        forward = jax.jit(lambda toks: latent_moe.forward(cfg, params,
+                                                          toks)[0])
+        for rid, prompt in prompts.items():
+            toks, want = list(prompt), []
+            for _ in range(new[rid]):
+                padded = np.zeros((1, 32), np.int32)
+                padded[0, :len(toks)] = toks
+                logits = forward(jnp.asarray(padded))
+                want.append(int(jnp.argmax(logits[0, len(toks) - 1])))
+                toks.append(want[-1])
+            assert got[rid] == want
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-4),
+                                           (jnp.bfloat16, 0.15)])
+    def test_prefill_then_decode_through_the_cache_is_the_references_forward(
+            self, dtype, tol):
+        """Logits, not tokens: one padded prefill written into a slot by
+        the engine's own ``_write_slot``, then a decode step a token with
+        the other rows masked out, against ONE float32 forward of the
+        plain reference (benchmarks/reference/glm_moe_lite.py, the
+        EXPANDED attention and a loop over the experts) over the whole
+        sequence. Tolerances as tests/test_latent_moe_model.py states
+        them; seed 8 routes bfloat16 as float32 does over these tokens."""
+        import test_latent_moe_model as lm
+        from horovod_tpu.serving import decode as serve_decode
+        from horovod_tpu.serving import engine as engine_mod
+        cfg = lm.tiny_config()
+        w = lm.drawn(cfg, seed=8)
+        mcfg, params = lm.model(cfg, w, dtype)
+        tokens, prompt_len, slots, slot, max_len = \
+            lm.sequence(30, 8), 13, 3, 1, 48
+        first = np.zeros((1, 16), np.int32)
+        first[0, :prompt_len] = tokens[:prompt_len]
+        row, state_row = jax.jit(serve_decode.prefill, static_argnums=0)(
+            mcfg, params, jnp.asarray(first), jnp.int32(prompt_len - 1))
+        assert state_row["latent"].shape == (3, 1, 16, 1, 128)
+        state = {k: jnp.zeros(a.shape, a.dtype) for k, a in
+                 serve_decode.state_shapes(mcfg, slots, max_len).items()}
+        state, _ = engine_mod._write_slot(
+            state, state_row, jnp.int32(slot), jnp.zeros(slots, jnp.int32),
+            jnp.int32(tokens[prompt_len]))
+        step = jax.jit(serve_decode.decode, static_argnums=0)
+        got = [np.asarray(row[0])]
+        mask = np.zeros(slots, bool)
+        mask[slot] = True
+        for j in range(prompt_len, len(tokens) - 1):
+            toks = np.zeros(slots, np.int32)
+            pos = np.full(slots, max_len - 1, np.int32)
+            toks[slot], pos[slot] = tokens[j], j
+            logits, state, routed = step(
+                mcfg, params, jnp.asarray(toks), jnp.asarray(pos), state,
+                jnp.asarray(mask))
+            assert routed.tolist() == [4, 1]   # one row, 2 layers x 2
+            got.append(np.asarray(logits[slot]))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(lm.ref.logits_at(
+                w, jnp.asarray(tokens[:-1]),
+                jnp.arange(prompt_len - 1, len(tokens) - 1), cfg, lm.LAYERS))
+        np.testing.assert_allclose(np.stack(got).astype(np.float32), want,
+                                   atol=tol)
+
+    def test_a_row_outside_the_mask_parks_its_latent_and_reads_nothing(self):
+        """``decode`` over two live rows with one masked out: the row in
+        the pass gets the logits and the cache row it gets alone; the
+        other's latent is written only where ``positions`` parks it, in
+        every plane, its live prefix is untouched, and it is routed to no
+        expert."""
+        from horovod_tpu.serving import decode as serve_decode
+        cfg, params = _tiny_latent_moe()
+        rng = np.random.default_rng(0)
+        shape = serve_decode.state_shapes(cfg, 2, 32)["latent"].shape
+        state = {"latent": jnp.asarray(rng.normal(size=shape), jnp.float32)}
+        toks = jnp.asarray([7, 9], jnp.int32)
+        both = jnp.asarray([5, 8], jnp.int32)
+        parked = jnp.asarray([5, 31], jnp.int32)
+        want, full, both_routed = serve_decode.decode(cfg, params, toks,
+                                                      both, state)
+        got, masked, routed = serve_decode.decode(
+            cfg, params, toks, parked, state, jnp.asarray([True, False]))
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   atol=1e-6)
+        before, after = np.asarray(state["latent"]), \
+            np.asarray(masked["latent"])
+        np.testing.assert_array_equal(after[:, 0],
+                                      np.asarray(full["latent"])[:, 0])
+        np.testing.assert_array_equal(after[:, 1, :31], before[:, 1, :31])
+        assert (after[:, 1, 31, 0, :40] != before[:, 1, 31, 0, :40]).all()
+        assert (after[:, 0, 5, 0, :40] != before[:, 0, 5, 0, :40]).all()
+        assert not after[:, :, [5, 31], 0, 40:][:, [0, 1], [0, 1]].any()
+        # one row of two routed: 2 experts a layer, not 3 or 4
+        assert routed.tolist() == [4, 1]
+        assert 4 <= int(both_routed[0]) <= 8
+
+    def test_the_cache_holds_what_the_expanded_forward_leaves(self, reg):
+        """After a prefill and some decode steps a slot's rows are the
+        latent of a forward over the whole sequence, no other slot was
+        written, and the cache has no per-head key or value anywhere."""
+        from horovod_tpu.models import latent_moe
+        cfg, params = _tiny_latent_moe()
+        engine = _never_ahead(_engine(cfg, params, num_slots=2, max_len=32))
+        prompt = _prompt(9, 4)
+        engine.submit(Request("r", prompt, max_new_tokens=6))
+        for _ in range(4):
+            engine.step()
+        (slot, st), = engine._active.items()
+        seq = list(prompt) + st.generated[:-1]
+        n = len(seq)
+        assert n == engine.kv.ledger.length(slot) == 13
+        _, latent, _ = latent_moe.hidden_states(
+            cfg, params, jnp.asarray([seq], jnp.int32))
+        held = np.asarray(engine.kv.arrays["latent"])
+        np.testing.assert_allclose(held[:, slot, :n],
+                                   np.asarray(latent)[:, 0], atol=1e-5)
+        assert not held[:, 1 - slot, :31].any()
+        assert all(a.shape[3] == 1 for a in engine.kv.arrays.values())
+
+    def test_the_step_record_counts_what_a_pass_routed(self, reg):
+        """``experts_touched`` and ``expert_tokens_max`` come back with
+        the pass's ids: on the step that READS the pass, which with a
+        pass in flight is the step after its launch; ``kv_bytes`` counts
+        the latent; no other family's record has the counts."""
+        cfg, params = _tiny_latent_moe()
+        engine = _engine(cfg, params, num_slots=2, max_len=32)
+        _warm(engine)
+        results, recs = _drive(engine, [("a", _prompt(5, 1), 6),
+                                        ("b", _prompt(9, 2), 6)])
+        assert all(r.outcome == "completed" for r in results.values())
+        decoded = [r for r in recs if r["active"]]
+        position = 3 * 128 * 4
+        for r in decoded:
+            assert r["kv_bytes"] % (8 * position) == 0 and r["kv_bytes"] > 0
+        ahead = [r for r in decoded if r["ahead"]]
+        assert ahead                       # both slots busy: passes in flight
+        for r in recs:
+            reads_a_pass = any(
+                p[0] == "decode_readback" for p in r["phases"])
+            assert ("experts_touched" in r) == reads_a_pass
+            if reads_a_pass:
+                # two expert layers, two rows of two experts each
+                assert 4 <= r["experts_touched"] <= 8
+                assert 1 <= r["expert_tokens_max"] <= 2
+        # a step that ran ahead read the pass of the step before it
+        first = recs.index(ahead[0])
+        assert "experts_touched" in recs[first + 1]
+        for name in ("dense", "hybrid", "looped"):
+            cfg2, params2 = MODELS[name]()
+            _, recs2 = _drive(_engine(cfg2, params2),
+                              [("x", _prompt(5, 1), 3)])
+            assert not any("experts_touched" in r for r in recs2)
+
+    def test_an_engine_over_a_mesh_is_refused_by_name(self):
+        from horovod_tpu.parallel import mesh as mesh_lib
+        cfg, params = _tiny_latent_moe()
+        mesh = mesh_lib.build_mesh(tp=2)
+        with pytest.raises(NotImplementedError,
+                           match="LatentMoEConfig serves on one chip"):
+            _engine(cfg, params, mesh=mesh)
+        with pytest.raises(NotImplementedError,
+                           match="LatentMoEConfig's cache .latent. has no "
+                                 "sharding over a mesh"):
+            KVCache(cfg, 2, max_len=32, mesh=mesh)
