@@ -363,6 +363,22 @@ def decode_attention_selected(cfg, slots, max_len):
     return {"kinds": kinds, "kernel": bool(kernel)}
 
 
+def grouped_experts_selected(cfg, slots):
+    """Which grouped product a decode pass over ``slots`` rows runs for a
+    model with routed experts: this repo's Mosaic kernel
+    (ops/grouped_matmul.py decides from the call: False on the CPU, over a
+    mesh, for float32 or widths that are no whole lane tiles) or
+    ``jax.lax.ragged_dot``. None for a model without experts."""
+    from horovod_tpu.models import latent_moe
+    from horovod_tpu.ops import grouped_matmul
+    if not isinstance(cfg, latent_moe.LatentMoEConfig) or \
+            not cfg.expert_layers:
+        return None
+    return {"kernel": bool(grouped_matmul.selected(
+        slots * cfg.experts_per_tok,
+        (cfg.num_experts, cfg.d_model, cfg.d_expert), cfg.dtype))}
+
+
 def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
               lengths=(5, 16, 100, 513, 1000, 7, 33, 250, 640, 90, 12, 400),
               tie_tol=SERVE_TIE_TOL, name="gpt2_small_tpu",
@@ -374,7 +390,8 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     before it (so a plain greedy decode yields the same sequence) or tie
     with it within ``tie_tol``. ``cfg`` is a TransformerConfig, a
     HybridConfig, a LoopedConfig or a LatentMoEConfig (``_served_model``).
-    The line says which decode attention ran (``decode_attention``).
+    The line says which decode attention ran (``decode_attention``) and,
+    for a model with experts, which grouped product (``experts``).
     ``routed_elsewhere`` (share, deficit): routing is discrete, and two
     bfloat16 paths of one model with experts (the expanded plain forward,
     the served prefill and absorbed decode) round a token's router scores
@@ -476,6 +493,7 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     emit("serve", model=name, layers=cfg.num_layers,
          slots=slots, max_len=max_len, kv_block=kv_block,
          decode_attention=decode_attention_selected(cfg, slots, max_len),
+         experts=grouped_experts_selected(cfg, slots),
          requests=len(reqs), prompt_lengths=list(lengths),
          new_tokens=[r.max_new_tokens for r in reqs], tokens=total,
          greedy_exact=exact, greedy_ties=ties, greedy_missed=missed,
@@ -754,10 +772,15 @@ def main(argv=None):
             route_scale=1.8, max_seq_len=1024, attention_impl="flash"),
             kv_block=128, name="latent_moe_small",
             # 8 experts of which 2: one token routed elsewhere is half its
-            # routed output; the v5e misses 4 of 500 tokens, the worst by
-            # 0.675 (PR 42, the same on every run: the requests are seeded):
-            # held to that reading, a fifth token or a wider miss fails
-            routed_elsewhere=(0.01, 0.8))
+            # routed output. WHICH tokens sit at a near tie moves with any
+            # change of rounding: the v5e misses 4 of 500 tokens, the worst
+            # by 0.675, with ragged_dot's grouped products (PR 42) and 7,
+            # the worst by 0.693, with the grouped kernel, which is the
+            # nearer of the two to a float32 loop at these widths (PR 43,
+            # both in one process; the same on every run: the requests are
+            # seeded). Held to 2% and 0.8: an eleventh token or a wider
+            # miss fails
+            routed_elsewhere=(0.02, 0.8))
     if "four_chips" in legs:
         if jax.device_count() >= 4:
             leg_four_chips(train_cfg, 16, 1024, first_loss)

@@ -22,7 +22,9 @@ module: ``route`` and ``experts``, plain functions over parameter leaves
 layer is DROPLESS: there is no capacity, every token gets every expert it
 chose, and an expert that no token chose is not computed and its weights
 are not read (tokens sorted by expert, one grouped product over the
-experts routed to). ``MoEMLP`` above stays what the training path runs.
+experts routed to: ops/grouped_matmul.py's kernel on one TPU chip,
+``jax.lax.ragged_dot`` elsewhere). ``MoEMLP`` above stays what the
+training path runs.
 """
 
 from typing import Optional
@@ -30,6 +32,8 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops import grouped_matmul
 
 
 class MoEMLP(nn.Module):
@@ -141,12 +145,15 @@ def experts(y, idx, weights, gate, up, down, mask=None):
     y [t, d]; idx, weights [t, k] (``route``); gate, up [E, d, f] and down
     [E, f, d], the experts' SwiGLU stacked. The ``t * k`` assignments are
     sorted by expert, so each expert's tokens lie together, and the three
-    products are GROUPED (``jax.lax.ragged_dot``: group e is expert e's
-    rows against expert e's matrix): an expert with no row costs no
-    product and no read of its weights beyond what the product's tiling
-    forces, and no expert is computed for a token under a mask. The
-    outputs go back to the tokens' order and are summed under their
-    weights in float32.
+    products are GROUPED (group e is expert e's rows against expert e's
+    matrix): an expert with no row costs no product and no read of its
+    weights, and no expert is computed for a token under a mask. On one
+    TPU chip, in bfloat16 at widths of whole lane tiles, that is ONE
+    Mosaic kernel that streams each touched expert's three matrices once
+    and keeps the hidden in VMEM (ops/grouped_matmul.py, which decides
+    from the call: ``selected``); everywhere else three
+    ``jax.lax.ragged_dot``. The outputs go back to the tokens' order and
+    are summed under their weights in float32.
 
     ``mask`` [t] bool: a token outside it (a slot that does not decode, a
     prompt's padding) is routed to NO expert (its assignments sort behind
@@ -163,9 +170,12 @@ def experts(y, idx, weights, gate, up, down, mask=None):
     load = jnp.sum(flat[:, None] == jnp.arange(num, dtype=flat.dtype),
                    axis=0, dtype=jnp.int32)
     rows = jnp.take(y, order // k, axis=0)               # [t * k, d]
-    hidden = jax.nn.silu(jax.lax.ragged_dot(rows, gate, load)) \
-        * jax.lax.ragged_dot(rows, up, load)
-    out = jax.lax.ragged_dot(hidden, down, load)         # [t * k, d]
+    if grouped_matmul.selected(t * k, gate.shape, rows.dtype):
+        out = grouped_matmul.grouped_swiglu(rows, gate, up, down, load)
+    else:
+        hidden = jax.nn.silu(jax.lax.ragged_dot(rows, gate, load)) \
+            * jax.lax.ragged_dot(rows, up, load)
+        out = jax.lax.ragged_dot(hidden, down, load)     # [t * k, d]
     # back to the tokens' order: assignment a sits at row inverse[a]
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(t * k, dtype=order.dtype))

@@ -97,6 +97,9 @@ def test_serve_leg(tiny, capsys, family):
     assert line["decode_attention"] == {
         "kinds": ["latent"] if family == "latent_moe" else ["k", "v"],
         "kernel": False}
+    # ...and ragged_dot for the one family with experts
+    assert line["experts"] == ({"kernel": False} if family == "latent_moe"
+                               else None)
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
     assert line["steps_ahead"] > 0   # three requests on two slots
@@ -371,6 +374,22 @@ def test_a_looped_stack_keeps_its_planes_in_place(topo, program, request):
     assert all("hvd.loop.passes/while/body" in c for c in calls), calls[0]
 
 
+def _grouped_calls(text, rows):
+    """The compiled program's calls of the grouped SwiGLU kernel over
+    ``rows`` assignments: each under ``hvd.moe.experts`` and with the
+    three stacks AS THEY LIE among its operands; and no grouped product of
+    XLA's own beside them."""
+    import re
+    calls = re.findall(r"%grouped_swiglu[.\d]* = .*tpu_custom_call.*", text)
+    for call in calls:
+        assert re.match(rf"%grouped_swiglu[.\d]* = bf16\[{rows},2048\]",
+                        call) and "hvd.moe.experts" in call, call[:400]
+        assert call.count("bf16[64,2048,1536]") == 2 and \
+            call.count("bf16[64,1536,2048]") == 1, call[:400]
+    assert "ragged-dot" not in text
+    return len(calls)
+
+
 @pytest.mark.parametrize("program", ["decode_kernel", "prefill"])
 def test_a_latent_cache_is_read_in_place_and_experts_are_grouped(
         topo, program, request):
@@ -380,11 +399,13 @@ def test_a_latent_cache_is_read_in_place_and_experts_are_grouped(
     shared, 64 slots x 1536; one dense and one expert layer so it
     compiles in seconds). Decode: the ONE latent kind is aliased to the
     donated input and no whole copy of it is held; the latent kernel once
-    a layer, reading the cache whole; the experts' three products are
-    grouped custom calls under ``hvd.moe.experts`` that take the stacks
-    as they lie (no copy, no re-laying of a stack); one int32 vector more
-    comes back. Prefill: the flash kernel at head width 256 once a layer,
-    and the row's latent of every layer."""
+    a layer, reading the cache whole; the experts' grouped SwiGLU is ONE
+    call of this repo's kernel (ops/grouped_matmul.py) under
+    ``hvd.moe.experts`` that takes the three stacks as they lie (no copy,
+    no re-laying of a stack) and XLA's own grouped product is gone; one
+    int32 vector more comes back. Prefill: the flash kernel at head width
+    256 once a layer, the row's latent of every layer, and the same one
+    call for the experts over 1,024 assignments."""
     import re
 
     import jax
@@ -430,8 +451,7 @@ def test_a_latent_cache_is_read_in_place_and_experts_are_grouped(
                  and line.count("bf16[20,256,256]") >= 4]   # out, q, k, v
         assert len(flash) == cfg.num_layers, len(flash)
         assert "bf16[2,1,256,1,640]" in text
-        assert len(re.findall(r"%ragged-dot[-\w.]* = f32|"
-                              r"%ragged-dot[-\w.]* = bf16", text)) == 3
+        assert _grouped_calls(text, rows=1024) == 1
         return
     compiled = engine_mod._decode_jit.lower(
         cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
@@ -450,10 +470,7 @@ def test_a_latent_cache_is_read_in_place_and_experts_are_grouped(
     assert len(calls) == cfg.num_layers
     assert all("hvd.mla.attend" in c and "bf16[2,64,1536,640]" in c
                for c in calls), calls[0][:400]
-    grouped = [line for line in text.splitlines()
-               if re.match(r"\s*%ragged-dot[-\w.]* = (f32|bf16)\[256,", line)]
-    assert len(grouped) == 3, len(grouped)
-    # XLA names its grouped product itself; the scope names what feeds it
+    assert _grouped_calls(text, rows=256) == 1
     for scope in ("hvd.moe.route", "hvd.moe.experts", "hvd.moe.shared"):
         assert scope in text, scope
     # what a pass routed comes back beside ids, positions and the cache

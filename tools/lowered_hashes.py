@@ -1,8 +1,9 @@
 """Does a change leave the programs alone? sha256 of the lowered text of
 five training steps, of ``sorted(sys.modules)`` after them, and of the three
-serving programs as ``ServeEngine`` itself feeds them (dense, hybrid and
-looped at the tests' sizes; greedy and sampled requests, one of them for a
-single token), with a hash of the tokens served. Run it from the root of
+serving programs as ``ServeEngine`` itself feeds them (dense, hybrid,
+looped and latent attention with experts at the tests' sizes; greedy and
+sampled requests, one of them for a single token), with a hash of the
+tokens served. Run it from the root of
 two trees and compare the lines (the set-up protocol, PERF.md §6):
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
@@ -67,7 +68,7 @@ def training(out):
 
 
 def served_models():
-    from horovod_tpu.models import hybrid, looped
+    from horovod_tpu.models import hybrid, latent_moe, looped
     from horovod_tpu.models import transformer as tr
     key = jax.random.PRNGKey(0)
     cfg = tr.TransformerConfig.tiny(dtype=jnp.float32, attention_impl="full")
@@ -80,6 +81,8 @@ def served_models():
     cfg = looped.LoopedConfig.tiny(max_seq_len=64, rope_theta=1e6,
                                    dtype=jnp.float32)
     yield "looped", cfg, looped.init_params(cfg, key)
+    cfg = latent_moe.LatentMoEConfig.tiny(max_seq_len=64, dtype=jnp.float32)
+    yield "latent_moe", cfg, latent_moe.init_params(cfg, key)
 
 
 REQUESTS = [((5, 9, 17), 9, 0.0), ((4, 8, 15, 16, 23, 42, 1, 2, 3, 4), 13, 0.8),
