@@ -37,6 +37,7 @@ import argparse
 import dataclasses
 import functools
 import json
+import math
 import os
 import re
 import shutil
@@ -65,6 +66,9 @@ KERNEL_ATOL = 3e-2
 #: forward round differently: on the chip 17 of 499 tokens differed, by at
 #: most 0.032; a wrong cache row or position is off by order 1.
 SERVE_TIE_TOL = 0.1
+# (share of served tokens that may miss the plain forward's choice, widest
+# miss) of the ``laguna_small`` leg: set from its reading on the v5e
+LAGUNA_SMALL_ROUTED = (0.08, 1.8)
 
 
 def device_fields():
@@ -132,6 +136,46 @@ def leg_kernels(shapes, atol=KERNEL_ATOL, dtype=None):
     emit("kernels", shapes=[list(s) for s in shapes],
          variants=list(fa.VARIANTS), max_abs_err=worst, atol=atol,
          seconds=round(time.perf_counter() - t0, 2))
+
+
+def leg_window_kernel(cases, atol=KERNEL_ATOL, dtype=None):
+    """The banded forward (``window_attention``: a window layer's prefill)
+    against dense attention under the band mask, computed in float32 from
+    the same operands; ``cases`` are (batch, seq, heads, kv_heads,
+    head_dim, window)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    dtype = dtype or jnp.bfloat16
+    t0 = time.perf_counter()
+    worst = 0.0
+    for b, s, h, hk, d, window in cases:
+        rng = np.random.RandomState(s + h + window)
+        q = jnp.asarray(rng.randn(b, s, h, d) * 0.5, dtype)
+        k, v = (jnp.asarray(rng.randn(b, s, hk, d) * 0.5, dtype)
+                for _ in range(2))
+
+        def dense(q, k, v):
+            kf, vf = (jnp.repeat(t.astype(jnp.float32), h // hk, axis=2)
+                      for t in (k, v))
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                                kf) * d ** -0.5
+            gap = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+            scores = jnp.where((gap >= 0) & (gap < window), scores, -jnp.inf)
+            return jnp.einsum("bhqk,bkhd->bqhd",
+                              jax.nn.softmax(scores, axis=-1), vf)
+        got = np.asarray(jax.jit(functools.partial(
+            fa.window_attention, window=window))(q, k, v).astype(jnp.float32))
+        _check(np.isfinite(got).all(), f"window_attention at "
+               f"{(b, s, h, hk, d, window)}: not finite")
+        err = float(np.max(np.abs(got - np.asarray(jax.jit(dense)(q, k, v)))))
+        _check(err <= atol, f"window_attention at {(b, s, h, hk, d, window)}"
+               f": max abs err {err:.4g} > {atol} against masked attention")
+        worst = max(worst, err)
+    emit("window_kernel", cases=[list(c) for c in cases], max_abs_err=worst,
+         atol=atol, seconds=round(time.perf_counter() - t0, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -317,11 +361,12 @@ def _served_model(cfg):
     model with a recurrent mixer its prefill (the chunked scan, no cache),
     once for each position, and for a looped stack its plain forward over
     every pass, for latent attention with experts its plain (expanded)
-    forward."""
+    forward, for window and full layers with experts its plain forward
+    (whole sequences under the band mask, no ring)."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.models import hybrid, latent_moe, looped
+    from horovod_tpu.models import hybrid, latent_moe, looped, window_moe
     from horovod_tpu.models import transformer as tr
 
     ref_cfg = dataclasses.replace(cfg, attention_impl="full")
@@ -336,6 +381,10 @@ def _served_model(cfg):
     elif isinstance(cfg, latent_moe.LatentMoEConfig):
         params = latent_moe.init_params(cfg, jax.random.PRNGKey(0))
         rows = jax.jit(lambda p, seq, at: latent_moe.forward(
+            ref_cfg, p, seq[None])[0][0, at].astype(jnp.float32))
+    elif isinstance(cfg, window_moe.WindowMoEConfig):
+        params = window_moe.init_params(cfg, jax.random.PRNGKey(0))
+        rows = jax.jit(lambda p, seq, at: window_moe.forward(
             ref_cfg, p, seq[None])[0][0, at].astype(jnp.float32))
     else:
         _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
@@ -358,8 +407,9 @@ def decode_attention_selected(cfg, slots, max_len):
     if kinds == ["latent"]:
         kernel = fa._latent_kernel_selected(shapes["latent"].shape,
                                             cfg.kv_rank)
-    else:
-        kernel = fa._decode_kernel_selected(shapes["k"].shape, None)
+    else:  # every class of K/V the model keeps: full rows, and rings
+        kernel = all(fa._decode_kernel_selected(shapes[k].shape, None)
+                     for k in kinds)
     return {"kinds": kinds, "kernel": bool(kernel)}
 
 
@@ -369,14 +419,38 @@ def grouped_experts_selected(cfg, slots):
     (ops/grouped_matmul.py decides from the call: False on the CPU, over a
     mesh, for float32 or widths that are no whole lane tiles) or
     ``jax.lax.ragged_dot``. None for a model without experts."""
-    from horovod_tpu.models import latent_moe
+    from horovod_tpu.models import latent_moe, window_moe
     from horovod_tpu.ops import grouped_matmul
-    if not isinstance(cfg, latent_moe.LatentMoEConfig) or \
+    if not isinstance(cfg, (latent_moe.LatentMoEConfig,
+                            window_moe.WindowMoEConfig)) or \
             not cfg.expert_layers:
         return None
     return {"kernel": bool(grouped_matmul.selected(
         slots * cfg.experts_per_tok,
         (cfg.num_experts, cfg.d_model, cfg.d_expert), cfg.dtype))}
+
+
+def laguna_small_config():
+    """The ``laguna_small`` leg's model: window (128) and full attention
+    layers with 6 and 8 query heads over 2 key/value heads in TWO classes
+    of cache (rows of 1,024, rings of 128 + 128), a per-head gate, partial
+    YaRN rotary on the full layers, 16 experts of which 4, one shared,
+    behind a dense first layer: at widths the decode kernel (both classes,
+    both groups), the banded and the flash forward and the grouped product
+    take."""
+    from horovod_tpu.models import window_moe
+    return window_moe.WindowMoEConfig(
+        vocab_size=4096, d_model=512, head_dim=128, num_kv_heads=2,
+        layer_types=("full", "window", "window", "window", "full"),
+        heads_per_layer=(6, 8, 8, 8, 6), window=128,
+        rope_full=window_moe.Rotary(
+            theta=500000.0, fraction=0.5, factor=8.0, original_len=256,
+            beta_fast=32.0, beta_slow=1.0,
+            attention_factor=0.1 * math.log(8.0) + 1.0),
+        rope_window=window_moe.Rotary(theta=10000.0), d_ff=1024,
+        first_dense=1, num_experts=16, experts_per_tok=4, d_expert=256,
+        d_shared=256, route_scale=2.5, max_seq_len=1024,
+        attention_impl="flash")
 
 
 def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
@@ -389,7 +463,8 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     each served token must be the plain forward's argmax given the tokens
     before it (so a plain greedy decode yields the same sequence) or tie
     with it within ``tie_tol``. ``cfg`` is a TransformerConfig, a
-    HybridConfig, a LoopedConfig or a LatentMoEConfig (``_served_model``).
+    HybridConfig, a LoopedConfig, a LatentMoEConfig or a WindowMoEConfig
+    (``_served_model``).
     The line says which decode attention ran (``decode_attention``) and,
     for a model with experts, which grouped product (``experts``).
     ``routed_elsewhere`` (share, deficit): routing is discrete, and two
@@ -741,6 +816,12 @@ def main(argv=None):
         # leg's prompts produce (16, 112, 528 -> 640, 1008 -> 1024)
         leg_kernels([(16, 1024, heads, head_dim)] +
                     [(1, s, heads, head_dim) for s in (16, 112, 528, 1008)])
+        # the banded forward: a window under, at and over a 512-tile, both
+        # group sizes of the laguna_small leg, a length no block divides
+        leg_window_kernel([(1, 1024, 8, 2, 128, 128),
+                           (1, 2048, 16, 2, 128, 512),
+                           (2, 640, 6, 2, 128, 128),
+                           (1, 1008, 8, 8, 128, 700)])
     if "lm_train" in legs or "four_chips" in legs:
         first_loss = leg_lm_train(train_cfg, 16, 1024)
     if "resnet" in legs:
@@ -781,6 +862,12 @@ def main(argv=None):
             # seeded). Held to 2% and 0.8: an eleventh token or a wider
             # miss fails
             routed_elsewhere=(0.02, 0.8))
+        # window and full layers in two classes of cache, with experts;
+        # prompts of 513, 640 and 1,000 leave their last 128 tokens in a
+        # ring, and every row wraps it. Top 4 of 16: a token routed
+        # elsewhere is a quarter of its routed output
+        leg_serve(laguna_small_config(), kv_block=128, name="laguna_small",
+                  routed_elsewhere=LAGUNA_SMALL_ROUTED)
     if "four_chips" in legs:
         if jax.device_count() >= 4:
             leg_four_chips(train_cfg, 16, 1024, first_loss)

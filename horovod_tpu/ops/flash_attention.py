@@ -1396,3 +1396,142 @@ def latent_decode_attention(q, cache, lengths, plane, value_dim,
                      latent[..., :value_dim],
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+# -- causal attention under a WINDOW (models/window_moe.py's prefill) ---------
+
+def _band_span(qi, block_q, block_k, window):
+    """(first, last) k tile that q tile ``qi`` (a number or a traced index)
+    reads under the band ``0 <= i - j < window``: its first key is ``window
+    - 1`` before its first query (``first`` may be negative: no such tile),
+    its last the diagonal's."""
+    return (qi * block_q - window + 1) // block_k, \
+        ((qi + 1) * block_q - 1) // block_k
+
+
+def band_tiles(s, block_q, block_k, window):
+    """``_band_span`` of every q tile of a sequence of ``s``, as two lists
+    (first, last)."""
+    spans = [_band_span(qi, block_q, block_k, window)
+             for qi in range(s // block_q)]
+    return [lo for lo, _ in spans], [hi for _, hi in spans]
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                 block_q, block_k, window, scale, steps):
+    """The online forward (``_fwd_kernel``'s tile, statistic for statistic)
+    over the k tiles of ONE q tile's band and no other: the grid's last
+    axis walks them, ``steps`` a q tile, and the pipeline hands over tile
+    ``first + t`` (``_band_index``). A step whose tile lies before the
+    sequence or past the diagonal computes nothing; its block index is
+    clamped onto a neighbour's, so nothing is fetched for it either."""
+    qi, t = pl.program_id(1), pl.program_id(2)
+    d = q_ref.shape[-1]
+    lanes = math.gcd(128, block_k, d)
+    first, last = _band_span(qi, block_q, block_k, window)
+    kb = first + t
+
+    @pl.when(t == 0)
+    def _begin():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when((kb >= 0) & (kb <= last))
+    def _tile():
+        k, v = k_ref[0], v_ref[0]
+        s = jnp.dot(q_ref[0], k.T, preferred_element_type=jnp.float32) \
+            * (scale * _LOG2E)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where((k_pos <= q_pos) & (q_pos - k_pos < window), s,
+                      _NEG_INF)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp2(m - m_new)
+        # a row that has met no key of its band yet (its keys begin in the
+        # next tile) must not count this tile's masked ones: exp2(0) = 1
+        p = jnp.where(s > _NEG_INF / 2,
+                      jnp.exp2(s - jnp.tile(m_new, (1, block_k // lanes))),
+                      0.0)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * jnp.tile(alpha, (1, d // lanes)) \
+            + jnp.dot(p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+    @pl.when(t == steps - 1)
+    def _end():
+        l = jnp.clip(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / jnp.tile(l, (1, d // lanes))).astype(
+            o_ref.dtype)
+
+
+def _band_index(block_q, block_k, window, group):
+    """The k/v block of grid step (head, q tile, t): the band's tile
+    ``first + t`` of the key/value head that query head reads, clamped
+    into the band (a step outside it names a tile that is there already)."""
+    def index(i, j, t):
+        first, last = _band_span(j, block_q, block_k, window)
+        return i // group, jnp.clip(first + t, 0, last), 0
+    return index
+
+
+def window_attention(q, k, v, window, block=512, interpret=None):
+    """Causal self-attention in which key ``j`` is visible to query ``i``
+    iff ``0 <= i - j < window``: the forward alone (a serving prefill;
+    there is no backward here).
+
+    q [b, s, heads, d]; k, v [b, s, kv_heads, d], ``kv_heads`` dividing
+    ``heads`` (query head i reads key/value head i // (heads / kv_heads),
+    read as it lies and never repeated: the block index does the
+    grouping). Numerically ``parallel.ring.full_attention`` under the band
+    mask: fp32 softmax statistics, matmuls in the input dtype with fp32
+    accumulation. The kernel visits the band's tiles only, at most
+    ``(window + block - 2) // block + 2`` a q tile however long the
+    sequence is (two at ``block == window``), through the pipeline's own
+    blocks, so VMEM holds one tile of each operand. Sequences that no
+    block divides are end-padded as ``flash_attention`` pads them (the
+    causal mask hides the pad's keys), ``d`` to whole lane tiles when
+    compiled."""
+    b, s, h, d = q.shape
+    hk = k.shape[2]
+    if k.shape != v.shape or k.shape[1] != s or h % hk or window < 1:
+        raise ValueError(f"window_attention: q {q.shape}, k {k.shape}, "
+                         f"v {v.shape}, window {window}")
+    interpret = _auto_interpret() if interpret is None else interpret
+    scale = d ** -0.5
+    blk = call_block(block, s, compiled=not interpret)
+    pad_s = -s % blk
+    pad_d = 0 if interpret else -d % 128
+    if pad_s or pad_d:
+        pads = ((0, 0), (0, pad_s), (0, 0), (0, pad_d))
+        q, k, v = jnp.pad(q, pads), jnp.pad(k, pads), jnp.pad(v, pads)
+    sp, dp = s + pad_s, d + pad_d
+    first, last = band_tiles(sp, blk, blk, window)
+    steps = max(hi - lo for lo, hi in zip(first, last)) + 1
+    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sp, dp)
+    kf = k.transpose(0, 2, 1, 3).reshape(b * hk, sp, dp)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * hk, sp, dp)
+    kv_spec = pl.BlockSpec((1, blk, dp),
+                           _band_index(blk, blk, window, h // hk))
+    lanes = math.gcd(128, blk, dp)
+    out = pl.pallas_call(
+        functools.partial(_band_kernel, block_q=blk, block_k=blk,
+                          window=window, scale=scale, steps=steps),
+        grid=(b * h, sp // blk, steps),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        in_specs=[pl.BlockSpec((1, blk, dp), lambda i, j, t: (i, j, 0)),
+                  kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, blk, dp), lambda i, j, t: (i, j, 0)),
+        out_shape=_out_struct((b * h, sp, dp), q.dtype, qf, kf, vf),
+        scratch_shapes=[pltpu.VMEM((blk, lanes), jnp.float32),
+                        pltpu.VMEM((blk, lanes), jnp.float32),
+                        pltpu.VMEM((blk, dp), jnp.float32)],
+        name="window_attention",
+        interpret=interpret,
+    )(qf, kf, vf)
+    return out.reshape(b, h, sp, dp).transpose(0, 2, 1, 3)[:, :s, :, :d]

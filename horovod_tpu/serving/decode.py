@@ -14,10 +14,10 @@ tests/test_flash_attention.py and tests/test_serving.py pin it by
 asserting logits equality and token-for-token greedy agreement against
 ``TransformerLM.apply``.
 
-A model of another kind (models/hybrid.py: a recurrent mixer beside
-grouped-query attention; models/latent_moe.py: latent attention, dropless
-experts) brings its own two forwards; ``state_shapes``, ``prefill`` and
-``decode`` at the end are what the engine and the cache call, by type.
+A model of another kind (models/hybrid.py: a recurrent mixer; latent_moe.py:
+latent attention, dropless experts; window_moe.py: window and full layers)
+brings its own two forwards; ``state_shapes``, ``prefill`` and ``decode`` at
+the end are what the engine and the cache call, by type.
 A model whose layer stack runs several times over one set of weights
 (models/looped.py) is served by THIS module: ``_stack`` is the dense block
 of ``prefill_forward`` / ``decode_step`` with a norm closing each branch,
@@ -40,7 +40,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid, latent_moe, looped
+from ..models import hybrid, latent_moe, looped, window_moe
 from ..models.transformer import _dispatch_attention, _rope
 from ..ops.flash_attention import decode_attention
 from ..parallel import mesh as mesh_lib
@@ -76,8 +76,8 @@ def _logits(cfg, params, x):
 def _check_dense(cfg):
     if cfg.num_experts > 0:
         raise NotImplementedError(
-            "a TransformerConfig is served dense (num_experts=0): experts "
-            "are served by models/latent_moe.py (LatentMoEConfig), dropless")
+            "a TransformerConfig is served dense (num_experts=0): experts are "
+            "served dropless, by a LatentMoEConfig or a WindowMoEConfig")
 
 
 def _check_served(cfg):
@@ -348,7 +348,8 @@ def decode(cfg, params, tokens, positions, state, mask=None):
 
 
 _OWN_FORWARDS = {hybrid.HybridConfig: hybrid,
-                 latent_moe.LatentMoEConfig: latent_moe}
+                 latent_moe.LatentMoEConfig: latent_moe,
+                 window_moe.WindowMoEConfig: window_moe}
 
 
 def positional_kinds(cfg):
@@ -359,6 +360,15 @@ def positional_kinds(cfg):
     pass must leave bit for bit for the rows it does not decode. ``k`` and
     ``v``, or what a model of its own declares (``POSITIONAL``)."""
     return getattr(_own(cfg), "POSITIONAL", ("k", "v"))
+
+
+def ring_kinds(cfg):
+    """The positional kinds that are RINGS (models/window_moe.py: K and V of
+    a layer whose attention looks back ``cfg.window`` tokens and no
+    further): a row holds ``cfg.window`` entries and a place to park,
+    position ``p`` at ``p mod cfg.window``, whatever ``max_len`` is. None
+    for every other model."""
+    return getattr(_own(cfg), "RING", ())
 
 
 #: what a model with experts returns from ``decode`` as a third result, an

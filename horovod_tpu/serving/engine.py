@@ -91,7 +91,6 @@ import numpy as np
 
 from ..common import config
 from ..common.exceptions import RanksLostError
-from ..ops.flash_attention import decode_block
 from ..utils import alerts as hvd_alerts
 from ..utils import history as hvd_history
 from ..utils import memory as hvd_memory
@@ -103,6 +102,7 @@ from .kv_cache import KVCache
 from .queue import AdmissionQueue, RequestResult
 from .sampling import sample_tokens
 from .scheduler import SlotScheduler
+
 
 log = logging.getLogger("horovod_tpu.serving")
 
@@ -149,8 +149,8 @@ def _write_slot(state, row, slot, ids, token):
     cache row ``slot`` (dynamic index) of every kind, and its first
     ``token`` into the device's ``ids`` at that slot: (state, ids). The
     extent along the axis after the slot is the row's own (static): the
-    padded prefix for K/V, the whole of it for a kind that is no sequence
-    — nothing of the slot's last occupant is left in those."""
+    padded prefix for K/V (of a ring, its window at most), the whole of it
+    for a kind that is no sequence: nothing of the last occupant is left."""
     state = {kind: arr.at[:, slot, :row[kind].shape[2]].set(row[kind][:, 0])
              for kind, arr in state.items()}
     return state, ids.at[slot].set(token)
@@ -343,11 +343,11 @@ class ServeEngine:
         # bytes of recurrent state one row holds over all layers: what a
         # decode pass reads and writes again per row it advances
         self._row_state_bytes = self.kv.row_state_bytes()
-        # the blocking under which decode attention reads a row's K/V
-        # (ops/flash_attention.py), and the bytes one block of one row
-        # holds over all planes: the step record's ``kv_bytes``
-        self._kv_block = decode_block(self.kv.max_len)
-        self._kv_block_bytes = self.kv.kv_block_bytes(self._kv_block)
+        # the step record's ``kv_bytes`` (what decode attention has to
+        # stream of each decoding row, under the blocking its kernel reads
+        # a row in) is the cache's to count, from its arrays' own shapes and
+        # for each class of positional state by its own row length and
+        # planes: ``KVCache.count_reads``, at the launch in ``_decode``
         # cache-writing programs whose first call on this engine has
         # yet to show that it consumed its arrays (_note_in_place)
         self._in_place_unchecked = {"write_slot", "decode"}
@@ -885,15 +885,15 @@ class ServeEngine:
                 if not slots:
                     continue
                 positions, temps, rows = feed or self._place_rows(slots)
-                blocks = 0
+                lengths = []
                 for slot in slots:
                     st = self._active[slot]
                     st.next_pos += 1
                     launched.append((slot, st))
-                    blocks += -(-st.next_pos // self._kv_block)
-                # what attention has to stream: each decoding row's K/V
-                # in whole blocks up to its length, known without a read
-                rec.count("kv_bytes", blocks * self._kv_block_bytes)
+                    lengths.append(st.next_pos)
+                # what attention has to stream: each decoding row's K/V in
+                # whole blocks up to its length (a ring's: to its window)
+                self.kv.count_reads(rec, lengths)
                 if self.kv.recurrent:
                     rec.count("state_rows", len(slots))
                     rec.count("state_bytes",

@@ -14,7 +14,10 @@ Two halves, deliberately separated:
     window beside them — one ledger, one slot index, one lifetime. The
     model also declares which kinds are POSITIONAL (one entry a position,
     hidden by the row's length: K and V, or the one latent of
-    models/latent_moe.py); every other kind is RECURRENT. A
+    models/latent_moe.py), and which of those are RINGS (K and V of a
+    layer that looks back a window and no further, models/window_moe.py:
+    a row is ``window`` entries and a place to park, not ``max_len``);
+    every other kind is RECURRENT. A
     PLANE is one layer's state; a stack that runs several times over its
     weights (models/looped.py) keeps one per (pass, layer), pass-major,
     so there are more planes than weight layers. Dense rather
@@ -160,12 +163,18 @@ class KVCache:
     each kind is positional or recurrent BY DECLARATION
     (``serving/decode.positional_kinds``), not by its name:
 
-      * ``positional``: ``[planes, slots, max_len, ...]``, one entry a
+      * ``positional``: ``[planes, slots, entries, ...]``, one entry a
         position of the row. Attention hides what lies above a row's
-        length, so a row that a pass does not decode parks its write at
-        ``max_len - 1``, a prefill writes the padded prefix and the
-        step record's ``kv_bytes`` counts these kinds
-        (``kv_block_bytes``). ``k`` and ``v`` ``[planes, slots, max_len,
+        length, so a row that a pass does not decode parks its write
+        behind it, a prefill writes the padded prefix and the step
+        record's ``kv_bytes`` counts these kinds (``count_reads``). A row
+        is ``max_len`` entries (parked at ``max_len - 1``), except of the
+        kinds in ``ring``: there it is ``window`` entries, position ``p``
+        at ``p mod window``, and a place to park OUTSIDE them (index
+        ``window``), so that a row never holds more than its last
+        ``window`` tokens and a decode pass never reads more. Classes
+        differ in their number of planes too: a model's full layers and
+        its window layers each stack their own. ``k`` and ``v`` ``[planes, slots, max_len,
         kv_heads, head_dim]`` for a dense, a looped and a hybrid model
         (``.k``/``.v`` read and rebind them; ``planes`` is the layers,
         times the passes of a looped stack); ``latent`` ``[planes, slots,
@@ -223,6 +232,9 @@ class KVCache:
         self.positional = tuple(k for k in decode.positional_kinds(cfg)
                                 if k in shapes)
         self.recurrent = tuple(sorted(set(shapes) - set(self.positional)))
+        # the positional kinds whose rows are rings of ``window`` entries
+        self.ring = tuple(k for k in decode.ring_kinds(cfg) if k in shapes)
+        self.window = cfg.window if self.ring else None
         if mesh is not None:
             # Tensor-parallel serving (docs/mesh.md): K and V gain a
             # head-sharded NamedSharding over the mesh's tp axis, so each
@@ -239,12 +251,30 @@ class KVCache:
                     f"a {type(cfg).__name__}'s cache "
                     f"({', '.join(self.positional)}) has no sharding over a "
                     "mesh yet: a latent is one key head, which tp cannot "
-                    "split (ROADMAP R4)")
+                    "split (ROADMAP R4), and a cache of two classes wants "
+                    "one spec a class (ROADMAP R3)")
             spec = mesh_lib.kv_cache_spec(shapes["k"].shape[3], mesh)
             self.k, self.v = mesh_lib.device_put_tree(
                 (self.k, self.v), (spec, spec), mesh)
             self.vector_sharding = mesh_lib.named_sharding(None, mesh)
         self.max_len = max_len
+        # the blocking under which decode attention reads a row of each
+        # class, and the bytes one block of ONE slot holds over the class's
+        # planes: what ``count_reads`` multiplies
+        from ..ops.flash_attention import decode_block
+        self._reads = []  # (block, bytes a block, entries a row may hold)
+        for kinds in (self._full, self.ring):
+            if kinds:
+                entries = self.arrays[kinds[0]].shape[2]
+                block = decode_block(entries)
+                self._reads.append(
+                    (block, self._block_bytes(kinds, block),
+                     self.window if kinds is self.ring else max_len))
+
+    @property
+    def _full(self):
+        """The positional kinds whose rows are ``max_len`` long."""
+        return tuple(k for k in self.positional if k not in self.ring)
 
     @property
     def k(self):
@@ -265,8 +295,10 @@ class KVCache:
     @property
     def planes(self):
         """Planes of the positional kinds: one a layer, or one a (pass,
-        layer) of a stack that runs several times."""
-        return self.arrays[self.positional[0]].shape[0]
+        layer) of a stack that runs several times; over both classes where
+        a model has window layers beside full ones."""
+        return sum(self.arrays[kinds[0]].shape[0]
+                   for kinds in (self._full, self.ring) if kinds)
 
     def bytes_by_kind(self):
         """{kind: bytes resident on ONE chip} over all its planes (the
@@ -296,14 +328,36 @@ class KVCache:
         return sum(by_kind[kind] for kind in self.recurrent) \
             // self.num_slots
 
-    def kv_block_bytes(self, block):
-        """Bytes of the positional kinds (K and V, or the latent) that
-        ``block`` positions of ONE slot hold over all planes (on one
-        chip): what a decode step streams for each block of a row it
-        reads (every pass of a looped stack reads its own planes)."""
+    def _block_bytes(self, kinds, block):
         by_kind = self.bytes_by_kind()
-        return sum(by_kind[kind] for kind in self.positional) * block \
-            // (self.num_slots * self.max_len)
+        return sum(by_kind[kind] * block
+                   // (self.num_slots * self.arrays[kind].shape[2])
+                   for kind in kinds)
+
+    def kv_block_bytes(self, block):
+        """Bytes of the positional kinds of ``max_len`` a row (K and V, or
+        the latent) that ``block`` positions of ONE slot hold over all
+        their planes (on one chip): what a decode step streams for each
+        block of a row it reads (every pass of a looped stack reads its
+        own planes). A ring's blocks are ``ring_block_bytes``."""
+        return self._block_bytes(self._full, block)
+
+    def ring_block_bytes(self, block):
+        """``kv_block_bytes`` of the kinds in ``ring``, over their planes:
+        0 for a model without window layers."""
+        return self._block_bytes(self.ring, block)
+
+    def count_reads(self, rec, lengths):
+        """Into the step record ``rec``: ``kv_bytes``, what a decode pass
+        over rows of ``lengths`` has to stream of the positional kinds,
+        each row in whole blocks (``ops/flash_attention.decode_block``) up
+        to its length, and of a ring up to ``min(length, window)``; and,
+        of a model with rings only, ``window_kv_bytes``: the rings' part."""
+        parts = [nbytes * sum(-(-min(n, most) // block) for n in lengths)
+                 for block, nbytes, most in self._reads]
+        rec.count("kv_bytes", sum(parts))
+        if self.ring:  # the last class
+            rec.count("window_kv_bytes", parts[-1])
 
     @property
     def num_slots(self):

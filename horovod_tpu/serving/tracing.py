@@ -375,7 +375,10 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # kv_bytes (not in STEP_COUNTS: only a step that decoded has it): bytes of
 # K and V the step's decode passes had to stream, each decoding row's in
 # whole blocks of ops/flash_attention.decode_block positions up to its
-# length, over all planes (layers x passes)
+# length, over all planes (layers x passes); of a ring (K and V of a window
+# layer, models/window_moe.py) up to min(length, window)
+# window_kv_bytes (likewise, and only a model with rings): the rings' part
+# of kv_bytes
 # passes (not in STEP_COUNTS, likewise): stack passes the step's decode
 # program runs each row, from the configuration: the passes of a looped
 # stack (models/looped.py), 1 for every other model

@@ -46,6 +46,17 @@ def test_kernels_leg(hvd, capsys):
     assert _last_json(capsys)["leg"] == "kernels"
 
 
+def test_window_kernel_leg(capsys):
+    import jax.numpy as jnp
+    chip_smoke.leg_window_kernel([(1, 40, 4, 2, 16, 8), (2, 32, 3, 1, 16, 16)],
+                                 atol=1e-4, dtype=jnp.float32)
+    line = _last_json(capsys)
+    assert line["leg"] == "window_kernel" and line["max_abs_err"] < 1e-4
+    with pytest.raises(AssertionError, match="against masked attention"):
+        chip_smoke.leg_window_kernel([(1, 32, 2, 1, 16, 8)], atol=-1.0,
+                                     dtype=jnp.float32)
+
+
 def test_lm_train_and_four_chip_legs(tiny, capsys):
     import horovod_tpu as hvd
     try:
@@ -73,7 +84,7 @@ def test_resnet_leg(capsys):
 
 
 @pytest.mark.parametrize("family", ["transformer", "hybrid", "looped",
-                                    "latent_moe"])
+                                    "latent_moe", "window_moe"])
 def test_serve_leg(tiny, capsys, family):
     """Every family through the one leg: a dense decoder, a model that
     keeps recurrent and convolution state beside its K/V, a stack that
@@ -89,16 +100,22 @@ def test_serve_leg(tiny, capsys, family):
     elif family == "latent_moe":
         from horovod_tpu.models import latent_moe
         tiny = latent_moe.LatentMoEConfig.tiny(dtype=jnp.float32)
+    elif family == "window_moe":  # window 8: every request wraps a ring
+        from horovod_tpu.models import window_moe
+        tiny = window_moe.WindowMoEConfig.tiny(dtype=jnp.float32)
     chip_smoke.leg_serve(tiny, slots=2, max_len=32, kv_block=8,
                          lengths=(3, 8, 12), tie_tol=1e-4, name=family)
     line = _last_json(capsys)
     assert line["model"] == family
     # the CPU backend: the einsum, whatever the kind
     assert line["decode_attention"] == {
-        "kinds": ["latent"] if family == "latent_moe" else ["k", "v"],
+        "kinds": {"latent_moe": ["latent"],
+                  "window_moe": ["k", "v", "k_ring", "v_ring"]}.get(
+                      family, ["k", "v"]),
         "kernel": False}
-    # ...and ragged_dot for the one family with experts
-    assert line["experts"] == ({"kernel": False} if family == "latent_moe"
+    # ...and ragged_dot for the two families with experts
+    assert line["experts"] == ({"kernel": False}
+                               if family in ("latent_moe", "window_moe")
                                else None)
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
@@ -474,6 +491,137 @@ def test_a_latent_cache_is_read_in_place_and_experts_are_grouped(
     for scope in ("hvd.moe.route", "hvd.moe.experts", "hvd.moe.shared"):
         assert scope in text, scope
     # what a pass routed comes back beside ids, positions and the cache
+    shapes = [str(s.shape) for s in jax.tree_util.tree_leaves(
+        compiled.out_info)]
+    assert shapes.count("(2,)") == 1
+
+
+@pytest.mark.parametrize("program", ["decode_kernel", "prefill"])
+def test_two_classes_of_cache_are_read_in_place_by_one_kernel(topo, program,
+                                                              request):
+    """The TPU compiler's word for window and full attention layers with
+    different head counts and 256 narrow experts (models/window_moe.py) at
+    Laguna-XS.2 widths (48 and 64 query heads over 8 key/value heads of
+    128, window 512, 256 experts of 512 of which 8, one shared, 64 slots x
+    5120; one dense full layer and one window expert layer so it compiles
+    in seconds). Decode: BOTH classes of K/V are aliased to the donated
+    input and no whole copy of either, or of a stack, is held; the ONE
+    decode kernel runs once a layer, at a group of 6 over the full plane
+    and of 8 over the ring (640 entries a row), each read whole and in
+    place; the experts' grouped SwiGLU is the Mosaic kernel
+    (``grouped_matmul.selected`` at ``[256, 2048, 512]``: two experts are
+    12.6 MB of the 48 MB it may fill) over 512 assignments, the stacks as
+    they lie. Prefill of 512 tokens: the flash kernel on the full layer,
+    the banded kernel on the window layer with K/V at their own 8 heads,
+    the ring's row is the window and no more, and 4,096 assignments are
+    the kernel's last; a prompt of 1,024 is past ``MAX_ROWS`` and takes
+    ``jax.lax.ragged_dot``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import window_moe
+    from horovod_tpu.ops import grouped_matmul
+    from horovod_tpu.serving import decode as serve_decode
+    from horovod_tpu.serving import engine as engine_mod
+
+    slots, max_len = 64, 5120
+    cfg = window_moe.WindowMoEConfig(
+        vocab_size=100352, d_model=2048, head_dim=128, num_kv_heads=8,
+        layer_types=("full", "window"), heads_per_layer=(48, 64),
+        window=512,
+        rope_full=window_moe.Rotary(
+            theta=500000.0, fraction=0.5, factor=64.0, original_len=4096,
+            beta_fast=64.0, beta_slow=1.0,
+            attention_factor=1.4158883083359672),
+        rope_window=window_moe.Rotary(theta=10000.0), d_ff=8192,
+        first_dense=1, num_experts=256, experts_per_tok=8, d_expert=512,
+        d_shared=512, route_scale=2.5, max_seq_len=262144,
+        dtype=jnp.bfloat16, attention_impl="flash")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    state = {k: arr(a.shape, a.dtype) for k, a in
+             serve_decode.state_shapes(cfg, slots, max_len).items()}
+    assert {k: a.shape for k, a in state.items()} == {
+        "k": (1, slots, max_len, 8, 128), "v": (1, slots, max_len, 8, 128),
+        "k_ring": (1, slots, 640, 8, 128), "v_ring": (1, slots, 640, 8, 128)}
+    params = jax.tree_util.tree_map(
+        lambda a: arr(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: window_moe.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    request.getfixturevalue("decode_kernel")
+    stack = (256, 2048, 512)
+    assert grouped_matmul.selected(slots * 8, stack, jnp.bfloat16)
+    assert grouped_matmul.selected(512 * 8, stack, jnp.bfloat16)
+    assert not grouped_matmul.selected(1024 * 8, stack, jnp.bfloat16)
+
+    def grouped(text, rows):
+        calls = re.findall(r"%grouped_swiglu[.\d]* = .*tpu_custom_call.*",
+                           text)
+        for call in calls:
+            assert re.match(rf"%grouped_swiglu[.\d]* = bf16\[{rows},2048\]",
+                            call) and "hvd.moe.experts" in call, call[:400]
+            assert call.count("bf16[256,2048,512]") == 2 and \
+                call.count("bf16[256,512,2048]") == 1, call[:400]
+        return len(calls)
+    if program == "prefill":
+        for s_pad, products in ((512, "kernel"), (1024, "ragged")):
+            engine_mod._prefill_jit.clear_cache()
+            try:
+                text = engine_mod._prefill_jit.lower(
+                    cfg, params, arr((1, s_pad), jnp.int32),
+                    arr((), jnp.int32), arr((), jnp.float32),
+                    arr((2,), jnp.uint32)).compile().as_text()
+            finally:
+                engine_mod._prefill_jit.clear_cache()
+            band = re.findall(
+                r"%window_attention[.\d]* = .*tpu_custom_call.*", text)
+            assert len(band) == 1 and "hvd.swa.attend" in band[0]
+            assert f"bf16[64,{s_pad},128]" in band[0] and \
+                band[0].count(f"bf16[8,{s_pad},128]") == 2   # K/V as they lie
+            flash = [line for line in text.splitlines()
+                     if "tpu_custom_call" in line and "hvd.full.attend" in line
+                     and line.count(f"bf16[48,{s_pad},128]") >= 4]
+            assert len(flash) == 1
+            # what the row leaves: the padded prefix, and a window at most
+            assert f"bf16[1,1,{s_pad},8,128]" in text
+            assert "bf16[1,1,512,8,128]" in text
+            if products == "kernel":
+                assert grouped(text, 4096) == 1 and "ragged-dot" not in text
+            else:
+                assert grouped(text, 8192) == 0 and "ragged-dot" in text
+        return
+    compiled = engine_mod._decode_jit.lower(
+        cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+        state, arr((slots,), jnp.float32), arr((slots,), jnp.bool_),
+        arr((2,), jnp.uint32), arr((), jnp.int32)).compile()
+    cache_bytes = 2 * slots * (max_len + 640) * 8 * 128 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    copies = _whole_copies(
+        compiled, (jnp.dtype(jnp.bfloat16), (1, slots, max_len, 8, 128)),
+        (jnp.dtype(jnp.bfloat16), (1, slots, max_len * 8, 128)),
+        (jnp.dtype(jnp.bfloat16), (1, slots, 640, 8, 128)),
+        (jnp.dtype(jnp.bfloat16), (1, slots, 640 * 8, 128)),
+        (jnp.dtype(jnp.bfloat16), stack),
+        (jnp.dtype(jnp.bfloat16), (256, 512, 2048)))
+    assert not copies, copies
+    text = compiled.as_text()
+    calls = re.findall(r"%decode_attention[.\d]* = .*tpu_custom_call.*",
+                       text)
+    assert len(calls) == 2
+    full, = (c for c in calls if "hvd.full.attend" in c)
+    ring, = (c for c in calls if "hvd.swa.attend" in c)
+    # 48 query heads over the rows of 5,120, 64 over the rings of 640
+    assert "bf16[64,48,128]" in full and \
+        full.count(f"bf16[1,64,{max_len * 8},128]") == 2
+    assert "bf16[64,64,128]" in ring and \
+        ring.count(f"bf16[1,64,{640 * 8},128]") == 2
+    assert grouped(text, 512) == 1 and "ragged-dot" not in text
+    for scope in ("hvd.moe.route", "hvd.moe.experts", "hvd.moe.shared"):
+        assert scope in text, scope
     shapes = [str(s.shape) for s in jax.tree_util.tree_leaves(
         compiled.out_info)]
     assert shapes.count("(2,)") == 1

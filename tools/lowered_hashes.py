@@ -1,8 +1,9 @@
 """Does a change leave the programs alone? sha256 of the lowered text of
 five training steps, of ``sorted(sys.modules)`` after them, and of the three
 serving programs as ``ServeEngine`` itself feeds them (dense, hybrid,
-looped and latent attention with experts at the tests' sizes; greedy and
-sampled requests, one of them for a single token), with a hash of the
+looped, latent attention with experts, and window layers with experts, at
+the tests' sizes; greedy and sampled requests, one of them for a single
+token), with a hash of the
 tokens served. Run it from the root of
 two trees and compare the lines (the set-up protocol, PERF.md §6):
 
@@ -83,6 +84,12 @@ def served_models():
     yield "looped", cfg, looped.init_params(cfg, key)
     cfg = latent_moe.LatentMoEConfig.tiny(max_seq_len=64, dtype=jnp.float32)
     yield "latent_moe", cfg, latent_moe.init_params(cfg, key)
+    try:  # a tree from before the family: its lines are absent, no more
+        from horovod_tpu.models import window_moe
+    except ImportError:
+        return
+    cfg = window_moe.WindowMoEConfig.tiny(max_seq_len=64, dtype=jnp.float32)
+    yield "window_moe", cfg, window_moe.init_params(cfg, key)
 
 
 REQUESTS = [((5, 9, 17), 9, 0.0), ((4, 8, 15, 16, 23, 42, 1, 2, 3, 4), 13, 0.8),
