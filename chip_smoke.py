@@ -178,6 +178,85 @@ def leg_window_kernel(cases, atol=KERNEL_ATOL, dtype=None):
          atol=atol, seconds=round(time.perf_counter() - t0, 2))
 
 
+def grouped_product(assignments, stack_shape, dtype):
+    """What computes a grouped SwiGLU of ``assignments`` rows over stacks
+    of ``stack_shape`` ``[E, d, f]``: this repo's Mosaic kernel with the
+    rows ``resident`` in VMEM or ``streamed`` through it from HBM
+    (ops/grouped_matmul.py decides from the call: never on the CPU, over
+    a mesh, for float32 or widths that are no whole lane tiles), or
+    ``ragged_dot``."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import grouped_matmul
+    if not grouped_matmul.selected(assignments, stack_shape, dtype):
+        return "ragged_dot"
+    return "resident" if grouped_matmul.resident(
+        assignments, stack_shape[1], jnp.dtype(dtype).itemsize) \
+        else "streamed"
+
+
+def leg_grouped_kernel(cases, atol=KERNEL_ATOL, dtype=None, products=None):
+    """The routed experts' grouped SwiGLU (``models/moe.experts``, which
+    picks the product from the call) against a plain loop over the experts
+    in float32 from the same operands; ``cases`` are (tokens, experts per
+    token, experts, hidden width, expert width, real tokens: the rest are
+    padding under the mask). ``products``: what each case has to run
+    (``grouped_product``), for a case chosen to drive one path."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import moe
+
+    dtype = dtype or jnp.bfloat16
+    t0 = time.perf_counter()
+    worst = 0.0
+    ran = [grouped_product(t * k, (num, d, f), dtype)
+           for t, k, num, d, f, _ in cases]
+    _check(products is None or ran == list(products),
+           f"grouped products {ran}, wanted {products}")
+    for t, k, num, d, f, real in cases:
+        keys = jax.random.split(jax.random.PRNGKey(t + num), 5)
+        y = jax.random.normal(keys[0], (t, d), jnp.float32).astype(dtype)
+        gate, up, down = (
+            (jax.random.normal(key, shape, jnp.float32)
+             / shape[1] ** 0.5).astype(dtype)
+            for key, shape in zip(keys[1:4], ((num, d, f), (num, d, f),
+                                              (num, f, d))))
+        idx, weights = moe.route(
+            y, jax.random.normal(keys[4], (d, num), jnp.float32), None, k)
+        mask = jnp.arange(t) < real
+
+        def loop(y, idx, weights, gate, up, down, mask):
+            y = y.astype(jnp.float32)
+            share = jnp.sum(jax.nn.one_hot(idx, num) * weights[..., None],
+                            axis=1) * mask[:, None]           # [t, E]
+
+            def one(e, out):
+                g, u, w = (a[e].astype(jnp.float32) for a in (gate, up, down))
+                return out + share[:, e, None] * (
+                    (jax.nn.silu(y @ g) * (y @ u)) @ w)
+            return jax.lax.fori_loop(0, num, one, jnp.zeros_like(y))
+        args = (y, idx, weights, gate, up, down, mask)
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(loop)(*args))
+        got, load = jax.jit(lambda *a: moe.experts(*a))(*args)
+        got = np.asarray(got.astype(jnp.float32))
+        case = (t, k, num, d, f, real)
+        _check(int(np.asarray(load).sum()) == real * k,
+               f"experts at {case}: {int(np.asarray(load).sum())} "
+               f"assignments, wanted {real * k}")
+        _check(np.isfinite(got).all() and not got[real:].any(),
+               f"experts at {case}: not finite, or a padding token got "
+               f"an expert")
+        err = float(np.max(np.abs(got - want)))
+        _check(err <= atol, f"experts at {case}: max abs err {err:.4g} > "
+               f"{atol} against the loop over the experts")
+        worst = max(worst, err)
+    emit("grouped_kernel", cases=[list(c) for c in cases], products=ran,
+         max_abs_err=worst, atol=atol,
+         seconds=round(time.perf_counter() - t0, 2))
+
+
 # ---------------------------------------------------------------------------
 # LM train
 # ---------------------------------------------------------------------------
@@ -413,21 +492,26 @@ def decode_attention_selected(cfg, slots, max_len):
     return {"kinds": kinds, "kernel": bool(kernel)}
 
 
-def grouped_experts_selected(cfg, slots):
-    """Which grouped product a decode pass over ``slots`` rows runs for a
-    model with routed experts: this repo's Mosaic kernel
-    (ops/grouped_matmul.py decides from the call: False on the CPU, over a
-    mesh, for float32 or widths that are no whole lane tiles) or
-    ``jax.lax.ragged_dot``. None for a model without experts."""
+def grouped_experts_selected(cfg, slots, prefill_lengths=()):
+    """Which grouped product a model with routed experts runs
+    (``grouped_product``): ``kernel`` for a decode pass over ``slots``
+    rows, and under ``prefill`` what each padded prompt length of
+    ``prefill_lengths`` runs, so that the line says whether ANY program of
+    the model still takes ``jax.lax.ragged_dot``. None for a model without
+    experts."""
     from horovod_tpu.models import latent_moe, window_moe
-    from horovod_tpu.ops import grouped_matmul
     if not isinstance(cfg, (latent_moe.LatentMoEConfig,
                             window_moe.WindowMoEConfig)) or \
             not cfg.expert_layers:
         return None
-    return {"kernel": bool(grouped_matmul.selected(
-        slots * cfg.experts_per_tok,
-        (cfg.num_experts, cfg.d_model, cfg.d_expert), cfg.dtype))}
+
+    def product(tokens):
+        return grouped_product(
+            tokens * cfg.experts_per_tok,
+            (cfg.num_experts, cfg.d_model, cfg.d_expert), cfg.dtype)
+    return {"kernel": product(slots) != "ragged_dot",
+            "prefill": {str(s): product(s)
+                        for s in sorted(set(prefill_lengths))}}
 
 
 def laguna_small_config():
@@ -466,7 +550,8 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     HybridConfig, a LoopedConfig, a LatentMoEConfig or a WindowMoEConfig
     (``_served_model``).
     The line says which decode attention ran (``decode_attention``) and,
-    for a model with experts, which grouped product (``experts``).
+    for a model with experts, which grouped product in the decode pass and
+    in the prefill of each padded length (``experts``).
     ``routed_elsewhere`` (share, deficit): routing is discrete, and two
     bfloat16 paths of one model with experts (the expanded plain forward,
     the served prefill and absorbed decode) round a token's router scores
@@ -568,7 +653,9 @@ def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
     emit("serve", model=name, layers=cfg.num_layers,
          slots=slots, max_len=max_len, kv_block=kv_block,
          decode_attention=decode_attention_selected(cfg, slots, max_len),
-         experts=grouped_experts_selected(cfg, slots),
+         experts=grouped_experts_selected(
+             cfg, slots, [min(-(-n // kv_block) * kv_block, max_len)
+                          for n in lengths]),
          requests=len(reqs), prompt_lengths=list(lengths),
          new_tokens=[r.max_new_tokens for r in reqs], tokens=total,
          greedy_exact=exact, greedy_ties=ties, greedy_missed=missed,
@@ -822,6 +909,14 @@ def main(argv=None):
                            (1, 2048, 16, 2, 128, 512),
                            (2, 640, 6, 2, 128, 128),
                            (1, 1008, 8, 8, 128, 700)])
+        # the grouped product at Laguna-XS.2's hidden width: rows resident
+        # (4,096 assignments, 64 a group) and streamed (8,192 of which
+        # 6,000 real, 94 a group, padding behind the last; 16,384 over 256
+        # experts, 64 a group)
+        leg_grouped_kernel([(512, 8, 64, 2048, 512, 512),
+                            (1024, 8, 64, 2048, 512, 750),
+                            (2048, 8, 256, 2048, 512, 2048)],
+                           products=["resident", "streamed", "streamed"])
     if "lm_train" in legs or "four_chips" in legs:
         first_loss = leg_lm_train(train_cfg, 16, 1024)
     if "resnet" in legs:

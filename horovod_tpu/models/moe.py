@@ -18,13 +18,15 @@ the GShard/Switch pattern, not a per-device gather/scatter runtime:
 
 Serving has another contract, and another layer for it at the end of this
 module: ``route`` and ``experts``, plain functions over parameter leaves
-(models/latent_moe.py calls them in its prefill and its decode). That
-layer is DROPLESS: there is no capacity, every token gets every expert it
-chose, and an expert that no token chose is not computed and its weights
-are not read (tokens sorted by expert, one grouped product over the
-experts routed to: ops/grouped_matmul.py's kernel on one TPU chip,
-``jax.lax.ragged_dot`` elsewhere). ``MoEMLP`` above stays what the
-training path runs.
+(models/latent_moe.py and models/window_moe.py call them in their prefill
+and their decode). That layer is DROPLESS: there is no capacity, every
+token gets every expert it chose, and an expert that no token chose is not
+computed and its weights are not read (tokens sorted by expert, one
+grouped product over the experts routed to: ops/grouped_matmul.py's kernel
+on one TPU chip at ANY number of assignments, the sorted rows resident in
+VMEM where they fit and streamed through it from HBM where they do not,
+which the kernel decides from the shape; ``jax.lax.ragged_dot``
+elsewhere). ``MoEMLP`` above stays what the training path runs.
 """
 
 from typing import Optional
@@ -150,10 +152,12 @@ def experts(y, idx, weights, gate, up, down, mask=None):
     weights, and no expert is computed for a token under a mask. On one
     TPU chip, in bfloat16 at widths of whole lane tiles, that is ONE
     Mosaic kernel that streams each touched expert's three matrices once
-    and keeps the hidden in VMEM (ops/grouped_matmul.py, which decides
-    from the call: ``selected``); everywhere else three
-    ``jax.lax.ragged_dot``. The outputs go back to the tokens' order and
-    are summed under their weights in float32.
+    and keeps the hidden in VMEM, whatever the number of rows
+    (ops/grouped_matmul.py, which decides from the call: ``selected``;
+    the sorted rows are gathered with ``room`` behind them for its last
+    window, and stay in HBM where VMEM does not hold them); everywhere
+    else three ``jax.lax.ragged_dot``. The outputs go back to the tokens'
+    order and are summed under their weights in float32.
 
     ``mask`` [t] bool: a token outside it (a slot that does not decode, a
     prompt's padding) is routed to NO expert (its assignments sort behind
@@ -169,8 +173,13 @@ def experts(y, idx, weights, gate, up, down, mask=None):
     order = jnp.argsort(flat, stable=True)
     load = jnp.sum(flat[:, None] == jnp.arange(num, dtype=flat.dtype),
                    axis=0, dtype=jnp.int32)
-    rows = jnp.take(y, order // k, axis=0)               # [t * k, d]
-    if grouped_matmul.selected(t * k, gate.shape, rows.dtype):
+    source = order // k              # the token of each sorted assignment
+    kernel = grouped_matmul.selected(t * k, gate.shape, y.dtype)
+    if kernel:  # its windows are whole: room behind the last row for one
+        source = jnp.pad(source, (0, grouped_matmul.room(t * k)))
+    # every index is in bounds: "clip" spares the pass that selects a fill
+    rows = jnp.take(y, source, axis=0, mode="clip")      # [t * k (+), d]
+    if kernel:
         out = grouped_matmul.grouped_swiglu(rows, gate, up, down, load)
     else:
         hidden = jax.nn.silu(jax.lax.ragged_dot(rows, gate, load)) \
@@ -179,7 +188,7 @@ def experts(y, idx, weights, gate, up, down, mask=None):
     # back to the tokens' order: assignment a sits at row inverse[a]
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(t * k, dtype=order.dtype))
-    out = jnp.take(out, inverse, axis=0).reshape(t, k, -1)
+    out = jnp.take(out, inverse, axis=0, mode="clip").reshape(t, k, -1)
     if mask is not None:  # rows behind the last group hold what they held
         out = jnp.where(mask[:, None, None], out, 0)
     out = jnp.sum(out.astype(jnp.float32) * weights[..., None], axis=1)

@@ -57,6 +57,23 @@ def test_window_kernel_leg(capsys):
                                      dtype=jnp.float32)
 
 
+def test_grouped_kernel_leg(capsys):
+    import jax.numpy as jnp
+    # (tokens, k, experts, d, f, real tokens); the CPU: ragged_dot
+    cases = [(24, 2, 8, 32, 16, 24), (40, 4, 16, 16, 32, 29)]
+    chip_smoke.leg_grouped_kernel(cases, atol=1e-4, dtype=jnp.float32,
+                                  products=["ragged_dot"] * 2)
+    line = _last_json(capsys)
+    assert line["leg"] == "grouped_kernel" and line["max_abs_err"] < 1e-4
+    assert line["products"] == ["ragged_dot", "ragged_dot"]
+    with pytest.raises(AssertionError, match="against the loop over"):
+        chip_smoke.leg_grouped_kernel(cases[:1], atol=-1.0,
+                                      dtype=jnp.float32)
+    with pytest.raises(AssertionError, match="wanted"):
+        chip_smoke.leg_grouped_kernel(cases[:1], dtype=jnp.float32,
+                                      products=["streamed"])
+
+
 def test_lm_train_and_four_chip_legs(tiny, capsys):
     import horovod_tpu as hvd
     try:
@@ -113,10 +130,11 @@ def test_serve_leg(tiny, capsys, family):
                   "window_moe": ["k", "v", "k_ring", "v_ring"]}.get(
                       family, ["k", "v"]),
         "kernel": False}
-    # ...and ragged_dot for the two families with experts
-    assert line["experts"] == ({"kernel": False}
-                               if family in ("latent_moe", "window_moe")
-                               else None)
+    # ...and ragged_dot for the two families with experts, in the decode
+    # pass and in the prefill of both padded lengths (8, 8 and 16)
+    assert line["experts"] == (
+        {"kernel": False, "prefill": {"8": "ragged_dot", "16": "ragged_dot"}}
+        if family in ("latent_moe", "window_moe") else None)
     assert line["greedy_exact"] + line["greedy_ties"] == line["tokens"]
     assert line["kv_in_place"] == 1
     assert line["steps_ahead"] > 0   # three requests on two slots
@@ -397,9 +415,11 @@ def _grouped_calls(text, rows):
     three stacks AS THEY LIE among its operands; and no grouped product of
     XLA's own beside them."""
     import re
+    from horovod_tpu.ops import grouped_matmul
+    held = rows + grouped_matmul.room(rows)   # and the last window's room
     calls = re.findall(r"%grouped_swiglu[.\d]* = .*tpu_custom_call.*", text)
     for call in calls:
-        assert re.match(rf"%grouped_swiglu[.\d]* = bf16\[{rows},2048\]",
+        assert re.match(rf"%grouped_swiglu[.\d]* = bf16\[{held},2048\]",
                         call) and "hvd.moe.experts" in call, call[:400]
         assert call.count("bf16[64,2048,1536]") == 2 and \
             call.count("bf16[64,1536,2048]") == 1, call[:400]
@@ -514,8 +534,10 @@ def test_two_classes_of_cache_are_read_in_place_by_one_kernel(topo, program,
     they lie. Prefill of 512 tokens: the flash kernel on the full layer,
     the banded kernel on the window layer with K/V at their own 8 heads,
     the ring's row is the window and no more, and 4,096 assignments are
-    the kernel's last; a prompt of 1,024 is past ``MAX_ROWS`` and takes
-    ``jax.lax.ragged_dot``."""
+    the last whose rows the kernel keeps in VMEM; a prompt of 1,024 (8,192
+    assignments, and every longer one of the cell up to 4,096 tokens) is
+    the SAME kernel with the rows streamed through it: no prefill program
+    holds a ``ragged-dot``."""
     import re
 
     import jax
@@ -555,20 +577,23 @@ def test_two_classes_of_cache_are_read_in_place_by_one_kernel(topo, program,
     request.getfixturevalue("decode_kernel")
     stack = (256, 2048, 512)
     assert grouped_matmul.selected(slots * 8, stack, jnp.bfloat16)
-    assert grouped_matmul.selected(512 * 8, stack, jnp.bfloat16)
-    assert not grouped_matmul.selected(1024 * 8, stack, jnp.bfloat16)
+    assert all(grouped_matmul.selected(s_pad * 8, stack, jnp.bfloat16)
+               for s_pad in range(512, 4097, 512))   # the cell's prefills
+    assert grouped_matmul.resident(512 * 8, 2048, 2)
+    assert not grouped_matmul.resident(1024 * 8, 2048, 2)
 
     def grouped(text, rows):
         calls = re.findall(r"%grouped_swiglu[.\d]* = .*tpu_custom_call.*",
                            text)
+        held = rows + grouped_matmul.room(rows)
         for call in calls:
-            assert re.match(rf"%grouped_swiglu[.\d]* = bf16\[{rows},2048\]",
+            assert re.match(rf"%grouped_swiglu[.\d]* = bf16\[{held},2048\]",
                             call) and "hvd.moe.experts" in call, call[:400]
             assert call.count("bf16[256,2048,512]") == 2 and \
                 call.count("bf16[256,512,2048]") == 1, call[:400]
         return len(calls)
     if program == "prefill":
-        for s_pad, products in ((512, "kernel"), (1024, "ragged")):
+        for s_pad in (512, 1024):   # rows resident, rows streamed
             engine_mod._prefill_jit.clear_cache()
             try:
                 text = engine_mod._prefill_jit.lower(
@@ -589,10 +614,8 @@ def test_two_classes_of_cache_are_read_in_place_by_one_kernel(topo, program,
             # what the row leaves: the padded prefix, and a window at most
             assert f"bf16[1,1,{s_pad},8,128]" in text
             assert "bf16[1,1,512,8,128]" in text
-            if products == "kernel":
-                assert grouped(text, 4096) == 1 and "ragged-dot" not in text
-            else:
-                assert grouped(text, 8192) == 0 and "ragged-dot" in text
+            assert grouped(text, s_pad * 8) == 1 and \
+                "ragged-dot" not in text
         return
     compiled = engine_mod._decode_jit.lower(
         cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
