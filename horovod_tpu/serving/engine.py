@@ -35,8 +35,8 @@ from the device:
     the program from the engine's key and the host's step count
     (prefills and passes share the count, one each). A step that only
     decodes uploads that count and nothing else. A prefill's key is
-    folded by one small eager dispatch just before its own, and its
-    prompt, index and temperature go up with its dispatch as host values.
+    the same fold made on the host (host_key.py): it goes up with the
+    dispatch as a host value, as prompt, index and temperature do.
   * a step that admits launches first and reads after. ``_admit``
     dispatches the prefill and the slot write of every request the step
     admits, back to back; ``_decode`` launches its pass over all active
@@ -96,7 +96,7 @@ from ..utils import history as hvd_history
 from ..utils import memory as hvd_memory
 from ..utils import metrics as hvd_metrics
 from ..utils import tracing as hvd_tracing
-from . import tracing as serve_tracing
+from . import host_key, tracing as serve_tracing
 from .decode import ROUTED_COUNTS, decode, passes, prefill
 from .kv_cache import KVCache
 from .queue import AdmissionQueue, RequestResult
@@ -256,11 +256,11 @@ class ServeEngine:
         self._step_count = 0
         self._replica = replica
         self._on_ranks_lost = on_ranks_lost
-        # router/canary hook (horovod_tpu/router/canary.py): called with
-        # the armed generation before a swap; returning False holds this
-        # replica on its current weights (the generation stays armed and
-        # is re-offered next step). None = swap whenever armed, the
-        # pre-router behavior.
+        self._key_words = np.asarray(self._rng)  # read once (host_key.py)
+        # router/canary hook (horovod_tpu/router/canary.py): called with the
+        # armed generation before a swap; returning False holds this replica on
+        # its current weights (the generation stays armed and is re-offered
+        # next step). None = swap whenever armed, the pre-router behavior.
         self._swap_gate = swap_gate
         # elasticity plane (docs/elasticity.md): a draining engine
         # refuses new submissions but keeps admitting ITS OWN queue and
@@ -720,8 +720,8 @@ class ServeEngine:
             serve_tracing.trace_of(req).on_prefill_start(slot, prompt_len)
             tokens = np.zeros((1, self._pad_len(prompt_len)), np.int32)
             tokens[0, :prompt_len] = req.prompt
-            with rec.launch("_threefry_fold_in"):
-                rng = jax.random.fold_in(self._rng, self._step_count)
+            # the key is folded on the host: no device call before the prefill
+            rng = host_key.fold_in(self._key_words, self._step_count)
             self._step_count += 1
             with rec.launch("_prefill_jit") as n:
                 tok, row = _prefill_jit(

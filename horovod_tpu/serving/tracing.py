@@ -397,9 +397,9 @@ STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
 # of a device program from the step (``StepTrace.launch``). ``n`` numbers
 # the launches of a tracer's life, across steps, as ``seq`` numbers its
 # steps; ``program`` is the name under which a profile shows the run, less
-# ``jit_`` and the hash (``_decode_jit``, ``_prefill_jit``, ``_write_slot``,
-# ``_threefry_fold_in`` for a prefill's eager key fold: one call, one entry,
-# though a TPU runs a ``convert_element_type`` of the count before it); the
+# ``jit_`` and the hash (``_decode_jit``, ``_prefill_jit``, ``_write_slot``:
+# an admission is two entries, its prefill and its slot write; the prefill's
+# key is folded on the host, serving/host_key.py, and launches nothing); the
 # two times are the host's call: the chip runs launches in the order of
 # their numbers.
 # reads: [n, start_us, end_us], one entry a read-back (``StepTrace.read``):

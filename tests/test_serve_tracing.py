@@ -574,7 +574,7 @@ class TestStepTrace:
                                                  "prefill"]
         assert _tiles(rec) and engine._rec is serve_tracing.NULL_STEP
         # the call that raised is in the ledger, and it was left
-        assert [lc[1] for lc in rec["launches"]] == [FOLD, PREFILL]
+        assert [lc[1] for lc in rec["launches"]] == [PREFILL]
         assert all(c0 < c1 for _, _, c0, c1 in rec["launches"])
         assert rec["reads"] == []
 
@@ -616,9 +616,10 @@ class TestStepTrace:
 # the launch it waited for
 # ---------------------------------------------------------------------------
 
-FOLD, PREFILL, WRITE, DECODE = ("_threefry_fold_in", "_prefill_jit",
-                                "_write_slot", "_decode_jit")
-ADMISSION = [FOLD, PREFILL, WRITE]
+PREFILL, WRITE, DECODE = "_prefill_jit", "_write_slot", "_decode_jit"
+# an admission's launches: its key is folded on the host (serving/host_key.py)
+# and is no launch
+ADMISSION = [PREFILL, WRITE]
 
 
 def _inside(rec, t0, t1):
@@ -640,21 +641,25 @@ class TestLaunchLedger:
         decodes ahead, retires and admits again."""
         import jax
         from horovod_tpu.serving import engine as engine_mod
-        calls = []
+        calls, eager_folds = [], []
 
         def counted(name, fn):
             def call(*a, **kw):
-                # the eager fold alone: the programs fold a traced count
-                if name != FOLD or isinstance(a[1], int):
-                    calls.append(name)
+                calls.append(name)
                 return fn(*a, **kw)
             return call
+
+        def fold_in(key, count):
+            # an eager fold has a Python count: the programs' is traced
+            if isinstance(count, int):
+                eager_folds.append(count)
+            return real_fold_in(key, count)
         for name, attr in ((PREFILL, "_prefill_jit"), (WRITE, "_write_slot"),
                            (DECODE, "_decode_jit")):
             monkeypatch.setattr(engine_mod, attr,
                                 counted(name, getattr(engine_mod, attr)))
-        monkeypatch.setattr(jax.random, "fold_in",
-                            counted(FOLD, jax.random.fold_in))
+        real_fold_in = jax.random.fold_in
+        monkeypatch.setattr(jax.random, "fold_in", fold_in)
         cfg, params = _tiny()
         engine = _engine(cfg, params)
         for i, new in enumerate((4, 3, 2, 5)):
@@ -665,6 +670,9 @@ class TestLaunchLedger:
         recs = stepping.steps()
         flat = [lc for r in recs for lc in r["launches"]]
         assert [lc[1] for lc in flat] == calls and DECODE in calls
+        # no device call but the three programs: a prefill's key is folded
+        # on the host, never by an eager ``jax.random.fold_in``
+        assert eager_folds == [] and calls.count(PREFILL) == 4
         assert [lc[0] for lc in flat] == list(range(1, len(flat) + 1))
         for r in recs:
             for n, program, c0, c1 in r["launches"]:
@@ -686,7 +694,24 @@ class TestLaunchLedger:
                 c1 = next(lc[3] for lc in flat if lc[0] == n)
                 assert c1 <= start
 
-    def test_a_step_that_admits_two_launches_all_seven_then_reads_the_two(
+    @pytest.mark.parametrize("temperature", [0.0, 1.0])
+    def test_an_admission_is_two_launches_its_prefill_and_its_slot_write(
+            self, stepping, temperature):
+        """Greedy or sampled: the prefill's key launches nothing."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params)
+        _warm(engine)
+        engine.submit(Request("a", (3, 1, 4), max_new_tokens=4,
+                              temperature=temperature))
+        engine.step()
+        rec = stepping.steps()[-1]
+        assert rec["admitted"] == 1
+        assert [lc[1] for lc in rec["launches"]] == [PREFILL, WRITE, DECODE]
+        # the first launch of the step is the prefill, and it is what the
+        # first token's read waited for
+        assert rec["reads"][0][0] == rec["launches"][0][0]
+
+    def test_a_step_that_admits_two_launches_all_five_then_reads_the_two(
             self, stepping):
         cfg, params = _tiny()
         engine = _engine(cfg, params)
@@ -698,9 +723,9 @@ class TestLaunchLedger:
         assert [lc[1] for lc in rec["launches"]] == 2 * ADMISSION + [DECODE]
         first = rec["launches"][0][0]
         assert [lc[0] for lc in rec["launches"]] == \
-            list(range(first, first + 7))
+            list(range(first, first + 5))
         # the two prefills, in admission order; the pass is in flight
-        assert [rd[0] for rd in rec["reads"]] == [first + 1, first + 4]
+        assert [rd[0] for rd in rec["reads"]] == [first, first + 2]
         assert rec["ahead"] == 1
         readbacks = [p[1:] for p in rec["phases"]
                      if p[0] == "prefill_readback"]
@@ -709,8 +734,8 @@ class TestLaunchLedger:
         # launch; b is on its last token, so that pass is read as well
         engine.step()
         nxt = stepping.steps()[-1]
-        assert [lc[:2] for lc in nxt["launches"]] == [[first + 7, DECODE]]
-        assert [rd[0] for rd in nxt["reads"]] == [first + 6, first + 7]
+        assert [lc[:2] for lc in nxt["launches"]] == [[first + 5, DECODE]]
+        assert [rd[0] for rd in nxt["reads"]] == [first + 4, first + 5]
         assert nxt["reads"][0][1] >= nxt["launches"][0][3]
 
     def test_a_third_admission_reads_the_oldest_unread_first(self, stepping):
@@ -724,15 +749,16 @@ class TestLaunchLedger:
         assert [lc[1] for lc in rec["launches"]] == 3 * ADMISSION + [DECODE]
         first = rec["launches"][0][0]
         assert [rd[0] for rd in rec["reads"]][:3] == \
-            [first + 1, first + 4, first + 7]
-        # a's token is read with b's prefill queued behind it and before
-        # c's key is folded; b's and c's after the decode launch
+            [first, first + 2, first + 4]
+        # a's token is read with b's prefill and slot write queued behind
+        # it and before c's prefill is launched; b's and c's after the
+        # decode launch
         a, b, c = rec["reads"][:3]
-        assert rec["launches"][5][3] <= a[1] and \
-            a[2] <= rec["launches"][6][2]
-        assert rec["launches"][9][3] <= b[1] <= c[1]
+        assert rec["launches"][3][3] <= a[1] and \
+            a[2] <= rec["launches"][4][2]
+        assert rec["launches"][6][3] <= b[1] <= c[1]
         # a free slot: the pass is read before the step returns
-        assert rec["ahead"] == 0 and rec["reads"][3][0] == first + 9
+        assert rec["ahead"] == 0 and rec["reads"][3][0] == first + 6
 
     @pytest.mark.parametrize("slow,where,key", [
         ("device_get", "decode_readback", "read"),

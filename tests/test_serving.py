@@ -1309,6 +1309,65 @@ class TestAnAdmittingStepLaunchesBeforeItReads:
         assert counts == list(range(start, start + len(counts)))
         assert len(counts) > len(REQUESTS)
 
+    @pytest.mark.parametrize("words", [
+        0, 3, 2**31 - 1, (0xFFFFFFF0, 0x80000001)])  # a seed, or the words
+    @pytest.mark.parametrize("count", [0, 1, 2**16, 2**31 - 1, "drawn"])
+    def test_the_hosts_fold_is_jaxs_fold_in_bit_for_bit(self, words, count):
+        """``host_key.fold_in`` over Python integers gives the 64 bits of
+        ``jax.random.fold_in``, as ``uint32[2]``: small and large counts,
+        three hundred drawn ones, keys of seeds and one whose two words
+        both lie above 2**31."""
+        from horovod_tpu.serving import host_key
+        if isinstance(words, int):
+            key = jax.random.PRNGKey(words)
+        else:
+            key = jnp.asarray(words, jnp.uint32)
+        words = np.asarray(key)
+        counts = [count]
+        if count == "drawn":
+            drawn = np.random.default_rng(int(words[1])).integers(
+                0, 2**31, 300)
+            counts = [int(c) for c in drawn]
+        for c in counts:
+            got = host_key.fold_in(words, c)
+            assert got.dtype == np.uint32 and got.shape == (2,)
+            assert np.array_equal(
+                got, np.asarray(jax.random.fold_in(key, c))), (words, c)
+
+    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    def test_sampled_tokens_are_those_of_the_eager_fold(
+            self, reg, monkeypatch, model):
+        """At temperature 1.0 an engine serves token for token what an
+        engine with the parent's eager fold patched back in serves: the
+        key handed to the prefill is the same 64 bits."""
+        from horovod_tpu.serving import engine as engine_mod
+        cfg, params = MODELS[model]()
+        served, _ = _drive(_engine(cfg, params, seed=3), REQUESTS,
+                           temperature=1.0)
+        eager = []
+
+        def fold(words, count):
+            eager.append(count)
+            return jax.random.fold_in(jnp.asarray(words), count)
+        monkeypatch.setattr(engine_mod.host_key, "fold_in", fold)
+        parents, _ = _drive(_engine(cfg, params, seed=3), REQUESTS,
+                            temperature=1.0)
+        assert len(eager) == len(REQUESTS)
+        assert _tokens(served) == _tokens(parents)
+        assert all(len(r.tokens) > 1 for r in served.values())
+
+    def test_the_engine_reads_its_keys_words_once(self, reg):
+        """The two words come to the host at construction; an admission
+        reads nothing of the device key."""
+        cfg, params = _tiny()
+        engine = _engine(cfg, params, seed=3)
+        assert isinstance(engine._key_words, np.ndarray)
+        assert np.array_equal(engine._key_words,
+                              np.asarray(jax.random.PRNGKey(3)))
+        words = engine._key_words
+        _warm(engine)
+        assert engine._key_words is words
+
     @pytest.mark.parametrize("feed", ["numpy", "jax"])
     def test_prefill_jit_is_the_parents_program_however_it_is_fed(
             self, reg, feed):
@@ -1327,7 +1386,8 @@ class TestAnAdmittingStepLaunchesBeforeItReads:
         tokens = np.zeros((1, 8), np.int32)
         tokens[0, :3] = (5, 9, 17)
         rng = jax.random.fold_in(jax.random.PRNGKey(3), 5)
-        fed = {"numpy": (tokens, np.int32(2), np.float32(0.8), rng),
+        fed = {"numpy": (tokens, np.int32(2), np.float32(0.8),
+                         np.asarray(rng)),
                "jax": (jnp.asarray(tokens), jnp.int32(2), jnp.float32(0.8),
                        rng)}
         before = engine_mod._prefill_jit._cache_size()
