@@ -92,12 +92,12 @@ def _check(cond, msg):
 # ---------------------------------------------------------------------------
 
 def leg_kernels(shapes, atol=KERNEL_ATOL, dtype=None):
-    """Forward (every variant) and backward of the flash kernels against
-    parallel.ring.full_attention, one compile per (shape, variant)."""
+    """Forward and backward of the flash kernels against
+    parallel.ring.full_attention, one compile per shape."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.ops.flash_attention import flash_attention
     from horovod_tpu.parallel.ring import full_attention
 
     dtype = dtype or jnp.bfloat16
@@ -121,21 +121,18 @@ def leg_kernels(shapes, atol=KERNEL_ATOL, dtype=None):
             return jax.jit(run)
 
         ref = out_and_grads(full_attention)(*qkvw)
-        for variant in fa.VARIANTS:
-            got = out_and_grads(functools.partial(
-                fa.flash_attention, causal=True, variant=variant))(*qkvw)
-            for name, a, b in zip(("out", "dq", "dk", "dv"), got, ref):
-                a = np.asarray(a.astype(jnp.float32))
-                b = np.asarray(b.astype(jnp.float32))
-                _check(np.isfinite(a).all(),
-                       f"{variant} {name} at {shape}: not finite")
-                err = float(np.max(np.abs(a - b)))
-                _check(err <= atol, f"{variant} {name} at {shape}: max "
-                       f"abs err {err:.4g} > {atol} against full_attention")
-                worst[name] = max(worst.get(name, 0.0), err)
-    emit("kernels", shapes=[list(s) for s in shapes],
-         variants=list(fa.VARIANTS), max_abs_err=worst, atol=atol,
-         seconds=round(time.perf_counter() - t0, 2))
+        got = out_and_grads(functools.partial(
+            flash_attention, causal=True))(*qkvw)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, ref):
+            a = np.asarray(a.astype(jnp.float32))
+            b = np.asarray(b.astype(jnp.float32))
+            _check(np.isfinite(a).all(), f"{name} at {shape}: not finite")
+            err = float(np.max(np.abs(a - b)))
+            _check(err <= atol, f"{name} at {shape}: max abs err "
+                   f"{err:.4g} > {atol} against full_attention")
+            worst[name] = max(worst.get(name, 0.0), err)
+    emit("kernels", shapes=[list(s) for s in shapes], max_abs_err=worst,
+         atol=atol, seconds=round(time.perf_counter() - t0, 2))
 
 
 def leg_window_kernel(cases, atol=KERNEL_ATOL, dtype=None):

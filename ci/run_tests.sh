@@ -28,14 +28,13 @@ python -m tools.hvdlint --concurrency
 echo "--- build native core"
 python setup.py build_native
 
-echo "--- kernel numerics (fast fail: flash variants vs reference softmax)"
-# The flash-attention forward variants (online/lazy/twopass) share one
-# backward and one lse contract; a numerics break here poisons every
-# training result, so the small-shape variant suite runs FIRST and
+echo "--- kernel numerics (fast fail: flash kernels vs reference softmax)"
+# A numerics break in the flash forward or its lse poisons every
+# training result, so the small-shape kernel suite runs FIRST and
 # fails the pipeline in ~2 min instead of after the full suite's
 # subprocess-heavy half hour. Big shapes are @slow and stay in the
 # nightly `-m slow` run.
-python -m pytest tests/test_flash_variants.py tests/test_flash_attention.py \
+python -m pytest tests/test_flash_forward.py tests/test_flash_attention.py \
     -q -m "not slow"
 
 echo "--- metrics (fast fail: telemetry registry, aggregation, stall gauges)"

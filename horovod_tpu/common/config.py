@@ -598,9 +598,6 @@ ENV_REGISTRY = (
     ("HVD_PLANE_SHM", False, "1", "_native/src/plane.h",
      "Set 0 to force TCP between same-host native planes instead of "
      "shared memory."),
-    ("HVD_FLASH_VARIANT", False, None, "ops/flash_attention.py",
-     "Flash-attention forward variant override (baseline, "
-     "lazy_rescale, two_pass)."),
     ("HVD_LOCKDEP", False, "0", "utils/lockdep.py",
      "Set 1 to swap every lockdep.lock() for an instrumented lock that "
      "witnesses acquisition orders and reports deadlock-shaped bugs "

@@ -76,7 +76,6 @@ class HybridConfig:
     max_seq_len: int = 2048
     dtype: jnp.dtype = jnp.bfloat16
     attention_impl: str = "full"
-    flash_variant: str = "auto"
 
     @property
     def d_ssm(self):

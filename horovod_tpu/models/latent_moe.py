@@ -53,9 +53,8 @@ that reads each row's live blocks once, in place; elsewhere an einsum
 under a length mask).
 
 The projections, norms, embedding, head, rotary and the dense SwiGLU are
-serving/decode.py's and models/transformer.py's own (``_dense``,
-``_rmsnorm`` with this model's eps, ``_embed``, ``_logits``, ``_mlp``,
-``_rope``), used and not copied. ``jax.named_scope`` names the parts in
+models/transformer.py's own (``_dense``, ``_rmsnorm`` with this model's
+eps, ``_embed``, ``_logits``, ``_mlp``, ``_rope``), used and not copied. ``jax.named_scope`` names the parts in
 both serving programs: ``hvd.mla.attend``, ``hvd.moe.route``,
 ``hvd.moe.experts``, ``hvd.moe.shared``.
 
@@ -71,7 +70,8 @@ import jax.numpy as jnp
 
 from ..ops.flash_attention import latent_decode_attention
 from . import moe
-from .transformer import _dispatch_attention, _rope
+from .transformer import (_dense, _dispatch_attention, _embed, _logits,
+                          _mlp, _rmsnorm, _rope)
 
 #: the kinds of ``state_shapes`` that hold one entry a position
 POSITIONAL = ("latent",)
@@ -109,7 +109,6 @@ class LatentMoEConfig:
     tie_embeddings: bool = False
     logits_fp32: bool = True
     attention_impl: str = "full"
-    flash_variant: str = "auto"
 
     @property
     def qk_dim(self):
@@ -203,21 +202,15 @@ def state_shapes(cfg, num_slots, max_len):
 
 # -- the block's parts, shared by every forward -------------------------------
 
-def _serve():
-    from ..serving import decode  # which imports this module
-    return decode
-
-
 def _norm(cfg, x, p):
-    return _serve()._rmsnorm(x, p["scale"], cfg.dtype, cfg.rms_eps)
+    return _rmsnorm(x, p["scale"], cfg.dtype, cfg.rms_eps)
 
 
 def _queries(cfg, p, y, positions):
     """y [b, s, d] -> (q_nope [b, s, h, nope], q_rope [b, s, h, rope]
     rotated)."""
-    dense = _serve()._dense
-    c_q = _norm(cfg, dense(y, p["q_a"]["kernel"], cfg.dtype), p["q_norm"])
-    q = dense(c_q, p["q_b"]["kernel"], cfg.dtype)
+    c_q = _norm(cfg, _dense(y, p["q_a"]["kernel"], cfg.dtype), p["q_norm"])
+    q = _dense(c_q, p["q_b"]["kernel"], cfg.dtype)
     q = q.reshape(q.shape[:-1] + (cfg.num_heads, cfg.qk_dim))
     return q[..., :cfg.nope_dim], \
         _rope(q[..., cfg.nope_dim:], positions, cfg.rope_theta)
@@ -227,7 +220,7 @@ def _latent(cfg, p, y, positions):
     """y [b, s, d] -> [b, s, 1, latent_lanes]: what the cache keeps of
     each token, c after its norm, the one rotary key after its rotation,
     zeros to the end of the last lane tile."""
-    ckr = _serve()._dense(y, p["kv_a"]["kernel"], cfg.dtype)
+    ckr = _dense(y, p["kv_a"]["kernel"], cfg.dtype)
     c = _norm(cfg, ckr[..., :cfg.kv_rank], p["kv_norm"])
     k_r = _rope(ckr[..., None, cfg.kv_rank:], positions, cfg.rope_theta)
     return _to_lanes(cfg, jnp.concatenate([c[..., None, :], k_r], axis=-1))
@@ -253,8 +246,8 @@ def _attend_expanded(cfg, p, y, positions):
     h = cfg.num_heads
     q_nope, q_rope = _queries(cfg, p, y, positions)
     latent = _latent(cfg, p, y, positions)
-    kv = _serve()._dense(latent[..., 0, :cfg.kv_rank], p["kv_b"]["kernel"],
-                         cfg.dtype).reshape(b, s, h, -1)
+    kv = _dense(latent[..., 0, :cfg.kv_rank], p["kv_b"]["kernel"],
+                cfg.dtype).reshape(b, s, h, -1)
     k_r = jnp.broadcast_to(latent[..., cfg.kv_rank:cfg.latent_dim],
                            (b, s, h, cfg.rope_dim))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -266,8 +259,8 @@ def _attend_expanded(cfg, p, y, positions):
         raise NotImplementedError(
             f"values wider than keys ({cfg.v_dim} > {cfg.qk_dim})")
     out = _dispatch_attention(cfg, q, k, v, None)[..., :cfg.v_dim]
-    return _serve()._dense(out.reshape(b, s, h * cfg.v_dim),
-                           p["out"]["kernel"], cfg.dtype), latent
+    return _dense(out.reshape(b, s, h * cfg.v_dim), p["out"]["kernel"],
+                  cfg.dtype), latent
 
 
 def _attend_absorbed(cfg, p, y, positions, cache, plane, rows, lengths):
@@ -289,17 +282,16 @@ def _attend_absorbed(cfg, p, y, positions, cache, plane, rows, lengths):
     # o_h = (sum_t p_t c_t) W^V_h: the value's expansion on the output
     out = jnp.einsum("bhc,chv->bhv", o_c, w_v,
                      preferred_element_type=jnp.float32).astype(cfg.dtype)
-    return _serve()._dense(out.reshape(b, 1, -1), p["out"]["kernel"],
-                           cfg.dtype), cache
+    return _dense(out.reshape(b, 1, -1), p["out"]["kernel"],
+                  cfg.dtype), cache
 
 
 def _feed_forward(cfg, layer, y, mask):
     """FFN of one layer over y [b, s, d]; ``mask`` [b, s] bool or None:
     the tokens that are there (the others are routed to no expert).
     Returns (out, (idx, weights, load) of the routed experts or None)."""
-    serve = _serve()
     if "experts" not in layer:
-        return serve._mlp(cfg, layer, y), None
+        return _mlp(cfg, layer, y), None
     b, s, d = y.shape
     with jax.named_scope("hvd.moe.route"):
         idx, weights = moe.route(
@@ -313,7 +305,7 @@ def _feed_forward(cfg, layer, y, mask):
             e["up"].astype(cfg.dtype), e["down"].astype(cfg.dtype),
             None if mask is None else mask.reshape(b * s))
     with jax.named_scope("hvd.moe.shared"):
-        shared = serve._mlp(cfg, layer["shared"], y)
+        shared = _mlp(cfg, layer["shared"], y)
     return shared + routed.reshape(b, s, d), (idx, weights, load)
 
 
@@ -334,9 +326,8 @@ def hidden_states(cfg, params, tokens, mask=None):
     (hidden [b, s, d], latent [layers, b, s, 1, latent_lanes], routing:
     one (idx [b*s, k], weights [b*s, k], load [E]) an expert layer)."""
     check_served(cfg)
-    serve = _serve()
     positions = jnp.arange(tokens.shape[1])[None, :]
-    x = serve._embed(cfg, params, tokens)
+    x = _embed(cfg, params, tokens)
     latents, routing = [], []
     for i in range(cfg.num_layers):
         x, latent, routed = _block(
@@ -352,7 +343,7 @@ def forward(cfg, params, tokens):
     """The plain forward, no cache: (logits [b, s, vocab], routing as
     ``hidden_states`` gives it)."""
     hidden, _, routing = hidden_states(cfg, params, tokens)
-    return _serve()._logits(cfg, params, hidden), routing
+    return _logits(cfg, params, hidden), routing
 
 
 def prefill(cfg, params, tokens, last_index):
@@ -364,7 +355,7 @@ def prefill(cfg, params, tokens, last_index):
     hidden, latent, _ = hidden_states(cfg, params, tokens, real)
     row = jax.lax.dynamic_index_in_dim(hidden, last_index, axis=1,
                                        keepdims=False)
-    return _serve()._logits(cfg, params, row), {"latent": latent}
+    return _logits(cfg, params, row), {"latent": latent}
 
 
 def decode(cfg, params, tokens, positions, state, mask=None):
@@ -378,14 +369,13 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     summed over the expert layers, and the most assignments any one
     expert got."""
     check_served(cfg)
-    serve = _serve()
     rows = jnp.arange(tokens.shape[0])
     lengths = positions + 1
     if mask is not None:
         lengths = jnp.where(mask, lengths, 0)
     cache = state["latent"]
     there = None if mask is None else mask[:, None]
-    x = serve._embed(cfg, params, tokens[:, None])
+    x = _embed(cfg, params, tokens[:, None])
     touched = fullest = jnp.zeros((), jnp.int32)
     for i in range(cfg.num_layers):
         # what the block keeps of attention here is the cache, written
@@ -397,5 +387,5 @@ def decode(cfg, params, tokens, positions, state, mask=None):
             touched = touched + jnp.sum(routed[2] > 0, dtype=jnp.int32)
             fullest = jnp.maximum(fullest, jnp.max(routed[2]))
     x = _norm(cfg, x, params["ln_f"])
-    return serve._logits(cfg, params, x)[:, 0], {"latent": cache}, \
+    return _logits(cfg, params, x)[:, 0], {"latent": cache}, \
         jnp.stack([touched, fullest])

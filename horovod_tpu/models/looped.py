@@ -18,7 +18,7 @@ configuration: ``passes`` (T), the rotary base, and the two norms that
 close a layer's branches (``sandwich_norm``; N2 and N4 above). With
 ``passes=1`` and ``sandwich_norm=False`` it IS the dense model, bit for
 bit on the same leaves (tests/test_looped_model.py). Every norm's eps is
-the program's 1e-6 (``serving/decode._rmsnorm``), which is what the
+the program's 1e-6 (``models/transformer._rmsnorm``), which is what the
 family publishes.
 
 What the loop costs a server: a token leaves ``passes x layers`` K/V
@@ -47,6 +47,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from .transformer import _logits
+
 
 @dataclasses.dataclass(frozen=True)
 class LoopedConfig:
@@ -64,7 +66,6 @@ class LoopedConfig:
     tie_embeddings: bool = False
     logits_fp32: bool = True
     attention_impl: str = "full"
-    flash_variant: str = "auto"
 
     @property
     def planes(self):
@@ -149,7 +150,9 @@ def forward(cfg, params, tokens):
     """The plain forward over whole sequences ``tokens`` [b, s], no cache:
     (logits [b, s, vocab] of the LAST pass, hidden [passes, b, s, d] (each
     pass's normalised hidden state), p_t [passes, b, s] float32)."""
-    from ..serving import decode as serve  # which imports this module
-    hidden, _, _ = serve.hidden_states(cfg, params, tokens)
-    return (serve._logits(cfg, params, hidden[-1]), hidden,
+    # the one import of serving/ left in models/: the looped forward and
+    # ``_stack`` live there until the block is one (ROADMAP D1 (a))
+    from ..serving.decode import hidden_states  # which imports this module
+    hidden, _, _ = hidden_states(cfg, params, tokens)
+    return (_logits(cfg, params, hidden[-1]), hidden,
             exit_distribution(exit_gates(cfg, params, hidden)))

@@ -70,26 +70,15 @@ class ResNet(nn.Module):
     num_classes: int = 1000
     num_filters: int = 64
     dtype: jnp.dtype = jnp.bfloat16
-    # "flax": stock nn.BatchNorm (the fast path on v5e — XLA's fused
-    # convert+reduce stats and conv-epilogue normalize measured faster
-    # than the Pallas alternative, see ops/batch_norm.py); "tpu":
-    # ops.batch_norm.TpuBatchNorm. Numerics match (tests/test_batch_norm).
-    norm_impl: str = "flax"
 
     @nn.compact
     def __call__(self, x, train=False):
         conv = functools.partial(nn.Conv, use_bias=False, dtype=self.dtype,
                                  padding="SAME")
-        if self.norm_impl not in ("flax", "tpu"):
-            raise ValueError(
-                f"norm_impl={self.norm_impl!r}: expected 'flax' or 'tpu'")
-        if self.norm_impl == "tpu":
-            # import confined here: the experimental pallas dependency
-            # stays off the default flax path
-            from ..ops.batch_norm import TpuBatchNorm as norm_cls
-        else:
-            norm_cls = nn.BatchNorm
-        norm = functools.partial(norm_cls, use_running_average=not train,
+        # flax's norm: XLA runs its convert+reduce near bandwidth and
+        # fuses the normalize into the conv epilogue, which a custom_vjp
+        # boundary forbids (docs/benchmarks.md)
+        norm = functools.partial(nn.BatchNorm, use_running_average=not train,
                                  momentum=0.9, epsilon=1e-5,
                                  dtype=self.dtype)
         x = x.astype(self.dtype)

@@ -57,12 +57,6 @@ class TransformerConfig:
     # inside shard_map with the 'sp' axis bound (parallel/ring.py); under
     # plain GSPMD jit the full path is used and XLA inserts gathers.
     attention_impl: str = "full"
-    # Forward accumulation variant of the flash kernel ('auto' | 'online'
-    # | 'lazy' | 'twopass' — ops/flash_attention.VARIANTS; only read when
-    # attention_impl routes through the flash kernel). 'auto' is the
-    # fastest measured (resolve_variant: online); HVD_FLASH_VARIANT
-    # overrides either way (the A/B hook).
-    flash_variant: str = "auto"
     # Mixture-of-Experts: num_experts > 0 replaces the dense MLP with
     # models/moe.py's expert layer (experts shard over the 'ep' mesh axis).
     num_experts: int = 0
@@ -140,8 +134,7 @@ def _dispatch_attention(cfg, q, k, v, sp):
         # ring_flash with the whole sequence on this worker: the flash
         # kernel IS the single-block ring
         from ..ops.flash_attention import flash_attention
-        attend = functools.partial(flash_attention, causal=True,
-                                   variant=cfg.flash_variant)
+        attend = functools.partial(flash_attention, causal=True)
         spec = _gspmd_attention_spec(q.shape)
         if spec is not None:
             # A pallas_call has no GSPMD partitioning rule: left bare
@@ -203,6 +196,43 @@ def _rope(x, positions, base=10000.0):
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x2 * cos + x1 * sin], axis=-1)
+
+
+# -- a checkpoint's leaves through the same flax primitives: what the
+# -- cached forwards (serving/decode.py and the other models' own) are built of
+
+def _dense(x, kernel, dtype):
+    return nn.Dense(kernel.shape[-1], use_bias=False,
+                    dtype=dtype).apply({"params": {"kernel": kernel}}, x)
+
+
+def _rmsnorm(x, scale, dtype, eps=1e-6):
+    norm = nn.RMSNorm(epsilon=eps, dtype=dtype)
+    return norm.apply({"params": {"scale": scale}}, x)
+
+
+def _embed(cfg, params, tokens):
+    return nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype).apply(
+        {"params": {"embedding": params["embed"]["embedding"]}}, tokens)
+
+
+def _logits(cfg, params, x):
+    # same head math as TransformerLM: logits straight from the MXU
+    # accumulator in acc precision, tied or separate kernel
+    acc = jnp.float32 if cfg.logits_fp32 else cfg.dtype
+    if cfg.tie_embeddings:
+        kernel = params["embed"]["embedding"].T
+    else:
+        kernel = params["lm_head"]["kernel"]
+    return jnp.dot(x.astype(cfg.dtype), kernel.astype(cfg.dtype),
+                   preferred_element_type=acc)
+
+
+def _mlp(cfg, layer, y):
+    gate = _dense(y, layer["mlp"]["gate"]["kernel"], cfg.dtype)
+    up = _dense(y, layer["mlp"]["up"]["kernel"], cfg.dtype)
+    return _dense(nn.silu(gate) * up, layer["mlp"]["down"]["kernel"],
+                  cfg.dtype)
 
 
 class Attention(nn.Module):

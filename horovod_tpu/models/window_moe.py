@@ -49,8 +49,8 @@ layer, after the rotation, in TWO classes of serving state:
 
 Planes of one class stack because the head counts differ on the query side
 only. The projections, norms, embedding, head and the dense SwiGLU are
-serving/decode.py's own (``_dense``, ``_rmsnorm`` with this model's eps,
-``_embed``, ``_logits``, ``_mlp``), used and not copied.
+models/transformer.py's own (``_dense``, ``_rmsnorm`` with this model's
+eps, ``_embed``, ``_logits``, ``_mlp``), used and not copied.
 ``jax.named_scope`` names the parts in both serving programs:
 ``hvd.full.attend``, ``hvd.swa.attend``, ``hvd.moe.route``,
 ``hvd.moe.experts``, ``hvd.moe.shared``.
@@ -71,7 +71,8 @@ import numpy as np
 from ..ops.flash_attention import (DECODE_BLOCK, decode_attention,
                                    window_attention)
 from . import moe
-from .transformer import _dispatch_attention
+from .transformer import (_dense, _dispatch_attention, _embed, _logits,
+                          _mlp, _rmsnorm)
 
 #: the kinds of ``state_shapes`` that hold one entry a position, and those
 #: of them that are rings of ``cfg.window`` entries
@@ -126,7 +127,6 @@ class WindowMoEConfig:
     tie_embeddings: bool = False
     logits_fp32: bool = True
     attention_impl: str = "full"
-    flash_variant: str = "auto"
 
     @property
     def num_layers(self):
@@ -261,7 +261,7 @@ def router_score(cfg, y, w_router):
 
 def shared_expert(cfg, layer, y):
     """The shared expert's branch (assumed: added unweighted)."""
-    return _serve()._mlp(cfg, layer["shared"], y)
+    return _mlp(cfg, layer["shared"], y)
 
 
 # -- rotary -------------------------------------------------------------------
@@ -309,13 +309,8 @@ def rotate(x, positions, law):
 
 # -- the block's parts, shared by every forward -------------------------------
 
-def _serve():
-    from ..serving import decode  # which imports this module
-    return decode
-
-
 def _norm(cfg, x, p):
-    return _serve()._rmsnorm(x, p["scale"], cfg.dtype, cfg.rms_eps)
+    return _rmsnorm(x, p["scale"], cfg.dtype, cfg.rms_eps)
 
 
 def _law(cfg, i):
@@ -325,13 +320,12 @@ def _law(cfg, i):
 def _qkv(cfg, i, p, y, positions):
     """y [b, s, d] -> q [b, s, heads of layer i, dh], k and v [b, s,
     kv_heads, dh], q and k rotated under the layer's law."""
-    dense = _serve()._dense
 
     def heads(t):
         return t.reshape(t.shape[:-1] + (-1, cfg.head_dim))
-    q = heads(dense(y, p["q"]["kernel"], cfg.dtype))
-    k = heads(dense(y, p["k"]["kernel"], cfg.dtype))
-    v = heads(dense(y, p["v"]["kernel"], cfg.dtype))
+    q = heads(_dense(y, p["q"]["kernel"], cfg.dtype))
+    k = heads(_dense(y, p["k"]["kernel"], cfg.dtype))
+    v = heads(_dense(y, p["v"]["kernel"], cfg.dtype))
     law = _law(cfg, i)
     return rotate(q, positions, law), rotate(k, positions, law), v
 
@@ -339,12 +333,11 @@ def _qkv(cfg, i, p, y, positions):
 def _gated_out(cfg, p, y, attended):
     """attended [b, s, h, dh] under the per-head gate of ``y``, through
     W_o: [b, s, d]."""
-    dense = _serve()._dense
-    gate = gate_activation(dense(y, p["gate"]["kernel"], cfg.dtype)
+    gate = gate_activation(_dense(y, p["gate"]["kernel"], cfg.dtype)
                            .astype(jnp.float32)).astype(cfg.dtype)
     gated = attended * gate[..., None]
-    return dense(gated.reshape(gated.shape[:2] + (-1,)), p["out"]["kernel"],
-                 cfg.dtype)
+    return _dense(gated.reshape(gated.shape[:2] + (-1,)), p["out"]["kernel"],
+                  cfg.dtype)
 
 
 def _attend_whole(cfg, i, q, k, v):
@@ -390,7 +383,7 @@ def _feed_forward(cfg, layer, y, mask):
     the tokens that are there (the others are routed to no expert).
     Returns (out, the experts' load [E] or None)."""
     if "experts" not in layer:
-        return _serve()._mlp(cfg, layer, y), None
+        return _mlp(cfg, layer, y), None
     b, s, d = y.shape
     with jax.named_scope("hvd.moe.route"):
         idx, weights = router_score(cfg, y.reshape(b * s, d),
@@ -425,9 +418,8 @@ def hidden_states(cfg, params, tokens, mask=None):
     (hidden [b, s, d], [(k, v) [b, s, kv_heads, dh] a layer, rotated],
     [load [E] an expert layer])."""
     check_served(cfg)
-    serve = _serve()
     positions = jnp.arange(tokens.shape[1])[None, :]
-    x = serve._embed(cfg, params, tokens)
+    x = _embed(cfg, params, tokens)
     kept, loads = [], []
     for i in range(cfg.num_layers):
         layer = params[f"layer_{i}"]
@@ -446,7 +438,7 @@ def forward(cfg, params, tokens):
     """The plain forward, no cache: (logits [b, s, vocab], the experts'
     loads as ``hidden_states`` gives them)."""
     hidden, _, loads = hidden_states(cfg, params, tokens)
-    return _serve()._logits(cfg, params, hidden), loads
+    return _logits(cfg, params, hidden), loads
 
 
 def prefill(cfg, params, tokens, last_index):
@@ -469,7 +461,7 @@ def prefill(cfg, params, tokens, last_index):
             state[name] = jnp.stack(full)
         if ring:
             state[name + "_ring"] = jnp.stack(ring)
-    return _serve()._logits(cfg, params, row), state
+    return _logits(cfg, params, row), state
 
 
 def decode(cfg, params, tokens, positions, state, mask=None):
@@ -487,7 +479,6 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     summed over the expert layers, and the most assignments any one
     expert got."""
     check_served(cfg)
-    serve = _serve()
     rows = jnp.arange(tokens.shape[0])
     pos2 = positions[:, None]
     lengths = positions + 1
@@ -498,7 +489,7 @@ def decode(cfg, params, tokens, positions, state, mask=None):
     ring_lengths = jnp.minimum(lengths, cfg.window)
     state = dict(state)
     there = None if mask is None else mask[:, None]
-    x = serve._embed(cfg, params, tokens[:, None])
+    x = _embed(cfg, params, tokens[:, None])
     touched = fullest = jnp.zeros((), jnp.int32)
     for i in range(cfg.num_layers):
         layer = params[f"layer_{i}"]
@@ -522,5 +513,5 @@ def decode(cfg, params, tokens, positions, state, mask=None):
             touched = touched + jnp.sum(load > 0, dtype=jnp.int32)
             fullest = jnp.maximum(fullest, jnp.max(load))
     x = _norm(cfg, x, params["ln_f"])
-    return serve._logits(cfg, params, x)[:, 0], state, \
+    return _logits(cfg, params, x)[:, 0], state, \
         jnp.stack([touched, fullest])

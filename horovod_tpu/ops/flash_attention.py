@@ -34,7 +34,6 @@ virtual mesh), selected automatically; a TPU never gets it.
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -45,49 +44,6 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
-
-#: Forward accumulation variants (the backward kernels are shared — every
-#: variant writes the same natural-log lse residual):
-#:   online  — the per-tile rescale chain; what 'auto' runs (measured
-#:             fastest, resolve_variant)
-#:   lazy    — deferred rescale: running max + un-normalized accumulator,
-#:             the [block_q, d] correction runs only on tiles that raise
-#:             the max (diagonal-first k order so it stabilizes early)
-#:   twopass — pass 1 computes the row max (matmul + rowmax only), pass 2
-#:             re-computes QK^T and accumulates exp2(s−m)@V with NO
-#:             loop-carried correction at all
-#: lazy and twopass are opt-in and lost on the chip (ROADMAP D8 deletes
-#: them with the option).
-VARIANTS = ("online", "lazy", "twopass")
-
-
-def resolve_variant(variant, causal=True, nk=1):
-    """Resolve 'auto' (and the HVD_FLASH_VARIANT env override, which wins
-    over any explicit argument: the A/B hook) to a concrete forward
-    variant. ``causal`` and ``nk`` (k tiles a row) are what the call can
-    see and a choice would be made from; today's table gives one answer
-    for all of them. Measured (PR 41, v5e, causal bf16 ``[bh, s, dh]`` =
-    ``[64, 4096, 128]`` at 512 x 512 blocks, nk = 8; kernel ms a step and
-    share of the compute roofline; docs/benchmarks.md has the whole
-    table): online 2.22 ms, 62.8% (3.31 ms, 42.2% before its rewrite);
-    twopass 4.65 ms, 30.0%; lazy 7.99 ms, 17.4%. So ``auto`` is online
-    at every ``nk``: at nk = 1 (most serving prefills) there is nothing to
-    defer or to pass over twice, and from nk = 2 on the other two pay for
-    what they save (lazy: a relayout of its 1-D statistics and a
-    vector-to-scalar branch every tile; twopass: half as many matmuls
-    again). Until PR 41 ``auto`` took lazy for nk >= 2 by reasoning, with
-    nothing timed: it was the slowest of the three."""
-    env = os.environ.get("HVD_FLASH_VARIANT", "").strip().lower()
-    if env:
-        variant = env
-    if variant not in VARIANTS + ("auto",):
-        raise ValueError(
-            f"unknown flash variant {variant!r}; expected one of "
-            f"{VARIANTS + ('auto',)}")
-    if variant == "auto":
-        return "online"
-    return variant
-
 
 def _auto_interpret():
     """Mosaic on a TPU, the Pallas interpreter on the CPU (the tests),
@@ -151,7 +107,7 @@ def _wait_all(streams, slot, i):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
                 seq_k, causal, scale, kv_resident):
-    """Online-softmax forward: what ``auto`` runs at every shape.
+    """The forward: online softmax, the rescale chain on every k tile.
 
     One k tile is two whole-tile matmuls with the softmax chain between
     them, in the exp2 domain with log2(e) folded into the scalar logit
@@ -180,13 +136,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
         a lane reduction leaves its result on every lane, so they are
         stored as they fall and broadcast against the tile by naming the
         same registers again (``jnp.tile``): no relayout. A 1-D
-        ``[block_q]`` statistic written to scratch (the lazy kernel) is
-        re-laid from sublanes to lanes and back on every tile: 1,172
-        ``vperm.slane`` and 1,630 stores in 2,980 bundles, 7.99 ms.
+        ``[block_q]`` statistic written to scratch is re-laid from
+        sublanes to lanes and back on every tile: 1,172 ``vperm.slane``
+        and 1,630 stores in 2,980 bundles.
       * K/V of the whole head in VMEM (2.84 -> 2.22 ms): streamed, each
         q block's first tile is waited for with nothing to hide it
         behind, about 1 us, 512 times a step. A third buffer does not
         help (2.85 ms) and the waits inside the loop are only 0.07 ms.
+
+    Two other accumulation schemes were timed beside this one there and
+    lost, so it is the only one: the rescale deferred to the tiles that
+    raise a row's maximum (7.99 ms: its gate is a vector-to-scalar
+    reduction feeding a branch, and its statistics were 1-D), and two
+    passes, the maximum first and then a chain-free accumulation
+    (4.65 ms: once the chain is out of the way the MXU bounds the
+    forward, and half as many matmuls again cost what they look like).
+    At one k tile a row (most serving prefills) there is nothing to defer
+    or to pass over twice.
 
     Dead ends at this shape, counted in bundles of the compiled loop body
     (1,190 a tile streamed, 1,131 resident) and the first timed on the
@@ -307,202 +273,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
         sem_v=pltpu.SemaphoreType.DMA((2,)), **stats)
 
 
-def _fwd_kernel_lazy(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q,
-                     block_k, seq_k, causal, scale):
-    """Lazy/deferred-rescale forward (splash-attention style). The online
-    kernel pays the full correction chain — exp2(m−m_new) + a [block_q]
-    and a [block_q, d] multiply-add — on EVERY k tile, even when the
-    running max did not move. Here m/l/acc live in VMEM scratch and the
-    correction is predicated on ``any(tile_max > m)``: tiles that do not
-    raise the row max (the common case once the max has stabilized) run
-    only matmul + rowmax + exp2 + two accumulates. K tiles are walked
-    diagonal-first (descending) so for causal attention the near-diagonal
-    tiles — where the largest logits live for recency-dominated heads —
-    set the max in the first iterations and the remaining tiles take the
-    cheap path. Worst case (max strictly rising every tile) it degrades
-    to exactly the online chain, gated once per tile, never to less
-    numerical care: a skipped rescale means every alpha was exactly 1.
-    Same lse contract as _fwd_kernel (natural log, 8-sublane replicated),
-    so the backward kernels are shared unchanged.
-
-    Measured (PR 41, v5e, [64, 4096, 128]): 7.99 ms a step, 17.4% of the
-    roofline, the slowest of the three: the 1-D statistics are re-laid
-    on their way into and out of the ``[2, block_q]`` scratch on every
-    tile, and the gate is a vector-to-scalar reduction feeding a branch.
-    Opt-in since then (``resolve_variant``)."""
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    d = q_ref.shape[-1]
-    q = q_ref[0]                                # [block_q, d]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    scale2 = scale * _LOG2E
-
-    nk_total = seq_k // block_k
-    if causal:
-        nk = jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k,
-                         nk_total)
-    else:
-        nk = nk_total
-
-    def scoped(k_scr, v_scr, stats_scr, acc_scr, sem_k, sem_v):
-        streams = [_stream(k_hbm, bh, block_k, k_scr, sem_k),
-                   _stream(v_hbm, bh, block_k, v_scr, sem_v)]
-        # diagonal-first: loop step t processes k tile nk-1-t
-        _start_all(streams, 0, nk - 1)
-        stats_scr[0] = jnp.full((block_q,), _NEG_INF, jnp.float32)  # m
-        stats_scr[1] = jnp.zeros((block_q,), jnp.float32)           # l
-        acc_scr[:] = jnp.zeros((block_q, d), jnp.float32)
-
-        def body(t, _):
-            kb = nk - 1 - t
-            slot = t % 2
-
-            @pl.when(t + 1 < nk)
-            def _prefetch():
-                _start_all(streams, (t + 1) % 2, kb - 1)
-
-            _wait_all(streams, slot, kb)
-            k = k_scr[slot]
-            v = v_scr[slot]
-            s = jnp.dot(q, k.T,
-                        preferred_element_type=jnp.float32) * scale2
-            if causal:
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-            m_tile = jnp.max(s, axis=-1)
-            m_cur = stats_scr[0]
-
-            @pl.when(jnp.any(m_tile > m_cur))
-            def _rescale():
-                m_new = jnp.maximum(m_cur, m_tile)
-                alpha = jnp.exp2(m_cur - m_new)
-                stats_scr[0] = m_new
-                stats_scr[1] = stats_scr[1] * alpha
-                acc_scr[:] = acc_scr[:] * alpha[:, None]
-
-            p = jnp.exp2(s - stats_scr[0][:, None])
-            stats_scr[1] = stats_scr[1] + jnp.sum(p, axis=-1)
-            acc_scr[:] = acc_scr[:] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            return 0
-
-        jax.lax.fori_loop(0, nk, body, 0)
-        m = stats_scr[0]
-        l = jnp.clip(stats_scr[1], 1e-30)
-        o_ref[0] = (acc_scr[:] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(
-            ((m + jnp.log2(l)) * _LN2)[None, :], (8, m.shape[0]))
-
-    pl.run_scoped(
-        scoped,
-        k_scr=pltpu.VMEM((2, block_k, d), k_hbm.dtype),
-        v_scr=pltpu.VMEM((2, block_k, d), v_hbm.dtype),
-        stats_scr=pltpu.VMEM((2, block_q), jnp.float32),
-        acc_scr=pltpu.VMEM((block_q, d), jnp.float32),
-        sem_k=pltpu.SemaphoreType.DMA((2,)),
-        sem_v=pltpu.SemaphoreType.DMA((2,)))
-
-
-def _fwd_kernel_twopass(q_ref, k_hbm, v_hbm, o_ref, lse_ref, *, block_q,
-                        block_k, seq_k, causal, scale):
-    """Two-pass forward: pass 1 streams K and reduces the row max (one
-    matmul + rowmax per tile — no exp, no corrections); pass 2 re-streams
-    K with V, re-computes QK^T against the now-final max, and accumulates
-    l += Σ exp2(s−m) and acc += p@V with ZERO loop-carried correction —
-    the serial m/l/acc-alpha dependency chain of the online form is gone
-    from the hot pass entirely. The price is one extra QK^T matmul per
-    tile (+50% forward MXU work) and K streamed twice (HBM traffic still
-    O(s·d)); the bet is shapes where the VPU softmax chain, not the MXU,
-    is the bottleneck. Numerics: m is exact (not running), so p ≤ 1
-    always; same lse contract, shared backward.
-
-    Measured (PR 41, v5e, [64, 4096, 128]): 4.65 ms a step, 30.0% of the
-    roofline: the forward is bound by the MXU once its chain is out of
-    the way, so half as many matmuls again cost what they look like.
-    Opt-in (``resolve_variant``)."""
-    bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    d = q_ref.shape[-1]
-    q = q_ref[0]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    scale2 = scale * _LOG2E
-
-    nk_total = seq_k // block_k
-    if causal:
-        nk = jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k,
-                         nk_total)
-    else:
-        nk = nk_total
-
-    def logits(k, kb):
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale2
-        if causal:
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        return s
-
-    def scoped(k_scr, v_scr, sem_k, sem_v):
-        k_stream = _stream(k_hbm, bh, block_k, k_scr, sem_k)
-        v_stream = _stream(v_hbm, bh, block_k, v_scr, sem_v)
-
-        # ---- pass 1: row max only (K stream alone)
-        k_stream(0, 0).start()
-
-        def max_body(kb, m):
-            slot = kb % 2
-
-            @pl.when(kb + 1 < nk)
-            def _prefetch():
-                k_stream((kb + 1) % 2, kb + 1).start()
-
-            k_stream(slot, kb).wait()
-            return jnp.maximum(m, jnp.max(logits(k_scr[slot], kb),
-                                          axis=-1))
-
-        m = jax.lax.fori_loop(
-            0, nk, max_body, jnp.full((block_q,), _NEG_INF, jnp.float32))
-
-        # ---- pass 2: correction-free accumulation (K and V streams)
-        streams = [k_stream, v_stream]
-        _start_all(streams, 0, 0)
-
-        def acc_body(kb, carry):
-            l, acc = carry
-            slot = kb % 2
-
-            @pl.when(kb + 1 < nk)
-            def _prefetch():
-                _start_all(streams, (kb + 1) % 2, kb + 1)
-
-            _wait_all(streams, slot, kb)
-            v = v_scr[slot]
-            p = jnp.exp2(logits(k_scr[slot], kb) - m[:, None])
-            l = l + jnp.sum(p, axis=-1)
-            acc = acc + jnp.dot(p.astype(v.dtype), v,
-                                preferred_element_type=jnp.float32)
-            return l, acc
-
-        l, acc = jax.lax.fori_loop(
-            0, nk, acc_body, (jnp.zeros((block_q,), jnp.float32),
-                              jnp.zeros((block_q, d), jnp.float32)))
-        l = jnp.clip(l, 1e-30)
-        o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(
-            ((m + jnp.log2(l)) * _LN2)[None, :], (8, m.shape[0]))
-
-    pl.run_scoped(
-        scoped,
-        k_scr=pltpu.VMEM((2, block_k, d), k_hbm.dtype),
-        v_scr=pltpu.VMEM((2, block_k, d), v_hbm.dtype),
-        sem_k=pltpu.SemaphoreType.DMA((2,)),
-        sem_v=pltpu.SemaphoreType.DMA((2,)))
-
-
-#: Bytes of VMEM the online forward may fill with one head's K and V, each
+#: Bytes of VMEM the forward may fill with one head's K and V, each
 #: held twice (the pipeline fetches the next head's while this one's are
 #: read): 8 MiB is what fits beside the kernel's own buffers under the
 #: default 16 MiB limit (compiled for a described v5e: bf16 heads of 128
@@ -511,7 +282,7 @@ _KV_RESIDENT_BYTES = 8 << 20
 
 
 def kv_resident(sk, d, dtype):
-    """Whether the online forward holds a head's whole K and V in VMEM
+    """Whether the forward holds a head's whole K and V in VMEM
     (handed over by the pipeline, fetched once a head) or streams them
     tile by tile: from the shapes alone. Streamed, every q block's first
     tile is waited for with nothing to hide it behind, about 1 us on a
@@ -522,12 +293,8 @@ def kv_resident(sk, d, dtype):
     return 4 * sk * d * jnp.dtype(dtype).itemsize <= _KV_RESIDENT_BYTES
 
 
-_FWD_KERNELS = {"online": _fwd_kernel, "lazy": _fwd_kernel_lazy,
-                "twopass": _fwd_kernel_twopass}
-
-
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, scale=None,
-               layout="bshd", variant="online"):
+               layout="bshd"):
     if layout == "bhsd":
         # head-major: the flatten to [b*h, s, d] is a free reshape — the
         # caller (e.g. the transformer block, which is in this layout for
@@ -555,18 +322,17 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, scale=None,
         kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
         vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
 
-    # K/V stay in HBM and the kernel DMAs block_k tiles into
-    # double-buffered VMEM scratch, so VMEM use is independent of sequence
-    # length; the online forward takes a head's whole K/V where they fit
-    kv_spec = pl.BlockSpec(memory_space=pl.ANY)
-    where = {}
-    if variant == "online":
-        where["kv_resident"] = kv_resident(sk, d, k.dtype)
-        if where["kv_resident"]:
-            kv_spec = pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0))
-    kernel = functools.partial(_FWD_KERNELS[variant], block_q=block_q,
+    # a head's whole K/V in VMEM where they fit; past that they stay in
+    # HBM and the kernel DMAs block_k tiles into double-buffered VMEM
+    # scratch, so VMEM use is independent of sequence length
+    resident = kv_resident(sk, d, k.dtype)
+    if resident:
+        kv_spec = pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0))
+    else:
+        kv_spec = pl.BlockSpec(memory_space=pl.ANY)
+    kernel = functools.partial(_fwd_kernel, block_q=block_q,
                                block_k=block_k, seq_k=sk, causal=causal,
-                               scale=scale, **where)
+                               scale=scale, kv_resident=resident)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, sq // block_q),
@@ -853,17 +619,17 @@ def call_block(block, s, compiled=True):
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_core(q, k, v, causal, block_q, block_k, interpret, scale,
-                block_q_dkv, block_k_dkv, layout, variant):
+                block_q_dkv, block_k_dkv, layout):
     out, _ = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
-                        scale=scale, layout=layout, variant=variant)
+                        scale=scale, layout=layout)
     return out
 
 
 def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
                     interpret=None, block_q_dkv=None, block_k_dkv=None,
-                    layout="bshd", variant="auto"):
+                    layout="bshd"):
     """Fused attention; q/k/v [batch, seq, heads, head_dim] (or
     [batch, heads, seq, head_dim] with ``layout="bhsd"`` — the flatten to
     the kernel's physical [batch·heads, seq, head_dim] is then a free
@@ -884,14 +650,7 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     Other non-divisible cases would need an explicit key mask the kernel
     doesn't carry, so they raise. On real TPU, head_dim is zero-padded to
     the 128-lane tile (softmax scale keeps the true head_dim; zero columns
-    drop out of every dot product).
-
-    ``variant`` selects the forward accumulation scheme (VARIANTS:
-    'online' | 'lazy' | 'twopass', or 'auto', which is 'online', the
-    fastest measured: see resolve_variant; the HVD_FLASH_VARIANT env var
-    overrides all of them, which is the A/B hook). All variants compute
-    the exact same softmax and write the same lse residual, so the
-    backward kernels are shared and gradients are variant-independent."""
+    drop out of every dot product)."""
     if layout not in ("bshd", "bhsd"):
         raise ValueError(f"unknown layout {layout!r}")
     seq_axis = 2 if layout == "bhsd" else 1
@@ -919,10 +678,8 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     if pad_d:
         pads = ((0, 0), (0, 0), (0, 0), (0, pad_d))
         q, k, v = jnp.pad(q, pads), jnp.pad(k, pads), jnp.pad(v, pads)
-    variant = resolve_variant(variant, causal=causal,
-                              nk=(sk + pad_k) // bk)
     out = _flash_core(q, k, v, causal, bq, bk, interpret_eff, scale,
-                      bq2, bk2, layout, variant)
+                      bq2, bk2, layout)
     if pad_d:
         out = out[..., :d]
     if pad_q:
@@ -931,14 +688,14 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
 
 
 def _vjp_fwd(q, k, v, causal, block_q, block_k, interpret, scale,
-             block_q_dkv, block_k_dkv, layout, variant):
+             block_q_dkv, block_k_dkv, layout):
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
-                          scale=scale, layout=layout, variant=variant)
+                          scale=scale, layout=layout)
     return out, (q, k, v, out, lse)
 
 
 def _vjp_bwd(causal, block_q, block_k, interpret, scale, block_q_dkv,
-             block_k_dkv, layout, variant, residuals, g):
+             block_k_dkv, layout, residuals, g):
     q, k, v, out, lse = residuals
     return _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k,
                       interpret, scale=scale, block_q_dkv=block_q_dkv,

@@ -6,10 +6,12 @@ RETURNS the per-layer K/V it computed (to seed the cache), and a
 one-token decode that reads/extends that cache. Rather than threading
 cache plumbing through the training model (risking its numerics and
 sharding annotations), this module re-runs the SAME flax primitives —
-nn.Dense / nn.RMSNorm / nn.Embed with identical dtype policy, the
-model's own ``_rope`` — applied directly to the checkpoint's param
-leaves. The param tree layout (embed / layer_i.{ln_attn,attn,ln_mlp,
-mlp} / ln_f / lm_head) is the numerics contract;
+nn.Dense / nn.RMSNorm / nn.Embed with identical dtype policy
+(models/transformer.py's ``_dense``, ``_rmsnorm``, ``_embed``,
+``_logits``, ``_mlp``), the model's own ``_rope`` — applied directly to
+the checkpoint's param leaves. The param tree layout (embed /
+layer_i.{ln_attn,attn,ln_mlp,mlp} / ln_f / lm_head) is the numerics
+contract;
 tests/test_flash_attention.py and tests/test_serving.py pin it by
 asserting logits equality and token-for-token greedy agreement against
 ``TransformerLM.apply``.
@@ -24,10 +26,9 @@ of ``prefill_forward`` / ``decode_step`` with a norm closing each branch,
 run ``passes`` times with the same ``_qkv``, ``_mlp``, ``_rmsnorm``,
 ``_logits``, ``_rope`` and kernels, K/V kept per (pass, layer) PLANE of
 the cache, ``passes x layers`` of them. The dense model's two forwards
-are kept as they were, line for line: their Python call path is part of
-what the chip's compiler is handed (a kernel's body carries its call
-site), and PR 37 measured the dense serving cell's set-up 8 s longer
-when they ran through ``_stack`` (PERF.md §6).
+keep a loop of their own: PR 37 measured the dense serving cell's
+set-up 8 s longer when they ran through ``_stack``, with the same
+lowered text and the cause not found (PERF.md §7).
 
 Attention: prefill uses the model's own dispatch (flash kernel on TPU,
 exact full attention on CPU); decode uses ops/flash_attention.py's
@@ -36,41 +37,14 @@ exact full attention on CPU); decode uses ops/flash_attention.py's
 each row's live blocks, elsewhere an einsum under a length mask).
 """
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..models import hybrid, latent_moe, looped, window_moe
-from ..models.transformer import _dispatch_attention, _rope
+from ..models.transformer import (_dense, _dispatch_attention, _embed,
+                                  _logits, _mlp, _rmsnorm, _rope)
 from ..ops.flash_attention import decode_attention
 from ..parallel import mesh as mesh_lib
-
-
-def _dense(x, kernel, dtype):
-    return nn.Dense(kernel.shape[-1], use_bias=False,
-                    dtype=dtype).apply({"params": {"kernel": kernel}}, x)
-
-
-def _rmsnorm(x, scale, dtype, eps=1e-6):
-    norm = nn.RMSNorm(epsilon=eps, dtype=dtype)
-    return norm.apply({"params": {"scale": scale}}, x)
-
-
-def _embed(cfg, params, tokens):
-    return nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype).apply(
-        {"params": {"embedding": params["embed"]["embedding"]}}, tokens)
-
-
-def _logits(cfg, params, x):
-    # same head math as TransformerLM: logits straight from the MXU
-    # accumulator in acc precision, tied or separate kernel
-    acc = jnp.float32 if cfg.logits_fp32 else cfg.dtype
-    if cfg.tie_embeddings:
-        kernel = params["embed"]["embedding"].T
-    else:
-        kernel = params["lm_head"]["kernel"]
-    return jnp.dot(x.astype(cfg.dtype), kernel.astype(cfg.dtype),
-                   preferred_element_type=acc)
 
 
 def _check_dense(cfg):
@@ -109,13 +83,6 @@ def _qkv(cfg, layer, y, positions):
     # the dense model's rotary base is ``_rope``'s own default
     rope = {"base": cfg.rope_theta} if _is_looped(cfg) else {}
     return _rope(q, positions, **rope), _rope(k, positions, **rope), v
-
-
-def _mlp(cfg, layer, y):
-    gate = _dense(y, layer["mlp"]["gate"]["kernel"], cfg.dtype)
-    up = _dense(y, layer["mlp"]["up"]["kernel"], cfg.dtype)
-    return _dense(nn.silu(gate) * up, layer["mlp"]["down"]["kernel"],
-                  cfg.dtype)
 
 
 def _stack(cfg, params, x, positions, attend, past):

@@ -600,10 +600,15 @@ class NumericsMonitor:
         the quantized-collectives work will A/B against). Host-side only
         — the compressor's compress() itself must stay jit-pure."""
         import numpy as np
-        import jax.numpy as jnp
-        row = np.asarray(jnp.stack([stats_vector(before),
-                                    stats_vector(after.astype(
-                                        jnp.asarray(before).dtype))]))
+        # both rows as ONE program. Op by op they are some thirty programs
+        # over a stacked buffer (a row a device), ten of them cross-device
+        # reductions, all in flight at once, and under load XLA's CPU
+        # client then stops for good, every thread of the process waiting:
+        # a worker that is alive and never reports (ROADMAP D13 (i); plain
+        # JAX on eight virtual devices does the same, and neither one
+        # program nor a wait after each reduction does)
+        row = np.asarray(_group_stats_fn(2, tuple(np.shape(before)))(
+            before, after.astype(before.dtype)))
         pre, post = float(row[0][S_L2]), float(row[1][S_L2])
         reg = metrics_mod.get_registry()
         reg.gauge(

@@ -4,6 +4,7 @@ test can pin: main() refuses a CPU, imports create no backend, the
 attention call sees its per-device shape under GSPMD, and Mosaic accepts
 the kernels at the serving prefill lengths."""
 
+import functools
 import json
 import os
 import subprocess
@@ -260,6 +261,31 @@ def test_mosaic_accepts_the_kernels_without_a_chip(topo):
                                    sharding=sharding)
         jax.jit(grads).trace(arg, arg, arg).lower(
             lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("shape, resident", [
+    ((1, 4096, 4, 128), True),    # the training cell's rows
+    ((1, 16384, 2, 128), False),  # past the VMEM budget: tiles streamed
+    ((1, 512, 4, 128), True),     # a serving prefill: one k tile a row
+    ((1, 1024, 2, 64), True),     # heads of 64, padded to the lane tile
+])
+def test_mosaic_accepts_the_forward_on_both_kv_paths(topo, shape, resident):
+    """The one forward at the widths the cells run, compiled for a v5e:
+    a head's whole K/V in VMEM beside the kernel's own buffers, and past
+    that budget the streamed tiles."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.ops import flash_attention as fa
+
+    _, s, _, d = shape
+    assert fa.kv_resident(s, -(-d // 128) * 128, jnp.bfloat16) is resident
+    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                               sharding=SingleDeviceSharding(topo.devices[0]))
+    compiled = jax.jit(functools.partial(
+        fa.flash_attention, causal=True, interpret=False)).trace(
+            arg, arg, arg).lower(lowering_platforms=("tpu",)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.fixture

@@ -1,35 +1,41 @@
-"""Numerics regression suite for every flash-attention forward variant
-(online / lazy / twopass) against an independent ``jax.nn.softmax``
-reference — NOT against ``full_attention`` (which shares this repo's
-lineage) and not against each other.
+"""Numerics of the flash forward (``ops/flash_attention._fwd_kernel``)
+against an independent ``jax.nn.softmax`` reference: NOT against
+``full_attention`` (which shares this repo's lineage).
 
-The grid (docs/benchmarks.md, "Three forwards, one backward"): dtype ∈ {fp32,
-bf16} × causal ∈ {True, False} × seq ∈ {128, 1024, 2048}, plus the
-ragged-tail case (seq not a block multiple → the causal end-padding
-path). Tolerances are asserted per dtype: fp32 2e-5 (fp32 MXU +
-exp2-domain softmax vs the reference's exp), bf16 5e-2 (bf16 matmul
-inputs). The flagship-sized sequences are marked ``slow`` — interpret
-mode executes them on CPU; tier 1 and the fast kernel-numerics CI job
-run the rest (see ci/run_tests.sh).
+The grid: dtype in {fp32, bf16} x causal in {True, False} x seq in {128,
+1024, 2048}, plus the ragged-tail case (seq not a block multiple: the
+causal end-padding path), each over both places the kernel reads K/V
+from (``kv``: a head's whole K/V in VMEM, or tiles streamed from HBM,
+which the shapes alone decide: ``kv_resident``). Tolerances are asserted
+per dtype: fp32 2e-5 (fp32 MXU + exp2-domain softmax vs the reference's
+exp), bf16 5e-2 (bf16 matmul inputs). The flagship-sized sequences are
+marked ``slow``: interpret mode executes them on CPU; tier 1 and the fast
+kernel-numerics CI job run the rest (see ci/run_tests.sh).
 
-Gradients are checked per variant even though the backward kernels are
-shared: each variant's forward writes the (out, lse) residuals the
-backward re-materializes probabilities from, so a variant that computed
-a subtly wrong lse would pass the forward check and still corrupt
-training.
+The log-sum-exp is held to the reference beside the output: the backward
+re-materializes probabilities from it and ``parallel/ring.py`` merges
+shards on it, so a forward with a subtly wrong lse would pass an output
+check and still corrupt training.
 """
-
-import os
 
 import numpy as np
 import pytest
 
 from tests.test_flash_attention import _qkv
 
-VARIANTS = ("online", "lazy", "twopass")
-
 # (rtol, atol) per input dtype, asserted on fp32-cast outputs
 _TOL = {"float32": (2e-5, 2e-5), "bfloat16": (5e-2, 5e-2)}
+
+
+@pytest.fixture(params=["resident", "streamed"])
+def kv(request, monkeypatch):
+    """Where the forward reads K/V from. Every shape of this file fits
+    the VMEM budget; ``streamed`` takes the budget away, which is what
+    rows past it (long context, ring shards) run."""
+    from horovod_tpu.ops import flash_attention as fa
+    if request.param == "streamed":
+        monkeypatch.setattr(fa, "_KV_RESIDENT_BYTES", 0)
+    return request.param
 
 
 def _ref_attention(q, k, v, causal):
@@ -49,71 +55,6 @@ def _ref_attention(q, k, v, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", p, vf)
 
 
-def _check(variant, dtype_name, causal, s, b=2, h=2, d=32, block=64,
-           rng=0):
-    import jax.numpy as jnp
-    dtype = getattr(jnp, dtype_name)
-    from horovod_tpu.ops.flash_attention import flash_attention
-    q, k, v = _qkv(rng, b=b, s=s, h=h, d=d, dtype=dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=block,
-                          block_k=block, variant=variant)
-    assert out.dtype == dtype
-    ref = _ref_attention(q, k, v, causal)
-    rtol, atol = _TOL[dtype_name]
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=rtol, atol=atol)
-
-
-class TestVariantNumerics:
-    @pytest.mark.parametrize("variant", VARIANTS)
-    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_seq128(self, hvd, variant, dtype, causal):
-        _check(variant, dtype, causal, s=128)
-
-    @pytest.mark.parametrize("variant", VARIANTS)
-    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    def test_ragged_tail(self, hvd, variant, dtype):
-        """seq 100 with 64-blocks: the causal end-padding path — the tail
-        block carries 36 padded keys the mask must discard exactly."""
-        _check(variant, dtype, causal=True, s=100, rng=4)
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("variant", VARIANTS)
-    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_seq1024(self, hvd, variant, dtype, causal):
-        # 4 k-tiles per q row at block 256: the lazy gate and the twopass
-        # re-stream both run multi-tile
-        _check(variant, dtype, causal, s=1024, b=1, h=2, block=256, rng=1)
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("variant", VARIANTS)
-    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_seq2048(self, hvd, variant, dtype, causal):
-        # seq 2048: four k tiles at the default block
-        _check(variant, dtype, causal, s=2048, b=1, h=1, block=512, rng=2)
-
-    @pytest.mark.parametrize("variant", ("lazy", "twopass"))
-    def test_adversarial_rising_max(self, hvd, variant):
-        """Keys scaled so each later k tile strictly raises the row max —
-        the lazy gate's worst case (rescale fires every tile) and the
-        regime where deferred-rescale schemes lose precision if the
-        accumulator correction is wrong."""
-        import jax.numpy as jnp
-        from horovod_tpu.ops.flash_attention import flash_attention
-        q, k, v = _qkv(9, b=1, s=128, h=1, d=32)
-        ramp = jnp.linspace(0.5, 8.0, 128)[None, :, None, None]
-        k = (k * ramp).astype(k.dtype)
-        out = flash_attention(q, k, v, causal=False, block_q=32,
-                              block_k=32, variant=variant)
-        ref = _ref_attention(q, k, v, causal=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
 def _ref_lse(q, k, causal):
     """Natural-log row log-sum-exp of the scaled (masked) logits,
     [b, h, s]: what the forward hands the backward and ring.py."""
@@ -128,36 +69,86 @@ def _ref_lse(q, k, causal):
     return jax.nn.logsumexp(s, axis=-1)
 
 
-class TestAutoForwardAtHeadDim128:
-    """What ``auto`` runs (PR 41: the online chain with lane-replicated
-    statistics in VMEM scratch) at the head width the cells run, 128,
-    with two or more k tiles a row: the statistics are then replicated
-    over all 128 lanes and rescaled between tiles, as compiled."""
+def _check(dtype_name, causal, s, b=2, h=2, d=32, block=64, rng=0):
+    import jax.numpy as jnp
+    dtype = getattr(jnp, dtype_name)
+    from horovod_tpu.ops.flash_attention import flash_attention
+    q, k, v = _qkv(rng, b=b, s=s, h=h, d=d, dtype=dtype)
+    out = flash_attention(q, k, v, causal=causal, block_q=block,
+                          block_k=block)
+    assert out.dtype == dtype
+    ref = _ref_attention(q, k, v, causal)
+    rtol, atol = _TOL[dtype_name]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=rtol, atol=atol)
 
-    @pytest.mark.parametrize("kv", ["resident", "streamed"])
+
+class TestForwardNumerics:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("causal", [True, False])
-    def test_out_and_lse(self, hvd, monkeypatch, dtype, causal, kv):
+    def test_seq128(self, hvd, kv, dtype, causal):
+        _check(dtype, causal, s=128)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_ragged_tail(self, hvd, kv, dtype):
+        """seq 100 with 64-blocks: the causal end-padding path — the tail
+        block carries 36 padded keys the mask must discard exactly."""
+        _check(dtype, causal=True, s=100, rng=4)
+
+    @pytest.mark.slow
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_seq1024(self, hvd, dtype, causal):
+        # 4 k-tiles per q row at block 256
+        _check(dtype, causal, s=1024, b=1, h=2, block=256, rng=1)
+
+    @pytest.mark.slow
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_seq2048(self, hvd, dtype, causal):
+        # seq 2048: four k tiles at the default block
+        _check(dtype, causal, s=2048, b=1, h=1, block=512, rng=2)
+
+
+class TestForwardAsCompiled:
+    """The forward (the online chain with lane-replicated statistics in
+    VMEM scratch) where its statistics span whole lane tiles, as
+    compiled: head widths of 64 (half a tile: the statistics are then 64
+    lanes wide), 128 (what the cells run) and 256 (two tiles an
+    accumulator row), two or more k tiles a row so that the statistics
+    are rescaled between tiles, and q and k blocks of different
+    lengths."""
+
+    # (head_dim, seq, block_q, block_k, dtype, causal)
+    @pytest.mark.parametrize("d, s, bq, bk, dtype, causal", [
+        (128, 384, 128, 128, "float32", True),
+        (128, 384, 128, 128, "float32", False),
+        (128, 384, 128, 128, "bfloat16", True),
+        (128, 384, 128, 128, "bfloat16", False),
+        (64, 384, 128, 128, "float32", True),
+        (64, 384, 128, 128, "bfloat16", False),
+        (256, 384, 128, 128, "float32", False),
+        (256, 384, 128, 128, "bfloat16", True),
+        (128, 512, 256, 128, "float32", True),   # two k tiles a q block
+        (128, 512, 128, 256, "float32", True),   # a k tile past the diagonal
+    ])
+    def test_out_and_lse(self, hvd, kv, d, s, bq, bk, dtype, causal):
         import jax.numpy as jnp
         from horovod_tpu.ops import flash_attention as fa
-        if kv == "streamed":  # what rows past the VMEM budget take
-            monkeypatch.setattr(fa, "_KV_RESIDENT_BYTES", 0)
-        assert fa.kv_resident(384, 128, dtype) is (kv == "resident")
-        q, k, v = _qkv(11, b=1, s=384, h=2, d=128,
-                       dtype=getattr(jnp, dtype))
-        variant = fa.resolve_variant("auto", causal=causal, nk=3)
-        out, lse = fa._flash_fwd(q, k, v, causal, 128, 128, True,
-                                 variant=variant)
+        assert fa.kv_resident(s, d, dtype) is (kv == "resident")
+        q, k, v = _qkv(11, b=1, s=s, h=2, d=d, dtype=getattr(jnp, dtype))
+        out, lse = fa._flash_fwd(q, k, v, causal, bq, bk, True)
         assert out.dtype == q.dtype and lse.dtype == jnp.float32
-        assert lse.shape == (2, 8, 384)
+        assert lse.shape == (2, 8, s)
         rtol, atol = _TOL[dtype]
         np.testing.assert_allclose(
             np.asarray(out, np.float32),
             np.asarray(_ref_attention(q, k, v, causal), np.float32),
             rtol=rtol, atol=atol)
-        want = np.asarray(_ref_lse(q, k, causal)).reshape(2, 1, 384)
+        want = np.asarray(_ref_lse(q, k, causal)).reshape(2, 1, s)
         np.testing.assert_allclose(
-            np.asarray(lse), np.broadcast_to(want, (2, 8, 384)),
+            np.asarray(lse), np.broadcast_to(want, (2, 8, s)),
             rtol=rtol, atol=atol)
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -182,7 +173,7 @@ class TestAutoForwardAtHeadDim128:
                                        np.asarray(b, np.float32),
                                        rtol=tol, atol=tol)
 
-    def test_rising_max(self, hvd):
+    def test_rising_max(self, hvd, kv):
         """Every later k tile raises the row max: the rescale of l and of
         the accumulator runs with alpha < 1 on every tile."""
         import jax.numpy as jnp
@@ -190,8 +181,7 @@ class TestAutoForwardAtHeadDim128:
         q, k, v = _qkv(13, b=1, s=512, h=1, d=128)
         ramp = jnp.linspace(0.5, 8.0, 512)[None, :, None, None]
         k = (k * ramp).astype(k.dtype)
-        out, lse = fa._flash_fwd(q, k, v, False, 128, 128, True,
-                                 variant=fa.resolve_variant("auto", nk=4))
+        out, lse = fa._flash_fwd(q, k, v, False, 128, 128, True)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(_ref_attention(q, k, v, False)),
             rtol=2e-5, atol=2e-5)
@@ -200,7 +190,7 @@ class TestAutoForwardAtHeadDim128:
             np.asarray(_ref_lse(q, k, False)).reshape(1, 512),
             rtol=2e-5, atol=2e-5)
 
-    def test_ragged_tail(self, hvd):
+    def test_ragged_tail(self, hvd, kv):
         """300 positions on 128-blocks: end-padded to 384, three k tiles
         on the last q block, 84 padded keys the mask has to discard."""
         import jax.numpy as jnp
@@ -214,18 +204,51 @@ class TestAutoForwardAtHeadDim128:
             np.asarray(_ref_attention(q, k, v, True), np.float32),
             rtol=5e-2, atol=5e-2)
 
+    def test_shards_merge_on_the_lse(self, hvd, kv):
+        """``parallel/ring.py``'s merge (``_ring_flash_fwd_impl``: on the
+        CPU the ring runs a pure-jax twin of the kernel, so the kernel's
+        own lse never meets the merge in tests/test_ring_attention.py):
+        a causal diagonal pair and a fully visible past pair, each from
+        the kernel, merged on their lse in natural-log units, are the
+        attention over both."""
+        import jax.numpy as jnp
+        from horovod_tpu.ops import flash_attention as fa
+        from horovod_tpu.parallel import ring
+        q, k, v = _qkv(15, b=1, s=512, h=2, d=128)
+        q = q[:, 256:]                      # the second shard's queries
+        out, lse = jnp.zeros(q.shape, jnp.float32), None
+        for keys, values, causal in ((k[:, 256:], v[:, 256:], True),
+                                     (k[:, :256], v[:, :256], False)):
+            o_i, lse_i = fa._flash_fwd(q, keys, values, causal, 128, 128,
+                                       True)
+            lse_i = ring._lse_to_bhs(lse_i, 1, 2, 256)
+            if lse is None:
+                out, lse = o_i, lse_i
+                continue
+            merged = jnp.logaddexp(lse, lse_i)
+            w, w_i = (jnp.exp(t - merged).transpose(0, 2, 1)[..., None]
+                      for t in (lse, lse_i))
+            out, lse = out * w + o_i * w_i, merged
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(_ref_attention(q, k, v, True)),
+            rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(_ref_lse(q, k, True)),
+            rtol=2e-5, atol=2e-5)
 
-class TestVariantGradients:
-    @pytest.mark.parametrize("variant", VARIANTS)
-    def test_grad_matches_reference(self, hvd, variant):
+
+class TestGradients:
+    def test_grad_matches_reference(self, hvd, kv):
+        """The backward reads the forward's (out, lse) residuals."""
         import jax
         import jax.numpy as jnp
         from horovod_tpu.ops.flash_attention import flash_attention
         q, k, v = _qkv(5, s=128)
 
         g = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=True, block_q=32, block_k=32,
-            variant=variant) ** 2), argnums=(0, 1, 2))(q, k, v)
+            q, k, v, causal=True, block_q=32, block_k=32) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
         g_ref = jax.grad(lambda q, k, v: jnp.sum(
             _ref_attention(q, k, v, causal=True).astype(q.dtype) ** 2),
             argnums=(0, 1, 2))(q, k, v)
@@ -233,34 +256,8 @@ class TestVariantGradients:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-4)
 
-    def test_lse_identical_across_variants(self, hvd):
-        """The backward contract: every variant writes the same
-        natural-log lse residual (this is what makes the backward kernels
-        shareable and ring.py's merge variant-agnostic)."""
-        from horovod_tpu.ops import flash_attention as fa
-        q, k, v = _qkv(6, s=128)
-        lses = []
-        for variant in VARIANTS:
-            _, lse = fa._flash_fwd(q, k, v, True, 32, 32, True,
-                                   variant=variant)
-            lses.append(np.asarray(lse))
-        for other in lses[1:]:
-            np.testing.assert_allclose(lses[0], other, rtol=1e-6,
-                                       atol=1e-6)
 
-
-class TestVariantSelection:
-    def test_explicit_names(self, hvd):
-        from horovod_tpu.ops.flash_attention import resolve_variant
-        for v in VARIANTS:
-            assert resolve_variant(v, nk=4) == v
-
-    def test_auto_heuristic(self, hvd):
-        from horovod_tpu.ops.flash_attention import resolve_variant
-        assert resolve_variant("auto", nk=1) == "online"
-        assert resolve_variant("auto", nk=2) == "online"
-        assert resolve_variant("auto", causal=False, nk=4) == "online"
-
+class TestWhatACallRunsWith:
     @pytest.mark.parametrize("s, block, nk", [
         (4096, 512, 8),    # the training cell: [64, 4096, 128]
         (128, 128, 1),     # serving prefill, the padded lengths
@@ -271,15 +268,14 @@ class TestVariantSelection:
         (1024, 512, 2),
     ])
     def test_what_a_call_runs_with(self, hvd, s, block, nk):
-        """``auto``, the blocks and where K/V are read from are pure
-        functions of the call's shapes (PR 41): at the training shape
-        and at every prefill length the online forward on a head's
-        whole K/V in VMEM, at the default 512-blocks or what fits."""
+        """The blocks and where K/V are read from are pure functions of
+        the call's shapes (PR 41): at the training shape and at every
+        prefill length a head's whole K/V in VMEM, at the default
+        512-blocks or what fits."""
         import jax.numpy as jnp
         from horovod_tpu.ops import flash_attention as fa
         assert fa.call_block(512, s) == block
         assert -(-s // block) == nk
-        assert fa.resolve_variant("auto", causal=True, nk=nk) == "online"
         assert fa.kv_resident(s, 128, jnp.bfloat16)
 
     @pytest.mark.parametrize("s, dtype, resident", [
@@ -298,47 +294,6 @@ class TestVariantSelection:
         assert fa.call_block(512, 200, compiled=False) == 200
         assert fa.call_block(512, 384) == 384
         assert fa.call_block(256, 4096) == 256
-
-    def test_unknown_raises(self, hvd):
-        from horovod_tpu.ops.flash_attention import resolve_variant
-        with pytest.raises(ValueError, match="unknown flash variant"):
-            resolve_variant("eager", nk=2)
-
-    def test_env_overrides_everything(self, hvd, monkeypatch):
-        from horovod_tpu.ops.flash_attention import resolve_variant
-        monkeypatch.setenv("HVD_FLASH_VARIANT", "twopass")
-        assert resolve_variant("online", nk=4) == "twopass"
-        assert resolve_variant("auto", nk=1) == "twopass"
-        monkeypatch.setenv("HVD_FLASH_VARIANT", "nonsense")
-        with pytest.raises(ValueError, match="unknown flash variant"):
-            resolve_variant("online", nk=4)
-
-    def test_env_empty_is_ignored(self, hvd, monkeypatch):
-        from horovod_tpu.ops.flash_attention import resolve_variant
-        monkeypatch.setenv("HVD_FLASH_VARIANT", "")
-        assert resolve_variant("auto", nk=4) == "online"
-
-    def test_transformer_config_plumbs_variant(self, hvd):
-        """cfg.flash_variant reaches the kernel: a model pinned to each
-        variant produces the same logits (numerics parity at the model
-        level, fp32)."""
-        import jax
-        import jax.numpy as jnp
-        from horovod_tpu.models import transformer as tr
-        tokens = jnp.asarray(
-            np.random.RandomState(0).randint(0, 256, (2, 64)), jnp.int32)
-        outs = []
-        for variant in VARIANTS:
-            cfg = tr.TransformerConfig.tiny(
-                dtype=jnp.float32, attention_impl="flash",
-                flash_variant=variant)
-            model = tr.TransformerLM(cfg)
-            params = model.init(jax.random.PRNGKey(0), tokens)["params"]
-            outs.append(np.asarray(
-                model.apply({"params": params}, tokens)))
-        for other in outs[1:]:
-            np.testing.assert_allclose(outs[0], other, rtol=2e-5,
-                                       atol=2e-5)
 
 
 class TestLatentDecodeKernel:

@@ -214,3 +214,39 @@ class TestTpuHeadShape:
 
         assert (tr.matmul_flops_per_token(a, 1024) ==
                 tr.matmul_flops_per_token(b, 1024))
+
+
+def _serving_imports(module):
+    """Every name a file of ``horovod_tpu/models/`` imports from
+    ``horovod_tpu.serving``, at module level or inside a function."""
+    import ast
+    import pathlib
+    import horovod_tpu.models
+    path = pathlib.Path(horovod_tpu.models.__file__).with_name(module + ".py")
+    found = []
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.ImportFrom):
+            # level 1 is horovod_tpu.models, level 2 horovod_tpu
+            base = ("", "horovod_tpu.models.", "horovod_tpu.")[node.level]
+            found += [f"{base}{node.module or ''}.{a.name}".replace("..", ".")
+                      for a in node.names]
+        elif isinstance(node, ast.Import):
+            found += [a.name for a in node.names]
+    return [name for name in found
+            if name.startswith("horovod_tpu.serving")]
+
+
+@pytest.mark.parametrize("module, allowed", [
+    ("transformer", []), ("moe", []), ("hybrid", []), ("latent_moe", []),
+    ("window_moe", []),
+    # the looped model's no-cache forward lives in serving/decode.py with
+    # ``_stack``; both move when training, prefill and decode share one
+    # block (ROADMAP D1 (a)), and this entry goes with them
+    ("looped", ["horovod_tpu.serving.decode.hidden_states"])])
+def test_models_do_not_import_serving(module, allowed):
+    """Imports point one way: ``serving/`` builds on ``models/``. A model
+    brings ``state_shapes``, ``prefill`` and ``decode``; the leaves they
+    are built of (``_dense``, ``_rmsnorm``, ``_embed``, ``_logits``,
+    ``_mlp``) are models/transformer.py's, so that a training path for a
+    served family imports no server."""
+    assert _serving_imports(module) == allowed

@@ -9,6 +9,7 @@ divergence drill with flight dumps and a postmortem verdict) lives in
 tests/test_chaos_plane.py.
 """
 
+import logging
 import math
 import os
 
@@ -543,6 +544,31 @@ class TestCompressionDelta:
                     compressor="topk") == pytest.approx(0.4, rel=1e-5)
         assert _val(reg, "hvd_compressed_tensors_total",
                     compressor="topk") == 1
+
+    def test_a_stacked_buffers_statistics_are_one_program(
+            self, monitor, reg, caplog):
+        """Over a buffer with a row a device every reduction is a
+        collective, and op by op ten of them were in flight at once: under
+        load XLA's CPU client then never returned, and a worker of
+        tests/test_quantization.py sat out its launcher's ten minutes
+        (ROADMAP D13 (i)). Counted where it cannot be missed: a first call
+        at a shape of its own compiles ONE program."""
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        rows = NamedSharding(Mesh(np.asarray(jax.devices()), ("dp",)),
+                             P("dp"))
+        n = len(jax.devices())
+        before = jax.device_put(
+            np.full((n, 1237), 2.0, np.float32), rows)
+        after = jax.device_put(np.full((n, 1237), 1.0, np.float32), rows)
+        with jax.log_compiles(), caplog.at_level(logging.WARNING, "jax"):
+            monitor.observe_compression("g", before, after, "int8")
+        compiled = [r.getMessage().split(" with ")[0]
+                    for r in caplog.records
+                    if r.getMessage().startswith("Compiling ")]
+        assert compiled == ["Compiling jit(impl)"]    # _group_stats_fn's
+        assert _val(reg, "hvd_compression_norm_delta", tensor="g",
+                    compressor="int8") == pytest.approx(0.5, rel=1e-5)
 
     def test_zero_norm_input_reports_zero_delta(self, monitor, reg):
         z = np.zeros((3,), np.float32)

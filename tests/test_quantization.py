@@ -14,6 +14,34 @@ from horovod_tpu.run.launch import run
 _ENV = {"JAX_PLATFORMS": "cpu"}
 
 
+def _run_watched(fn, tmp_path, env, num_proc=1):
+    """``run(fn)`` for a ``fn`` of a few seconds, cut at 120 s instead of
+    the launcher's (a deployment's) 600: a worker still inside ``fn``
+    after 90 s dumps every thread's stack to a file of its own, and the
+    failure carries what it wrote (ROADMAP D13 (i): a worker that is
+    alive and never reports)."""
+    stacks = str(tmp_path / "stacks-rank")
+
+    def armed():
+        import faulthandler
+        import os
+        with open(stacks + os.environ.get("HVD_PROCESS_ID", "0"), "w") as f:
+            faulthandler.dump_traceback_later(90, exit=False, file=f)
+            try:
+                return fn()
+            finally:
+                faulthandler.cancel_dump_traceback_later()
+
+    try:
+        return run(armed, num_proc=num_proc, env=env, start_timeout_s=120)
+    except Exception as e:
+        dumped = "".join(f"--- {p.name}\n{p.read_text()}"
+                         for p in sorted(tmp_path.glob("stacks-rank*")))
+        raise AssertionError(
+            f"{e}\nwhere each worker stood 90 s into fn (nothing: it "
+            f"never got there, or had left):\n{dumped}") from e
+
+
 def _q():
     from horovod_tpu.ops import quantization
     return quantization
@@ -282,7 +310,7 @@ class TestEagerQuantizedPath:
     """End-to-end through hvd.allreduce with HVD_COMPRESSION set
     (single process: the stacked/replicated simulated wire)."""
 
-    def test_allreduce_quantized_with_metrics(self):
+    def test_allreduce_quantized_with_metrics(self, tmp_path):
         env = dict(_ENV, HVD_COMPRESSION="int8", HVD_QUANT_MIN_BYTES="0",
                    HVD_METRICS="1")
 
@@ -317,14 +345,14 @@ class TestEagerQuantizedPath:
             return (float(err / scale), bool((zi == z).all()),
                     wire.get("int8", 0), raw.get("int8", 0))
 
-        (rel_err, ints_exact, wire_b, raw_b), = run(fn, num_proc=1,
-                                                    env=env)
+        (rel_err, ints_exact, wire_b, raw_b), = _run_watched(
+            fn, tmp_path, env)
         assert rel_err < 0.02
         assert ints_exact
         # encoded bytes crossed the accounting: ~4x smaller than raw
         assert 0 < wire_b < raw_b / 3
 
-    def test_unknown_codec_name_fails_at_init(self):
+    def test_unknown_codec_name_fails_at_init(self, tmp_path):
         env = dict(_ENV, HVD_COMPRESSION="zstd")
 
         def fn():
@@ -339,10 +367,10 @@ class TestEagerQuantizedPath:
                     hvd.shutdown()
             return "no error"
 
-        (out,) = run(fn, num_proc=1, env=env)
+        (out,) = _run_watched(fn, tmp_path, env)
         assert "unknown compression codec" in out and "zstd" in out
 
-    def test_codec_mismatch_fails_loudly_at_negotiation(self):
+    def test_codec_mismatch_fails_loudly_at_negotiation(self, tmp_path):
         """Acceptance: rank-asymmetric codec config must fail at
         negotiation (versioned plan field), never corrupt a sum."""
         env = dict(_ENV, HVD_QUANT_MIN_BYTES="0", HVD_NEGOTIATION="1")
@@ -364,7 +392,7 @@ class TestEagerQuantizedPath:
             hvd.shutdown()
             return outcome
 
-        for outcome in run(fn, num_proc=2, env=env):
+        for outcome in _run_watched(fn, tmp_path, env, num_proc=2):
             assert "Mismatched wire-codec config" in outcome
             assert "int8" in outcome and "none" in outcome
 
@@ -373,7 +401,7 @@ class TestEagerQuantizedPath:
 # the eager data-parallel step of the examples
 # ---------------------------------------------------------------------------
 
-def test_int8_wire_on_the_eager_step():
+def test_int8_wire_on_the_eager_step(tmp_path):
     """The eager data-parallel step of the examples
     (bench_common._eager_step: stacked per-shard gradients, one fused
     eager allreduce, one apply) under each codec, toggled on the live
@@ -434,7 +462,7 @@ def test_int8_wire_on_the_eager_step():
         return moved, losses
 
     env = dict(_ENV, HVD_METRICS="1", HVD_QUANT_MIN_BYTES="1024")
-    (moved, losses), = run(fn, num_proc=1, env=env)
+    (moved, losses), = _run_watched(fn, tmp_path, env)
     assert moved["int8"] > 0
     assert moved["bf16"] >= 1.8 * moved["int8"], moved
     full, int8 = losses["none"][-1], losses["int8"][-1]
