@@ -66,6 +66,9 @@ KERNEL_ATOL = 3e-2
 #: forward round differently: on the chip 17 of 499 tokens differed, by at
 #: most 0.032; a wrong cache row or position is off by order 1.
 SERVE_TIE_TOL = 0.1
+#: a v5e's HBM peak (benchmarks/peaks.json): what the new kernels' lines
+#: state their time against; a statement, never a check
+HBM_BYTES_PER_S = 819e9
 # (share of served tokens that may miss the plain forward's choice, widest
 # miss) of the ``laguna_small`` leg: set from its reading on the v5e
 LAGUNA_SMALL_ROUTED = (0.08, 1.8)
@@ -438,11 +441,14 @@ def _served_model(cfg):
     once for each position, and for a looped stack its plain forward over
     every pass, for latent attention with experts its plain (expanded)
     forward, for window and full layers with experts its plain forward
-    (whole sequences under the band mask, no ring)."""
+    (whole sequences under the band mask, no ring), for the
+    decoder-hybrid-decoder its plain forward (every layer at every
+    position, no cache, no short-cut)."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.models import hybrid, latent_moe, looped, window_moe
+    from horovod_tpu.models import (hybrid, latent_moe, looped, sambay,
+                                    window_moe)
     from horovod_tpu.models import transformer as tr
 
     ref_cfg = dataclasses.replace(cfg, attention_impl="full")
@@ -462,6 +468,13 @@ def _served_model(cfg):
         params = window_moe.init_params(cfg, jax.random.PRNGKey(0))
         rows = jax.jit(lambda p, seq, at: window_moe.forward(
             ref_cfg, p, seq[None])[0][0, at].astype(jnp.float32))
+    elif isinstance(cfg, sambay.SambaYConfig):
+        # served in bfloat16 as every other leg's model
+        params = jax.tree_util.tree_map(
+            lambda a: a.astype(cfg.dtype),
+            sambay.init_params(cfg, jax.random.PRNGKey(0)))
+        rows = jax.jit(lambda p, seq, at: sambay.forward(
+            ref_cfg, p, seq[None])[0, at].astype(jnp.float32))
     else:
         _, params = tr.init_params(cfg, jax.random.PRNGKey(0))
         ref_model = tr.TransformerLM(ref_cfg)
@@ -483,6 +496,9 @@ def decode_attention_selected(cfg, slots, max_len):
     if kinds == ["latent"]:
         kernel = fa._latent_kernel_selected(shapes["latent"].shape,
                                             cfg.kv_rank)
+    elif hasattr(cfg, "lanes"):  # rows of packed heads (models/sambay.py)
+        kernel = all(fa._packed_kernel_selected(shapes[k].shape, cfg.lanes)
+                     for k in kinds)
     else:  # every class of K/V the model keeps: full rows, and rings
         kernel = all(fa._decode_kernel_selected(shapes[k].shape, None)
                      for k in kinds)
@@ -532,6 +548,156 @@ def laguna_small_config():
         first_dense=1, num_experts=16, experts_per_tok=4, d_expert=256,
         d_shared=256, route_scale=2.5, max_seq_len=1024,
         attention_impl="flash")
+
+
+def phi4flash_small_config():
+    """The ``phi4flash_small`` leg's model: Phi-4-mini-flash's published
+    WIDTHS (hidden 2,560, 40 query and 20 key/value heads of 64, SwiGLU
+    10,240, window 512, a state of 16 over 5,120 channels) at 8 layers, one
+    period of each kind (mamba, window, mamba, window, mamba, full, gmu,
+    cross), and a vocabulary of 32,768: the packed decode kernel over the
+    one plane (two readers) and two rings of 512 + 128, the banded and the
+    flash forward at scale 1/8, the scan and the state's update."""
+    from horovod_tpu.models import sambay
+    return sambay.SambaYConfig(
+        vocab_size=32768, num_layers=8, d_model=2560, d_ff=10240,
+        num_heads=40, num_kv_heads=20, window=512, d_state=16, d_conv=4,
+        expand=2, dt_rank=160, max_seq_len=1024, attention_impl="flash")
+
+
+def leg_packed_kernel(cases, atol=KERNEL_ATOL):
+    """``packed_decode_attention`` (the Mosaic kernel: selected here or the
+    leg fails) against the two-softmax definition at the cell's shapes:
+    ``cases`` of (planes, rows, s_max, pairs, lengths): every plain head's
+    softmax over its own 64 lanes of the pair's key, the pair's whole
+    value, in float32 over the same bfloat16 cache. Says what the kernel
+    took and what that is of the HBM peak for the live tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+    out = []
+    for planes, b, s_max, pairs, lengths in cases:
+        shape = (planes, b, s_max, 1, pairs * 128)
+        _check(fa._packed_kernel_selected(shape),
+               f"the packed decode kernel is not selected at {shape}")
+        rng = np.random.RandomState(s_max + b)
+        k = jnp.asarray(rng.randn(*shape) * 0.5, jnp.bfloat16)
+        v = jnp.asarray(rng.randn(*shape) * 0.5, jnp.bfloat16)
+        # [pair, differential head, (q1, q2), half of the lanes, 64]
+        q = rng.randn(b, pairs, 2, 2, 2, 64)
+        q[:, :, :, 0, 1] = 0.0                  # [q1|0]
+        q[:, :, :, 1, 0] = 0.0                  # [0|q2]
+        q = jnp.asarray(q.reshape(b, pairs * 4, 128), jnp.bfloat16)
+        n = jnp.asarray(lengths, jnp.int32)
+        plane = planes - 1
+
+        def definition(q, k, v, n):
+            f32 = jnp.float32
+            kp = k[plane].reshape(b, s_max, pairs, 2, 64).astype(f32)
+            vp = v[plane].reshape(b, s_max, pairs, 128).astype(f32)
+            qh = q.reshape(b, pairs, 2, 2, 2, 64).astype(f32)
+            valid = (jnp.arange(s_max)[None, :] < n[:, None])
+            outs = []
+            for j in (0, 1):  # the head's own half of the pair's key
+                logits = jnp.einsum("bphd,bspd->bphs", qh[:, :, :, j, j],
+                                    kp[:, :, :, j], precision="highest") / 8
+                p = jax.nn.softmax(jnp.where(valid[:, None, None], logits,
+                                             -jnp.inf), axis=-1)
+                outs.append(jnp.einsum("bphs,bspd->bphd", p, vp,
+                                       precision="highest"))
+            return jnp.stack(outs, axis=3).reshape(b, pairs * 4, 128)
+        kernel = jax.jit(lambda q, k, v, n: fa.packed_decode_attention(
+            q, k, v, n, plane, 0.125))
+        got = np.asarray(kernel(q, k, v, n))
+        want = np.asarray(jax.jit(definition)(q, k, v, n))
+        live = np.asarray(lengths) > 0
+        err = float(np.abs(got[live] - want[live]).max())
+        _check(np.isfinite(got).all() and err <= atol,
+               f"packed decode kernel at {shape}: max abs err {err:.4g} > "
+               f"{atol} against the two-softmax definition")
+        _check(not got[~live].any(), "a row of length 0 read something")
+        t0 = time.perf_counter()
+        for _ in range(10):
+            last = kernel(q, k, v, n)
+        last.block_until_ready()
+        took = (time.perf_counter() - t0) / 10
+        need = 2 * pairs * 128 * 2 * float(np.sum(lengths))
+        out.append({"cache": list(shape), "max_abs_err": round(err, 5),
+                    "kernel_ms": round(took * 1e3, 3),
+                    "hbm_roofline_pct": round(
+                        100 * need / HBM_BYTES_PER_S / took, 1)})
+    emit("packed_decode_kernel", cases=out, atol=atol)
+
+
+def leg_mamba1(channels=5120, states=16, rows=96, planes=9, lengths=(256,)):
+    """The Mamba-1 recurrence at the published state (16 x 5,120 a row and
+    layer): the prefill scan against the literal position-by-position
+    definition over a right-padded prompt, and the decode step's update in
+    place in the stacked state against ``state_step``, a masked row bit
+    for bit. float32 on both sides: 1e-5. Says what each took."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import mamba1
+    rng = np.random.RandomState(0)
+    a = -jnp.broadcast_to(jnp.arange(1, states + 1, dtype=jnp.float32)
+                          [:, None], (states, channels))
+    scans = []
+    for s in lengths:
+        x = jnp.asarray(rng.randn(1, s, channels), jnp.bfloat16)
+        dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1),
+                                            (1, s, channels))), jnp.float32)
+        dt = dt.at[:, s - 9:].set(0.0)       # a right-padded prompt
+        b, c = (jnp.asarray(rng.randn(1, s, states), jnp.bfloat16)
+                for _ in range(2))
+        scan = jax.jit(mamba1.selective_scan)
+        y, last = scan(x, dt, a, b, c)
+        head = 48                            # the literal loop, unrolled
+        want_y, _ = jax.jit(mamba1.literal_scan)(
+            x[:, :head], dt[:, :head], a, b[:, :head], c[:, :head])
+        err = float(jnp.abs(y[:, :head] - want_y).max())
+        _, before = scan(x[:, :s - 9], dt[:, :s - 9], a, b[:, :s - 9],
+                         c[:, :s - 9])
+        held = float(jnp.abs(last - before).max())
+        _check(err <= 1e-5 and held <= 1e-5,
+               f"the scan at {s}: {err:.3g} from the literal definition, "
+               f"{held:.3g} moved over the pad")
+        t0 = time.perf_counter()
+        for _ in range(5):
+            y, last = scan(x, dt, a, b, c)
+        last.block_until_ready()
+        scans.append({"positions": s, "max_abs_err": err,
+                      "ms": round((time.perf_counter() - t0) / 5 * 1e3, 3)})
+    ssm = jnp.asarray(rng.randn(planes, rows, states, channels), jnp.float32)
+    x = jnp.asarray(rng.randn(rows, channels), jnp.bfloat16)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1),
+                                        (rows, channels))), jnp.float32)
+    b, c = (jnp.asarray(rng.randn(rows, states), jnp.bfloat16)
+            for _ in range(2))
+    mask = jnp.arange(rows) != 3
+    want, want_y = mamba1.state_step(ssm[2], x, dt, a, b, c)
+    kept = np.asarray(ssm[2, 3])
+    update = jax.jit(lambda ssm, *t: mamba1.decode_update(ssm, 2, *t),
+                     donate_argnums=0)
+    ssm, y = update(ssm, x, dt, a, b, c, mask)
+    err = float(jnp.abs(jnp.where(mask[:, None, None], ssm[2] - want,
+                                  0.0)).max())
+    err = max(err, float(jnp.abs(jnp.where(mask[:, None], y - want_y,
+                                           0.0)).max()))
+    _check(err <= 1e-5, f"the state's update: {err:.3g} from state_step")
+    _check((np.asarray(ssm[2, 3]) == kept).all(),
+           "a row outside the mask did not keep its state bit for bit")
+    t0 = time.perf_counter()
+    for _ in range(10):
+        ssm, y = update(ssm, x, dt, a, b, c, mask)
+    ssm.block_until_ready()
+    took = (time.perf_counter() - t0) / 10
+    emit("mamba1", scans=scans, update={
+        "state": [planes, rows, states, channels], "max_abs_err": err,
+        "ms": round(took * 1e3, 3), "hbm_roofline_pct": round(
+            100 * 2 * (rows - 1) * states * channels * 4
+            / HBM_BYTES_PER_S / took, 1)})
 
 
 def leg_serve(cfg, slots=8, max_len=1024, kv_block=16,
@@ -914,6 +1080,14 @@ def main(argv=None):
                             (1024, 8, 64, 2048, 512, 750),
                             (2048, 8, 256, 2048, 512, 2048)],
                            products=["resident", "streamed", "streamed"])
+        # the packed decode kernel at the reasoning cell's cache: the one
+        # plane of 96 x 3,072 at the lengths a window holds, and a ring of
+        # 512 + 128, ten pairs a row; rows of length 0 and 1 among them
+        lens = np.random.RandomState(1)
+        leg_packed_kernel([
+            (1, 96, 3072, 10, [0, 1] + list(lens.randint(400, 1500, 94))),
+            (8, 96, 640, 10, [0, 129] + [512] * 94)])
+        leg_mamba1(lengths=(256, 1024))
     if "lm_train" in legs or "four_chips" in legs:
         first_loss = leg_lm_train(train_cfg, 16, 1024)
     if "resnet" in legs:
@@ -960,6 +1134,11 @@ def main(argv=None):
         # elsewhere is a quarter of its routed output
         leg_serve(laguna_small_config(), kv_block=128, name="laguna_small",
                   routed_elsewhere=LAGUNA_SMALL_ROUTED)
+        # the decoder-hybrid-decoder at published widths: prompts of 513,
+        # 640 and 1,000 lie above the window of 512 and leave a wrapped
+        # ring, the others below it; ONE plane, read by two layers
+        leg_serve(phi4flash_small_config(), kv_block=128,
+                  name="phi4flash_small")
     if "four_chips" in legs:
         if jax.device_count() >= 4:
             leg_four_chips(train_cfg, 16, 1024, first_loss)

@@ -629,7 +629,7 @@ def _flash_core(q, k, v, causal, block_q, block_k, interpret, scale,
 
 def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
                     interpret=None, block_q_dkv=None, block_k_dkv=None,
-                    layout="bshd"):
+                    layout="bshd", scale=None):
     """Fused attention; q/k/v [batch, seq, heads, head_dim] (or
     [batch, heads, seq, head_dim] with ``layout="bhsd"`` — the flatten to
     the kernel's physical [batch·heads, seq, head_dim] is then a free
@@ -650,13 +650,15 @@ def flash_attention(q, k, v, causal=True, block_q=512, block_k=512,
     Other non-divisible cases would need an explicit key mask the kernel
     doesn't carry, so they raise. On real TPU, head_dim is zero-padded to
     the 128-lane tile (softmax scale keeps the true head_dim; zero columns
-    drop out of every dot product)."""
+    drop out of every dot product). ``scale`` is the softmax scale where
+    it is not ``head_dim ** -0.5`` (models/sambay.py: heads of 64 packed
+    two to a 128-lane row)."""
     if layout not in ("bshd", "bhsd"):
         raise ValueError(f"unknown layout {layout!r}")
     seq_axis = 2 if layout == "bhsd" else 1
     sq, sk = q.shape[seq_axis], k.shape[seq_axis]
     d = q.shape[-1]
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     interpret_eff = interpret if interpret is not None else _auto_interpret()
 
     fit = functools.partial(call_block, compiled=not interpret_eff)
@@ -843,6 +845,21 @@ def _decode_kernel(layer_ref, total_ref, row_ref, blk_ref, len_ref, q_ref,
     jax.lax.fori_loop(0, total, body, 0)
 
 
+def _live_blocks(lengths, b, s_max, block):
+    """The decode kernels' work list over rows of ``lengths`` (clipped to
+    ``s_max``): item i is block ``blk_of[i]`` of row ``row_of[i]``, the
+    rows' live blocks one row after another; ``ends[-1]`` items in all.
+    Returns (lengths, ends, row_of, blk_of), int32."""
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, s_max)
+    # item i is block i - ends[row - 1] of the row whose blocks end after i
+    ends = jnp.cumsum((lengths + block - 1) // block)
+    item = jnp.arange(b * (s_max // block), dtype=jnp.int32)
+    before = ends[None, :] <= item[:, None]
+    row_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    blk_of = item - jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    return lengths, ends, row_of, blk_of
+
+
 def _decode_attention_kernel(q, k, v, lengths, layer, scale):
     """``decode_attention`` over layer ``layer`` of the whole cache
     ``k``/``v`` ``[layers, batch, s_max, kv_heads, d]`` as the Mosaic
@@ -854,14 +871,7 @@ def _decode_attention_kernel(q, k, v, lengths, layer, scale):
     block = decode_block(s_max)
     rows = -(-h // 16) * 16  # query heads, padded to a bf16 tile's sublanes
     cols = block * hk
-    lengths = jnp.clip(lengths.astype(jnp.int32), 0, s_max)
-    # the work list: item i is block i - ends[row - 1] of the row whose
-    # blocks end after i
-    ends = jnp.cumsum((lengths + block - 1) // block)
-    item = jnp.arange(b * (s_max // block), dtype=jnp.int32)
-    before = ends[None, :] <= item[:, None]
-    row_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
-    blk_of = item - jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    lengths, ends, row_of, blk_of = _live_blocks(lengths, b, s_max, block)
     r = jnp.arange(rows, dtype=jnp.int32)[:, None]
     c = jnp.arange(cols, dtype=jnp.int32)[None, :]
     key = jnp.where(c % hk == r // (h // hk), c, 2 ** 30).astype(jnp.int32)
@@ -1077,13 +1087,7 @@ def _latent_decode_attention_kernel(q, cache, lengths, plane, value_dim,
     h = q.shape[1]
     block = decode_block(s_max)
     rows = -(-h // 16) * 16  # query heads, padded to a bf16 tile's sublanes
-    lengths = jnp.clip(lengths.astype(jnp.int32), 0, s_max)
-    # the work list of ``_decode_attention_kernel``
-    ends = jnp.cumsum((lengths + block - 1) // block)
-    item = jnp.arange(b * (s_max // block), dtype=jnp.int32)
-    before = ends[None, :] <= item[:, None]
-    row_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
-    blk_of = item - jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    lengths, ends, row_of, blk_of = _live_blocks(lengths, b, s_max, block)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
@@ -1153,6 +1157,205 @@ def latent_decode_attention(q, cache, lengths, plane, value_dim,
                      latent[..., :value_dim],
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+# -- single-query attention over a cache of PACKED heads (models/sambay.py) ----
+
+#: lanes of one packed head: a row of such a cache is ``groups`` of them
+PACKED_LANES = 128
+_PACKED_ROWS = 16  # a group's query heads, padded to a bf16 tile's sublanes
+
+
+def _packed_kernel_selected(cache_shape, lanes=PACKED_LANES):
+    """Whether ``packed_decode_attention`` over a cache of ``cache_shape``
+    ``[planes, batch, s_max, 1, groups * lanes]`` runs as the Mosaic kernel:
+    the conditions of ``_decode_kernel_selected`` for rows that are ONE
+    run of whole lane tiles (20 key/value heads of 64 as ``[.., 20, 64]``
+    or 10 pairs as ``[.., 10, 128]`` would be re-laid for every call: the
+    tiled layout pads 20 or 10 sublanes to 32 or 16)."""
+    if not _on_one_tpu_chip():
+        return False
+    _, _, s_max, one, width = cache_shape
+    return lanes == PACKED_LANES and one == 1 and \
+        s_max % DECODE_BLOCK == 0 and width % PACKED_LANES == 0
+
+
+def _packed_decode_kernel(plane_ref, total_ref, row_ref, blk_ref, len_ref,
+                          q_ref, k_hbm, v_hbm, o_ref, k_scr, v_scr, sem,
+                          m_scr, l_scr, acc_scr, *, block, groups, scale):
+    """``_decode_kernel`` for rows of ``groups`` packed heads: the same loop
+    over the LIVE blocks of the plane, row after row, so a block above a
+    row's length is never asked for. A block ``[block, groups * 128]`` of K
+    and one of V come in as one DMA each; group ``g``'s query heads (its
+    ``_PACKED_ROWS`` rows of ``q_ref``) meet lanes ``128 g .. 128 g + 127``
+    of both and no others, so every head reads its own key/value head and
+    the block is read from HBM once for all of them. Softmax statistics are
+    fp32, in the exp2 domain as the forward kernels'."""
+    plane = plane_ref[0]
+    total = total_ref[0]
+    nbuf = k_scr.shape[0]
+    per = _PACKED_ROWS
+    # a row of length 0 (a slot that does not decode) has no item
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def copies(i, buf):
+        start = pl.multiple_of(blk_ref[i] * block, block)
+        return [pltpu.make_async_copy(
+            hbm.at[plane, row_ref[i], pl.ds(start, block), :], scr.at[buf],
+            sem.at[j, buf])
+            for j, (hbm, scr) in enumerate(((k_hbm, k_scr), (v_hbm, v_scr)))]
+
+    for j in range(nbuf - 1):
+        @pl.when(j < total)
+        def _prime():
+            for c in copies(j, j):
+                c.start()
+
+    def body(i, _):
+        buf = i % nbuf
+
+        @pl.when(i + nbuf - 1 < total)
+        def _prefetch():  # into the buffer item i - 1 has finished with
+            for c in copies(i + nbuf - 1, (i + nbuf - 1) % nbuf):
+                c.start()
+
+        row = row_ref[i]
+        live = len_ref[row] - blk_ref[i] * block
+
+        @pl.when(blk_ref[i] == 0)
+        def _begin_row():
+            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        for c in copies(i, buf):
+            c.wait()
+
+        @pl.when(live < block)
+        def _hide_the_tail():  # 0 x NaN, as in ``_decode_kernel``
+            pos = jax.lax.broadcasted_iota(jnp.int32, v_scr.shape[1:], 0)
+            v_scr[buf] = jnp.where(
+                pos < live, v_scr[buf].astype(jnp.float32),
+                0.0).astype(v_scr.dtype)
+
+        def lanes(g):
+            return pl.ds(g * PACKED_LANES, PACKED_LANES)
+        # every group's products first, then ONE softmax update over all
+        # the rows: ten small products that wait for nothing of each other
+        # and statistics a few registers wide, not ten chains of
+        # product, reduction, product
+        s = jnp.concatenate([
+            jax.lax.dot_general(q_ref[row, pl.ds(g * per, per), :],
+                                k_scr[buf, :, lanes(g)],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for g in range(groups)], axis=0)
+        there = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < live
+        s = jnp.where(there, s * (scale * _LOG2E), _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp2(m_prev - m_new)
+        p = jnp.exp2(s - m_new).astype(v_scr.dtype)
+        m_scr[...] = m_new
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(
+            p.astype(jnp.float32), axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.concatenate([
+            jnp.dot(p[g * per:(g + 1) * per], v_scr[buf, :, lanes(g)],
+                    preferred_element_type=jnp.float32)
+            for g in range(groups)], axis=0)
+
+        @pl.when(live <= block)
+        def _end_row():
+            o_ref[row] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                          ).astype(o_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, total, body, 0)
+
+
+def _packed_decode_attention_kernel(q, k, v, lengths, plane, scale):
+    planes, b, s_max, _, width = k.shape
+    groups = width // PACKED_LANES
+    per = q.shape[1] // groups
+    block = decode_block(s_max)
+    lengths, ends, row_of, blk_of = _live_blocks(lengths, b, s_max, block)
+    # each group's query heads on sublanes of their own tile
+    qg = jnp.pad(q.reshape(b, groups, per, PACKED_LANES),
+                 ((0, 0), (0, 0), (0, _PACKED_ROWS - per), (0, 0)))
+    rows = groups * _PACKED_ROWS
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    flat = (planes, b, s_max, width)
+    out = pl.pallas_call(
+        functools.partial(_packed_decode_kernel, block=block, groups=groups,
+                          scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, rows, PACKED_LANES), jnp.float32),
+        in_specs=[smem] * 5 + [vmem, hbm, hbm],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((_DECODE_BUFFERS, block, width), k.dtype),
+            pltpu.VMEM((_DECODE_BUFFERS, block, width), v.dtype),
+            pltpu.SemaphoreType.DMA((2, _DECODE_BUFFERS)),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, PACKED_LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="packed_decode_attention",
+        interpret=_auto_interpret(),
+    )(jnp.asarray(plane, jnp.int32).reshape(1), ends[-1:], row_of, blk_of,
+      lengths, qg.reshape(b, rows, PACKED_LANES), k.reshape(flat),
+      v.reshape(flat))
+    return out.reshape(b, groups, _PACKED_ROWS, PACKED_LANES)[:, :, :per] \
+        .reshape(b, groups * per, PACKED_LANES)
+
+
+def packed_decode_attention(q, k, v, lengths, plane, scale):
+    """Single-query attention over a cache whose row is ``groups`` PACKED
+    heads of ``lanes`` side by side (models/sambay.py: two key heads, or
+    two value heads, are one packed head).
+
+    q        [batch, heads, lanes], ``heads`` a multiple of ``groups``:
+             query head i reads packed head ``i // (heads / groups)``
+    k, v     the WHOLE cache [planes, batch, s_max, 1, groups * lanes]; only
+             the first ``lengths[b]`` positions of row b in plane ``plane``
+             are real
+    lengths  [batch] int32; a row of length 0 attends to nothing and its
+             output means nothing
+    scale    the softmax scale (a packed head has no width of its own)
+
+    Returns float32 [batch, heads, lanes]. Two implementations of one
+    contract, as ``decode_attention``: on one TPU chip, at ``lanes`` =
+    128, a Mosaic kernel that takes the lengths as data and streams each
+    row's blocks of ``DECODE_BLOCK`` positions of the plane ONCE for all
+    heads, in place, and none above its length; elsewhere an einsum over
+    the whole plane under a length mask (``_packed_kernel_selected``
+    decides from the call; there is no option). fp32 softmax, products in
+    the cache's dtype with fp32 accumulation."""
+    if q.ndim != 3 or k.ndim != 5 or k.shape != v.shape or \
+            k.shape[3] != 1 or k.shape[4] % q.shape[2] or \
+            q.shape[1] % (k.shape[4] // q.shape[2]):
+        raise ValueError(f"packed_decode_attention wants q [b, h, lanes] "
+                         f"and caches [planes, b, s, 1, groups * lanes], "
+                         f"got {q.shape}, {k.shape} and {v.shape}")
+    b, h, lanes = q.shape
+    s_max = k.shape[2]
+    groups = k.shape[4] // lanes
+    if _packed_kernel_selected(k.shape, lanes):
+        return _packed_decode_attention_kernel(q, k, v, lengths, plane,
+                                               scale)
+    kp = k[plane].reshape(b, s_max, groups, lanes)
+    vp = v[plane].reshape(b, s_max, groups, lanes)
+    qg = q.reshape(b, groups, h // groups, lanes)
+    logits = jnp.einsum("bgrd,bsgd->bgrs", qg, kp,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(s_max, dtype=jnp.int32)[None, None, None, :] \
+        < lengths.astype(jnp.int32)[:, None, None, None]
+    p = jax.nn.softmax(jnp.where(valid, logits, _NEG_INF), axis=-1)
+    out = jnp.einsum("bgrs,bsgd->bgrd", p.astype(vp.dtype), vp,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, lanes)
 
 
 # -- causal attention under a WINDOW (models/window_moe.py's prefill) ---------
@@ -1236,7 +1439,8 @@ def _band_index(block_q, block_k, window, group):
     return index
 
 
-def window_attention(q, k, v, window, block=512, interpret=None):
+def window_attention(q, k, v, window, block=512, interpret=None,
+                     scale=None):
     """Causal self-attention in which key ``j`` is visible to query ``i``
     iff ``0 <= i - j < window``: the forward alone (a serving prefill;
     there is no backward here).
@@ -1252,14 +1456,14 @@ def window_attention(q, k, v, window, block=512, interpret=None):
     blocks, so VMEM holds one tile of each operand. Sequences that no
     block divides are end-padded as ``flash_attention`` pads them (the
     causal mask hides the pad's keys), ``d`` to whole lane tiles when
-    compiled."""
+    compiled. ``scale`` is the softmax scale where it is not ``d ** -0.5``."""
     b, s, h, d = q.shape
     hk = k.shape[2]
     if k.shape != v.shape or k.shape[1] != s or h % hk or window < 1:
         raise ValueError(f"window_attention: q {q.shape}, k {k.shape}, "
                          f"v {v.shape}, window {window}")
     interpret = _auto_interpret() if interpret is None else interpret
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     blk = call_block(block, s, compiled=not interpret)
     pad_s = -s % blk
     pad_d = 0 if interpret else -d % 128

@@ -17,7 +17,8 @@ asserting logits equality and token-for-token greedy agreement against
 ``TransformerLM.apply``.
 
 A model of another kind (models/hybrid.py: a recurrent mixer; latent_moe.py:
-latent attention, dropless experts; window_moe.py: window and full layers)
+latent attention, dropless experts; window_moe.py: window and full layers;
+sambay.py: a self-decoder and a cross-decoder that reads its one plane)
 brings its own two forwards; ``state_shapes``, ``prefill`` and ``decode`` at
 the end are what the engine and the cache call, by type.
 A model whose layer stack runs several times over one set of weights
@@ -40,7 +41,7 @@ each row's live blocks, elsewhere an einsum under a length mask).
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid, latent_moe, looped, window_moe
+from ..models import hybrid, latent_moe, looped, sambay, window_moe
 from ..models.transformer import (_dense, _dispatch_attention, _embed,
                                   _logits, _mlp, _rmsnorm, _rope)
 from ..ops.flash_attention import decode_attention
@@ -316,7 +317,8 @@ def decode(cfg, params, tokens, positions, state, mask=None):
 
 _OWN_FORWARDS = {hybrid.HybridConfig: hybrid,
                  latent_moe.LatentMoEConfig: latent_moe,
-                 window_moe.WindowMoEConfig: window_moe}
+                 window_moe.WindowMoEConfig: window_moe,
+                 sambay.SambaYConfig: sambay}
 
 
 def positional_kinds(cfg):
@@ -336,6 +338,26 @@ def ring_kinds(cfg):
     position ``p`` at ``p mod cfg.window``, whatever ``max_len`` is. None
     for every other model."""
     return getattr(_own(cfg), "RING", ())
+
+
+def plane_readers(cfg):
+    """Layers that read each plane of the ``max_len`` class in one decode
+    step: 1 where every layer that attends owns its plane (every model
+    but one); models/sambay.py's one plane is read by its full layer and by
+    every cross layer, which have no K or V of their own (``readers``)."""
+    own = _own(cfg)
+    return own.readers(cfg) if hasattr(own, "readers") else 1
+
+
+def prefill_extents(cfg, padded_len):
+    """{count: tokens} of the step record for a prefill of ``padded_len``
+    positions by a model whose prefill has TWO token extents
+    (models/sambay.py ``prefill_extents``: the self-decoder over the padded
+    prompt, the cross-decoder over the last token alone); {} for every
+    other model."""
+    own = _own(cfg)
+    return own.prefill_extents(cfg, padded_len) \
+        if hasattr(own, "prefill_extents") else {}
 
 
 #: what a model with experts returns from ``decode`` as a third result, an

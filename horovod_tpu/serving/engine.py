@@ -97,7 +97,8 @@ from ..utils import memory as hvd_memory
 from ..utils import metrics as hvd_metrics
 from ..utils import tracing as hvd_tracing
 from . import host_key, tracing as serve_tracing
-from .decode import ROUTED_COUNTS, decode, passes, prefill
+from .decode import (ROUTED_COUNTS, decode, passes, prefill,
+                     prefill_extents)
 from .kv_cache import KVCache
 from .queue import AdmissionQueue, RequestResult
 from .sampling import sample_tokens
@@ -753,6 +754,9 @@ class ServeEngine:
             rec.count("admitted")
             rec.count("prompt_tokens", prompt_len)
             rec.count("state_bytes", self._row_state_bytes)
+            # a prefill of two token extents says both (models/sambay.py)
+            for name, n in prefill_extents(self.cfg, tokens.shape[1]).items():
+                rec.count(name, n)
 
     def _read_first_tokens(self, keep=0, launched=False):
         """Read the first token of this step's admissions, in admission

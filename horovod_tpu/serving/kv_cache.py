@@ -262,13 +262,17 @@ class KVCache:
         # class, and the bytes one block of ONE slot holds over the class's
         # planes: what ``count_reads`` multiplies
         from ..ops.flash_attention import decode_block
+        # layers that read each ``max_len`` plane in a step: 1, or what a
+        # model whose layers SHARE a plane declares (models/sambay.py)
+        self.readers = decode.plane_readers(cfg)
         self._reads = []  # (block, bytes a block, entries a row may hold)
         for kinds in (self._full, self.ring):
             if kinds:
                 entries = self.arrays[kinds[0]].shape[2]
                 block = decode_block(entries)
+                times = 1 if kinds is self.ring else self.readers
                 self._reads.append(
-                    (block, self._block_bytes(kinds, block),
+                    (block, times * self._block_bytes(kinds, block),
                      self.window if kinds is self.ring else max_len))
 
     @property
@@ -352,12 +356,17 @@ class KVCache:
         over rows of ``lengths`` has to stream of the positional kinds,
         each row in whole blocks (``ops/flash_attention.decode_block``) up
         to its length, and of a ring up to ``min(length, window)``; and,
-        of a model with rings only, ``window_kv_bytes``: the rings' part."""
+        of a model with rings only, ``window_kv_bytes``: the rings' part. A
+        ``max_len`` plane that ``readers`` layers read is counted once a
+        READER (it is held once), and of such a model only,
+        ``shared_kv_bytes`` is that part."""
         parts = [nbytes * sum(-(-min(n, most) // block) for n in lengths)
                  for block, nbytes, most in self._reads]
         rec.count("kv_bytes", sum(parts))
         if self.ring:  # the last class
             rec.count("window_kv_bytes", parts[-1])
+        if self.readers > 1:  # the first class, every reader's pass
+            rec.count("shared_kv_bytes", parts[0])
 
     @property
     def num_slots(self):

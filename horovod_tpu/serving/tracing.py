@@ -379,6 +379,12 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # layer, models/window_moe.py) up to min(length, window)
 # window_kv_bytes (likewise, and only a model with rings): the rings' part
 # of kv_bytes
+# shared_kv_bytes (likewise, and only a model whose layers SHARE a plane,
+# models/sambay.py): the part of kv_bytes that is the one full plane's,
+# counted once a reading layer (it is held once)
+# self_tokens, cross_tokens (only a step that admitted, and only that
+# model): positions its prefills ran the self-decoder over (the padded
+# prompts) and the cross-decoder over (one an admission)
 # passes (not in STEP_COUNTS, likewise): stack passes the step's decode
 # program runs each row, from the configuration: the passes of a looped
 # stack (models/looped.py), 1 for every other model

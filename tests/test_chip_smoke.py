@@ -4,6 +4,7 @@ test can pin: main() refuses a CPU, imports create no backend, the
 attention call sees its per-device shape under GSPMD, and Mosaic accepts
 the kernels at the serving prefill lengths."""
 
+import dataclasses
 import functools
 import json
 import os
@@ -58,6 +59,34 @@ def test_window_kernel_leg(capsys):
                                      dtype=jnp.float32)
 
 
+def test_packed_kernel_and_mamba1_legs(capsys, monkeypatch):
+    """The new family's two kernel legs at sizes the CPU runs: the packed
+    decode kernel (interpreted: the leg insists that the kernel is what
+    runs) against the two-softmax definition over a plane and a ring, rows
+    of length 0 and 1 among them; the scan against the literal definition
+    over a right-padded prompt and the update in place, a masked row bit
+    for bit."""
+    from horovod_tpu.ops import flash_attention as fa
+    with pytest.raises(AssertionError, match="is not selected"):
+        chip_smoke.leg_packed_kernel([(1, 2, 128, 1, [5, 9])])
+    monkeypatch.setattr(fa, "_on_one_tpu_chip", lambda: True)
+    chip_smoke.leg_packed_kernel([(1, 4, 256, 2, [0, 1, 130, 256]),
+                                  (2, 3, 128, 1, [128, 7, 64])])
+    line = _last_json(capsys)
+    assert line["leg"] == "packed_decode_kernel"
+    assert [c["cache"] for c in line["cases"]] == [[1, 4, 256, 1, 256],
+                                                   [2, 3, 128, 1, 128]]
+    assert all(c["max_abs_err"] < 2e-2 for c in line["cases"])
+    with pytest.raises(AssertionError, match="two-softmax"):
+        chip_smoke.leg_packed_kernel([(1, 2, 128, 1, [5, 9])], atol=-1.0)
+    chip_smoke.leg_mamba1(channels=128, states=4, rows=5, planes=3,
+                          lengths=(64,))
+    line = _last_json(capsys)
+    assert line["leg"] == "mamba1" and line["scans"][0]["positions"] == 64
+    assert line["update"]["state"] == [3, 5, 4, 128]
+    assert line["update"]["max_abs_err"] < 1e-5
+
+
 def test_grouped_kernel_leg(capsys):
     import jax.numpy as jnp
     # (tokens, k, experts, d, f, real tokens); the CPU: ragged_dot
@@ -102,7 +131,7 @@ def test_resnet_leg(capsys):
 
 
 @pytest.mark.parametrize("family", ["transformer", "hybrid", "looped",
-                                    "latent_moe", "window_moe"])
+                                    "latent_moe", "window_moe", "sambay"])
 def test_serve_leg(tiny, capsys, family):
     """Every family through the one leg: a dense decoder, a model that
     keeps recurrent and convolution state beside its K/V, a stack that
@@ -121,6 +150,9 @@ def test_serve_leg(tiny, capsys, family):
     elif family == "window_moe":  # window 8: every request wraps a ring
         from horovod_tpu.models import window_moe
         tiny = window_moe.WindowMoEConfig.tiny(dtype=jnp.float32)
+    elif family == "sambay":  # ONE plane with two readers, rings, state
+        from horovod_tpu.models import sambay
+        tiny = sambay.SambaYConfig.tiny(dtype=jnp.float32)
     chip_smoke.leg_serve(tiny, slots=2, max_len=32, kv_block=8,
                          lengths=(3, 8, 12), tie_tol=1e-4, name=family)
     line = _last_json(capsys)
@@ -128,7 +160,8 @@ def test_serve_leg(tiny, capsys, family):
     # the CPU backend: the einsum, whatever the kind
     assert line["decode_attention"] == {
         "kinds": {"latent_moe": ["latent"],
-                  "window_moe": ["k", "v", "k_ring", "v_ring"]}.get(
+                  "window_moe": ["k", "v", "k_ring", "v_ring"],
+                  "sambay": ["k", "v", "k_ring", "v_ring"]}.get(
                       family, ["k", "v"]),
         "kernel": False}
     # ...and ragged_dot for the two families with experts, in the decode
@@ -674,6 +707,129 @@ def test_two_classes_of_cache_are_read_in_place_by_one_kernel(topo, program,
     shapes = [str(s.shape) for s in jax.tree_util.tree_leaves(
         compiled.out_info)]
     assert shapes.count("(2,)") == 1
+
+
+@pytest.mark.parametrize("program", ["decode_kernel", "prefill"])
+def test_one_plane_is_read_in_place_by_every_layer_that_shares_it(
+        topo, program, request):
+    """The TPU compiler's word for the decoder-hybrid-decoder
+    (models/sambay.py) at Phi-4-mini-flash's widths (40 query and 20
+    key/value heads of 64 as ten packed pairs of 128 lanes, window 512, a
+    state of 16 x 5,120; 96 slots x 3,072; 8 layers, one of each kind's
+    period, so that it compiles in seconds). Decode: all six kinds of state
+    are aliased to the donated input and no whole copy of any is held; the
+    packed decode kernel runs FOUR times (two rings of 640 entries, the
+    ONE plane for the full layer and again for the cross layer), each
+    reading its cache whole and in place as ``[.., 1280]`` rows; the state
+    is held state-major ``[16, 5120]`` and updated by the Mosaic kernel,
+    once a Mamba layer, the stacked array in and out as one buffer. Prefill of 256 tokens: the banded
+    kernel on the two window layers with the pairs as they lie, the flash
+    kernel on the full layer, the packed kernel for the cross layer's ONE
+    query over the plane the row leaves, the scan kernel three times and no
+    loop; the cross-decoder's products carry one token."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.models import sambay
+    from horovod_tpu.serving import decode as serve_decode
+    from horovod_tpu.serving import engine as engine_mod
+
+    slots, max_len = 96, 3072
+    cfg = dataclasses.replace(chip_smoke.phi4flash_small_config(),
+                              max_seq_len=262144)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    state = {k: arr(a.shape, a.dtype) for k, a in
+             serve_decode.state_shapes(cfg, slots, max_len).items()}
+    assert {k: (a.shape, str(a.dtype)) for k, a in state.items()} == {
+        "k": ((1, slots, max_len, 1, 1280), "bfloat16"),
+        "v": ((1, slots, max_len, 1, 1280), "bfloat16"),
+        "k_ring": ((2, slots, 640, 1, 1280), "bfloat16"),
+        "v_ring": ((2, slots, 640, 1, 1280), "bfloat16"),
+        "ssm": ((3, slots, 16, 5120), "float32"),
+        "conv": ((3, slots, 3, 5120), "bfloat16")}
+    params = jax.tree_util.tree_map(
+        lambda a: arr(a.shape, jnp.float32 if a.ndim == 1 and
+                      a.shape[0] == 5120 or a.shape == (16, 5120)
+                      else jnp.bfloat16),
+        jax.eval_shape(lambda k: sambay.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    request.getfixturevalue("decode_kernel")
+
+    def packed(text):
+        return re.findall(
+            r"%packed_decode_attention[.\d]* = .*tpu_custom_call.*", text)
+    if program == "prefill":
+        engine_mod._prefill_jit.clear_cache()
+        try:
+            text = engine_mod._prefill_jit.lower(
+                cfg, params, arr((1, 256), jnp.int32), arr((), jnp.int32),
+                arr((), jnp.float32), arr((2,), jnp.uint32)) \
+                .compile().as_text()
+        finally:
+            engine_mod._prefill_jit.clear_cache()
+        band = re.findall(r"%window_attention[.\d]* = .*tpu_custom_call.*",
+                          text)
+        assert len(band) == 2 and all("hvd.diff.window" in b for b in band)
+        # 40 packed query heads over the 10 pairs as they lie
+        assert all("bf16[40,256,128]" in b and
+                   b.count("bf16[10,256,128]") == 2 for b in band)
+        flash = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and "hvd.diff.full" in line]
+        assert len(flash) == 1 and flash[0].count("bf16[40,256,128]") >= 4
+        cross = packed(text)
+        assert len(cross) == 1 and "hvd.diff.cross" in cross[0] and \
+            cross[0].count("bf16[1,1,256,1280]") == 2
+        scans = re.findall(r"%selective_scan[.\d]* = .*tpu_custom_call.*",
+                           text)
+        assert len(scans) == 3 and " while(" not in text
+        assert all("hvd.mamba1.mix" in c and "f32[1,16,5120]" in c
+                   for c in scans)
+        # what the row leaves: the plane's padded prefix, rings of the
+        # window at most, the state after the last real token
+        for shape in ("bf16[1,1,256,1,1280]", "bf16[2,1,256,1,1280]",
+                      "f32[3,1,16,5120]", "bf16[3,1,3,5120]"):
+            assert shape in text, shape
+        # the cross-decoder over ONE token: its gated unit's product
+        assert "bf16[1,1,5120]" in text or "bf16[1,5120]" in text
+        return
+    compiled = engine_mod._decode_jit.lower(
+        cfg, params, arr((slots,), jnp.int32), arr((slots,), jnp.int32),
+        state, arr((slots,), jnp.float32), arr((slots,), jnp.bool_),
+        arr((2,), jnp.uint32), arr((), jnp.int32)).compile()
+    import math
+    held = sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in state.values())
+    assert compiled.memory_analysis().alias_size_in_bytes == held
+    copies = _whole_copies(
+        compiled, (jnp.dtype(jnp.bfloat16), (1, slots, max_len, 1, 1280)),
+        (jnp.dtype(jnp.bfloat16), (1, slots, max_len, 1280)),
+        (jnp.dtype(jnp.bfloat16), (2, slots, 640, 1, 1280)),
+        (jnp.dtype(jnp.bfloat16), (2, slots, 640, 1280)),
+        (jnp.dtype(jnp.float32), (3, slots, 16, 5120)))
+    assert not copies, copies
+    text = compiled.as_text()
+    calls = packed(text)
+    assert len(calls) == 4
+    ring = [c for c in calls if "hvd.diff.window" in c]
+    full = [c for c in calls if "hvd.diff.full" in c]
+    cross = [c for c in calls if "hvd.diff.cross" in c]
+    assert (len(ring), len(full), len(cross)) == (2, 1, 1)
+    for c in ring:
+        assert c.count(f"bf16[2,{slots},640,1280]") == 2
+    for c in full + cross:   # the SAME plane, each with queries of its own
+        assert c.count(f"bf16[1,{slots},{max_len},1280]") == 2
+    updates = re.findall(
+        r"%mamba1_state_update[.\d]* = .*tpu_custom_call.*", text)
+    assert len(updates) == 3 and all(
+        "hvd.mamba1.mix" in c and
+        c.count(f"f32[3,{slots},16,5120]") >= 2 for c in updates)
+    for scope in ("hvd.mamba1.mix", "hvd.gmu"):
+        assert scope in text, scope
 
 
 def test_init_names_the_process_that_holds_the_chip(monkeypatch):

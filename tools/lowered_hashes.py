@@ -1,8 +1,8 @@
 """Does a change leave the programs alone? sha256 of the lowered text of
 five training steps, of ``sorted(sys.modules)`` after them, and of the three
 serving programs as ``ServeEngine`` itself feeds them (dense, hybrid,
-looped, latent attention with experts, and window layers with experts, at
-the tests' sizes; greedy and sampled requests, one of them for a single
+looped, latent attention with experts, window layers with experts, and the
+decoder-hybrid-decoder, at the tests' sizes; greedy and sampled requests, one of them for a single
 token), with a hash of the
 tokens served. Run it from the root of
 two trees and compare the lines (the set-up protocol, PERF.md §6):
@@ -90,6 +90,12 @@ def served_models():
         return
     cfg = window_moe.WindowMoEConfig.tiny(max_seq_len=64, dtype=jnp.float32)
     yield "window_moe", cfg, window_moe.init_params(cfg, key)
+    try:
+        from horovod_tpu.models import sambay
+    except ImportError:
+        return
+    cfg = sambay.SambaYConfig.tiny(max_seq_len=64, dtype=jnp.float32)
+    yield "sambay", cfg, sambay.init_params(cfg, key)
 
 
 REQUESTS = [((5, 9, 17), 9, 0.0), ((4, 8, 15, 16, 23, 42, 1, 2, 3, 4), 13, 0.8),
