@@ -4,9 +4,11 @@
 One process drives the main paths once, through the entry points a user
 calls, at the full width of the flagship LM and of ResNet-50:
 
-  kernels     the three flash-attention forwards and both backwards,
-              compiled by Mosaic, against parallel.ring.full_attention at
-              the training shape and at the serving prefill lengths
+  kernels     the flash-attention forward and backward, compiled by
+              Mosaic, against parallel.ring.full_attention at the training
+              shape and at the serving prefill lengths; the forward and
+              the backward on both sides of its rule timed under the
+              profiler at the training cell's shape
   lm_train    hvd.init -> build_mesh -> trainer.make_gspmd_step on
               gpt2_small_tpu (12 layers, batch 16 x seq 1024, flash):
               loss finite, falling, first step equal to full attention;
@@ -69,6 +71,8 @@ SERVE_TIE_TOL = 0.1
 #: a v5e's HBM peak (benchmarks/peaks.json): what the new kernels' lines
 #: state their time against; a statement, never a check
 HBM_BYTES_PER_S = 819e9
+#: and its bfloat16 peak, which the flash kernels' times stand beside
+BF16_FLOPS = 197e12
 # (share of served tokens that may miss the plain forward's choice, widest
 # miss) of the ``laguna_small`` leg: set from its reading on the v5e
 LAGUNA_SMALL_ROUTED = (0.08, 1.8)
@@ -136,6 +140,108 @@ def leg_kernels(shapes, atol=KERNEL_ATOL, dtype=None):
             worst[name] = max(worst.get(name, 0.0), err)
     emit("kernels", shapes=[list(s) for s in shapes], max_abs_err=worst,
          atol=atol, seconds=round(time.perf_counter() - t0, 2))
+
+
+def mosaic_ms(fn, args, calls=4):
+    """{kernel: (ms, events) a call} of the Mosaic events one call of
+    the jitted ``fn(*args)`` leaves on chip 0's ``XLA Ops`` line: the
+    median of ``calls`` calls under the profiler. An event goes by its
+    instruction's name: the kernel's own where the call has one
+    (``flash_backward``), else the jitted function's, so the dQ and
+    dK/dV kernels of one call are ONE key of two events. A kernel alone
+    under the profiler and the same kernel inside a cell's traced step
+    agree to 0.3% (docs/benchmarks.md, lesson 8). Empty where the trace
+    has no TPU plane. Read with the benchmark's own reader of the
+    profiler's file (benchmarks/lib/xplane.py)."""
+    import tempfile
+
+    import jax
+
+    from benchmarks.lib import xplane
+
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as tracedir:
+        with jax.profiler.trace(tracedir):
+            for _ in range(calls):
+                jax.block_until_ready(fn(*args))
+        ops = xplane.load(xplane.find(tracedir)).ops.get(0, [])
+    took = {}
+    for e in ops:
+        if xplane.op_class(e.name) == "mosaic":
+            name = re.match(r"%?([\w\-]+?)(\.\d+)? ", e.name + " ").group(1)
+            took.setdefault(name, []).append(1e3 * (e.end - e.start))
+    out = {}
+    for name, ms in took.items():
+        n = len(ms) // calls    # events a call
+        out[name] = (float(np.median(
+            [sum(ms[c * n:(c + 1) * n]) for c in range(calls)])), n)
+    return out
+
+
+def leg_flash_timing(bh=64, s=4096, d=128, block=512, on_chip=True):
+    """The flash forward and the backward on BOTH sides of its rule
+    (``flash_attention.bwd_one_pass``: one pass over a head's tiles, and
+    the dQ and dK/dV kernels, reached here as the tests reach them, by
+    taking the budget away) at the training cell's shape, causal bfloat16
+    ``[bh, s, d]``: the gradients of the two sides against each other,
+    and each kernel's ms a call under the profiler beside the MXU's own
+    time for it (benchmarks/counts/baichuan.py: 2 and 5 products of
+    ``bh * s^2 * d`` over the causal half, at 197 TFLOP/s)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    rng = np.random.RandomState(bh + s + d)
+    q, k, v, g = (jnp.asarray(rng.randn(1, bh, s, d) * 0.5, jnp.bfloat16)
+                  for _ in range(4))
+    forward = jax.jit(lambda q, k, v: fa._flash_fwd(
+        q, k, v, True, block, block, None, layout="bhsd"))
+    out, lse = forward(q, k, v)
+
+    def backward():
+        # a fresh jit a side: the rule is read when the call is traced
+        return jax.jit(lambda *a: fa._flash_bwd(
+            *a, True, block, block, None, layout="bhsd"))
+
+    _check(fa.bwd_one_pass(s, s, d, q.dtype),
+           f"[{bh}, {s}, {d}] is past the one-pass backward's budget")
+    sides = {"one_pass": backward()}
+    grads = {"one_pass": sides["one_pass"](q, k, v, out, lse, g)}
+    budget, fa._BWD_ONE_PASS_BYTES = fa._BWD_ONE_PASS_BYTES, 0
+    try:
+        sides["two_kernel"] = backward()
+        grads["two_kernel"] = sides["two_kernel"](q, k, v, out, lse, g)
+    finally:
+        fa._BWD_ONE_PASS_BYTES = budget
+    apart = {}
+    for name, a, b in zip(("dq", "dk", "dv"), *grads.values()):
+        _check(bool(jnp.isfinite(a.astype(jnp.float32)).all()),
+               f"{name}: not finite")
+        apart[name] = float(jnp.max(jnp.abs(
+            a.astype(jnp.float32) - b.astype(jnp.float32))))
+        _check(apart[name] <= KERNEL_ATOL,
+               f"{name}: one pass and two kernels {apart[name]:.4g} apart")
+    fields = dict(shape=[bh, s, d], block=block, max_abs_apart=apart)
+    if on_chip:
+        took = {"forward": mosaic_ms(forward, (q, k, v))}
+        took.update((name, mosaic_ms(fn, (q, k, v, out, lse, g)))
+                    for name, fn in sides.items())
+        events = {side: {k: n for k, (_, n) in by.items()}
+                  for side, by in took.items()}
+        _check(events["one_pass"] == {"flash_backward": 1}
+               and "flash_backward" not in events["two_kernel"]
+               and sum(events["two_kernel"].values()) == 2,
+               f"the sides' Mosaic events a call are {events}")
+        # products of bh * s^2 * d a side has to make, over the peak
+        least = {name: 1e3 * n * bh * s * s * d / BF16_FLOPS
+                 for name, n in (("forward", 2), ("one_pass", 5),
+                                 ("two_kernel", 5))}
+        ms = {side: sum(t for t, _ in by.values())
+              for side, by in took.items()}
+        fields.update(kernel_ms=ms, least_ms=least, roofline_share={
+            side: round(least[side] / ms[side], 4) for side in ms})
+    emit("flash_timing", **fields)
 
 
 def leg_window_kernel(cases, atol=KERNEL_ATOL, dtype=None):
@@ -293,6 +399,7 @@ def leg_lm_train(cfg, batch, seq, steps=4, on_chip=True):
     import horovod_tpu as hvd
     from horovod_tpu import trainer
     from horovod_tpu.models import transformer as tr
+    from horovod_tpu.ops import flash_attention as flash_mod
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.utils import costmodel
     from horovod_tpu.utils import history as hvd_history
@@ -311,12 +418,16 @@ def leg_lm_train(cfg, batch, seq, steps=4, on_chip=True):
 
     kernels = mosaic_kernel_counts(step.lower(params, opt_state, toks))
     if on_chip:
-        layers = cfg.num_layers
-        fwd = sum(n for k, n in kernels.items() if k.startswith("_fwd_kernel"))
-        _check(fwd == layers and kernels.get("_dq_kernel") == layers
-               and kernels.get("_dkv_kernel") == layers,
-               f"lowered step lacks the Mosaic calls (forward, dq, dkv per "
-               f"layer): {kernels} — the kernels were interpreted")
+        # a layer is one forward and the backward its shapes choose: one
+        # pass over the head's tiles, or the dQ and dK/dV kernels
+        head_dim = cfg.d_model // cfg.num_heads
+        backward = ["flash_backward"] if flash_mod.bwd_one_pass(
+            seq, seq, -(-head_dim // 128) * 128, cfg.dtype) \
+            else ["_dq_kernel", "_dkv_kernel"]
+        want = {k: cfg.num_layers for k in ["_fwd_kernel"] + backward}
+        _check(kernels == want,
+               f"lowered step's Mosaic calls are {kernels}, not {want} — "
+               f"the kernels were interpreted")
 
     t0 = time.perf_counter()
     params, opt_state, first, _ = _run_steps(step, params, opt_state, toks, 1)
@@ -1066,6 +1177,8 @@ def main(argv=None):
         # leg's prompts produce (16, 112, 528 -> 640, 1008 -> 1024)
         leg_kernels([(16, 1024, heads, head_dim)] +
                     [(1, s, heads, head_dim) for s in (16, 112, 528, 1008)])
+        # the training cell's attention, timed: [64, 4096, 128]
+        leg_flash_timing()
         # the banded forward: a window under, at and over a 512-tile, both
         # group sizes of the laguna_small leg, a length no block divides
         leg_window_kernel([(1, 1024, 8, 2, 128, 128),

@@ -19,14 +19,19 @@ O(s·d):
     t+1 issued before compute on tile t, so VMEM residency is O(block·d)
     regardless of sequence length
 
-Backward is the standard flash-attention recomputation scheme, also as
-Pallas kernels: the forward additionally writes the per-row log-sum-exp
-(lse), so the backward re-materializes each probability tile as
-``exp(s − lse)`` without ever storing the [s, s] matrix — one kernel
-accumulates dQ (gridded over Q blocks, streaming K/V), a second
-accumulates dK/dV (gridded over K blocks, streaming Q/dO/lse/delta, and
-starting at the diagonal for causal). Memory is O(s·d) in backward too,
-which is what makes long-context training with this kernel viable.
+Backward is the standard flash-attention recomputation scheme, also in
+Pallas: the forward additionally writes the per-row log-sum-exp (lse),
+so the backward re-materializes each probability tile as
+``exp(s − lse)`` without ever storing the [s, s] matrix. Where one
+head's operands and a float32 dQ accumulator fit VMEM (``bwd_one_pass``,
+from the shapes alone) ONE kernel takes dQ, dK and dV from one pass over
+the head's tile pairs, each pair's scores computed once
+(``_bwd_kernel``). Past that budget two kernels stand: one accumulates
+dQ (gridded over Q blocks, streaming K/V), a second accumulates dK/dV
+(gridded over K blocks, streaming Q/dO/lse/delta, and starting at the
+diagonal for causal), seven products a pair for the mathematics' five.
+Memory is O(s·d) in backward too, which is what makes long-context
+training with this kernel viable.
 
 On the CPU backend the kernel runs in Pallas interpret mode (the tests'
 virtual mesh), selected automatically; a TPU never gets it.
@@ -39,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..utils import metrics as hvd_metrics
 
 
 _NEG_INF = -1e30
@@ -499,6 +506,125 @@ def _dkv_kernel(k_ref, v_ref, q_hbm, do_hbm, lse_hbm, delta_hbm, dk_ref,
         sem_dl=pltpu.SemaphoreType.DMA((2,)))
 
 
+def _bwd_kernel(q_ref, do_ref, stat_ref, k_ref, v_ref, dq_ref, dk_ref,
+                dv_ref, dq_acc, dk_acc, dv_acc, *, block_q, block_k, causal,
+                scale):
+    """The whole backward of one head in one program: dQ, dK and dV from
+    ONE pass over the head's tile pairs, each pair's scores, p, dP and dS
+    computed once (five products a pair, the mathematics' count; the two
+    kernels below make seven, ``s`` and ``dP`` twice).
+
+    Walks K blocks j and, inside, the q blocks i the mask lets see them
+    (from the diagonal on, causal): dV_j and dK_j accumulate over i as in
+    ``_dkv_kernel``, and ``dS k_j`` adds into rows i of a float32
+    ``[sq, d]`` accumulator that stays in VMEM for the head, j ascending:
+    the order of ``_dq_kernel``'s sum. The logit scale goes onto dQ and
+    dK once, after their sums. Everything the head reads and writes is in
+    VMEM whole, handed over by the pipeline, which fetches the next
+    head's behind this one's tiles: no tile is waited for.
+
+    A tile pair is computed TRANSPOSED, ``s^T = k q^T`` ``[block_k,
+    block_q]``, so that the row statistics (``stat_ref``: lse in row 0,
+    delta in row 1 of the ``[8, sq]`` sublane-replicated layout, rows
+    along the lanes) broadcast over the sublanes as they lie, and dV and
+    dK are plain products of p^T and dS^T; only dQ's left operand is
+    transposed. With ``s = q k^T`` the statistics are re-laid from lanes
+    to sublanes every tile and two products want a transpose.
+
+    Measured on a v5e at the training cell's shape, causal bf16
+    ``[64, 4096, 128]``, 512 x 512 blocks (PR 50; docs/benchmarks.md,
+    "Flash-kernel lessons", has the table and the other blocks): 4.41 ms
+    a step where the two kernels take 8.63, 79% of the compute roofline;
+    the MXU's own time is 3.93 ms (five products of 512 cycles, 36 tile
+    pairs a head) and the compiled body is 2,307 bundles for those 2,560
+    cycles. Beside it there: ``s = q k^T`` 4.95 ms (2,612 bundles); a
+    grid over (head, k block) with the accumulator revisited 4.50 ms, and
+    5.21 with q, dO and the statistics streamed tile by tile.
+    """
+    sq = q_ref.shape[1]
+    sk = k_ref.shape[1]
+    scale2 = scale * _LOG2E
+    contract_last = (((1,), (1,)), ((), ()))
+    contract_first = (((0,), (0,)), ((), ()))
+    dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    def k_block(j, _):
+        k_rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[0, k_rows, :]    # input dtype into the MXU (_fwd_kernel)
+        v = v_ref[0, k_rows, :]
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+        def q_block(i, _):
+            q_rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            q = q_ref[0, q_rows, :]
+            do = do_ref[0, q_rows, :]
+            lse2 = stat_ref[0, pl.ds(0, 1), q_rows] * _LOG2E  # [1, block_q]
+            delta = stat_ref[0, pl.ds(1, 1), q_rows]
+            st = jax.lax.dot_general(
+                k, q, contract_last,
+                preferred_element_type=jnp.float32) * scale2
+            if causal:
+                k_pos = j * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                q_pos = i * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                st = jnp.where(k_pos <= q_pos, st, _NEG_INF)
+            pt = jnp.exp2(st - lse2)                  # [block_k, block_q]
+            dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(v, do, contract_last,
+                                      preferred_element_type=jnp.float32)
+            dst = (pt * (dpt - delta)).astype(q.dtype)
+            dk_acc[...] += jnp.dot(dst, q,
+                                   preferred_element_type=jnp.float32)
+            dq_acc[q_rows, :] += jax.lax.dot_general(
+                dst, k, contract_first, preferred_element_type=jnp.float32)
+            return 0
+
+        # first q block whose last row can see this k block's first row
+        first = (j * block_k) // block_q if causal else 0
+        jax.lax.fori_loop(first, sq // block_q, q_block, 0)
+        dk_ref[0, k_rows, :] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, k_rows, :] = dv_acc[...].astype(dv_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, sk // block_k, k_block, 0)
+    dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+#: Bytes of VMEM the one-pass backward may fill with one head: q, dO, K,
+#: V and the three gradients, each held twice (the pipeline fetches the
+#: next head's and writes the last one's back while this one computes),
+#: the float32 dQ accumulator and the row statistics. The call asks for
+#: ``_BWD_VMEM_LIMIT`` and leaves the rest to a tile pair's
+#: ``[block_k, block_q]`` float32 temporaries.
+_BWD_ONE_PASS_BYTES = 40 << 20
+_BWD_VMEM_LIMIT = 64 << 20
+
+
+def bwd_one_pass(sq, sk, d, dtype):
+    """Whether the backward is the one-pass kernel (``_bwd_kernel``) or
+    the two that stand past its VMEM budget: from the shapes alone, as
+    ``kv_resident`` decides for the forward."""
+    item = jnp.dtype(dtype).itemsize
+    held = 2 * item * d * (3 * sq + 4 * sk)       # operands and gradients
+    held += 4 * sq * d + 2 * 4 * 8 * sq           # accumulator, statistics
+    return held <= _BWD_ONE_PASS_BYTES
+
+
+def _count_backward(kernel):
+    """Trace-time count of which backward a call took (a compiled step
+    cannot count at run time): once a layer a (re)trace."""
+    reg = hvd_metrics.get_registry()
+    if reg.enabled:
+        reg.counter(
+            "hvd_flash_backward_traced_total",
+            "Flash-attention backward calls, counted at trace time, by "
+            "the kernel the call's shapes chose.",
+            labels=("kernel",)).labels(kernel=kernel).inc()
+
+
 def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
                scale=None, block_q_dkv=None, block_k_dkv=None,
                layout="bshd"):
@@ -528,13 +654,54 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
             return t.reshape(b * h, s, d)
         return t.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
+    def unflat(t, s):
+        if layout == "bhsd":
+            return t.reshape(b, h, s, d)
+        return t.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+
     qf, kf, vf = flat(q, sq), flat(k, sk), flat(v, sk)
     dof, of = flat(g, sq), flat(out, sq)
     # delta_i = Σ_d dO_i ⊙ O_i — the dP correction term; elementwise, XLA
     # fuses it, no kernel needed. Same sublane-replicated layout as lse.
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1)
-    delta = jnp.broadcast_to(delta[:, None, :], (b * h, 8, sq))
+                    axis=-1)[:, None, :]
+
+    if bwd_one_pass(sq, sk, d, q.dtype):
+        _count_backward("one_pass")
+        # lse and delta as ONE operand, rows 0 and 1 of the layout they
+        # share: five operands, which benchmarks/readers/flash_roofline.py
+        # takes for neither a forward (three) nor HALF a backward (six)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1)
+        stats = jnp.where(row == 1, delta, lse)
+
+        operands = (qf, dof, stats, kf, vf)
+
+        def head(s):
+            return pl.BlockSpec((1, s, d), lambda i: (i, 0, 0))
+
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_kernel, block_q=block_q, block_k=block_k,
+                              causal=causal, scale=scale),
+            grid=(b * h,),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=_BWD_VMEM_LIMIT),
+            in_specs=[head(sq), head(sq),
+                      pl.BlockSpec((1, 8, sq), lambda i: (i, 0, 0)),
+                      head(sk), head(sk)],
+            out_specs=[head(sq), head(sk), head(sk)],
+            out_shape=[_out_struct((b * h, s, d), t.dtype, *operands)
+                       for s, t in ((sq, q), (sk, k), (sk, v))],
+            scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            name="flash_backward",
+            interpret=interpret,
+        )(*operands)
+        return unflat(dq, sq), unflat(dk, sk), unflat(dv, sk)
+
+    _count_backward("two_kernel")
+    delta = jnp.broadcast_to(delta, (b * h, 8, sq))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
@@ -581,12 +748,6 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
     )(kf, vf, qf, dof, lse, delta)
-
-    def unflat(t, s):
-        if layout == "bhsd":
-            return t.reshape(b, h, s, d)
-        return t.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-
     return unflat(dq, sq), unflat(dk, sk), unflat(dv, sk)
 
 

@@ -240,13 +240,13 @@ def roofline(costs, spec):
     return out
 
 
-# profile_decomposition class → cost-model class (the three flash
+# profile_decomposition class → cost-model class (the four flash
 # kernel classes are one analytic "attention"; copies/fusions/other are
 # modeled as pure HBM traffic under "other")
 _PROFILE_TO_MODEL = {
     "flash_fwd": "attention", "flash_dq": "attention",
-    "flash_dkv": "attention", "matmul": "matmul",
-    "collective": "collective",
+    "flash_dkv": "attention", "flash_bwd": "attention",
+    "matmul": "matmul", "collective": "collective",
 }
 
 

@@ -160,6 +160,7 @@ _OP_CLASSES = (
     ("flash_fwd", ("fwd_kernel",)),
     ("flash_dq", ("dq_kernel",)),
     ("flash_dkv", ("dkv_kernel",)),
+    ("flash_bwd", ("flash_backward",)),   # the one-pass backward, by name
     ("collective", ("all-reduce", "allreduce", "all-gather", "allgather",
                     "reduce-scatter", "all-to-all", "collective",
                     "psum", "ppermute")),

@@ -48,6 +48,39 @@ def test_kernels_leg(hvd, capsys):
     assert _last_json(capsys)["leg"] == "kernels"
 
 
+def test_flash_timing_leg(hvd, capsys):
+    """Both sides of the backward's rule run and agree; the times are the
+    chip's (no TPU plane in a CPU trace)."""
+    chip_smoke.leg_flash_timing(bh=2, s=64, d=16, block=32, on_chip=False)
+    line = _last_json(capsys)
+    assert line["leg"] == "flash_timing" and "kernel_ms" not in line
+    assert max(line["max_abs_apart"].values()) < 3e-2
+
+
+def test_flash_timing_leg_reads_each_sides_events(hvd, capsys, monkeypatch):
+    """The timed branch on events shaped as the chip gives them: the named
+    call is its own key, the unnamed dQ and dK/dV kernels are ONE key of two
+    events under the jitted function's name; a two-kernel side that ran
+    the one-pass kernel is refused."""
+    def events(*sides):
+        it = iter(sides)
+        monkeypatch.setattr(chip_smoke, "mosaic_ms",
+                            lambda fn, args, calls=4: next(it))
+
+    events({"_lambda_": (2.0, 1)}, {"flash_backward": (4.0, 1)},
+           {"_lambda_": (8.0, 2)})
+    chip_smoke.leg_flash_timing(bh=2, s=64, d=16, block=32)
+    line = _last_json(capsys)
+    assert line["kernel_ms"] == {"forward": 2.0, "one_pass": 4.0,
+                                 "two_kernel": 8.0}
+    assert line["roofline_share"]["one_pass"] == pytest.approx(
+        2 * line["roofline_share"]["two_kernel"], rel=1e-3)
+    events({"_lambda_": (2.0, 1)}, {"flash_backward": (4.0, 1)},
+           {"flash_backward": (4.0, 1)})
+    with pytest.raises(AssertionError, match="events a call"):
+        chip_smoke.leg_flash_timing(bh=2, s=64, d=16, block=32)
+
+
 def test_window_kernel_leg(capsys):
     import jax.numpy as jnp
     chip_smoke.leg_window_kernel([(1, 40, 4, 2, 16, 8), (2, 32, 3, 1, 16, 16)],
@@ -244,7 +277,8 @@ def test_attention_runs_on_its_per_device_shape(tiny, layout, per_device_bh):
     shapes = _pallas_operand_shapes(
         jax.make_jaxpr(step)(params, opt_state, toks).jaxpr, [])
     head_dim = tiny.d_model // tiny.num_heads
-    assert len(shapes) == 3 * tiny.num_layers  # forward, dq, dkv
+    # a layer's forward and its one-pass backward, each on the slice
+    assert len(shapes) == 2 * tiny.num_layers
     assert set(shapes) == {(per_device_bh, 64, head_dim)}
 
 
@@ -319,6 +353,39 @@ def test_mosaic_accepts_the_forward_on_both_kv_paths(topo, shape, resident):
         fa.flash_attention, causal=True, interpret=False)).trace(
             arg, arg, arg).lower(lowering_platforms=("tpu",)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape, one_pass", [
+    ((1, 4096, 4, 128), True),    # the training cell's rows
+    ((1, 8192, 2, 128), True),    # the longest bfloat16 rows inside it
+    ((1, 16384, 2, 128), False),  # past the budget: the two kernels
+    ((1, 1024, 2, 64), True),     # heads of 64, padded to the lane tile
+])
+def test_mosaic_accepts_the_backward_on_both_sides_of_its_rule(
+        topo, shape, one_pass):
+    """The backward at the widths the cells run, compiled for a v5e: one
+    head's operands, gradients and float32 dQ accumulator in VMEM under
+    the limit the call asks for, ONE custom call beside the forward's;
+    past the budget the dQ and dK/dV kernels."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from horovod_tpu.ops import flash_attention as fa
+
+    _, s, _, d = shape
+    assert fa.bwd_one_pass(s, s, -(-d // 128) * 128, jnp.bfloat16) is one_pass
+    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                               sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).trace(arg, arg, arg).lower(
+        lowering_platforms=("tpu",)).compile()
+    calls = compiled.as_text().count("custom_call_target=\"tpu_custom_call\"")
+    assert calls == (2 if one_pass else 3)
 
 
 @pytest.fixture

@@ -173,13 +173,14 @@ class TestMeasuredClassMs:
             {"class": "flash_fwd", "ms_per_step": 1.0},
             {"class": "flash_dq", "ms_per_step": 2.0},
             {"class": "flash_dkv", "ms_per_step": 3.0},
+            {"class": "flash_bwd", "ms_per_step": 1.5},
             {"class": "matmul", "ms_per_step": 10.0},
             {"class": "collective", "ms_per_step": 4.0},
             {"class": "copy", "ms_per_step": 0.5},
             {"class": "fusion", "ms_per_step": 0.5},
         ]}
         ms = costmodel.measured_class_ms(dec)
-        assert ms == {"attention": 6.0, "matmul": 10.0,
+        assert ms == {"attention": 7.5, "matmul": 10.0,
                       "collective": 4.0, "other": 1.0}
 
     def test_empty(self):

@@ -106,6 +106,8 @@ class TestClassifyOp:
         ("custom-call.3", "%custom-call.3 = ... fwd_kernel", "flash_fwd"),
         ("custom-call.4", "%custom-call.4 = ... dq_kernel", "flash_dq"),
         ("custom-call.5", "%custom-call.5 = ... dkv_kernel", "flash_dkv"),
+        ("flash_backward.2", "%flash_backward.2 = ... custom-call(",
+         "flash_bwd"),
         ("copy.9", "", "copy"),
         ("transpose.1", "", "copy"),
         ("dynamic-update-slice.6", "", "copy"),
