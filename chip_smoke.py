@@ -8,7 +8,9 @@ calls, at the full width of the flagship LM and of ResNet-50:
               Mosaic, against parallel.ring.full_attention at the training
               shape and at the serving prefill lengths; the forward and
               the backward on both sides of its rule timed under the
-              profiler at the training cell's shape
+              profiler at the training cell's shape; the decode program's
+              sampler alone at the two largest (rows, vocabulary) served,
+              a greedy batch beside one with a sampling row
   lm_train    hvd.init -> build_mesh -> trainer.make_gspmd_step on
               gpt2_small_tpu (12 layers, batch 16 x seq 1024, flash):
               loss finite, falling, first step equal to full attention;
@@ -142,17 +144,10 @@ def leg_kernels(shapes, atol=KERNEL_ATOL, dtype=None):
          atol=atol, seconds=round(time.perf_counter() - t0, 2))
 
 
-def mosaic_ms(fn, args, calls=4):
-    """{kernel: (ms, events) a call} of the Mosaic events one call of
-    the jitted ``fn(*args)`` leaves on chip 0's ``XLA Ops`` line: the
-    median of ``calls`` calls under the profiler. An event goes by its
-    instruction's name: the kernel's own where the call has one
-    (``flash_backward``), else the jitted function's, so the dQ and
-    dK/dV kernels of one call are ONE key of two events. A kernel alone
-    under the profiler and the same kernel inside a cell's traced step
-    agree to 0.3% (docs/benchmarks.md, lesson 8). Empty where the trace
-    has no TPU plane. Read with the benchmark's own reader of the
-    profiler's file (benchmarks/lib/xplane.py)."""
+def profiled(fn, args, calls=4):
+    """The profiler's trace (benchmarks/lib/xplane.py ``Trace``) of
+    ``calls`` calls of the jitted ``fn(*args)``, after one that is not
+    traced; no TPU plane, no events."""
     import tempfile
 
     import jax
@@ -164,9 +159,24 @@ def mosaic_ms(fn, args, calls=4):
         with jax.profiler.trace(tracedir):
             for _ in range(calls):
                 jax.block_until_ready(fn(*args))
-        ops = xplane.load(xplane.find(tracedir)).ops.get(0, [])
+        return xplane.load(xplane.find(tracedir))
+
+
+def mosaic_ms(fn, args, calls=4):
+    """{kernel: (ms, events) a call} of the Mosaic events one call of
+    the jitted ``fn(*args)`` leaves on chip 0's ``XLA Ops`` line: the
+    median of ``calls`` calls under the profiler. An event goes by its
+    instruction's name: the kernel's own where the call has one
+    (``flash_backward``), else the jitted function's, so the dQ and
+    dK/dV kernels of one call are ONE key of two events. A kernel alone
+    under the profiler and the same kernel inside a cell's traced step
+    agree to 0.3% (docs/benchmarks.md, lesson 8). Empty where the trace
+    has no TPU plane. Read with the benchmark's own reader of the
+    profiler's file (benchmarks/lib/xplane.py)."""
+    from benchmarks.lib import xplane
+
     took = {}
-    for e in ops:
+    for e in profiled(fn, args, calls).ops.get(0, []):
         if xplane.op_class(e.name) == "mosaic":
             name = re.match(r"%?([\w\-]+?)(\.\d+)? ", e.name + " ").group(1)
             took.setdefault(name, []).append(1e3 * (e.end - e.start))
@@ -242,6 +252,79 @@ def leg_flash_timing(bh=64, s=4096, d=128, block=512, on_chip=True):
         fields.update(kernel_ms=ms, least_ms=least, roofline_share={
             side: round(least[side] / ms[side], 4) for side in ms})
     emit("flash_timing", **fields)
+
+
+def sampler_ms(fn, args, rows, vocab, calls=4):
+    """What one call of the jitted sampler ``fn(*args)`` takes on chip 0,
+    ms: ``call`` (the median of its ``XLA Modules`` events: everything the
+    chip does for it), and of its ``XLA Ops`` events those over the
+    ``f32[rows, vocab]`` logits, a call: ``draw`` (the one that also reads
+    the threefry counters, ``u32[rows]``: bits, Gumbel noise and an
+    argmax) and ``argmax`` (the logits alone). Names and operands as the
+    decode program's own trace has them
+    (benchmarks/fixtures/phi4flash_events_v5e.json)."""
+    from benchmarks.lib import xplane
+
+    trace = profiled(fn, args, calls)
+    took = {"draw": 0.0, "argmax": 0.0}
+    for e in trace.ops.get(0, []):
+        shapes = xplane.shapes(e.name)
+        # a reduction over the logits: it reads them and makes [rows]
+        if xplane.opcode(e.name) == "fusion" and \
+                ("f32", (rows, vocab)) in shapes[1:] and \
+                shapes[0][1] == (rows,):
+            took["draw" if ("u32", (rows,)) in shapes else "argmax"] += \
+                1e3 * (e.end - e.start) / calls
+    took["call"] = float(np.median(
+        [1e3 * (m.end - m.start) for m in trace.modules.get(0, [])]))
+    return took
+
+
+def leg_sampler_timing(shapes=((96, 200064), (64, 154880)), temperature=0.7,
+                       on_chip=True):
+    """``serving.sampling.sample_tokens`` alone at the serving cells'
+    (rows, vocabulary): every row greedy beside one row at ``temperature``.
+    A greedy batch is served its argmax and runs NO event of the draw;
+    with one sampling row the draw runs whole and the greedy rows are
+    still served their argmax. ms a call of each under the profiler.
+    (``call`` holds a copy the decode program does not make: this lone
+    program is handed its logits in HBM and moves them into VMEM for the
+    conditional's operand, 97 us at 96 x 200,064, whichever branch runs;
+    the decode program's head writes them there. PERF.md §6, PR 51.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.serving.sampling import sample_tokens
+
+    sampler = jax.jit(sample_tokens)
+    cases = []
+    for rows, vocab in shapes:
+        logits = jax.random.normal(jax.random.PRNGKey(vocab), (rows, vocab),
+                                   jnp.float32)
+        rng = jax.random.PRNGKey(rows)
+        batches = {"greedy": jnp.zeros(rows, jnp.float32),
+                   "one_row": jnp.zeros(rows, jnp.float32).at[0].set(
+                       temperature)}
+        top = np.asarray(jnp.argmax(logits, axis=-1))
+        case = {"shape": [rows, vocab]}
+        for name, temps in batches.items():
+            ids = np.asarray(sampler(rng, logits, temps))
+            keep = np.asarray(temps) <= 0.0
+            _check((ids[keep] == top[keep]).all(),
+                   f"{name} at {rows} x {vocab}: a greedy row was not "
+                   f"served its argmax")
+            if on_chip:
+                case[name] = {k: round(v, 4) for k, v in sampler_ms(
+                    sampler, (rng, logits, temps), rows, vocab).items()}
+        if on_chip:
+            _check(case["greedy"]["draw"] == 0.0 < case["one_row"]["draw"]
+                   and case["greedy"]["argmax"] > 0.0,
+                   f"the sampler's events at {rows} x {vocab}: {case}")
+            _check(case["greedy"]["call"] < case["one_row"]["call"],
+                   f"a greedy batch took no less than one that draws: "
+                   f"{case}")
+        cases.append(case)
+    emit("sampler_timing", temperature=temperature, cases=cases)
 
 
 def leg_window_kernel(cases, atol=KERNEL_ATOL, dtype=None):
@@ -1179,6 +1262,9 @@ def main(argv=None):
                     [(1, s, heads, head_dim) for s in (16, 112, 528, 1008)])
         # the training cell's attention, timed: [64, 4096, 128]
         leg_flash_timing()
+        # the sampler alone at the two largest (rows, vocabulary) served:
+        # a greedy batch draws nothing
+        leg_sampler_timing()
         # the banded forward: a window under, at and over a 512-tile, both
         # group sizes of the laguna_small leg, a length no block divides
         leg_window_kernel([(1, 1024, 8, 2, 128, 128),

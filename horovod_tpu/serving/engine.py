@@ -333,6 +333,12 @@ class ServeEngine:
             "their step queued behind their prefill (the step's decode "
             "pass, or a later admission's prefill): the chip ran on "
             "while the host read and booked it.")
+        self._m_drew = reg.counter(
+            "hvd_serve_decode_draw_steps_total",
+            "Engine steps whose decode pass held a row with a "
+            "temperature above 0, so the sampler ran its categorical "
+            "draw over rows x vocabulary; a pass of greedy rows alone "
+            "draws nothing (serving/sampling.py).")
         state_bytes = reg.gauge(
             "hvd_serve_state_bytes",
             "Bytes of per-slot serving state resident on one chip, by "
@@ -922,6 +928,10 @@ class ServeEngine:
                     self._note_in_place("decode", went_in)
         rec.count("active", len(launched))
         rec.count("cohorts", len(gens))
+        # the program's sampler draws only where a row of its pass asks
+        if any(st.request.temperature > 0.0 for _, st in launched):
+            rec.count("drew")
+            self._m_drew.inc()
         if launched:  # stack passes the launched program runs each row
             rec.count("passes", passes(self.cfg))
         # everything is launched: now what came before it, in the chip's

@@ -372,6 +372,11 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # launched behind the last one (a request that asks for one token into an
 # idle batch, a row the ledger refused before the launch, a dispatch that
 # compiles: what is unread is read before it)
+# drew: 1 when a row of the step's decode pass(es) had a temperature above
+# 0, so the program's sampler ran its categorical draw (threefry bits and
+# Gumbel noise over rows x vocabulary, serving/sampling.py); 0 when every
+# row was greedy and the pass drew nothing. The host's own knowledge of
+# the requests' temperatures (``_decode``), no device read
 # kv_bytes (not in STEP_COUNTS: only a step that decoded has it): bytes of
 # K and V the step's decode passes had to stream, each decoding row's in
 # whole blocks of ops/flash_attention.decode_block positions up to its
@@ -397,7 +402,8 @@ STEP_PHASES = ("control", "admit", "prefill", "prefill_readback",
 # pass in flight they land in the record of the step that reads it, as its
 # tokens do (``ahead``)
 STEP_COUNTS = ("admitted", "active", "retired", "cohorts", "prompt_tokens",
-               "state_rows", "state_bytes", "ahead", "admitted_ahead")
+               "state_rows", "state_bytes", "ahead", "admitted_ahead",
+               "drew")
 # Beside the phases a record holds two lists, in the order things happened:
 # launches: [n, program, call_start_us, call_end_us], one entry a dispatch
 # of a device program from the step (``StepTrace.launch``). ``n`` numbers

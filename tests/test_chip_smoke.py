@@ -81,6 +81,75 @@ def test_flash_timing_leg_reads_each_sides_events(hvd, capsys, monkeypatch):
         chip_smoke.leg_flash_timing(bh=2, s=64, d=16, block=32)
 
 
+def test_sampler_timing_leg(capsys):
+    """A greedy batch and one with a sampling row are both served their
+    greedy rows' argmax; the times are the chip's."""
+    chip_smoke.leg_sampler_timing(shapes=((4, 300), (3, 129)),
+                                  on_chip=False)
+    line = _last_json(capsys)
+    assert line["leg"] == "sampler_timing"
+    assert line["cases"] == [{"shape": [4, 300]}, {"shape": [3, 129]}]
+
+
+@pytest.mark.parametrize("greedy_draws", [False, True])
+def test_sampler_timing_leg_reads_the_draws_events(capsys, monkeypatch,
+                                                   greedy_draws):
+    """The timed branch on events shaped as the chip gives them (the lone
+    sampler on a v5e, PR 51): the argmax is the fusion over the logits
+    alone; the logits are copied into VMEM for the conditional's operand;
+    ``%conditional`` is an event that SPANS its branch's events, and the
+    draw is the fusion inside it that reads the logits AND the threefry
+    counters. A greedy batch that runs the draw is refused."""
+    from benchmarks.lib import xplane
+    rows, vocab = 4, 300
+    hbm = f"f32[{rows},{vocab}]{{1,0:T(8,128)}}"
+    vmem = f"f32[{rows},{vocab}]{{1,0:T(8,128)S(1)}}"
+    counters = f"u32[{rows}]{{0:T(128)S(1)}}"
+    result = f"(bf16[{rows}]{{0:T(256)(128)(2,1)}}, s32[{rows}]{{0:T(128)}})"
+    argmax = (f"%iota_reduce_fusion = {result} fusion({hbm} %logits.1), "
+              "kind=kLoop, calls=%fused_computation.35")
+    copy = (f"%copy-done = {vmem} copy-done(({vmem}, {hbm}, u32[]{{:S(2)}}) "
+            "%copy-start)")
+    cond = (f"%conditional = (s32[{rows}]{{0:T(128)}}) conditional("
+            f"s32[]{{:T(128)}} %convert_element_type.1, (s32[{rows}]"
+            f"{{0:T(128)}}) %tuple.17, (f32[{rows}]{{0:T(128)S(1)}}, {vmem}, "
+            "u32[2]{0:T(128)S(1)}) %tuple.25), "
+            "branch_computations={%region_2.4, %region_3.8}")
+    bits = f"%fusion.27 = {counters} fusion(), kind=kLoop, calls=%fc.31"
+    draw = (f"%fusion.5 = {result} fusion({vmem} %get-tuple-element.8, "
+            f"{counters} %broadcast_add_fusion, {counters} %fusion.27, "
+            f"f32[{rows}]{{0:T(128)S(1)}} %broadcast_maximum_fusion, "
+            "u32[]{:T(128)S(6)} %xor.139), kind=kLoop, calls=%fc.19")
+
+    def trace(drew, calls=4):
+        """[(text, start us, us)] a call: 0.6 us of conditional where the
+        branch returns the argmax, the draw's 460 inside it where not."""
+        call = [(argmax, 1.0, 105.0), (copy, 106.0, 97.0)]
+        call += [(cond, 203.0, 460.6), (bits, 203.1, 0.1),
+                 (draw, 204.0, 459.5)] if drew else [(cond, 203.0, 0.6)]
+        took = 1e-6 * (call[2][1] + call[2][2] + 1.0)
+        ops, modules = [], []
+        for c in range(calls):
+            t0 = c * 1e-3
+            ops += [xplane.Event(name, t0 + 1e-6 * at, t0 + 1e-6 * (at + us))
+                    for name, at, us in call]
+            modules.append(xplane.Event("jit_sample_tokens(7)", t0,
+                                        t0 + took))
+        return xplane.Trace({0: ops}, {0: modules}, [])
+    traces = iter([trace(greedy_draws), trace(True)])
+    monkeypatch.setattr(chip_smoke, "profiled",
+                        lambda fn, args, calls=4: next(traces))
+    if greedy_draws:
+        with pytest.raises(AssertionError, match="sampler's events"):
+            chip_smoke.leg_sampler_timing(shapes=((rows, vocab),))
+        return
+    chip_smoke.leg_sampler_timing(shapes=((rows, vocab),))
+    (case,) = _last_json(capsys)["cases"]
+    assert case["greedy"] == {"draw": 0.0, "argmax": 0.105, "call": 0.2046}
+    assert case["one_row"] == {"draw": 0.4595, "argmax": 0.105,
+                               "call": 0.6646}
+
+
 def test_window_kernel_leg(capsys):
     import jax.numpy as jnp
     chip_smoke.leg_window_kernel([(1, 40, 4, 2, 16, 8), (2, 32, 3, 1, 16, 16)],

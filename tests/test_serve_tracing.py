@@ -432,6 +432,8 @@ class TestStepTrace:
         assert rec["prompt_tokens"] == 3 + 5
         assert rec["retired"] == len(retires) == 0
         assert rec["ahead"] == 1 and rec["admitted_ahead"] == 2
+        # both rows greedy: the pass's sampler drew nothing
+        assert "drew" in serve_tracing.STEP_COUNTS and rec["drew"] == 0
         # the next step launches its pass, then reads the one before
         # (the first readback) and, b being on its last token, its own
         (b,) = engine.step()

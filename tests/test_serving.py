@@ -717,6 +717,46 @@ REQUESTS = [("a", (5, 9, 17), 9), ("b", (4, 8, 15, 16, 23, 42), 13),
             ("c", (7, 7, 1), 6)]
 
 
+class TestAGreedyBatchDrawsNothing:
+    """The step record's ``drew`` and ``hvd_serve_decode_draw_steps_total``
+    say on which steps the decode program's sampler ran its categorical
+    draw: those whose pass held a row with a temperature above 0
+    (serving/sampling.py), by the host's own knowledge of its requests."""
+
+    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    def test_a_greedy_run_never_draws(self, reg, model):
+        cfg, params = MODELS[model]()
+        _, recs = _drive(_engine(cfg, params), REQUESTS)
+        assert sum(r["active"] for r in recs) > 20
+        assert [r["drew"] for r in recs] == [0] * len(recs)
+        assert not _value(reg.snapshot(),
+                          "hvd_serve_decode_draw_steps_total")
+
+    @pytest.mark.parametrize("model", ["dense", "hybrid"])
+    def test_a_step_draws_while_a_sampling_row_decodes(self, reg, model):
+        """One sampling request of four tokens beside a greedy one of
+        nine: the three passes it takes part in draw, the passes after it
+        has left do not, and the greedy request is served what it is
+        served alone."""
+        cfg, params = MODELS[model]()
+        greedy = ("a", (5, 9, 17), 9)
+        alone, _ = _drive(_engine(cfg, params, seed=3), [greedy])
+        engine = _engine(cfg, params, seed=3)
+        first = len(hvd_tracing_steps())
+        engine.submit(Request(*greedy[:2], max_new_tokens=greedy[2]))
+        engine.submit(Request("s", (4, 8, 15), max_new_tokens=4,
+                              temperature=0.9))
+        done = {r.request_id: r for r in engine.run_to_completion()}
+        recs = [r for r in hvd_tracing_steps()[first:] if r["active"]]
+        assert [r["drew"] for r in recs] == [1] * 3 + [0] * (len(recs) - 3)
+        assert [r["active"] for r in recs][:4] == [2, 2, 2, 1]
+        assert len(recs) > 3
+        assert _value(reg.snapshot(),
+                      "hvd_serve_decode_draw_steps_total") == 3
+        assert len(done["s"].tokens) == 4
+        assert list(done["a"].tokens) == list(alone["a"].tokens)
+
+
 class TestTheStepDoesNotWaitForTheHost:
     @pytest.fixture(autouse=True)
     def tracer(self):
